@@ -278,7 +278,7 @@ class SelfAttentionLayer(Layer):
         qkv = (xc @ wqkv).reshape(b, t, 3, h, f // h)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         seq_ctx = active_sequence_sharding()
-        if seq_ctx is not None:
+        if seq_ctx is not None and seq_ctx[1] is not None:
             # sequence-parallel route: the time axis is sharded over the
             # mesh — the one op that mixes timesteps runs as ring attention
             # (K/V shards rotate over ppermute; see parallel/sequence.py).

@@ -33,6 +33,10 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
     from .flash_attention import flash_attention, flash_available
     if q.ndim == 4 and q.shape == k.shape == v.shape \
             and flash_available(q.shape, mask):
+        ctx = active_sequence_sharding()
+        if ctx is not None and ctx[1] is None and ctx[2] is not None:
+            return _flash_over_batch(q, k, v, causal, scale, mask,
+                                     mesh=ctx[0], batch_axis=ctx[2])
         return flash_attention(q, k, v, causal, scale, mask=mask)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
@@ -51,6 +55,25 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
     p = jnp.where(jnp.isneginf(logits), 0.0, jnp.exp(logits - m_safe))
     weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+def _flash_over_batch(q, k, v, causal, scale, mask, *, mesh, batch_axis):
+    """The flash kernel under a batch-sharded GSPMD step (data parallel):
+    the TPU compiler will not partition a Mosaic kernel by itself, so the
+    call is wrapped in a ``shard_map`` over the batch axis and every
+    device runs the kernel on its own examples."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from .flash_attention import flash_attention
+    spec = P(batch_axis, None, None, None)
+    if mask is None:
+        mask = jnp.ones(q.shape[:2], jnp.float32)
+    # check_vma=False: see make_ring_attention
+    return shard_map(
+        lambda q, k, v, m: flash_attention(q, k, v, causal, scale, mask=m),
+        mesh=mesh, in_specs=(spec, spec, spec, P(batch_axis, None)),
+        out_specs=spec, check_vma=False)(
+            q, k, v, jnp.asarray(mask, jnp.float32))
 
 
 def _block_attend(q, k, v, m_prev, num_prev, den_prev, *, scale,
@@ -88,21 +111,25 @@ def _block_attend(q, k, v, m_prev, num_prev, den_prev, *, scale,
 def ring_flash_available(t_local: int) -> bool:
     """Should ring attention run its hops through the Pallas flash kernel?
 
-    Same trace-time contract as ``flash_attention.flash_available``:
+    Same trace-time contract as ``flash_attention.flash_available``: only
+    where a kernel can run (``util.xla.kernel_mode`` — the TPU backend,
+    or interpret mode when a caller asked for it, which is how CPU test
+    meshes exercise the real carry/VJP protocol); then
     ``DL4JTPU_FLASH_ATTENTION=1`` forces the kernel-in-ring path at any
-    length (interpret-mode off-TPU, so CPU test meshes exercise the real
-    carry/VJP protocol), ``0`` forces the JAX-level online-softmax block
-    (the parity oracle), unset = auto — on for per-device shards of
-    t_local ≥ 1024 on the TPU backend. Non-divisible t_local is handled
-    by the flash path itself (end-of-shard padding under a key mask), so
-    divisibility never forces the oracle."""
+    length, ``0`` forces the JAX-level online-softmax block (the parity
+    oracle), unset = auto — on for per-device shards of t_local ≥ 1024 on
+    the TPU backend. Non-divisible t_local is handled by the flash path
+    itself (end-of-shard padding under a key mask), so divisibility never
+    forces the oracle."""
     import os
+    from ..util.xla import kernel_mode
     flag = os.environ.get("DL4JTPU_FLASH_ATTENTION", "auto")
-    if flag == "0":
+    mode = kernel_mode()
+    if flag == "0" or mode is None:
         return False
     if flag == "1":
         return True
-    return t_local >= 1024 and jax.devices()[0].platform == "tpu"
+    return t_local >= 1024 and mode == "mosaic"
 
 
 def ring_attention(q, k, v, *, axis_name: str, causal: bool = False,
@@ -325,12 +352,13 @@ def _ring_flash_attention(q, k, v, mask, *, axis_name: str, causal: bool,
     not divide the flash tile is padded at the END of every shard (keys
     masked out, query rows sliced off after), which preserves global
     causal order because the hop trichotomy (pre/diagonal/post) only
-    compares shard indices. ``interpret`` is resolved at trace time so CPU
-    meshes run the kernels in interpret mode."""
+    compares shard indices. ``interpret`` is resolved at trace time from
+    ``util.xla.kernel_mode`` (compiled unless a caller asked otherwise)."""
+    from ..util.xla import kernel_mode
     t_local = q.shape[1]
     d = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / float(d) ** 0.5
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = kernel_mode() == "interpret"
     bq = block_q or (128 if t_local >= 128 else -(-t_local // 8) * 8)
     pad = (-t_local) % bq
     if mask is None:
@@ -361,18 +389,15 @@ def make_ring_attention(mesh, axis_name: str = "seq", *,
     ``with_mask=True`` returns ``fn(q, k, v, mask)`` where mask is the
     GLOBAL [b, t] key-validity array (sharded over ``axis_name`` like the
     time axis); mask shards rotate around the ring with their K/V."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(batch_axis, axis_name, None, None)
     mspec = P(batch_axis, axis_name)
-    # check_rep=False: the flash route's pallas_call has no shard_map
+    # check_vma=False: the flash route's pallas_call has no shard_map
     # replication rule (the ring touches no replicated operands anyway —
     # everything it moves is axis-sharded)
-    smap = functools.partial(shard_map, mesh=mesh, check_rep=False)
+    smap = functools.partial(shard_map, mesh=mesh, check_vma=False)
 
     if with_mask:
         @functools.partial(smap, in_specs=(spec, spec, spec, mspec),
@@ -397,8 +422,13 @@ _SEQ_SHARDING: Optional[tuple] = None
 
 
 class sequence_sharding:
-    """Trace-time context that routes ``SelfAttentionLayer`` (and any other
-    time-mixing op that opts in) to ring attention over ``seq_axis``.
+    """Trace-time context that tells the attention ops how the step being
+    traced is sharded over ``mesh``. With a ``seq_axis`` it routes
+    ``SelfAttentionLayer`` (and any other time-mixing op that opts in) to
+    ring attention over that axis. With ``seq_axis=None`` and a
+    ``batch_axis`` (a data-parallel step) attention stays local to each
+    example, and only the Pallas kernel route changes: it runs inside a
+    ``shard_map`` over the batch axis (``_flash_over_batch``).
 
     Usage — activate around the *trace* of a step function::
 
@@ -412,7 +442,7 @@ class sequence_sharding:
     program.
     """
 
-    def __init__(self, mesh, seq_axis: str = "seq",
+    def __init__(self, mesh, seq_axis: Optional[str] = "seq",
                  batch_axis: Optional[str] = None):
         self.value = (mesh, seq_axis, batch_axis)
 

@@ -21,14 +21,18 @@ runs at sequence lengths where XLA's attention cannot even compile.
 Gradients match the dense path (CPU interpret + on-chip parity tests).
 A JAX-blockwise fallback backward remains behind ``DL4JTPU_FLASH_BWD=jax``.
 
-Measured numbers live in PERF.md ("Pallas flash attention" + "Pallas
-backward kernels" sections — the single source of truth): fwd+grad
-2.2-2.3× over the XLA fused path at t≥4096 (forward alone 1.8-2.8×), and
-t=16384 runs fwd+bwd where XLA OOMs.
+What is measured lives in PERF.md, with the installation each figure
+was taken on: the kernels compile under the installed TPU compiler and
+agree with the XLA path on the v5e (``chip_smoke.py``, PR 21); their
+speed-up over XLA (about 2–3× fwd+grad at t ≥ 4096, t=16384 running
+where XLA ran out of memory) dates from before PR 1 on an installation
+that no longer exists and has not been re-measured.
 
 Routing (``ops.attention.dot_product_attention``): auto at t ≥ 4096 on
 the TPU backend; ``DL4JTPU_FLASH_ATTENTION=1`` forces it on (any length),
-``0`` forces the XLA path.
+``0`` forces the XLA path. Where no kernel can run (not a TPU, and no
+caller asked for interpret mode: ``util.xla.kernel_mode``) every route
+is the XLA path.
 """
 
 from __future__ import annotations
@@ -709,11 +713,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     granularity, upgraded to wider tiles when t and the VMEM budget allow;
     an explicit ``block_q`` is used as-is). ``mask``: optional [b, t_kv]
     key-validity mask (1=attend); rows with no attendable keys output 0.
-    ``interpret``: None = auto at trace time — interpret-mode off-TPU, so
-    ``DL4JTPU_FLASH_ATTENTION=1`` exercises the kernel math on the CPU
-    test backend too."""
+    ``interpret``: None = what ``util.xla.kernel_mode`` says at trace
+    time — compiled for the TPU unless a caller asked for interpret mode
+    (``util.xla.interpret_kernels``). Off the TPU with no such request
+    the call fails in the Pallas lowering; it never quietly interprets."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        from ..util.xla import kernel_mode
+        interpret = kernel_mode() == "interpret"
     if mask is None:
         mask = jnp.ones((q.shape[0], q.shape[1]), jnp.float32)
     return _flash_core(q, k, v, jnp.asarray(mask, jnp.float32), causal,
@@ -723,6 +729,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 def flash_available(q_shape, mask, block_q: int = 128) -> bool:
     """Should the Pallas path serve this call?
 
+    Only where a kernel can run at all (``util.xla.kernel_mode``: on the
+    TPU backend, or in interpret mode when a caller asked for it — any
+    other backend takes the XLA path whatever the flag says). Then
     ``DL4JTPU_FLASH_ATTENTION``: ``1`` forces it on, ``0`` off; unset =
     auto — on for t ≥ 4096 on the TPU backend (where it measures ≥2× over
     the XLA path on v5e; below that XLA's fusion already sits at the
@@ -733,12 +742,14 @@ def flash_available(q_shape, mask, block_q: int = 128) -> bool:
     function (or clear jit caches via ``fn.clear_cache()`` /
     ``jax.clear_caches()``) for a toggle to take effect."""
     import os
+    from ..util.xla import kernel_mode
     flag = os.environ.get("DL4JTPU_FLASH_ATTENTION", "auto")
-    if flag == "0" or q_shape[1] % block_q:
+    mode = kernel_mode()
+    if flag == "0" or mode is None or q_shape[1] % block_q:
         return False
     if mask is not None and getattr(mask, "shape", None) is not None \
             and tuple(mask.shape) != (q_shape[0], q_shape[1]):
         return False   # only [b, t_kv] key masks map onto the kernel
     if flag == "1":
         return True
-    return q_shape[1] >= 4096 and jax.devices()[0].platform == "tpu"
+    return q_shape[1] >= 4096 and mode == "mosaic"

@@ -28,15 +28,12 @@ the same commit gate over its store-backed gather.
 
 from __future__ import annotations
 
-import logging
 import os
 from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-logger = logging.getLogger("deeplearning4j_tpu")
 
 _initialized = False
 
@@ -71,33 +68,8 @@ def initialize(coordinator_address: Optional[str] = None,
         kwargs["process_id"] = process_id
     if local_device_ids is not None:
         kwargs["local_device_ids"] = list(local_device_ids)
-    _enable_cpu_collectives()
     jax.distributed.initialize(**kwargs)
     _initialized = True
-
-
-def _enable_cpu_collectives() -> None:
-    """A multi-process CPU runtime needs a cross-process collectives
-    implementation — the default ("none") raises INVALID_ARGUMENT
-    ("Multiprocess computations aren't implemented on the CPU backend")
-    at the FIRST collective, which presents as a mysteriously failing
-    worker. Select gloo before the backend initializes; harmless for
-    TPU/GPU runtimes (the knob only affects CPU client creation)."""
-    try:
-        from jax._src import xla_bridge as _xb
-        cur = _xb.CPU_COLLECTIVES_IMPLEMENTATION.value
-    except Exception:
-        return                      # older/newer jax: nothing to do
-    if cur != "none":
-        return
-    try:
-        from jax._src.lib import xla_client
-        if not hasattr(xla_client._xla, "make_gloo_tcp_collectives"):
-            return                  # jaxlib built without gloo
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:               # never block a TPU pod bootstrap
-        logger.warning("could not enable gloo CPU collectives",
-                       exc_info=True)
 
 
 def is_initialized() -> bool:

@@ -23,11 +23,7 @@ from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import rng as _rng
@@ -77,14 +73,9 @@ def make_pipeline_forward(mesh: Mesh, axis: str, block_fn: BlockFn,
 
         # carries become device-varying inside the loop (ppermute / masked
         # writes), so their initial values must carry the same
-        # mesh-variance type; older jax has no varying-type tracking (and
-        # no lax.pcast), so the zeros pass through untyped there
-        if hasattr(lax, "pcast"):
-            inflight0 = lax.pcast(jnp.zeros_like(xm[0]), axis, to="varying")
-            outs0 = lax.pcast(jnp.zeros_like(xm), axis, to="varying")
-        else:
-            inflight0 = jnp.zeros_like(xm[0])
-            outs0 = jnp.zeros_like(xm)
+        # mesh-variance type
+        inflight0 = lax.pcast(jnp.zeros_like(xm[0]), axis, to="varying")
+        outs0 = lax.pcast(jnp.zeros_like(xm), axis, to="varying")
         (_, outs), _ = lax.scan(tick, (inflight0, outs0),
                                 jnp.arange(M + S - 1))
         # replicate the last stage's outputs to every device

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import rng as _rng
+from ..ops.attention import sequence_sharding
 from ..optimize import updaters as _updaters
 from .mesh import data_parallel_mesh
 from .stats import maybe_time_phase
@@ -117,6 +118,12 @@ class ParallelWrapper:
             # override: net.fit then runs SPMD transparently (the
             # override slot bypasses the trace-env cache keying)
             net._jit_cache["train_step_override"] = self._make_sync_step()
+            # lay the net's trees over the mesh now, as the step's
+            # out_shardings will leave them: otherwise the second step
+            # sees differently-placed inputs and compiles a second time
+            net.params, net.updater_state, net.state = jax.device_put(
+                (net.params, net.updater_state, net.state),
+                NamedSharding(self.mesh, P()))
         elif self.averaging_frequency < 1:
             raise ValueError("averaging_frequency must be >= 1")
 
@@ -136,8 +143,13 @@ class ParallelWrapper:
         guard = self.nonfinite_guard
 
         def step(params, opt_state, states, x, y, mask, rng, iteration):
-            (loss, new_states), grads = jax.value_and_grad(
-                net._loss_fn, has_aux=True)(params, states, x, y, mask, rng)
+            # trace-time: tells attention the batch is sharded over
+            # "data", so a Pallas kernel (which the compiler will not
+            # partition by itself) is wrapped in a shard_map over it
+            with sequence_sharding(self.mesh, None, "data"):
+                (loss, new_states), grads = jax.value_and_grad(
+                    net._loss_fn, has_aux=True)(params, states, x, y, mask,
+                                                rng)
             if guard is not None:
                 ok = jnp.logical_and(_updaters.all_finite(grads),
                                      _updaters.all_finite(loss))
@@ -335,10 +347,7 @@ class _LocalSgdState:
         self._avg = self._make_avg()
 
     def _make_step(self):
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         net = self.net
         t = net.training

@@ -1558,6 +1558,11 @@ class DecodeScheduler:
         while not self._stopped:
             progressed = self.step_once()
             if progressed:
+                # a tight loop re-takes the dispatch lock before a thread
+                # parked on it (set_model's fence, the fleet heartbeat
+                # probe) can wake: give up the GIL once per tick so a
+                # busy replica cannot starve them for a whole decode
+                time.sleep(0)
                 continue
             with self._cond:
                 if self._stopped:

@@ -45,10 +45,10 @@ PEAK_FLOPS = {
 def peak_flops_per_sec(device=None) -> Optional[float]:
     """bf16 peak of the attached chip (first device by default), or None
     for an unknown device kind (CPU, GPU, a TPU generation not in the
-    table) — callers decide what "no denominator" means for them: bench
-    falls back to an assumed chip, :func:`mfu` raises asking for an
-    explicit peak, and the live ``measured_mfu`` gauge degrades to a
-    flops/sec gauge (util/ingest.py)."""
+    table) — callers decide what "no denominator" means for them:
+    ``bench.py`` and :func:`mfu` raise (an MFU without a known chip is an
+    error), and the live ``measured_mfu`` gauge degrades to a flops/sec
+    gauge (util/ingest.py)."""
     import jax
     d = device or jax.devices()[0]
     kind = getattr(d, "device_kind", "").lower()
@@ -283,9 +283,7 @@ def time_steps(step_fn: Callable[[], object], steps: int = 10,
     """Wall-time a step callable with a proper device barrier per sample.
 
     The completion barrier is a device→host transfer of (a tiny slice of)
-    the step result — on remote-attached devices ``block_until_ready`` can
-    return before execution finishes (see bench.py), so a d2h read is the
-    only trustworthy fence.
+    the step result.
     """
     def run_once() -> float:
         t0 = time.perf_counter()

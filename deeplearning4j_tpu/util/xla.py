@@ -16,6 +16,7 @@ literal ``off`` to disable all options (including any future defaults).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import os
@@ -64,18 +65,78 @@ def train_step_options() -> Optional[Dict[str, str]]:
 
 
 # ----------------------------------------------------------------------
+# persistent compilation cache
+# ----------------------------------------------------------------------
+
+def use_compile_cache() -> str:
+    """Point this process at the persistent XLA compilation cache and
+    return its directory. Where the caller placed one
+    (``JAX_COMPILATION_CACHE_DIR``) JAX already uses it and this sets no
+    other; otherwise the cache lives at a FIXED path inside the checkout,
+    ``<repo>/.jax_cache`` — never a temp name, pid or timestamp, because
+    the directory is part of the cache key and one that moves never hits.
+    Every program is cached whatever its compile time, so a second run of
+    the same command finds all of them. For entry-point scripts
+    (``chip_smoke.py``, ``bench.py``); library code never calls this."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        path = os.path.join(repo, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# ----------------------------------------------------------------------
 # trace-time routing flags
 # ----------------------------------------------------------------------
 
+_interpret_kernels = False
+
+
+@contextlib.contextmanager
+def interpret_kernels():
+    """Ask for the Pallas kernels to run in interpret mode while the
+    context is active: how the CPU test suite (``tests/conftest.py``) and
+    ``chip_smoke.py --small`` exercise the kernel math without a TPU.
+    Read at TRACE time like the routing flags (:func:`trace_env_key`
+    carries it). Interpret mode is only ever ASKED for, never inferred
+    from the platform, so a program that was meant for the chip cannot
+    end up in a slow interpreted kernel without anyone noticing."""
+    global _interpret_kernels
+    prev, _interpret_kernels = _interpret_kernels, True
+    try:
+        yield
+    finally:
+        _interpret_kernels = prev
+
+
+def kernel_mode() -> Optional[str]:
+    """The ONE place that decides how a Pallas kernel runs in this
+    process: ``"interpret"`` inside :func:`interpret_kernels`,
+    ``"mosaic"`` (compiled by the TPU compiler) on the ``tpu`` backend,
+    ``None`` anywhere else. None means there is no kernel to run: the
+    routers (``flash_available`` / ``ring_flash_available``) take the XLA
+    path whatever ``DL4JTPU_FLASH_ATTENTION`` says."""
+    if _interpret_kernels:
+        return "interpret"
+    import jax
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
 def trace_env_key() -> str:
-    """Cache-key suffix for jitted step functions capturing every env
-    flag that is read at TRACE time and baked into the compiled program
-    (currently the flash-attention routing flags). The runtimes append it
-    to their ``_jit_cache`` keys, so flipping ``DL4JTPU_FLASH_ATTENTION``
-    / ``DL4JTPU_FLASH_BWD`` takes effect on the next call — a fresh trace
-    under the new routing — without manual jit-cache clearing."""
+    """Cache-key suffix for jitted step functions capturing everything
+    that is read at TRACE time and baked into the compiled program
+    (the flash-attention routing flags and :func:`kernel_mode`). The
+    runtimes append it to their ``_jit_cache`` keys, so flipping
+    ``DL4JTPU_FLASH_ATTENTION`` / ``DL4JTPU_FLASH_BWD`` takes effect on
+    the next call — a fresh trace under the new routing — without manual
+    jit-cache clearing."""
     return (f"fa={os.environ.get('DL4JTPU_FLASH_ATTENTION', 'auto')}"
-            f"|fabwd={os.environ.get('DL4JTPU_FLASH_BWD', 'pallas')}")
+            f"|fabwd={os.environ.get('DL4JTPU_FLASH_BWD', 'pallas')}"
+            f"|kern={kernel_mode()}")
 
 
 def pow2_bucket(n: int, cap: int) -> int:
@@ -187,9 +248,7 @@ def compiled_costs(fn: Callable, *args, **kwargs) -> Optional[Dict[str, float]]:
         ca = lower(*args, **kwargs).cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
+    if ca is None:
         return None
     out: Dict[str, float] = {}
     if ca.get("flops"):

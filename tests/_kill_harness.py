@@ -227,11 +227,27 @@ def elastic_fleet_configs(n: int, store_dir: str, base_dir: str, *,
 
 
 def spawn_fleet_child(config: dict) -> "subprocess.Popen":
+    """Fleet children log to a FILE, not a pipe: nobody reads until the
+    child exits, and a child that outwrites the pipe buffer blocks for
+    good (XLA's CPU backend logs a ~3 KB line per program it loads from a
+    warm persistent compile cache — enough to wedge a replica in its
+    warm-up). Read it back with :func:`reap`."""
+    import tempfile
     repo_root, env = _child_env()
-    return subprocess.Popen(
+    err = tempfile.TemporaryFile(mode="w+")
+    proc = subprocess.Popen(
         [sys.executable, HARNESS, json.dumps(config)],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-        env=env, cwd=repo_root)
+        stdout=subprocess.DEVNULL, stderr=err, env=env, cwd=repo_root)
+    proc.stderr_file = err
+    return proc
+
+
+def reap(proc) -> str:
+    """Wait for a :func:`spawn_fleet_child` process; returns its stderr."""
+    proc.wait()
+    with proc.stderr_file as f:
+        f.seek(0)
+        return f.read()
 
 
 def fleet_result(config: dict):
@@ -276,9 +292,8 @@ def run_fleet(configs: list, *, timeout: float = 300.0,
                 rc = p.poll()
                 if rc is None or out[h]["rc"] is not None:
                     continue
-                _, err = p.communicate()
                 out[h]["rc"] = rc
-                out[h]["stderr"] += err or ""
+                out[h]["stderr"] += reap(p)
                 if h in restarts:
                     due[h] = (restarts.pop(h),
                               _time.monotonic() + restart_delay_s)
@@ -296,14 +311,13 @@ def run_fleet(configs: list, *, timeout: float = 300.0,
                 # only wedged ranks left: reclaim them
                 for h in pending:
                     procs[h].kill()
-                    _, err = procs[h].communicate()
                     out[h]["rc"] = "killed_hung"
-                    out[h]["stderr"] += err or ""
+                    out[h]["stderr"] += reap(procs[h])
                 break
             if _time.monotonic() > deadline:
                 for h in pending:
                     procs[h].kill()
-                    procs[h].communicate()
+                    reap(procs[h])
                 raise TimeoutError(
                     f"fleet did not finish within {timeout}s; still "
                     f"running: {pending}")
@@ -312,7 +326,7 @@ def run_fleet(configs: list, *, timeout: float = 300.0,
         for p in procs.values():
             if p.poll() is None:
                 p.kill()
-                p.communicate()
+                reap(p)
     for h in out:
         out[h]["result"] = fleet_result(by_host[h])
     return out

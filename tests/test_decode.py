@@ -752,7 +752,10 @@ class TestServingGenerateHTTP:
         server = self._make_server(oracle_net)
         base = f"http://127.0.0.1:{server.port}"
         swap_zip = str(tmp_path / "swap.zip")
-        save_model(_net(seed=99), swap_zip)
+        # built BEFORE the long call starts: building a net takes longer
+        # than 600 tiny decode steps, so doing it mid-decode loses the race
+        swap_net = _net(seed=99)
+        save_model(swap_net, swap_zip)
         done = {}
 
         def long_call():
@@ -771,7 +774,7 @@ class TestServingGenerateHTTP:
                 time.sleep(0.005)
             assert server.decode.active_count() == 1
             with pytest.raises(RuntimeError, match="in flight"):
-                server.set_model(_net(seed=99))
+                server.set_model(swap_net)
             # over HTTP the refusal is a retriable 409, not a 400
             try:
                 self._post(base, "/model", {"path": swap_zip})
@@ -785,7 +788,7 @@ class TestServingGenerateHTTP:
             assert len(done["r"]["tokens"]) == 600
             assert server.decode.active_count() == 0
             # step boundary reached: the swap now goes through
-            server.set_model(_net(seed=99))
+            server.set_model(swap_net)
             # and a draining server sheds new generates with 503
             try:
                 self._post(base, "/generate", {"prompt_ids": [1]})

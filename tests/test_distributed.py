@@ -192,6 +192,7 @@ def _spawn_two_process(n_steps, mode="sync", timeout=300, attempts=2,
     import socket
     import subprocess
     import sys as _sys
+    import tempfile
     from pathlib import Path
 
     worker = str(Path(__file__).parent / "_two_process_worker.py")
@@ -204,15 +205,19 @@ def _spawn_two_process(n_steps, mode="sync", timeout=300, attempts=2,
         with socket.socket() as s:
             s.bind(("localhost", 0))
             port = s.getsockname()[1]
+        # stderr to files, not pipes: rank 1's pipe is not read until rank
+        # 0 exits, and a worker that fills it (XLA logs a long line per
+        # program loaded from a warm compile cache) blocks mid-collective
+        errs = [tempfile.TemporaryFile(mode="w+") for _ in (0, 1)]
         procs = [subprocess.Popen(
             [_sys.executable, worker, str(port), str(rank),
              str(n_steps), mode],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            stdout=subprocess.PIPE, stderr=errs[rank], text=True,
             env=env) for rank in (0, 1)]
         outs, failed = [], False
-        for p in procs:
+        for p, errf in zip(procs, errs):
             try:
-                out, err = p.communicate(timeout=timeout)
+                out, _ = p.communicate(timeout=timeout)
             except subprocess.TimeoutExpired:
                 for q in procs:
                     q.kill()
@@ -220,8 +225,11 @@ def _spawn_two_process(n_steps, mode="sync", timeout=300, attempts=2,
                 failed, last_err = True, f"timeout after {timeout}s"
                 break
             if p.returncode != 0:
-                failed, last_err = True, err[-3000:]
+                errf.seek(0)
+                failed, last_err = True, errf.read()[-3000:]
             outs.append(out)
+        for errf in errs:
+            errf.close()
         if failed:
             for q in procs:
                 if q.poll() is None:

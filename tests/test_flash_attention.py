@@ -108,6 +108,15 @@ class TestFlashAttention:
         assert flash_available(q.shape, np.ones((2, 256)))   # key masks ok
         assert not flash_available(q.shape, np.ones((2, 9)))  # odd mask shape
         assert not flash_available((2, 250, 2, 64), None)     # t % block
+        # the flag cannot conjure a kernel: off the TPU and with nobody
+        # asking for interpret mode (the suite does, conftest.py), even
+        # "1" takes the XLA path instead of interpreting by surprise
+        from deeplearning4j_tpu.util import xla
+        with monkeypatch.context() as m:
+            m.setattr(xla, "_interpret_kernels", False)
+            assert xla.kernel_mode() is None
+            assert not flash_available(q.shape, None)
+        assert xla.kernel_mode() == "interpret"
         # auto: long sequences only, and only on a real TPU backend
         monkeypatch.delenv("DL4JTPU_FLASH_ATTENTION")
         assert not flash_available((2, 256, 2, 64), None)

@@ -251,6 +251,35 @@ class TestGraphParallel:
             assert np.allclose(a, b, atol=1e-5), \
                 "graph sync dp diverged from single-device"
 
+    def test_sync_step_runs_the_flash_kernel_under_shard_map(
+            self, rng, monkeypatch):
+        """The TPU compiler refuses to partition a Mosaic kernel by itself
+        ("wrap the call in a shard_map" — met on four real chips, PR 21),
+        so the batch-sharded step must run the flash route inside a
+        shard_map over "data". Same losses as one device."""
+        from deeplearning4j_tpu.models import transformer_lm
+        from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
+        from deeplearning4j_tpu.ops import attention
+        monkeypatch.setenv("DL4JTPU_FLASH_ATTENTION", "1")
+        wrapped = []
+        inner = attention._flash_over_batch
+        monkeypatch.setattr(
+            attention, "_flash_over_batch",
+            lambda *a, **kw: wrapped.append(kw["batch_axis"]) or inner(
+                *a, **kw))
+        mk = lambda: ComputationGraph(transformer_lm(   # noqa: E731
+            11, n_layers=1, d_model=16, n_heads=2, d_ff=32, updater="sgd",
+            learning_rate=0.01, seed=3, input_ids=True)).init()
+        ids = rng.integers(0, 11, (4, 129)).astype(np.int32)
+        x, y = ids[:, :-1], ids[:, 1:]
+        ref = mk()
+        want = [float(ref.fit_batch(x, y)) for _ in range(2)]
+        assert not wrapped                 # one device: the plain kernel
+        pw = ParallelWrapper(mk(), mesh=data_parallel_mesh(4))
+        got = [float(pw.fit_batch(x, y)) for _ in range(2)]
+        assert wrapped == ["data"]         # traced once, wrapped
+        assert got == pytest.approx(want, rel=1e-5)
+
     def test_local_sgd_runs_and_averages(self, rng):
         x, y = _data(rng, n=64)
         net = self._graph_net()

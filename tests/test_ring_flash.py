@@ -1,7 +1,7 @@
 """Ring-flash attention: the Pallas flash kernel riding the ppermute ring
 (ops/flash_attention.py block-callable carry entry + ops/attention.py ring
 VJP), parity-tested against the dense oracle on the 8-device CPU mesh —
-the kernels run in interpret mode off-TPU, so the carry protocol, the
+the kernels run in interpret mode (conftest.py asks), so the carry protocol, the
 cross-hop masking trichotomy, and the VJP-through-ppermute are the REAL
 code paths, not stand-ins."""
 
