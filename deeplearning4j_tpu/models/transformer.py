@@ -385,9 +385,10 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
         probs, k_pools, v_pools = paged_decode_forward(
             net, params, k_pools, v_pools, cur[:, None], page_tables,
             slot[:, None], rel_pos + i)
-        u = jax.lax.dynamic_index_in_dim(uniforms, i, axis=1,
-                                         keepdims=False)
-        tok = pick(probs[:, 0, :], u)
+        with jax.named_scope("sample"):
+            u = jax.lax.dynamic_index_in_dim(uniforms, i, axis=1,
+                                             keepdims=False)
+            tok = pick(probs[:, 0, :], u)
         emit = jnp.logical_not(done)
         n_emitted = n_emitted + emit.astype(jnp.int32)
         hit_eos = (eos_ids >= 0) & (tok == eos_ids)

@@ -248,17 +248,20 @@ class SelfAttentionLayer(Layer):
         xc, wqkv = policy.cast_to_compute(x, params["Wqkv"])
         b, t_new, f = xc.shape
         h = self.n_heads
-        qkv = (xc @ wqkv).reshape(b, t_new, 3, h, f // h)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("attn.qkv"):
+            qkv = (xc @ wqkv).reshape(b, t_new, 3, h, f // h)
+            q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         k_pool = paged_write(k_pool, k_new, page_table, write_slots)
         v_pool = paged_write(v_pool, v_new, page_table, write_slots)
         kh = paged_gather(k_pool, page_table)
         vh = paged_gather(v_pool, page_table)
         scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
         att = paged_attention(q, kh, vh, rel_pos, scale)
-        wo = params["Wo"].astype(att.dtype)
-        out = att.reshape(b, t_new, f) @ wo + params["b"].astype(att.dtype)
-        out = self._act(self.activation or "identity")(out)
+        with jax.named_scope("attn.out"):
+            wo = params["Wo"].astype(att.dtype)
+            out = (att.reshape(b, t_new, f) @ wo
+                   + params["b"].astype(att.dtype))
+            out = self._act(self.activation or "identity")(out)
         return out, k_pool, v_pool
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
@@ -275,8 +278,9 @@ class SelfAttentionLayer(Layer):
             return self._apply_streaming(params, xc, state, policy)
         b, t, f = xc.shape
         h = self.n_heads
-        qkv = (xc @ wqkv).reshape(b, t, 3, h, f // h)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("attn.qkv"):
+            qkv = (xc @ wqkv).reshape(b, t, 3, h, f // h)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         seq_ctx = active_sequence_sharding()
         if seq_ctx is not None and seq_ctx[1] is not None:
             # sequence-parallel route: the time axis is sharded over the
@@ -299,9 +303,10 @@ class SelfAttentionLayer(Layer):
         else:
             att = dot_product_attention(q, k, v, causal=self.causal,
                                         mask=mask)
-        wo = params["Wo"].astype(att.dtype)
-        out = att.reshape(b, t, f) @ wo + params["b"].astype(att.dtype)
-        out = self._act(self.activation or "identity")(out)
-        if mask is not None:
-            out = out * mask[:, :, None].astype(out.dtype)
+        with jax.named_scope("attn.out"):
+            wo = params["Wo"].astype(att.dtype)
+            out = att.reshape(b, t, f) @ wo + params["b"].astype(att.dtype)
+            out = self._act(self.activation or "identity")(out)
+            if mask is not None:
+                out = out * mask[:, :, None].astype(out.dtype)
         return out, state

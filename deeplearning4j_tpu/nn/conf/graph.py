@@ -17,6 +17,7 @@ masks to an output mask).
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 import json
@@ -182,8 +183,11 @@ class LayerVertex(GraphVertex):
             x = call_preprocessor(self.preprocessor, x, minibatch_size=mb,
                                   rng=rng)
             mask = self.preprocessor.transform_mask(mask, minibatch_size=mb)
-        return self.layer.apply(params, x, state=state, train=train, rng=rng,
-                                mask=mask, policy=policy)
+        scope = self.layer._trace_scope
+        with (contextlib.nullcontext() if scope is None
+              else jax.named_scope(scope)):
+            return self.layer.apply(params, x, state=state, train=train,
+                                    rng=rng, mask=mask, policy=policy)
 
 
 @register_vertex("merge")

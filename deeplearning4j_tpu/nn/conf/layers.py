@@ -117,6 +117,10 @@ class Layer:
     bias_learning_rate: Optional[float] = None
 
     _type_name = "base"
+    # jax.named_scope that LayerVertex.apply puts around this layer's
+    # forward (metadata only: a stable name in lowered programs and device
+    # traces); None for layers that scope their own parts
+    _trace_scope = None
 
     # ---- shape inference hooks (parity Layer.java:130-185) ----
     def output_type(self, input_type: InputType) -> InputType:
@@ -234,12 +238,15 @@ class BaseOutputLayer(FeedForwardLayer):
     runtime nn/layers/BaseOutputLayer.java:92-115 — score via ILossFunction)."""
 
     loss: str = "negativeloglikelihood"
+    _trace_scope = "head"
 
     def compute_score_array(self, params, x, labels, *, mask=None, policy=None):
         from ... import losses as _losses
-        pre = self.pre_output(params, x, policy=policy)
-        return _losses.score_array(self.loss, labels, pre,
-                                   self.activation or "sigmoid", mask)
+        with jax.named_scope("head"):
+            pre = self.pre_output(params, x, policy=policy)
+        with jax.named_scope("loss"):
+            return _losses.score_array(self.loss, labels, pre,
+                                       self.activation or "sigmoid", mask)
 
 
 @register_layer("output")
@@ -366,6 +373,7 @@ class EmbeddingSequenceLayer(FeedForwardLayer):
     cast through a compute dtype (bf16 rounds ids past 256)."""
 
     has_bias: bool = False
+    _trace_scope = "embed"
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
@@ -675,6 +683,8 @@ class LayerNormalization(Layer):
     ``models/transformer.py``). Stateless — per-example statistics, no
     running averages — and shape-preserving on [b, f], [b, t, f], NHWC.
     """
+
+    _trace_scope = "ln"
 
     n_out: Optional[int] = None          # feature count (inferred)
     eps: float = 1e-5

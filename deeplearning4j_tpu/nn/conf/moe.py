@@ -48,6 +48,7 @@ class MoELayer(Layer):
     n_experts: int = 8
     top_k: int = 2
     aux_weight: float = 0.01
+    _trace_scope = "ffn"
 
     def output_type(self, input_type: InputType) -> InputType:
         n = self.n_out or self.n_in

@@ -256,6 +256,8 @@ class TimeDistributedDenseLayer(BaseRecurrentLayer):
     matmul without layout round-trips. Inherits BaseRecurrentLayer's
     input handling (FeedForwardToRnn / CnnToRnn preprocessors)."""
 
+    _trace_scope = "ffn"      # a position-wise dense layer, wherever it sits
+
     def param_shapes(self, policy=None):
         return {"W": (self.n_in, self.n_out), "b": (self.n_out,)}
 

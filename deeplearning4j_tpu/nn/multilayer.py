@@ -450,6 +450,10 @@ class MultiLayerNetwork:
                                         act_stats, stats_cfg, loss=loss)
             return params, opt_state, new_states, loss, stats
 
+        # the XLA module carries the retrace-guard name, so a device
+        # trace tells the train step from any other jit_step
+        step.__name__ = ("MultiLayerNetwork_train_step_stats" if collect
+                         else "MultiLayerNetwork_train_step")
         return jax.jit(step, donate_argnums=(0, 1),
                        compiler_options=_xla.train_step_options())
 

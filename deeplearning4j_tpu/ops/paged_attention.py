@@ -30,15 +30,21 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
 Everything is plain gather/scatter + einsum: XLA lowers it well on both
 the CPU test mesh and TPU, and there is no dynamic shape anywhere — the
 scheduler can admit/retire sequences every step without retracing.
+
+Each primitive runs under a ``jax.named_scope`` (``attn.paged_write``,
+``attn.paged_gather``, ``attn.paged_softmax``): metadata only, so that a
+device trace can tell the paged read from the rest of a decode step.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_gather", "paged_attention"]
 
 
+@jax.named_scope("attn.paged_write")
 def paged_write(pool, new, page_table, write_slots):
     """Scatter new K (or V) rows into the block pool.
 
@@ -107,6 +113,7 @@ def _paged_write_q8(pool, new, page_table, write_slots):
     return (q, new_scales)
 
 
+@jax.named_scope("attn.paged_gather")
 def paged_gather(pool, page_table):
     """Gather each lane's pages into a contiguous view.
 
@@ -129,6 +136,7 @@ def paged_gather(pool, page_table):
     return g.reshape(s, p * page_size, h, d)
 
 
+@jax.named_scope("attn.paged_softmax")
 def paged_attention(q, k_view, v_view, rel_pos, scale):
     """Causal attention of new queries over the gathered paged view.
 

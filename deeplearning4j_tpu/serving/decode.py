@@ -70,6 +70,14 @@ rides ``serving_shed_total``; ``decode_batch_occupancy``,
 ``kv_pages_in_use``, ``decode_retired_total{reason}``, TTFT and
 time-per-output-token histograms land in the scheduler's registry and
 the ``/metrics`` exposition when wired into an ``InferenceServer``.
+Every phase is timed by ``util.tracing.region`` and by nothing else:
+``engine.enqueue`` / ``engine.device_wait`` / ``engine.fetch`` per
+dispatch (``decode_dispatch_phase_seconds{kind, phase}``, with the bytes
+fetched in ``decode_d2h_bytes_total{kind}``), ``sched.tick`` ⊃
+``sched.retire_expired`` / ``sched.admit`` / ``sched.prefill`` /
+``sched.decode`` per tick, ``sched.wait_idle`` / ``sched.wait_blocked``
+in the loop (``decode_sched_wait_seconds{why}``); under a profiler
+session they are host spans on the device trace's clock.
 
 Fault seam: ``"serving.decode_step"`` before every prefill/decode
 dispatch (chaos tests script outages at exact step boundaries).
@@ -82,8 +90,9 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..models import transformer as _transformer
@@ -95,6 +104,7 @@ from ..util import metrics as _metrics
 from ..util import tracing as _tracing
 from ..util import xla as _xla
 from ..util.resilience import SYSTEM_CLOCK, Clock, Deadline
+from ..util.tracing import region
 from .kv_cache import PagedKVArena
 
 __all__ = ["PagedDecodeEngine", "DecodeScheduler", "DecodeRequest",
@@ -121,7 +131,9 @@ class DecodeRequest:
 
     ``ttft_breakdown`` (stamped at the first token, when the scheduler
     has a clock that advances) decomposes the measured TTFT into
-    components that sum to it: ``queue_wait`` (submit → lane admission),
+    components that sum to it (each also observed into
+    ``decode_ttft_component_seconds``): ``queue_wait`` (submit → lane
+    admission),
     ``prefill`` (this request's own prefill-dispatch wall, compile
     excluded), ``compile`` (fresh-trace compiles its prefill ticks
     paid — 0 after ``warmup()``), and ``dispatch`` (the remainder: the
@@ -131,8 +143,8 @@ class DecodeRequest:
     __slots__ = ("prompt", "max_new_tokens", "temperature", "eos_id",
                  "deadline", "rng", "tokens", "finish_reason", "error",
                  "event", "t_submit", "t_admit", "t_first_token",
-                 "t_done", "top_k", "top_p", "span", "queue_span",
-                 "ttft_breakdown", "prefix_covered_tokens")
+                 "t_done", "top_k", "top_p", "span", "ttft_breakdown",
+                 "prefix_covered_tokens", "blocked_by", "deliveries")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  temperature: float, eos_id: Optional[int],
@@ -155,11 +167,17 @@ class DecodeRequest:
         self.t_first_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.span = None            # request-root tracing span
-        self.queue_span = None      # child span covering queue wait
         self.ttft_breakdown: Optional[Dict[str, float]] = None
         # prompt tokens covered by a prefix-cache hit at admission
         # (0 = miss or caching disabled) — stamped by the scheduler
         self.prefix_covered_tokens = 0
+        # why the last admission pass that ran while this request was
+        # queued stopped short: "lanes", "pages", or "none" if no pass
+        # was refused between its submit and its admission
+        self.blocked_by = "none"
+        # (scheduler-clock instant, tokens handed over) per delivery: a
+        # fused block hands up to block_len tokens at once
+        self.deliveries: List[Tuple[float, int]] = []
 
     @property
     def done(self) -> bool:
@@ -182,6 +200,19 @@ class DecodeRequest:
 
 # sequence states inside the scheduler
 _PREFILL, _DECODE = "prefill", "decode"
+
+# a dispatch and its parts span 0.1 ms (an enqueue) to 1 s (a fused block)
+_TICK_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                 0.025, 0.05, 0.1, 0.25, 1.0)
+
+
+def _module_name(ladder_key: str) -> str:
+    """``paged_decode[S8xT128xP128]`` → ``paged_decode_S8_T128_P128``:
+    the trace-ladder key as a function name, so each decode program's
+    XLA module (the profiler's ``XLA Modules`` line) carries its ladder
+    rung instead of twelve ``jit_step``."""
+    head, _, dims = ladder_key.partition("[")
+    return "_".join([head, *dims.rstrip("]").split("x")])
 
 
 class _Sequence:
@@ -334,11 +365,23 @@ class PagedDecodeEngine:
             "decode_host_tick_seconds",
             "Scheduler tick wall split into dispatch (device compute + "
             "sync) vs host bookkeeping components", ("component",),
-            buckets=[0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
-                     0.01, 0.025, 0.05, 0.1, 0.25, 1.0])
+            buckets=_TICK_BUCKETS)
+        self._m_phase = self.registry.histogram(
+            "decode_dispatch_phase_seconds",
+            "One dispatch split where it happens: enqueue (the jitted "
+            "call returns), device_wait (block_until_ready of its "
+            "outputs), fetch (device->host copy of them); the three sum "
+            "to decode_host_tick_seconds{component=dispatch}",
+            ("kind", "phase"),
+            buckets=_TICK_BUCKETS)
+        self._m_d2h = self.registry.counter(
+            "decode_d2h_bytes_total",
+            "Bytes of dispatch outputs copied device->host", ("kind",))
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
+        # why the last acquire_lane() refused: "lanes" | "pages" | None
+        self.refused_by: Optional[str] = None
 
     # -- construction-time validation ---------------------------------
 
@@ -419,7 +462,8 @@ class PagedDecodeEngine:
     def acquire_lane(self, total_tokens: int,
                      prompt=None) -> Optional[int]:
         """Admission: a free lane + a worst-case page reservation, or
-        None when either is unavailable (the request stays queued).
+        None when either is unavailable (the request stays queued;
+        ``refused_by`` then says which: "lanes" or "pages").
 
         With the prefix cache enabled and ``prompt`` given, the longest
         resident full-page prefix is mapped (retained) into the lane's
@@ -431,7 +475,9 @@ class PagedDecodeEngine:
         token with a dropped write (the K/V is already resident; the
         re-feed only produces the first-token distribution), so the
         feed cursor starts at ``len(prompt) - 1``."""
+        self.refused_by = None
         if not self._free_lanes:
+            self.refused_by = "lanes"
             return None
         alloc = self.arena.allocator
         index = self.arena.prefix_index
@@ -448,10 +494,12 @@ class PagedDecodeEngine:
                 else:
                     need = worst - len(covered_pages)
                 if not alloc.admit(need, covered_pages):
+                    self.refused_by = "pages"
                     return None
         else:
             need = min(self.pages_per_seq, worst)
             if not alloc.reserve(need):
+                self.refused_by = "pages"
                 return None
         lane = self._free_lanes.popleft()
         cov = len(covered_pages)
@@ -631,28 +679,44 @@ class PagedDecodeEngine:
         and keeps serving on the fresh pools. ``sync=True`` transfers
         the outputs to host (one host round-trip, counted); ``sync=
         False`` returns them as device arrays (a later sync waits them
-        out)."""
+        out). The dispatch is timed where its three parts happen:
+        enqueue, device wait, fetch (warm-up dispatches are compile
+        calls and stay out of every series)."""
+        step.__name__ = _module_name(name)
         fn = _xla.keyed_jit(
             self._jit_cache, step, extra=name,
             wrap=lambda f: _xla.retrace_guard(f, name, self.registry),
             donate_argnums=(1, 2))
-        t0 = time.perf_counter()
+        hist = None if self._warming else self._m_phase
+        wall, nbytes = 0.0, 0
         try:
-            *outputs, k_pools, v_pools = fn(
-                params, arena.k_pools, arena.v_pools, *args)
+            with region("engine.enqueue", hist, kind=kind,
+                        phase="enqueue") as enqueue:
+                *outputs, k_pools, v_pools = fn(
+                    params, arena.k_pools, arena.v_pools, *args)
             arena.k_pools = list(k_pools)
             arena.v_pools = list(v_pools)
+            wall = enqueue.seconds
             if sync:
                 # the sync lives INSIDE the try: on device backends an
                 # async kernel failure surfaces here, not at fn() — the
                 # rebuild must cover it or the errored pools just stored
                 # above would poison every later dispatch (this sync also
-                # surfaces failures from earlier sync=False dispatches)
-                outputs = [np.asarray(o) for o in outputs]
+                # surfaces failures from earlier sync=False dispatches).
+                # np.asarray would have waited for the device anyway:
+                # waiting first splits that wait from the copy
+                with region("engine.device_wait", hist, kind=kind,
+                            phase="device_wait") as wait:
+                    jax.block_until_ready(outputs)
+                with region("engine.fetch", hist, kind=kind,
+                            phase="fetch") as fetch:
+                    outputs = [np.asarray(o) for o in outputs]
+                wall += wait.seconds + fetch.seconds
+                nbytes = sum(o.nbytes for o in outputs)
         except Exception:
             self._reset_all_pools()
             raise
-        self._note_dispatch(t0, kind, sync=sync)
+        self._note_dispatch(wall, kind, nbytes, sync=sync)
         return outputs
 
     def _reset_all_pools(self) -> None:
@@ -671,21 +735,23 @@ class PagedDecodeEngine:
         h = self.registry.get("xla_compile_seconds")
         return 0.0 if h is None else h.total_sum()
 
-    def _note_dispatch(self, t0: float, kind: str,
+    def _note_dispatch(self, wall: float, kind: str, nbytes: int,
                        sync: bool = True) -> None:
+        """Account one dispatch whose phases took ``wall`` seconds in
+        all and fetched ``nbytes`` to the host."""
         if self._warming:
             # warmup dispatches are compile calls — folding their
             # multi-second walls into the steady-state tick histogram
             # (or the sync/token ratio) would bury the signal the
             # satellite metric exists to show
             return
-        dt = time.perf_counter() - t0
-        self._tick_dispatch_wall += dt
+        self._tick_dispatch_wall += wall
         self._tick_dispatches += 1
         self._m_dispatches.inc(kind=kind)
         if sync:
             self._m_syncs.inc()
-            self._m_tick.observe(dt, component="dispatch")
+            self._m_tick.observe(wall, component="dispatch")
+            self._m_d2h.inc(nbytes, kind=kind)
 
     # -- fused multi-token block --------------------------------------
 
@@ -957,6 +1023,27 @@ class DecodeScheduler:
             "Steady-state seconds per output token, per finished sequence",
             buckets=[0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                      0.1, 0.25, 0.5, 1.0])
+        self._m_ttft_part = reg.histogram(
+            "decode_ttft_component_seconds",
+            "The four parts of each request's TTFT (their sums add up to "
+            "decode_ttft_seconds): queue_wait, prefill, compile, "
+            "dispatch; blocked_by says what the last refused admission "
+            "pass lacked while the request queued (queue_wait only)",
+            ("component", "blocked_by"))
+        self._m_gap = reg.histogram(
+            "decode_delivery_gap_seconds",
+            "Time between consecutive deliveries of tokens to one "
+            "request (a fused block hands block_len tokens at once, "
+            "which the per-token mean averages away)",
+            buckets=[0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                     0.5, 1.0, 2.5, 5.0])
+        self._m_wait = reg.histogram(
+            "decode_sched_wait_seconds",
+            "Scheduler loop asleep: idle (no queue, nothing active) or "
+            "blocked (queued work, nothing admissible, nothing running)",
+            ("why",),
+            buckets=[0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
+                     0.1])
         self._m_draft = reg.counter(
             "decode_draft_tokens_total",
             "Speculative draft tokens, by verify outcome", ("result",))
@@ -1010,7 +1097,8 @@ class DecodeScheduler:
         (``decode.request``) with child spans for queue wait, each
         prefill chunk, and each decode/spec block dispatch — the
         per-request timeline ``/debug/timeline`` and
-        ``util.timeline.request_timelines`` render. ``trace_ctx`` (a
+        ``util.timeline.request_timelines`` render; their durations are
+        the numbers the histograms observe. ``trace_ctx`` (a
         traceparent string or extracted SpanContext, e.g. from an HTTP
         header) parents the root span on the caller's trace."""
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
@@ -1049,7 +1137,6 @@ class DecodeScheduler:
                 "decode.request", parent=trace_ctx,
                 attributes={"prompt_len": int(prompt.size),
                             "max_new_tokens": n_new})
-            req.queue_span = self.tracer.start("queue", parent=req.span)
         try:
             with self._cond:
                 # flags checked under the lock: a submit racing stop()
@@ -1074,13 +1161,21 @@ class DecodeScheduler:
             raise
         return req
 
-    @staticmethod
-    def _end_request_spans(req: DecodeRequest,
+    def _end_request_spans(self, req: DecodeRequest,
                            status: Optional[str] = None) -> None:
-        if req.queue_span is not None:
-            req.queue_span.end(status)
-        if req.span is not None:
-            req.span.end(status)
+        if req.span is None:
+            return
+        if req.t_admit is None:       # died in the queue (or at its door)
+            self._record_queue_span(req, self.clock.monotonic(), status)
+        req.span.end(status)
+
+    def _record_queue_span(self, req: DecodeRequest, until: float,
+                           status: Optional[str] = None, **attrs) -> None:
+        """The request's ``queue`` span: submit → ``until`` on the
+        scheduler's clock, the number ``queue_wait`` reports."""
+        self.tracer.record(
+            "queue", until - req.t_submit, parent=req.span, status=status,
+            attributes={"blocked_by": req.blocked_by, **attrs})
 
     # -- the continuous-batching tick ---------------------------------
 
@@ -1092,31 +1187,32 @@ class DecodeScheduler:
         safe for the next admissions)."""
         with self._dispatch_lock:
             eng = self.engine
-            t_tick = time.perf_counter()
             eng._tick_dispatch_wall = 0.0
             eng._tick_dispatches = 0
-            progressed = self._retire_expired()
-            progressed = self._admit() or progressed
-            try:
-                progressed = self._prefill_tick() or progressed
-                progressed = self._decode_tick() or progressed
-            except Exception as e:  # noqa: BLE001 — fail the batch, keep serving
-                _flight.record("decode_error",
-                               error=f"{type(e).__name__}: {e}",
-                               in_flight=len(self._active))
-                for seq in list(self._active.values()):
-                    seq.req.error = f"{type(e).__name__}: {e}"
-                    self._retire(seq, "error")
-                progressed = True
+            with region("sched.tick") as tick:
+                with region("sched.retire_expired"):
+                    progressed = self._retire_expired()
+                with region("sched.admit"):
+                    progressed = self._admit() or progressed
+                try:
+                    progressed = self._prefill_tick() or progressed
+                    progressed = self._decode_tick() or progressed
+                except Exception as e:  # noqa: BLE001 — keep serving
+                    _flight.record("decode_error",
+                                   error=f"{type(e).__name__}: {e}",
+                                   in_flight=len(self._active))
+                    for seq in list(self._active.values()):
+                        seq.req.error = f"{type(e).__name__}: {e}"
+                        self._retire(seq, "error")
+                    progressed = True
             # the measured split behind the fused-block design: dispatch
             # wall (device compute + sync, observed per dispatch by the
             # engine) vs everything else this tick did on the host —
             # only ticks that dispatched count, so idle polling doesn't
             # flood the bookkeeping series
             if eng._tick_dispatches:
-                total = time.perf_counter() - t_tick
                 eng._m_tick.observe(
-                    max(0.0, total - eng._tick_dispatch_wall),
+                    max(0.0, tick.seconds - eng._tick_dispatch_wall),
                     component="bookkeeping")
             return progressed
 
@@ -1150,14 +1246,17 @@ class DecodeScheduler:
             lane = self.engine.acquire_lane(
                 len(req.prompt) + req.max_new_tokens, prompt=req.prompt)
             if lane is None:          # no lane / page pressure: stay queued
+                # the head holds up everything behind it: the whole queue
+                # waited out this pass for the same want
+                with self._cond:
+                    for waiting in self._queue:
+                        waiting.blocked_by = self.engine.refused_by
                 break
             with self._cond:
                 self._queue.popleft()
             req.t_admit = self.clock.monotonic()
-            if req.queue_span is not None:
-                req.queue_span.set_attribute("lane", lane)
-                req.queue_span.end()
-                req.queue_span = None
+            if req.span is not None:
+                self._record_queue_span(req, req.t_admit, lane=lane)
             seq = _Sequence(req, lane)
             # prefix-cache hit: the engine parked the feed cursor past
             # the covered tokens (a full cover re-feeds the last prompt
@@ -1195,6 +1294,11 @@ class DecodeScheduler:
         seqs = [s for s in self._active.values() if s.state == _PREFILL]
         if not seqs:
             return False
+        with region("sched.prefill"):
+            self._prefill_chunk(seqs)
+        return True
+
+    def _prefill_chunk(self, seqs: List[_Sequence]) -> None:
         eng = self.engine
         c = eng.prefill_chunk
         chunk_len: List[int] = []
@@ -1259,17 +1363,23 @@ class DecodeScheduler:
                 self._emit_token(seq, probs[i, n - 1])
                 if seq.lane in self._active:
                     seq.state = _DECODE
-        return True
 
     def _decode_tick(self) -> bool:
         seqs = [s for s in self._active.values() if s.state == _DECODE]
         if not seqs:
             return False
         eng = self.engine
-        if eng.draft_net is not None:
-            return self._spec_block_tick(seqs)
-        if eng.block_len > 1:
-            return self._fused_block_tick(seqs)
+        with region("sched.decode"):
+            if eng.draft_net is not None:
+                self._spec_block_tick(seqs)
+            elif eng.block_len > 1:
+                self._fused_block_tick(seqs)
+            else:
+                self._ticked_step(seqs)
+        return True
+
+    def _ticked_step(self, seqs: List[_Sequence]) -> None:
+        eng = self.engine
         for seq in seqs:
             eng.ensure_pages(seq.lane, 1)
         ids, wslots, rel, tables = self._compact(seqs, 1)
@@ -1297,7 +1407,6 @@ class DecodeScheduler:
             eng.advance(seq.lane, 1)
             self._emit_token(seq, probs[i, 0],
                              greedy_tok=int(greedy[i]))
-        return True
 
     def _block_arrays(self, seqs: List[_Sequence], n_uniform: int):
         """Per-lane arrays for a fused/speculative block over a
@@ -1337,7 +1446,7 @@ class DecodeScheduler:
                 arr["u"][i] = req.rng.random(n_uniform)
         return arr
 
-    def _fused_block_tick(self, seqs: List[_Sequence]) -> bool:
+    def _fused_block_tick(self, seqs: List[_Sequence]) -> None:
         """One FUSED block: N device-resident decode steps, one
         dispatch, one host sync — retire/admit happen at this block
         boundary, finished lanes self-retired on device mid-block."""
@@ -1369,17 +1478,13 @@ class DecodeScheduler:
             m = int(n_emitted[i])
             eng.advance(seq.lane, m)
             emitted_total += m
-            for j in range(m):
-                self._absorb_token(seq, int(toks[i, j]))
-                if seq.req.done:
-                    break
+            self._deliver(seq, toks[i, :m])
         self._m_tokens.inc(emitted_total, phase="decode")
         _flight.record("decode_block", kind="fused", lanes=len(seqs),
                        block_len=n, tokens=emitted_total,
                        active=len(self._active))
-        return True
 
-    def _spec_block_tick(self, seqs: List[_Sequence]) -> bool:
+    def _spec_block_tick(self, seqs: List[_Sequence]) -> None:
         """One SPECULATIVE block: the draft scans K+1 steps, the target
         verifies all K drafts in one batched chunk, accept/reject +
         bonus land on device — 1..K+1 tokens per lane for two dispatches
@@ -1423,17 +1528,13 @@ class DecodeScheduler:
         emitted_total = 0
         emitted_per_seq: List[int] = []
         for i, seq in enumerate(seqs):
-            m = 0
-            for j in range(k + 1):
-                if not valid[i, j]:
-                    break
-                self._absorb_token(seq, int(emitted[i, j]))
-                m += 1
-                if seq.req.done:
-                    break
+            # ``valid`` is a prefix mask: its first False ends the run
+            n_valid = (k + 1 if valid[i].all()
+                       else int(np.argmin(valid[i])))
+            m = self._deliver(seq, emitted[i, :n_valid])
             emitted_per_seq.append(m)
             if not seq.req.done:
-                # a finished lane was already released by _absorb_token's
+                # a finished lane was already released by _deliver's
                 # retire — advancing it would stamp a phantom position
                 # onto a freed lane
                 eng.advance(seq.lane, m)
@@ -1456,7 +1557,6 @@ class DecodeScheduler:
         _flight.record("decode_block", kind="speculative",
                        lanes=len(seqs), draft_k=k, tokens=emitted_total,
                        sampled_lanes=n_sampled, active=len(self._active))
-        return True
 
     def _record_block_spans(self, seqs: List[_Sequence], kind: str,
                             bucket: int, tokens: List[int],
@@ -1482,47 +1582,71 @@ class DecodeScheduler:
                else _transformer.sample_token(probs, req.temperature,
                                               req.rng, top_k=req.top_k,
                                               top_p=req.top_p))
-        self._absorb_token(seq, tok)
+        self._deliver(seq, (tok,))
 
-    def _absorb_token(self, seq: _Sequence, tok: int) -> None:
-        """Account one generated token (host-sampled by
-        :meth:`_emit_token`, or device-sampled inside a fused/spec
-        block): append, stamp TTFT, retire on EOS/max-tokens — the ONE
-        copy of the finish rules, so device self-retire decisions and
-        host bookkeeping cannot disagree."""
+    def _deliver(self, seq: _Sequence, toks: Sequence[int]) -> int:
+        """Hand one dispatch's generated tokens to the request
+        (host-sampled by :meth:`_emit_token`, or device-sampled inside a
+        fused/spec block): append up to the finishing token, stamp TTFT
+        and the delivery, retire on EOS/max-tokens — the ONE copy of the
+        finish rules, so device self-retire decisions and host
+        bookkeeping cannot disagree. Returns how many were taken."""
         req = seq.req
+        if len(toks) == 0:            # the lane emitted nothing this block
+            return 0
+        now = self.clock.monotonic()
         if req.t_first_token is None:
-            req.t_first_token = self.clock.monotonic()
-            ttft = req.t_first_token - req.t_submit
-            self._m_ttft.observe(ttft)
-            # the decomposition SUMS to the measured TTFT: queue wait
-            # (submit → admission) + this request's own prefill dispatch
-            # wall + the compiles its ticks paid + everything else the
-            # shared ticks did in between (other lanes' dispatches, host
-            # bookkeeping). Components use the same clock as the TTFT
-            # histogram, so the identity holds by construction.
-            queue_wait = max(0.0, (req.t_admit if req.t_admit is not None
-                                   else req.t_submit) - req.t_submit)
-            prefill = min(seq.prefill_s, max(0.0, ttft - queue_wait))
-            compile_s = min(seq.compile_s,
-                            max(0.0, ttft - queue_wait - prefill))
-            req.ttft_breakdown = {
-                "queue_wait": queue_wait, "prefill": prefill,
-                "compile": compile_s,
-                "dispatch": max(0.0, ttft - queue_wait - prefill
-                                - compile_s)}
-            if req.span is not None:
-                req.span.set_attribute("ttft_ms", round(ttft * 1000, 3))
-                req.span.set_attribute(
-                    "ttft_breakdown_ms",
-                    {k: round(v * 1000, 3)
-                     for k, v in req.ttft_breakdown.items()})
-        req.tokens.append(tok)
-        seq.last_token = tok
-        if req.eos_id is not None and tok == req.eos_id:
-            self._retire(seq, "eos")
-        elif len(req.tokens) >= req.max_new_tokens:
-            self._retire(seq, "max_tokens")
+            self._stamp_first_token(seq, now)
+        reason, taken = None, 0
+        for tok in toks:
+            req.tokens.append(int(tok))
+            taken += 1
+            if req.eos_id is not None and tok == req.eos_id:
+                reason = "eos"
+            elif len(req.tokens) >= req.max_new_tokens:
+                reason = "max_tokens"
+            if reason is not None:
+                break
+        seq.last_token = req.tokens[-1]
+        if req.deliveries:
+            self._m_gap.observe(now - req.deliveries[-1][0])
+        req.deliveries.append((now, taken))
+        if reason is not None:
+            self._retire(seq, reason)
+        return taken
+
+    def _stamp_first_token(self, seq: _Sequence, now: float) -> None:
+        req = seq.req
+        req.t_first_token = now
+        ttft = req.t_first_token - req.t_submit
+        self._m_ttft.observe(ttft)
+        # the decomposition SUMS to the measured TTFT: queue wait
+        # (submit → admission) + this request's own prefill dispatch
+        # wall + the compiles its ticks paid + everything else the
+        # shared ticks did in between (other lanes' dispatches, host
+        # bookkeeping). Components use the same clock as the TTFT
+        # histogram, so the identity holds by construction.
+        queue_wait = max(0.0, (req.t_admit if req.t_admit is not None
+                               else req.t_submit) - req.t_submit)
+        prefill = min(seq.prefill_s, max(0.0, ttft - queue_wait))
+        compile_s = min(seq.compile_s,
+                        max(0.0, ttft - queue_wait - prefill))
+        req.ttft_breakdown = {
+            "queue_wait": queue_wait, "prefill": prefill,
+            "compile": compile_s,
+            "dispatch": max(0.0, ttft - queue_wait - prefill
+                            - compile_s)}
+        for part, seconds in req.ttft_breakdown.items():
+            self._m_ttft_part.observe(
+                seconds, component=part,
+                blocked_by=(req.blocked_by if part == "queue_wait"
+                            else "none"))
+        if req.span is not None:
+            req.span.set_attribute("ttft_ms", round(ttft * 1000, 3))
+            req.span.set_attribute(
+                "ttft_breakdown_ms",
+                {k: round(v * 1000, 3)
+                 for k, v in req.ttft_breakdown.items()})
 
     def _retire(self, seq: _Sequence, reason: str) -> None:
         self.engine.release_lane(seq.lane)
@@ -1568,11 +1692,15 @@ class DecodeScheduler:
                 if self._stopped:
                     break
                 if not self._queue and not self._active:
-                    self._cond.wait(timeout=0.05)
+                    with region("sched.wait_idle", self._m_wait,
+                                why="idle"):
+                        self._cond.wait(timeout=0.05)
                 else:
                     # queued work that could not admit yet (page/lane
                     # pressure resolves at the next retirement)
-                    self._cond.wait(timeout=0.002)
+                    with region("sched.wait_blocked", self._m_wait,
+                                why="blocked"):
+                        self._cond.wait(timeout=0.002)
 
     def active_count(self) -> int:
         return len(self._active)

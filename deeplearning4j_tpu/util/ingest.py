@@ -33,8 +33,12 @@ numerics:
 
 Observability (all into the PR-2 metrics registry): prefetch queue depth
 gauge, h2d bytes/seconds counters, staged-batch counts, a
-host-gap-between-dispatches histogram recorded by the fit loops, and
-optional per-batch ingest spans when a tracer is attached.
+host-gap-between-dispatches histogram and the step's phases recorded by
+the fit loop, and optional per-batch ingest spans when a tracer is
+attached. Every phase is timed by ``util.tracing.region`` (``fit.step``
+⊃ ``fit.hooks`` / ``fit.dispatch`` / ``fit.device_wait``,
+``fit.source_wait`` between steps, ``ingest.stage`` in the staging
+thread), so a profiler session shows them on the device trace's clock.
 """
 
 from __future__ import annotations
@@ -44,7 +48,6 @@ import logging
 import os
 import queue
 import threading
-import time
 from typing import Iterable, Iterator, Optional, Tuple
 
 import numpy as np
@@ -52,6 +55,7 @@ import numpy as np
 from . import faults as _faults
 from . import flightrecorder as _flight
 from . import metrics as _metrics
+from .tracing import region
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -251,68 +255,13 @@ def pipeline_batches_counter(registry=None) -> _metrics.Counter:
         "Batches assembled by the record input pipeline", ("stage",))
 
 
-def measured_flops_gauge(registry=None) -> _metrics.Gauge:
-    return _reg(registry).gauge(
-        "measured_flops_per_sec",
-        "Live training FLOP/s: the compiled train step's HLO "
-        "cost-analysis FLOPs (compiled_flops) over wall time between "
-        "dispatches — measured, not analytic", ("model",))
-
-
-def measured_mfu_gauge(registry=None) -> _metrics.Gauge:
-    return _reg(registry).gauge(
-        "measured_mfu",
-        "Live model FLOPs utilization: measured_flops_per_sec over the "
-        "attached chip's published bf16 peak (series absent when the "
-        "device kind has no known peak — CPU runs read "
-        "measured_flops_per_sec instead)", ("model",))
-
-
-class _MfuMeter:
-    """Live measured-performance gauges for :func:`run_fit_loop`.
-
-    Combines the guarded train step's cost-analysis FLOPs
-    (``compiled_flops{fn}``, recorded by ``util.xla.retrace_guard`` at
-    compile time) with wall time between dispatches into
-    ``measured_flops_per_sec{model}`` and — when the chip's peak is known
-    — ``measured_mfu{model}``. The first dispatch (the compiling one)
-    only anchors the clock: its wall time is compile, not compute.
-    Unknown peaks (CPU) degrade to the flops/sec gauge; an unguarded step
-    override (no compiled_flops series) records nothing.
-    """
-
-    def __init__(self, model_label: str, registry=None):
-        from . import profiling as _profiling
-        from . import xla as _xla
-        self.model_label = model_label
-        self._flops = _xla.compiled_flops_gauge(registry)
-        self._rate = measured_flops_gauge(registry)
-        self._mfu = measured_mfu_gauge(registry)
-        try:
-            self._peak = _profiling.peak_flops_per_sec()
-        except Exception:
-            self._peak = None
-        self._t0: Optional[float] = None
-        self._total = 0.0
-
-    def on_dispatch(self, kind: str) -> None:
-        fn = (f"{self.model_label}.train_scan" if kind == "scan"
-              else f"{self.model_label}.train_step")
-        now = time.perf_counter()
-        if self._t0 is None:
-            self._t0 = now
-            return
-        flops = self._flops.value(fn=fn)
-        if not flops:
-            return
-        self._total += flops
-        elapsed = now - self._t0
-        if elapsed <= 0:
-            return
-        rate = self._total / elapsed
-        self._rate.set(rate, model=self.model_label)
-        if self._peak:
-            self._mfu.set(rate / self._peak, model=self.model_label)
+def step_phase_histogram(registry=None) -> _metrics.Histogram:
+    return _reg(registry).histogram(
+        "fit_step_phase_seconds",
+        "One fit() step split where it happens: dispatch (fit_batch / "
+        "fit_scan returns), device_wait (blocked on the oldest in-flight "
+        "step), hooks (flight record, fault seam, session)",
+        ("model", "phase"), buckets=_GAP_BUCKETS)
 
 
 # ----------------------------------------------------------------------
@@ -437,10 +386,6 @@ class _StagedStream:
 
     def _stage_one(self, batch: Tuple) -> Tuple:
         import jax
-        span = (self.tracer.start("ingest.stage",
-                                  attributes={"stage": self.stage_name})
-                if self.tracer is not None else None)
-        t0 = time.perf_counter()
         host_bytes = 0
 
         def put_el(el):
@@ -453,20 +398,19 @@ class _StagedStream:
                 host_bytes += int(getattr(el, "nbytes", 0))
             return jax.device_put(el, self.device)
 
-        staged = tuple(put_el(el) for el in batch)
-        # wait for the DMA here, on the producer thread — that wait IS the
-        # overlap with the consumer's in-flight step
-        for leaf in jax.tree_util.tree_leaves(staged):
-            if hasattr(leaf, "block_until_ready"):
-                leaf.block_until_ready()
-        dt = time.perf_counter() - t0
-        self._seconds.inc(dt, stage=self.stage_name)
+        with region("ingest.stage", tracer=self.tracer,
+                    attributes={"stage": self.stage_name}) as staging:
+            staged = tuple(put_el(el) for el in batch)
+            # wait for the DMA here, on the producer thread — that wait
+            # IS the overlap with the consumer's in-flight step
+            for leaf in jax.tree_util.tree_leaves(staged):
+                if hasattr(leaf, "block_until_ready"):
+                    leaf.block_until_ready()
+            staging.set_attribute("bytes", host_bytes)
+        self._seconds.inc(staging.seconds, stage=self.stage_name)
         if host_bytes:
             self._bytes.inc(host_bytes, stage=self.stage_name)
         self._staged.inc(stage=self.stage_name)
-        if span is not None:
-            span.attributes["bytes"] = host_bytes
-            span.end()
         return staged
 
     def _producer(self) -> None:
@@ -614,9 +558,11 @@ def run_fit_loop(net, data, labels, mask, epochs: int,
 
     Observability riders: every dispatched step lands a ``train_step``
     flight-recorder event (the black box a watchdog/preemption dump
-    replays); a :class:`_MfuMeter` keeps ``measured_mfu{model}`` /
-    ``measured_flops_per_sec{model}`` live from the compiled step's
-    cost-analysis FLOPs; and ``DL4JTPU_PROFILE_STEPS=start:stop[:dir]``
+    replays); each step runs under a ``fit.step`` profiler step
+    annotation with its phases timed into
+    ``fit_step_phase_seconds{model, phase}`` (the wait for the next batch
+    between steps stays ``fit_host_gap_seconds``); and
+    ``DL4JTPU_PROFILE_STEPS=start:stop[:dir]``
     brackets exactly that dispatch range (0-based, stop-exclusive,
     counted across epochs within this call) with a ``jax.profiler``
     capture — the in-flight window is drained before the profiler stops,
@@ -637,9 +583,16 @@ def run_fit_loop(net, data, labels, mask, epochs: int,
         k = 0
     elif net.listeners and coalesce is None:
         k = 0
+    from jax.profiler import StepTraceAnnotation
+
     from . import profiling as _profiling
     gap_hist = host_gap_histogram()
-    meter = _MfuMeter(model_label)
+    phase_hist = step_phase_histogram()
+
+    def phase(name: str) -> region:
+        return region(f"fit.{name}", phase_hist, model=model_label,
+                      phase=name)
+
     profile_range = _profiling.profile_steps_env()
     capture = (_profiling.StepCapture(profile_range[2])
                if profile_range is not None else None)
@@ -668,50 +621,70 @@ def run_fit_loop(net, data, labels, mask, epochs: int,
                                tracer=getattr(net, "ingest_tracer", None))
                 source = staged
             n_batches = 0
-            t_prev = None
+            gap = None           # seconds the last next() of the source took
             stopped = False
+            batches = iter(coalesced(source, k))
             try:
-                for kind, payload in coalesced(source, k):
-                    t_now = time.perf_counter()
-                    if t_prev is not None:
-                        gap_hist.observe(t_now - t_prev, model=model_label)
-                    if (capture is not None and not capture.active
-                            and dispatch_idx == profile_range[0]):
-                        capture.start()
-                    _flight.record(
-                        "train_step", model=model_label,
-                        epoch=net.epoch_count,
-                        iteration=net.iteration_count, dispatch=kind,
-                        host_gap_s=(round(t_now - t_prev, 6)
-                                    if t_prev is not None else None))
-                    _faults.check("training.step", {
-                        "model": model_label, "epoch": net.epoch_count,
-                        "iteration": net.iteration_count, "kind": kind})
-                    if kind == "scan":
-                        xs, ys = payload
-                        window.push(net.fit_scan(xs, ys))
-                        consumed = int(xs.shape[0])
-                    else:
-                        window.push(net.fit_batch(*payload))
-                        consumed = 1
-                    meter.on_dispatch(kind)
-                    dispatch_idx += 1
-                    if (capture is not None and capture.active
-                            and dispatch_idx >= profile_range[1]):
-                        # the bracketed steps' device work must land
-                        # inside the capture, not after it
-                        window.drain()
-                        capture.stop()
-                    n_batches += consumed
-                    if session is not None and not session.on_step(
-                            net, consumed):
-                        # clean stop (preemption / max_steps): every
-                        # dispatched step must land before the caller
-                        # checkpoints the stop instant
-                        window.drain()
-                        stopped = True
+                while True:
+                    # rebinding `payload` lets go of the previous batch's
+                    # device buffers: part of the gap, as it always was
+                    with region("fit.source_wait") as wait:
+                        kind, payload = next(batches, (None, None))
+                    if kind is None:
                         break
-                    t_prev = time.perf_counter()
+                    if n_batches:
+                        # the epoch's first fetch starts the producer and
+                        # is not a gap between two dispatches
+                        gap = wait.seconds
+                        gap_hist.observe(gap, model=model_label)
+                    with StepTraceAnnotation("fit.step",
+                                             step_num=net.iteration_count):
+                        with phase("hooks"):
+                            if (capture is not None and not capture.active
+                                    and dispatch_idx == profile_range[0]):
+                                capture.start()
+                            _flight.record(
+                                "train_step", model=model_label,
+                                epoch=net.epoch_count,
+                                iteration=net.iteration_count,
+                                dispatch=kind,
+                                host_gap_s=(None if gap is None
+                                            else round(gap, 6)))
+                            _faults.check("training.step", {
+                                "model": model_label,
+                                "epoch": net.epoch_count,
+                                "iteration": net.iteration_count,
+                                "kind": kind})
+                        with phase("dispatch"):
+                            if kind == "scan":
+                                xs, ys = payload
+                                token = net.fit_scan(xs, ys)
+                                consumed = int(xs.shape[0])
+                            else:
+                                token = net.fit_batch(*payload)
+                                consumed = 1
+                        with phase("device_wait"):
+                            window.push(token)
+                        dispatch_idx += 1
+                        n_batches += consumed
+                        if (capture is not None and capture.active
+                                and dispatch_idx >= profile_range[1]):
+                            # the bracketed steps' device work must land
+                            # inside the capture, not after it
+                            with phase("device_wait"):
+                                window.drain()
+                            capture.stop()
+                        with phase("hooks"):
+                            carry_on = (session is None
+                                        or session.on_step(net, consumed))
+                        if not carry_on:
+                            # clean stop (preemption / max_steps): every
+                            # dispatched step must land before the caller
+                            # checkpoints the stop instant
+                            with phase("device_wait"):
+                                window.drain()
+                            stopped = True
+                            break
             finally:
                 if staged is not None:
                     staged.close()

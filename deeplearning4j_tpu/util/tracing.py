@@ -23,6 +23,13 @@ extracted :class:`SpanContext` is a valid ``parent=`` for
 :mod:`deeplearning4j_tpu.util.timeline` merges the per-process exports
 into one fleet/request timeline.
 
+Timing a phase: :class:`region` is the ONE way the program times a
+phase. One pair of clock reads feeds up to three sinks — a
+``jax.profiler.TraceAnnotation`` (so the phase lands on the profiler's
+``/host:CPU`` plane on the device trace's own clock; a no-op while no
+profiler session runs), a histogram series, and a :class:`Span` of a
+:class:`Tracer`. ``Tracer.span()`` is a region with only the third sink.
+
 Chaos-test integration: entering ``span()`` stamps the active span into
 the :mod:`deeplearning4j_tpu.util.faults` seam context, so a scripted
 fault records WHICH span it landed in (``FaultPlan.trigger_context``) —
@@ -47,6 +54,7 @@ import os
 import random
 import re
 import socket
+import sys
 import threading
 import time
 import weakref
@@ -164,17 +172,20 @@ def env_context() -> Optional[SpanContext]:
 
 class Span:
     """One timed operation. ``start_unix`` is wall time (for humans and
-    cross-process alignment); durations come from the monotonic clock.
-    ``host``/``pid`` name the process that produced the span, so merged
-    multi-process timelines keep their provenance."""
+    cross-process alignment); ``start_mono`` is ``time.monotonic()`` at
+    the same instant — the clock of ``DecodeRequest.t_*`` and of a
+    benchmark's window — and durations come from it. ``host``/``pid``
+    name the process that produced the span, so merged multi-process
+    timelines keep their provenance."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attributes",
-                 "start_unix", "_start_mono", "duration_ms", "status",
+                 "start_unix", "start_mono", "duration_ms", "status",
                  "host", "pid", "_tracer")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str],
-                 attributes: Optional[Dict[str, Any]] = None):
+                 attributes: Optional[Dict[str, Any]] = None,
+                 start_mono: Optional[float] = None):
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -184,7 +195,8 @@ class Span:
         self.pid = os.getpid()
         self.attributes: Dict[str, Any] = dict(attributes or {})
         self.start_unix = time.time()
-        self._start_mono = time.perf_counter()
+        self.start_mono = (time.monotonic() if start_mono is None
+                           else start_mono)
         self.duration_ms: Optional[float] = None
         self.status = "ok"
 
@@ -192,11 +204,15 @@ class Span:
         self.attributes[key] = value
         return self
 
-    def end(self, status: Optional[str] = None) -> None:
-        """Close the span (idempotent) and hand it to the tracer."""
+    def end(self, status: Optional[str] = None,
+            end_mono: Optional[float] = None) -> None:
+        """Close the span (idempotent) and hand it to the tracer.
+        ``end_mono`` is a ``time.monotonic()`` the caller already read."""
         if self.duration_ms is not None:
             return
-        self.duration_ms = (time.perf_counter() - self._start_mono) * 1000.0
+        if end_mono is None:
+            end_mono = time.monotonic()
+        self.duration_ms = (end_mono - self.start_mono) * 1000.0
         if status is not None:
             self.status = status
         self._tracer._finish(self)
@@ -206,14 +222,12 @@ class Span:
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "name": self.name}
 
-    def traceparent(self) -> str:
-        return inject(self)
-
     def to_dict(self) -> Dict[str, Any]:
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
                 "host": self.host, "pid": self.pid,
                 "start_unix": self.start_unix,
+                "start_mono": self.start_mono,
                 "duration_ms": self.duration_ms, "status": self.status,
                 "attributes": self.attributes}
 
@@ -249,7 +263,8 @@ class Tracer:
     # -- creation ------------------------------------------------------
 
     def start(self, name: str, parent: Optional[Any] = None,
-              attributes: Optional[Dict[str, Any]] = None) -> Span:
+              attributes: Optional[Dict[str, Any]] = None,
+              start_mono: Optional[float] = None) -> Span:
         """Explicit-lifetime span (cross-thread safe): caller must call
         ``span.end()``. Defaults the parent to this thread's active span.
         ``parent`` may be a :class:`Span` or an extracted
@@ -258,40 +273,32 @@ class Tracer:
             parent = self.current()
         trace_id = parent.trace_id if parent else _new_trace_id()
         return Span(self, name, trace_id,
-                    parent.span_id if parent else None, attributes)
+                    parent.span_id if parent else None, attributes,
+                    start_mono)
 
     def span(self, name: str, parent: Optional[Any] = None,
-             attributes: Optional[Dict[str, Any]] = None):
-        """Context manager: starts a span, makes it this thread's active
-        span (and the fault-seam context), ends it on exit — status
-        "error" if the block raised."""
-        tracer = self
-        s = self.start(name, parent, attributes)
-
-        class _Ctx:
-            def __enter__(self):
-                tracer._active.stack.append(s)
-                return s
-
-            def __exit__(self, exc_type, exc, tb):
-                stack = tracer._active.stack
-                if stack and stack[-1] is s:
-                    stack.pop()
-                s.end("error" if exc_type is not None else None)
-                return False
-
-        return _Ctx()
+             attributes: Optional[Dict[str, Any]] = None) -> "region":
+        """Context manager yielding the :class:`Span`: a :class:`region`
+        whose span is this thread's active span (and the fault-seam
+        context) inside the block — status "error" if the block raised."""
+        return _SpanRegion(name, tracer=self, parent=parent,
+                           attributes=attributes)
 
     def record(self, name: str, seconds: float,
                parent: Optional[Any] = None,
-               attributes: Optional[Dict[str, Any]] = None) -> Span:
+               attributes: Optional[Dict[str, Any]] = None,
+               status: Optional[str] = None) -> Span:
         """An already-finished span of explicit duration ending NOW —
-        for phases whose boundaries were measured inline (a poll loop's
-        successful tail) rather than wrapped in a context manager."""
+        for phases measured elsewhere (a :class:`region`'s ``seconds``
+        shared by several requests, a poll loop's successful tail); both
+        start stamps are back-dated by the duration."""
         s = self.start(name, parent, attributes)
         seconds = max(0.0, float(seconds))
         s.start_unix -= seconds
+        s.start_mono -= seconds
         s.duration_ms = seconds * 1000.0
+        if status is not None:
+            s.status = status
         self._finish(s)
         return s
 
@@ -339,9 +346,98 @@ class Tracer:
                 f.write(json.dumps(s.to_dict()) + "\n")
         return len(spans)
 
-    def clear(self) -> None:
-        with self._lock:
-            self._finished.clear()
+
+# ---------------------------------------------------------------------------
+# region: the one way the program times a phase
+# ---------------------------------------------------------------------------
+
+_trace_annotation = None     # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _annotation(name: str):
+    """A profiler host span for ``name``, or None while this process has
+    not imported JAX (then no profiler session can be running, and this
+    module must not be the one to import it)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
+class region:
+    """``with region(name, ...) as r:`` times the block with ONE pair of
+    ``time.monotonic()`` reads (``r.seconds`` afterwards) and feeds:
+
+    - a ``jax.profiler.TraceAnnotation(name)``: while a profiler session
+      runs the phase is a host span on the device trace's own clock, so
+      an idle gap of the device can be named by what the host was doing;
+      with no session it costs well under a microsecond;
+    - ``hist.observe(seconds, **labels)`` when a histogram is passed —
+      only if the block did not raise (a failed phase is not a sample of
+      that phase's duration);
+    - a :class:`Span` (``r.span``) when a ``tracer`` is passed: child of
+      ``parent``, else of the thread's active span, else a new trace. It
+      is the thread's active span inside the block and ends with status
+      "error" if the block raised.
+    """
+
+    __slots__ = ("name", "hist", "labels", "tracer", "parent",
+                 "attributes", "span", "seconds", "_t0", "_ann")
+
+    def __init__(self, name: str, hist=None, *,
+                 tracer: Optional["Tracer"] = None,
+                 parent: Optional[Any] = None,
+                 attributes: Optional[Dict[str, Any]] = None, **labels):
+        self.name = name
+        self.hist = hist
+        self.labels = labels
+        self.tracer = tracer
+        self.parent = parent
+        self.attributes = attributes
+        self.span: Optional[Span] = None
+        self.seconds = 0.0
+
+    def set_attribute(self, key: str, value: Any) -> None:
+        if self.span is not None:
+            self.span.attributes[key] = value
+
+    def __enter__(self) -> "region":
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        if self.tracer is not None:
+            self.span = self.tracer.start(self.name, self.parent,
+                                          self.attributes, self._t0)
+            self.tracer._active.stack.append(self.span)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.monotonic()
+        self.seconds = t1 - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self.span is not None:
+            stack = self.tracer._active.stack
+            if stack and stack[-1] is self.span:
+                stack.pop()
+            self.span.end("error" if exc_type is not None else None, t1)
+        if self.hist is not None and exc_type is None:
+            self.hist.observe(self.seconds, **self.labels)
+        return False
+
+
+class _SpanRegion(region):
+    """What ``Tracer.span()`` returns: a region that yields its span."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> Span:
+        super().__enter__()
+        return self.span
 
 
 # ---------------------------------------------------------------------------
@@ -372,10 +468,6 @@ def active_span() -> Optional[Span]:
 # The process-default tracer: components take ``tracer=None`` and fall
 # back to it, so one export shows the whole process.
 TRACER = Tracer()
-
-
-def default_tracer() -> Tracer:
-    return TRACER
 
 
 def _seam_context() -> Dict[str, Any]:

@@ -55,12 +55,16 @@ def _post(url):
 
 
 # ----------------------------------------------------------------------
-# compiled-cost metrics + measured MFU
+# compiled-cost metrics + the fit step's phases
 # ----------------------------------------------------------------------
 
 class TestCompiledCostMetrics:
-    def test_fit_records_compile_time_flops_and_live_gauges(self):
+    def test_fit_records_compile_time_flops_and_step_phases(self):
         net = _small_mln()
+        from deeplearning4j_tpu.util.ingest import step_phase_histogram
+        phases = step_phase_histogram()
+        before = {p: phases.count(model="MultiLayerNetwork", phase=p)
+                  for p in ("hooks", "dispatch", "device_wait")}
         net.fit(_batches(6))
 
         hist = _metrics.REGISTRY.get("xla_compile_seconds")
@@ -74,16 +78,19 @@ class TestCompiledCostMetrics:
         bytes_g = _metrics.REGISTRY.get("compiled_bytes")
         assert bytes_g.value(fn="MultiLayerNetwork.train_step") > 0
 
-        # the live measured gauge: CPU has no published peak, so
-        # measured_mfu degrades to a flops/sec series (the family is
-        # still registered — the acceptance surface exists everywhere)
-        rate = _metrics.REGISTRY.get("measured_flops_per_sec")
-        assert rate is not None
-        assert rate.value(model="MultiLayerNetwork") > 0
-        mfu_g = _metrics.REGISTRY.get("measured_mfu")
-        assert mfu_g is not None
-        assert not [s for s in mfu_g.snapshot()["series"]
-                    if s["labels"].get("model") == "MultiLayerNetwork"]
+        # the step's phases, timed where they happen: one dispatch and
+        # one wait on the in-flight window a step, hooks before and after
+        # it. The live measured_mfu / measured_flops_per_sec gauges are
+        # gone: they divided compiled_flops, which does not see inside a
+        # Mosaic call, by wall time on every dispatch (PERF.md section 3)
+        after = {p: phases.count(model="MultiLayerNetwork", phase=p)
+                 for p in before}
+        assert after["dispatch"] - before["dispatch"] == 6
+        assert after["device_wait"] - before["device_wait"] == 6
+        assert after["hooks"] - before["hooks"] == 12
+        assert phases.sum(model="MultiLayerNetwork", phase="dispatch") > 0
+        assert _metrics.REGISTRY.get("measured_mfu") is None
+        assert _metrics.REGISTRY.get("measured_flops_per_sec") is None
 
     def test_compile_flight_event_recorded(self):
         net = _small_mln(seed=11)
@@ -105,7 +112,7 @@ class TestCompiledCostMetrics:
     def test_inference_server_metrics_exposition(self):
         """Acceptance: GET /metrics on a live InferenceServer (aggregating
         into the process registry) shows xla_compile_seconds,
-        compiled_flops, and — after a fit — the measured gauges."""
+        compiled_flops, and — after a fit — the step's phases."""
         from deeplearning4j_tpu.serving.server import InferenceServer
 
         net = _small_mln(seed=23)
@@ -118,9 +125,8 @@ class TestCompiledCostMetrics:
             assert "xla_compile_seconds_bucket{" in body
             assert 'compiled_flops{fn="MultiLayerNetwork.train_step"}' \
                 in body
-            assert "# TYPE measured_mfu gauge" in body
-            assert ('measured_flops_per_sec{model="MultiLayerNetwork"}'
-                    in body)
+            assert ('fit_step_phase_seconds_count{model="MultiLayerNetwork"'
+                    ',phase="device_wait"}' in body)
             assert "# TYPE device_memory_bytes gauge" in body
         finally:
             server.stop(drain=False)
