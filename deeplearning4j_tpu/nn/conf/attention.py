@@ -227,10 +227,14 @@ class SelfAttentionLayer(Layer):
         """Paged-arena streaming decode (the serving continuous-batching
         path): K/V live in shared ``[num_pages, page_size, h, d]`` block
         pools instead of a per-sequence dense cache; each lane's page
-        table reassembles its window by gather. The math mirrors
-        :meth:`_apply_streaming` exactly — ``tests/test_decode.py`` pins
-        greedy decode through the arena bit-exact against the dense
-        full-cache path for sequences within the window. Sliding-window
+        table names its window, which ``ops.paged_attention.
+        paged_read_attention`` reads a chunk of pages at a time, as far
+        as the furthest live position of the dispatch, under a running
+        softmax. Against :meth:`_apply_streaming`: the same keys in the
+        same dtypes, the float32 sums formed chunk by chunk, so logits
+        agree to rounding and ``tests/test_decode.py`` pins the GREEDY
+        TOKENS through the arena equal to the dense full-cache path for
+        sequences within the window. Sliding-window
         overflow is PAGE eviction, done host-side by the serving engine
         (page table shifts, ``rel_pos`` stays put); positions stay
         global throughout, but past the window the paged and dense
@@ -242,8 +246,7 @@ class SelfAttentionLayer(Layer):
         rel_pos: ``[S]`` view-relative position of the first new query.
         Returns ``(out, k_pool, v_pool)``.
         """
-        from ...ops.paged_attention import (paged_attention, paged_gather,
-                                            paged_write)
+        from ...ops.paged_attention import paged_read_attention, paged_write
         policy = policy or _dtypes.default_policy()
         xc, wqkv = policy.cast_to_compute(x, params["Wqkv"])
         b, t_new, f = xc.shape
@@ -253,10 +256,9 @@ class SelfAttentionLayer(Layer):
             q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         k_pool = paged_write(k_pool, k_new, page_table, write_slots)
         v_pool = paged_write(v_pool, v_new, page_table, write_slots)
-        kh = paged_gather(k_pool, page_table)
-        vh = paged_gather(v_pool, page_table)
         scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
-        att = paged_attention(q, kh, vh, rel_pos, scale)
+        att = paged_read_attention(q, k_pool, v_pool, page_table, rel_pos,
+                                   scale)
         with jax.named_scope("attn.out"):
             wo = params["Wo"].astype(att.dtype)
             out = (att.reshape(b, t_new, f) @ wo

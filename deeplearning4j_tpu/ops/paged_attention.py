@@ -1,18 +1,26 @@
-"""Paged KV-cache attention primitives (gather/scatter, pure XLA).
+"""Paged KV-cache attention primitives (scatter, chunked gather, pure XLA).
 
 vLLM-style block cache (PAPERS: PagedAttention/SOSP'23) for the
 continuous-batching decode path: per-layer K/V live in preallocated
 ``[num_pages, page_size, heads, head_dim]`` block pools; each sequence
 owns an ordered *page table* of physical page ids. A decode step scatters
-the new tokens' K/V into the pools at (page, offset) and gathers each
-sequence's pages back into a contiguous ``[window, heads, head_dim]``
-view — the gathered view IS the dense streaming cache reassembled, so the
-attention math here mirrors ``SelfAttentionLayer._apply_streaming`` term
-for term and greedy decode through the arena is bit-exact against the
-dense full-cache path for sequences within the window (the parity suite
-in ``tests/test_decode.py`` pins it; past the window the paths evict at
-different granularity — a page here, a token there — and diverge by
-design).
+the new tokens' K/V into the pools at (page, offset) and then reads them
+back through :func:`paged_read_attention`, which walks the page table a
+chunk of pages at a time and keeps a running softmax. The walk stops at
+the furthest live position of the dispatch (``max(rel_pos) + t_new``,
+read on the device), so the empty tail of the window is never gathered.
+
+What holds against the dense streaming cache
+(``SelfAttentionLayer._apply_streaming``): the same keys take part
+(every key the causal window admits, and no other), in the same dtypes
+(logits, max-subtracted exp and sums in float32, the pools' own dtype
+for K/V), with the same conventions for a fully masked row (``m_safe``,
+the ``1e-30`` floor). The float32 sums are formed chunk by chunk rather
+than over the whole window at once, so logits differ from the dense
+path by rounding, not bit for bit; GREEDY TOKENS are equal on the
+parity suite in ``tests/test_decode.py`` for sequences within the
+window (past the window the paths evict at different granularity — a
+page here, a token there — and diverge by design).
 
 Layout conventions (shared with ``serving/kv_cache.py`` and
 ``serving/decode.py``):
@@ -27,9 +35,10 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
   (the page table shifts, ``base`` advances) — positions stay global, and
   the causal mask below automatically hides a recycled page's stale tail.
 
-Everything is plain gather/scatter + einsum: XLA lowers it well on both
-the CPU test mesh and TPU, and there is no dynamic shape anywhere — the
-scheduler can admit/retire sequences every step without retracing.
+Every SHAPE is static (tables stay ``[lanes, pages_per_seq]``, a chunk
+is :data:`READ_CHUNK_TOKENS` tokens), so the scheduler admits and
+retires sequences every step without retracing; only the read loop's
+trip count is data (:func:`read_trip_count`, a ``while`` on the device).
 
 Each primitive runs under a ``jax.named_scope`` (``attn.paged_write``,
 ``attn.paged_gather``, ``attn.paged_softmax``): metadata only, so that a
@@ -41,7 +50,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["paged_write", "paged_gather", "paged_attention"]
+__all__ = ["paged_write", "paged_gather", "paged_read_attention",
+           "read_chunk_pages", "read_trip_count", "READ_CHUNK_TOKENS"]
+
+# tokens of K/V one trip of the read loop gathers (a whole number of
+# pages; the whole table where the window is shorter)
+READ_CHUNK_TOKENS = 128
 
 
 @jax.named_scope("attn.paged_write")
@@ -115,13 +129,14 @@ def _paged_write_q8(pool, new, page_table, write_slots):
 
 @jax.named_scope("attn.paged_gather")
 def paged_gather(pool, page_table):
-    """Gather each lane's pages into a contiguous view.
+    """Gather pages into a contiguous view: the read loop's gather, one
+    chunk of each lane's table at a time.
 
     pool: ``[num_pages, page_size, h, d]`` (or the int8
     ``(q, scales)`` tuple — dequantized here, the one place reads
     happen); page_table: ``[S, P]`` → ``[S, P·page_size, h, d]``.
     Sentinel entries read as zeros (masked by the causal window in
-    :func:`paged_attention` anyway).
+    :func:`paged_read_attention` anyway).
     """
     if isinstance(pool, tuple):
         q, scales = pool
@@ -129,37 +144,94 @@ def paged_gather(pool, page_table):
         sc = jnp.take(scales, page_table, axis=0,
                       mode="fill", fill_value=0.0)            # [S, P, h]
         g = g.astype(jnp.float32) * sc[:, :, None, :, None]
-        s, p, page_size, h, d = g.shape
-        return g.reshape(s, p * page_size, h, d)
-    g = jnp.take(pool, page_table, axis=0, mode="fill", fill_value=0)
+    else:
+        g = jnp.take(pool, page_table, axis=0, mode="fill", fill_value=0)
     s, p, page_size, h, d = g.shape
     return g.reshape(s, p * page_size, h, d)
 
 
-@jax.named_scope("attn.paged_softmax")
-def paged_attention(q, k_view, v_view, rel_pos, scale):
-    """Causal attention of new queries over the gathered paged view.
+def read_chunk_pages(page_size: int, pages_per_seq: int) -> int:
+    """Pages one trip of the read loop gathers."""
+    return min(max(1, READ_CHUNK_TOKENS // page_size), pages_per_seq)
 
-    The EXACT streaming-decode softmax math from
+
+def read_trip_count(rel_pos, t_new: int, page_size: int,
+                    pages_per_seq: int, xp=jnp):
+    """Chunks the read of one dispatch visits: up to the furthest live
+    position ``max(rel_pos) + t_new`` over its lanes, clamped to the
+    table. ``xp`` is ``jnp`` inside the program and ``numpy`` where the
+    engine counts the same number on the host."""
+    cp = read_chunk_pages(page_size, pages_per_seq)
+    chunk = cp * page_size
+    n_chunks = -(-pages_per_seq // cp)
+    return xp.minimum((xp.max(rel_pos) + t_new + chunk - 1) // chunk,
+                      n_chunks)
+
+
+@jax.jit      # every layer of a program reads at the same shapes: traced once
+def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
+    """Causal attention of new queries over each lane's paged window,
+    read only as far as the furthest live position of the dispatch.
+
+    The streaming-decode softmax of
     ``SelfAttentionLayer._apply_streaming`` (max-subtraction in f32,
-    masked exp, 1e-30 denominator floor) — kept identical on purpose so
-    the paged path is bit-exact against the dense cache.
+    masked exp, 1e-30 denominator floor) as a running softmax over
+    chunks of :func:`read_chunk_pages` pages: trip ``c`` gathers
+    ``page_table[:, c·cp:(c+1)·cp]`` of K and of V, masks that chunk's
+    logits by ``key_idx <= rel_pos + query offset`` and folds them into
+    the running max ``m``, sum ``l`` and accumulator. The trip count is
+    :func:`read_trip_count`, so the loop is a ``while`` and the chunks
+    past the furthest lane are never gathered; a key the mask hid
+    contributed exactly 0 before and is simply not read now.
 
-    q: ``[S, t_new, h, d]`` (compute dtype); k_view/v_view:
-    ``[S, W, h, d]`` (cache dtype); rel_pos: ``[S]`` view-relative
-    position of each lane's FIRST new query (``global_pos - base``).
-    Returns ``[S, t_new, h, d]``.
+    q: ``[S, t_new, h, d]`` (compute dtype); k_pool/v_pool:
+    ``[num_pages, page_size, h, d]`` (or int8 ``(q, scales)`` tuples);
+    page_table: ``[S, P]``; rel_pos: ``[S]`` view-relative position of
+    each lane's FIRST new query (``global_pos - base``). Returns
+    ``[S, t_new, h, d]``.
     """
-    t_new = q.shape[1]
-    w = k_view.shape[1]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view) * scale
-    key_idx = jnp.arange(w)
+    codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
+    num_pages, page_size = codes.shape[0], codes.shape[1]
+    kv_dtype = jnp.float32 if isinstance(k_pool, tuple) else codes.dtype
+    out_dtype = jnp.result_type(q.dtype, kv_dtype)
+    s, t_new, h, d = q.shape
+    pages_per_seq = page_table.shape[1]
+    cp = read_chunk_pages(page_size, pages_per_seq)
+    chunk = cp * page_size
+    pad = -pages_per_seq % cp
+    if pad:     # a last, partial chunk reads sentinel pages: zeros, masked
+        page_table = jnp.pad(page_table, ((0, 0), (0, pad)),
+                             constant_values=num_pages)
+    trips = read_trip_count(rel_pos, t_new, page_size, pages_per_seq)
     q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]     # [S, t_new]
-    allow = key_idx[None, None, :] <= q_idx[:, :, None]       # [S, t_new, W]
-    logits = jnp.where(allow[:, None], logits.astype(jnp.float32),
-                       -jnp.inf)
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
-    p = jnp.where(jnp.isneginf(logits), 0.0, jnp.exp(logits - m_safe))
-    weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v_view)
+
+    def fold(c, carry):
+        m, l, acc = carry
+        table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
+                                               axis=1)
+        k_c = paged_gather(k_pool, table_c)                   # [S, chunk, h, d]
+        v_c = paged_gather(v_pool, table_c)
+        with jax.named_scope("attn.paged_softmax"):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_c) * scale
+            key_idx = c * chunk + jnp.arange(chunk)
+            allow = key_idx[None, None, :] <= q_idx[:, :, None]
+            logits = jnp.where(allow[:, None], logits.astype(jnp.float32),
+                               -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+            m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+            p = jnp.where(jnp.isneginf(logits), 0.0,
+                          jnp.exp(logits - m_safe))
+            alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v_c)
+            acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
+        return m_new, l, acc
+
+    init = (jnp.full((s, h, t_new, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((s, h, t_new, 1), jnp.float32),
+            jnp.zeros((s, t_new, h, d),
+                      jnp.promote_types(out_dtype, jnp.float32)))
+    _, l, acc = jax.lax.fori_loop(0, trips, fold, init)
+    with jax.named_scope("attn.paged_softmax"):
+        out = acc / jnp.swapaxes(jnp.maximum(l, 1e-30), 1, 2)
+        return out.astype(out_dtype)

@@ -53,11 +53,12 @@ per-step math is identical; the verify chunk equals sequential feeding
 the same way multi-chunk prefill does) — ``tests/test_fused_decode.py``
 pins fused == ticked == oracle and speculative == target-only.
 
-Greedy output through this path is BIT-EXACT against the single-sequence
-full-cache oracle (``models.transformer.generate``) for every sequence
-that stays within the window (prompt + generated ≤ page_size ×
-pages_per_seq): the paged gather reassembles the same dense window the
-oracle's streaming cache holds, and both paths share ``sample_token``.
+Greedy TOKENS through this path equal the single-sequence full-cache
+oracle's (``models.transformer.generate``) for every sequence that stays
+within the window (prompt + generated ≤ page_size × pages_per_seq): the
+paged read visits the very keys the oracle's streaming cache holds, in
+the same dtypes (its float32 sums are formed chunk by chunk, so logits
+agree to rounding), and both paths share ``sample_token``.
 ``tests/test_decode.py`` pins it. PAST the window the two legitimately
 diverge — the arena evicts a PAGE at a time while the oracle slides
 token-by-token, so their attention windows differ by up to
@@ -77,7 +78,10 @@ fetched in ``decode_d2h_bytes_total{kind}``), ``sched.tick`` ⊃
 ``sched.retire_expired`` / ``sched.admit`` / ``sched.prefill`` /
 ``sched.decode`` per tick, ``sched.wait_idle`` / ``sched.wait_blocked``
 in the loop (``decode_sched_wait_seconds{why}``); under a profiler
-session they are host spans on the device trace's clock.
+session they are host spans on the device trace's clock. How far the
+paged read of each dispatch went is counted beside what its lanes'
+whole windows hold: ``decode_kv_read_tokens_total{kind}`` over
+``decode_kv_window_tokens_total{kind}``.
 
 Fault seam: ``"serving.decode_step"`` before every prefill/decode
 dispatch (chaos tests script outages at exact step boundaries).
@@ -98,6 +102,7 @@ import numpy as np
 from ..models import transformer as _transformer
 from ..nn.conf.attention import SelfAttentionLayer
 from ..nn.conf.layers import EmbeddingSequenceLayer
+from ..ops import paged_attention as _paged
 from ..util import faults as _faults
 from ..util import flightrecorder as _flight
 from ..util import metrics as _metrics
@@ -377,6 +382,17 @@ class PagedDecodeEngine:
         self._m_d2h = self.registry.counter(
             "decode_d2h_bytes_total",
             "Bytes of dispatch outputs copied device->host", ("kind",))
+        self._m_kv_read = self.registry.counter(
+            "decode_kv_read_tokens_total",
+            "Key positions the paged read of the dispatches visited: "
+            "lanes of the bucket x the chunks up to the furthest live "
+            "position x chunk tokens, for every step of a block",
+            ("kind",))
+        self._m_kv_window = self.registry.counter(
+            "decode_kv_window_tokens_total",
+            "Key positions of the same dispatches' whole windows: lanes "
+            "of the bucket x window, for every step of a block",
+            ("kind",))
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
@@ -664,6 +680,7 @@ class PagedDecodeEngine:
         (probs,) = self._dispatch(name, step, self.arena, self.net.params,
                                   (ids, tables, write_slots, rel_pos),
                                   kind="paged")
+        self._note_kv_read("paged", rel_pos, t_new)
         return probs
 
     def _dispatch(self, name: str, step, arena, params, args: tuple, *,
@@ -753,6 +770,23 @@ class PagedDecodeEngine:
             self._m_tick.observe(wall, component="dispatch")
             self._m_d2h.inc(nbytes, kind=kind)
 
+    def _note_kv_read(self, kind: str, rel: np.ndarray, t_new: int,
+                      steps: int = 1) -> None:
+        """Account how far the paged read of one dispatch went: its
+        trip count (the very helper the program's loop bound comes from,
+        on the same ``rel``) for each of the block's ``steps``, beside
+        the whole windows the lanes hold."""
+        if self._warming:
+            return
+        chunk = self.page_size * _paged.read_chunk_pages(
+            self.page_size, self.pages_per_seq)
+        visited = sum(
+            min(self.window, chunk * int(_paged.read_trip_count(
+                rel + i, t_new, self.page_size, self.pages_per_seq, xp=np)))
+            for i in range(steps))
+        self._m_kv_read.inc(len(rel) * visited, kind=kind)
+        self._m_kv_window.inc(len(rel) * self.window * steps, kind=kind)
+
     # -- fused multi-token block --------------------------------------
 
     def run_fused(self, last: np.ndarray, tables: np.ndarray,
@@ -779,6 +813,8 @@ class PagedDecodeEngine:
             name, step, self.arena, self.net.params,
             (last, tables, rel, active, budget, eos, temps, top_k, top_p,
              uniforms), kind="fused")
+        # the block's loop ends with its last live lane
+        self._note_kv_read("fused", rel, 1, steps=int(n_emitted.max()))
         return toks, valid, n_emitted
 
     # -- speculative draft / verify -----------------------------------
@@ -801,6 +837,7 @@ class PagedDecodeEngine:
                        self.draft_net.params,
                        (ids, tables, write_slots, rel_pos),
                        kind="draft_prefill", sync=False)
+        self._note_kv_read("draft_prefill", rel_pos, t)
 
     def run_draft(self, last: np.ndarray, tables: np.ndarray,
                   rel: np.ndarray, active: np.ndarray,
@@ -824,6 +861,7 @@ class PagedDecodeEngine:
             name, step, self.draft_arena, self.draft_net.params,
             (last, tables, rel, active, write_budget, temps, top_k,
              top_p, uniforms), kind="draft", sync=False)
+        self._note_kv_read("draft", rel, 1, steps=k1)
         return d_toks, d_dists
 
     def run_verify(self, last: np.ndarray, tables: np.ndarray,
@@ -851,6 +889,7 @@ class PagedDecodeEngine:
             name, step, self.arena, self.net.params,
             (last, tables, rel, active, write_budget, d_toks, d_dists,
              temps, top_k, top_p, u_accept, u_fix), kind="verify")
+        self._note_kv_read("verify", rel, k + 1)
         return emitted, valid, accepts
 
     def warmup(self) -> None:

@@ -1,0 +1,343 @@
+"""The paged read (``ops/paged_attention.paged_read_attention``): the
+chunked, bounded read against the full-window gather + masked softmax it
+replaced, the trip count on host and device, the inner ``while`` in the
+lowered fused block, and the two counters that say how much of the window
+a dispatch visits (``paged_read_window_share`` of the benchmark)."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.models.transformer import transformer_lm
+from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
+from deeplearning4j_tpu.ops.paged_attention import (
+    READ_CHUNK_TOKENS, paged_gather, paged_read_attention, paged_write,
+    read_chunk_pages, read_trip_count)
+from deeplearning4j_tpu.serving.decode import (DecodeScheduler,
+                                               PagedDecodeEngine)
+
+H, D = 2, 8
+
+
+def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale):
+    """What ``apply_paged`` did before the bounded read: gather every
+    lane's whole table, then mask (kept here as the test's reference)."""
+    k_view = paged_gather(k_pool, page_table)
+    v_view = paged_gather(v_pool, page_table)
+    t_new, w = q.shape[1], k_view.shape[1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view) * scale
+    q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]
+    allow = jnp.arange(w)[None, None, :] <= q_idx[:, :, None]
+    logits = jnp.where(allow[:, None], logits.astype(jnp.float32), -jnp.inf)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
+    p = jnp.where(jnp.isneginf(logits), 0.0, jnp.exp(logits - m_safe))
+    weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v_view)
+
+
+def _arena(rng, lanes, page_size, pages_per_seq, live, *, int8=False):
+    """Random pools and a table whose lane ``i`` holds ``live[i]`` tokens
+    (the pages beyond are sentinels); a stale tail is left in each last
+    page, as a recycled page would have."""
+    num_pages = lanes * pages_per_seq + 3
+    k = rng.standard_normal((num_pages, page_size, H, D)).astype(np.float32)
+    v = rng.standard_normal((num_pages, page_size, H, D)).astype(np.float32)
+    table = np.full((lanes, pages_per_seq), num_pages, np.int32)
+    perm = rng.permutation(num_pages)
+    at = 0
+    for i, n in enumerate(live):
+        need = -(-int(n) // page_size)
+        table[i, :need] = perm[at:at + need]
+        at += need
+    if not int8:
+        return jnp.asarray(k), jnp.asarray(v), jnp.asarray(table)
+    pools = []
+    for x in (k, v):
+        scales = np.abs(x).max(axis=(1, 3)) / 127.0          # [pages, h]
+        codes = np.round(x / scales[:, None, :, None]).astype(np.int8)
+        pools.append((jnp.asarray(codes), jnp.asarray(scales)))
+    return pools[0], pools[1], jnp.asarray(table)
+
+
+# (page_size, pages_per_seq): two chunks of 8 pages; 16 chunks as in the
+# benchmark's window; a table that is no whole number of chunks
+GEOMETRIES = {"2chunks": (16, 16), "window2048": (16, 128),
+              "ragged_tail": (16, 20), "page_over_chunk": (256, 3)}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("t_new", [1, 5, READ_CHUNK_TOKENS])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_bounded_read_equals_full_window_read(geometry, t_new, int8):
+    """Ragged lanes: one at position 0, one whose last query sits in the
+    window's last slot, two in between."""
+    page_size, pages_per_seq = GEOMETRIES[geometry]
+    window = page_size * pages_per_seq
+    rng = np.random.default_rng(7 * t_new + pages_per_seq + int(int8))
+    rel = np.array([0, window - t_new, window // 3, 17], np.int32)
+    k, v, table = _arena(rng, 4, page_size, pages_per_seq, rel + t_new,
+                         int8=int8)
+    q = jnp.asarray(rng.standard_normal((4, t_new, H, D)), jnp.float32)
+    scale = jnp.float32(1.0 / np.sqrt(D))
+    want = full_window_read(q, k, v, table, jnp.asarray(rel), scale)
+    got = jax.jit(paged_read_attention)(q, k, v, table, jnp.asarray(rel),
+                                        scale)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("t_new", [1, 5])
+def test_window_shorter_than_one_chunk_is_one_trip(t_new):
+    page_size, pages_per_seq = 4, 8                     # window 32 < 128
+    assert read_chunk_pages(page_size, pages_per_seq) == pages_per_seq
+    rng = np.random.default_rng(t_new)
+    rel = np.array([0, 32 - t_new, 9], np.int32)
+    k, v, table = _arena(rng, 3, page_size, pages_per_seq, rel + t_new)
+    q = jnp.asarray(rng.standard_normal((3, t_new, H, D)), jnp.float32)
+    want = full_window_read(q, k, v, table, jnp.asarray(rel), 0.5)
+    got = paged_read_attention(q, k, v, table, jnp.asarray(rel), 0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    assert int(read_trip_count(jnp.asarray(rel), t_new, page_size,
+                               pages_per_seq)) == 1
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_all_padded_dispatch_reads_finite_zeros(int8):
+    """Warm-up and retired lanes: every table entry the sentinel."""
+    rng = np.random.default_rng(3)
+    k, v, table = _arena(rng, 2, 16, 16, [0, 0], int8=int8)
+    q = jnp.asarray(rng.standard_normal((2, 5, H, D)), jnp.float32)
+    out = paged_read_attention(q, k, v, table, jnp.zeros(2, jnp.int32), 0.3)
+    assert np.array_equal(np.asarray(out), np.zeros((2, 5, H, D)))
+
+
+def test_stale_keys_past_the_live_position_are_not_read():
+    """NaN in every page of the chunks beyond the furthest live lane: the
+    bounded read never gathers them, so the output is what it was (a
+    full-window read would turn it to NaN through 0 * NaN)."""
+    page_size, pages_per_seq = 16, 32                   # 4 chunks
+    rng = np.random.default_rng(11)
+    rel = np.array([100, 3], np.int32)                  # bound: chunk 0
+    k, v, table = _arena(rng, 2, page_size, pages_per_seq, [512, 512])
+    clean = paged_read_attention(
+        jnp.ones((2, 1, H, D)), k, v, table, jnp.asarray(rel), 0.2)
+    far = np.asarray(table)[:, 8:].reshape(-1)          # chunks 1..3
+    k_bad, v_bad = k.at[far].set(jnp.nan), v.at[far].set(jnp.nan)
+    out = paged_read_attention(
+        jnp.ones((2, 1, H, D)), k_bad, v_bad, table, jnp.asarray(rel), 0.2)
+    assert np.array_equal(np.asarray(out), np.asarray(clean))
+
+
+def test_write_then_read_round_trip_matches_dense_attention():
+    """Scatter 40 tokens through a shuffled table, read them back."""
+    page_size, pages_per_seq, n = 16, 16, 40
+    rng = np.random.default_rng(5)
+    k, v, table = _arena(rng, 1, page_size, pages_per_seq, [n])
+    new_k = jnp.asarray(rng.standard_normal((1, n, H, D)), jnp.float32)
+    new_v = jnp.asarray(rng.standard_normal((1, n, H, D)), jnp.float32)
+    slots = jnp.arange(n, dtype=jnp.int32)[None]
+    k = paged_write(k, new_k, table, slots)
+    v = paged_write(v, new_v, table, slots)
+    q = jnp.asarray(rng.standard_normal((1, n, H, D)), jnp.float32)
+    got = paged_read_attention(q, k, v, table, jnp.zeros(1, jnp.int32), 0.35)
+    logits = np.einsum("bqhd,bkhd->bhqk", q, new_k) * 0.35
+    logits = np.where(np.tril(np.ones((n, n), bool))[None, None], logits,
+                      -np.inf)
+    w = np.exp(logits - logits.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    want = np.einsum("bhqk,bkhd->bqhd", w, new_v)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+
+
+TRIP_CASES = [
+    # rel_pos, t_new, page_size, pages_per_seq, trips
+    ([0], 1, 16, 128, 1),
+    ([127], 1, 16, 128, 1),
+    ([128], 1, 16, 128, 2),
+    ([0, 500, 37], 1, 16, 128, 4),
+    ([0], 128, 16, 128, 1),
+    ([1], 128, 16, 128, 2),
+    ([2047], 1, 16, 128, 16),
+    ([4000], 1, 16, 128, 16),          # clamped to the table
+    ([300], 5, 16, 20, 3),             # 20 pages: chunks of 8, 8, 4
+    ([31], 1, 4, 8, 1),                # window under one chunk
+    ([600], 1, 256, 3, 3),             # a page larger than the chunk
+]
+
+
+@pytest.mark.parametrize("rel,t_new,page_size,pages_per_seq,trips",
+                         TRIP_CASES)
+def test_trip_count_same_on_host_and_device(rel, t_new, page_size,
+                                            pages_per_seq, trips):
+    rel = np.asarray(rel, np.int32)
+    host = read_trip_count(rel, t_new, page_size, pages_per_seq, xp=np)
+    dev = jax.jit(lambda r: read_trip_count(r, t_new, page_size,
+                                            pages_per_seq))(rel)
+    assert int(host) == int(dev) == trips
+
+
+# ---------------------------------------------------------------------------
+# the engine: the loop in the lowered programs, and the two counters
+# ---------------------------------------------------------------------------
+
+VOCAB = 48
+
+
+@pytest.fixture(scope="module")
+def net():
+    return ComputationGraph(transformer_lm(
+        VOCAB, n_layers=2, d_model=16, n_heads=2, d_ff=32, seed=3,
+        input_ids=True)).init()
+
+
+def _engine(net, **kw):
+    kw = {"max_batch": 2, "page_size": 16, "pages_per_seq": 16,
+          "prefill_chunk": 8, "block_len": 4, **kw}
+    return PagedDecodeEngine(net, **kw)
+
+
+@pytest.fixture(scope="module")
+def fused_text(net):
+    eng = _engine(net)
+    eng.warmup()
+    fn = next(f for key, f in eng._jit_cache.items()
+              if key.endswith("fused_decode[S1xN4xP16]"))
+    tables = np.full((1, 16), eng.arena.sentinel, np.int32)
+    zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+    return fn.__wrapped__.lower(
+        net.params, eng.arena.k_pools, eng.arena.v_pools, zi, tables, zi,
+        np.zeros(1, bool), zi, np.full(1, -1, np.int32), zf, zi,
+        np.ones(1, np.float32),
+        np.zeros((1, 4), np.float32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", ["attn.paged_gather", "attn.paged_softmax"])
+def test_fused_block_reads_inside_an_inner_while(fused_text, scope):
+    """The read is one function of the program (traced once, called by
+    every layer from inside the block's ``while``); both scopes sit in
+    the body of its own ``while``."""
+    import re
+    assert f"while/body/{scope}/" in fused_text
+    assert len(re.findall(r"func\.func private @paged_read_attention\(",
+                          fused_text)) == 1
+    assert len(re.findall(r"call @paged_read_attention\(", fused_text)) == 2
+    assert fused_text.count("stablehlo.while") == 2      # block + read
+
+
+def test_fused_block_gathers_a_chunk_never_the_window(fused_text):
+    """No gather of the program yields a whole 256-token window of K/V
+    (the chunk is 8 pages of 16): ``tensor<1x16x16x2x8`` would be it."""
+    assert "stablehlo.gather" in fused_text
+    assert "tensor<1x16x16x2x8x" not in fused_text
+    assert "tensor<1x8x16x2x8x" in fused_text
+
+
+def _serve(net, prompts, new_tokens, **kw):
+    eng = _engine(net, **kw)
+    sched = DecodeScheduler(eng, start_thread=False)
+    reqs = [sched.submit(p, n) for p, n in zip(prompts, new_tokens)]
+    for _ in range(400):
+        if all(r.done for r in reqs):
+            break
+        sched.step_once()
+    assert all(r.done for r in reqs)
+    return eng
+
+
+def _kv_counters(eng):
+    read = eng.registry.get("decode_kv_read_tokens_total")
+    window = eng.registry.get("decode_kv_window_tokens_total")
+    assert read is not None and window is not None
+    kinds = {s["labels"]["kind"] for s in read.snapshot()["series"]}
+    return ({k: read.value(kind=k) for k in kinds},
+            {k: window.value(kind=k) for k in kinds})
+
+
+@pytest.mark.parametrize("block_len,kinds", [(4, {"paged", "fused"}),
+                                             (1, {"paged"})])
+def test_short_sequences_read_a_share_of_the_window(net, block_len, kinds):
+    eng = _serve(net, [[1, 2, 3, 4, 5], [6, 7, 8]], [6, 5],
+                 block_len=block_len)
+    read, window = _kv_counters(eng)
+    assert set(read) == kinds
+    for kind in kinds:
+        # lanes at 3-11 tokens of a 256-token window: one chunk of two
+        assert 0 < read[kind] < window[kind]
+        assert read[kind] / window[kind] == pytest.approx(0.5)
+    assert eng.registry.get("decode_dispatches_total").value(
+        kind="paged") > 0
+
+
+def test_a_lane_at_the_windows_end_reads_all_of_it(net):
+    """249 prompt tokens + 7 generated fill the 256-token window: the
+    last dispatches visit both chunks."""
+    eng = _engine(net, max_batch=1, block_len=1)
+    read = eng.registry.get("decode_kv_read_tokens_total")
+    window = eng.registry.get("decode_kv_window_tokens_total")
+    tables = np.full((1, 16), eng.arena.sentinel, np.int32)
+    eng.run(np.zeros((1, 1), np.int32), np.full((1, 1), -1, np.int32),
+            np.array([255], np.int32), tables)
+    assert read.value(kind="paged") == window.value(kind="paged") == 256
+    eng.run(np.zeros((1, 1), np.int32), np.full((1, 1), -1, np.int32),
+            np.array([5], np.int32), tables)
+    assert read.value(kind="paged") == 256 + 128
+    assert window.value(kind="paged") == 512
+
+
+def test_warmup_counts_nothing(net):
+    eng = _engine(net)
+    eng.warmup()
+    read, window = _kv_counters(eng)
+    assert not any(read.values()) and not any(window.values())
+
+
+def test_speculative_kinds_are_counted(net):
+    from deeplearning4j_tpu.models.transformer import draft_transformer_lm
+    draft = ComputationGraph(draft_transformer_lm(
+        VOCAB, d_model=16, n_heads=2, d_ff=32, seed=5)).init()
+    eng = _serve(net, [[1, 2, 3, 4, 5]], [6], block_len=1, draft_net=draft,
+                 draft_k=2)
+    read, window = _kv_counters(eng)
+    assert {"paged", "draft_prefill", "draft", "verify"} <= set(read)
+    for kind in ("draft", "verify"):
+        assert 0 < read[kind] < window[kind]
+    # the draft block runs K+1 steps of the read, the verify one
+    assert window["draft"] == 3 * window["verify"]
+
+
+def test_benchmark_metric_names_counters_the_registry_has(net):
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "metrics", "paged_read_window_share.json")
+    with open(path) as f:
+        metric = json.load(f)
+    assert metric["name"] == "paged_read_window_share"
+    assert metric["layer"] == "paged read"
+    assert metric["moves"] == "serve_tokens_per_s"
+    assert metric["reader"]["kind"] == "counter_ratio"
+    eng = _serve(net, [[1, 2, 3]], [4])
+    values = {}
+    for side in ("num", "den"):
+        spec = metric["reader"][side]
+        family = eng.registry.get(spec["metric"])
+        assert family is not None, spec["metric"]
+        want = spec.get("labels", {})
+        values[side] = sum(
+            s["value"] for s in family.snapshot()["series"]
+            if all(s["labels"].get(k) == x for k, x in want.items()))
+    share = metric["reader"]["scale"] * values["num"] / values["den"]
+    assert 0 < share < 100
+    with open(os.path.join(os.path.dirname(path), "..", "..",
+                           "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == metric["name"]]
+    assert len(entry) == 1
+    assert entry[0]["workloads"] == ["serve-opt-chat", "serve-opt-docqa"]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[0][key] == metric[key]
