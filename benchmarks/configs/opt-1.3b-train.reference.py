@@ -112,13 +112,16 @@ def _diff_norms(a, b):
     return {k: jnp.sqrt(jnp.sum(jnp.square(a[k] - b[k]))) for k in a}
 
 
-def train_steps(weights, batches, *, n_layers: int, n_heads: int, lr: float,
-                mode: str = "f32", q_block: int = 1024,
-                row_block: int = 2048, token_weight=None,
-                first_grads=None, keep_first_grads: bool = False):
+def train_steps(weights, batches, *, cfg: dict, mode: str = "f32",
+                token_weight=None, first_grads=None,
+                keep_first_grads: bool = False):
     """Run ``len(batches)`` Adam steps from ``weights`` (the flat dict of
     ``lib/weights.py``; CONSUMED: its buffers are donated) on batches of
-    ``(ids [b, t], labels [b, t])``.
+    ``(ids [b, t], labels [b, t])``. Depth, heads and the learning rate are
+    the configuration's (``num_hidden_layers``, ``num_attention_heads``,
+    ``learning_rate``), and so are the sizes of the blocks it computes in
+    (``reference_q_block`` query rows, ``reference_row_block`` rows of
+    logits).
 
     ``token_weight [t]`` (default all ones) weights each position's loss:
     the planted fault "half of the batch left out, the mean over the rest"
@@ -135,6 +138,10 @@ def train_steps(weights, batches, *, n_layers: int, n_heads: int, lr: float,
     all the steps), ``grad_diff_norms`` (empty without ``first_grads``) and
     ``first_grads`` (empty unless kept).
     """
+    n_layers, n_heads = cfg["num_hidden_layers"], cfg["num_attention_heads"]
+    lr = cfg["learning_rate"]
+    q_block = cfg.get("reference_q_block", 1024)
+    row_block = cfg.get("reference_row_block", 2048)
     p = dict(weights)
     start = {k: jnp.array(v, copy=True) for k, v in p.items()}
     m = {k: jnp.zeros(v.shape, jnp.float32) for k, v in p.items()}

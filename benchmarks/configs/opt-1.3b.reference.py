@@ -147,25 +147,27 @@ def _jit_head(mode: str):
     return jax.jit(head)
 
 
-def hidden(weights, ids, *, n_layers: int, n_heads: int, mode: str = "f32",
+def hidden(weights, ids, *, cfg: dict, mode: str = "f32",
            q_block: int = 1024):
     """Residual stream after the last layer (before the final LayerNorm)
-    for one sequence of token ids ``[t]``, layer by layer."""
+    for one sequence of token ids ``[t]``, layer by layer; depth and heads
+    are the configuration's (``num_hidden_layers``,
+    ``num_attention_heads``)."""
     x = jnp.take(weights["embed"], jnp.asarray(ids, jnp.int32), axis=0)
     x = x.astype(jnp.float32)
-    f = _jit_block(n_heads, mode, min(q_block, x.shape[0]))
-    for i in range(n_layers):
+    f = _jit_block(cfg["num_attention_heads"], mode,
+                   min(q_block, x.shape[0]))
+    for i in range(cfg["num_hidden_layers"]):
         x = f(layer_params(weights, i), x)
     return x
 
 
-def logits_at(weights, ids, positions, *, n_layers: int, n_heads: int,
-              mode: str = "f32", q_block: int = 1024):
+def logits_at(weights, ids, positions, *, cfg: dict, mode: str = "f32",
+              q_block: int = 1024):
     """Next-token logits ``[len(positions), V]`` (float32) at the given
     positions of one sequence: row j rates the token FOLLOWING position
     ``positions[j]``."""
-    x = hidden(weights, ids, n_layers=n_layers, n_heads=n_heads, mode=mode,
-               q_block=q_block)
+    x = hidden(weights, ids, cfg=cfg, mode=mode, q_block=q_block)
     rows = jnp.take(x, jnp.asarray(positions, jnp.int32), axis=0)
     return _jit_head(mode)(rows, weights["lnf_g"], weights["lnf_b"],
                            weights["head_w"], weights["head_b"])

@@ -4,6 +4,7 @@ traced slice of the window."""
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import threading
@@ -28,6 +29,43 @@ def process_age_s() -> float:
 def load_json(*parts: str) -> dict:
     with open(os.path.join(BENCH, *parts)) as f:
         return json.load(f)
+
+
+def find(dirs, kind: str, filename: str) -> str:
+    """The path of ``<kind>/<filename>`` in the first of ``dirs``, each laid
+    out like ``benchmarks/``, that has it; none fails with every path
+    looked for."""
+    paths = [os.path.join(d, kind, filename) for d in dirs]
+    for path in paths:
+        if os.path.isfile(path):
+            return path
+    raise FileNotFoundError(
+        f"benchmark: no {kind}/{filename}: looked for "
+        + ", ".join(os.path.relpath(p, ROOT) for p in paths))
+
+
+def load_module(path: str, name: str):
+    """The Python file at ``path`` as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def find_module(dirs, kind: str, name: str):
+    """``<kind>/<name>.py``: a family, or a reader of a kind of its own."""
+    return load_module(find(dirs, kind, f"{name}.py"),
+                       f"bench_{kind}_{name}")
+
+
+def load_family(cfg: dict, dirs=(BENCH,)):
+    """The family file that a configuration's ``model_type`` names."""
+    return find_module(dirs, "families", cfg["model_type"])
+
+
+def load_reference(cfg: dict):
+    """The plain reference that a configuration names, beside it."""
+    return load_module(os.path.join(ROOT, cfg["reference"]), "cell_reference")
 
 
 def use_compile_cache() -> str:

@@ -23,7 +23,8 @@ def half_batch(rows):
     return rows if h == 0 else np.concatenate([rows[:h], rows[:h]])
 
 
-def reference_training(ref, cfg: dict, mix: dict, seed: int, chips: int = 1,
+def reference_training(ref, family, cfg: dict, mix: dict, seed: int,
+                       chips: int = 1,
                        mode: str = "f32", fault: str = None,
                        first_grads=None, keep_first_grads=False) -> dict:
     """The first ``check_steps`` steps by the plain reference, as the
@@ -42,23 +43,20 @@ def reference_training(ref, cfg: dict, mix: dict, seed: int, chips: int = 1,
     elif fault is not None:
         raise ValueError(f"unknown fault {fault!r}")
     return ref.train_steps(
-        weights.make_weights(cfg, seed), batches,
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"], lr=cfg["learning_rate"],
-        mode=mode, q_block=cfg.get("reference_q_block", 1024),
-        row_block=cfg.get("reference_row_block", 2048),
+        weights.make_weights(family, cfg, seed), batches, cfg=cfg, mode=mode,
         token_weight=token_weight, first_grads=first_grads,
         keep_first_grads=keep_first_grads)
 
 
-def control_against_reference(ref, cfg, mix, seed, chips=1, **control):
+def control_against_reference(ref, family, cfg, mix, seed, chips=1,
+                              **control):
     """A control (``mode="fp8"``) or a planted fault (``fault=...``) put in
     the program's place: it runs first and keeps its first gradients, then
     the float32 reference follows the same steps and both go to the
     comparison. Returns the verdict."""
-    put = reference_training(ref, cfg, mix, seed, chips,
+    put = reference_training(ref, family, cfg, mix, seed, chips,
                              keep_first_grads=True, **control)
-    sound = reference_training(ref, cfg, mix, seed, chips,
+    sound = reference_training(ref, family, cfg, mix, seed, chips,
                                first_grads=put.pop("first_grads"))
     return training_verdict(cfg, put, sound)
 

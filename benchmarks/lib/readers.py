@@ -1,22 +1,27 @@
 """Readers of the per-layer metrics, chosen by ``reader.kind`` in each
 metric's file (``metrics/<name>.json``). A later PR adds a metric by adding
-a file; it needs new code only where no kind below fits.
+a file. Where no kind below fits, the kind is a file of its own,
+``readers/<kind>.py``, with ``read(spec, run)`` and, where it needs the
+program's counters or gauges read around the window, ``wants(spec)`` and
+``gauges(spec)``.
 
 Every reader takes ``(spec, run)``: ``spec`` is the file's ``reader`` object
 and ``run`` is what the cell's runner measured: ``window_s``, ``steps`` or
 ``requests``, ``edges`` (the program's counters at the window's two edges),
 ``gauge_peaks``, ``trace`` (the reduced trace, ``--trace 1`` only), ``cfg``,
-``mix``, ``peaks``, ``chips``, ``memory_peak_bytes``, ``compiles_in_window``
-and ``flops`` (the analytic operations of the window). A reader that finds
-nothing to read returns None and the metric is left out of the line; none
-returns 0 for a share of a roofline or of a peak.
+``mix``, ``peaks``, ``chips``, ``memory_peak_bytes``, ``compiles_in_window``,
+``flops`` (the analytic operations of the window) and ``family`` (the
+configuration's family file, which a kernel's reader asks for the kernel's
+shape, operations and bytes). A reader that finds nothing to read returns
+None and the metric is left out of the line; none returns 0 for a share of
+a roofline or of a peak.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-from . import ops_count, xplane
+from . import common, ops_count, xplane
 
 
 def _path(obj: dict, dotted: str):
@@ -25,8 +30,8 @@ def _path(obj: dict, dotted: str):
     return obj
 
 
-def wants(spec: dict) -> list:
-    """The counters a metric's reader needs read at the window's edges."""
+def counters(spec: dict) -> list:
+    """The counters that the kinds below read at the window's edges."""
     return [spec[k] for k in ("counter", "num", "den")
             if isinstance(spec.get(k), dict)]
 
@@ -94,9 +99,8 @@ def flash_roofline(spec, run) -> Optional[float]:
     t = run.get("trace")
     if not t:
         return None
-    cfg, mix, peaks = run["cfg"], run["mix"], run["peaks"]
-    shape = (mix["batch"], mix["seq_len"], cfg["num_attention_heads"],
-             cfg["hidden_size"] // cfg["num_attention_heads"])
+    peaks = run["peaks"]
+    shape = run["family"].attention_shape(run["cfg"], run["mix"])
 
     def least(products, tensors):
         return max(ops_count.causal_attention_flops(*shape, products)
@@ -155,16 +159,27 @@ KINDS = {f.__name__: f for f in (
     request_percentile, request_ratio, gauge_peak_share)}
 
 
-def gauges(spec: dict) -> list:
-    return [spec["gauge"]] if spec.get("kind") == "gauge_peak_share" else []
+def _file_of(spec: dict, dirs):
+    """The reader file of a kind that is none of ``KINDS``."""
+    return common.find_module(dirs, "readers", spec["kind"])
 
 
-def read(metric_file: dict, run: dict) -> Optional[float]:
+def wants(spec: dict, dirs=(common.BENCH,)) -> list:
+    """The counters a metric's reader needs read at the window's edges."""
+    if spec["kind"] in KINDS:
+        return counters(spec)
+    return getattr(_file_of(spec, dirs), "wants", counters)(spec)
+
+
+def gauges(spec: dict, dirs=(common.BENCH,)) -> list:
+    """The gauges a metric's reader needs sampled while the window is open."""
+    if spec["kind"] in KINDS:
+        return [spec["gauge"]] if spec["kind"] == "gauge_peak_share" else []
+    return getattr(_file_of(spec, dirs), "gauges", lambda spec: [])(spec)
+
+
+def read(metric_file: dict, run: dict,
+         dirs=(common.BENCH,)) -> Optional[float]:
     spec = metric_file["reader"]
-    try:
-        fn = KINDS[spec["kind"]]
-    except KeyError:
-        raise KeyError(f"metric {metric_file['name']}: no reader of kind "
-                       f"{spec['kind']!r} in benchmarks/lib/readers.py"
-                       ) from None
+    fn = KINDS.get(spec["kind"]) or _file_of(spec, dirs).read
     return fn(spec, run)

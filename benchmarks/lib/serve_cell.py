@@ -26,9 +26,9 @@ import time
 
 import numpy as np
 
-from . import common, compare, control, ops_count, traffic, weights
+from . import common, compare, control, traffic, weights
 from .readers import percentile
-from .train_cell import build_net, load_reference
+from .train_cell import build_net
 
 
 class Clients:
@@ -161,9 +161,7 @@ def reference_logits(ref, cfg, flat, sample, mode="f32"):
         positions = np.arange(first, first + r["output_tokens"])
         padded = np.zeros(-(-len(ids) // pad) * pad, np.int32)
         padded[:len(ids)] = ids
-        z = ref.logits_at(flat, padded, positions,
-                          n_layers=cfg["num_hidden_layers"],
-                          n_heads=cfg["num_attention_heads"], mode=mode,
+        z = ref.logits_at(flat, padded, positions, cfg=cfg, mode=mode,
                           q_block=pad)
         out.append(np.asarray(z))
     return out
@@ -187,10 +185,11 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
     from deeplearning4j_tpu.serving import InferenceServer
     from deeplearning4j_tpu.util import metrics
 
+    family = env["family"]
     eng = dict(cfg["engine"], **env.get("engine_override", {}))
     window_tokens = eng["page_size"] * eng["pages_per_seq"]
     parts = {"imports": common.process_age_s()}
-    net = build_net(cfg, args.seed, max_cache_t=window_tokens)
+    net = build_net(family, cfg, args.seed, max_cache_t=window_tokens)
     parts["build_net"] = common.process_age_s()
     if env.get("plant") is not None:          # tests plant faults here
         env["plant"](net)
@@ -201,8 +200,8 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
     sched = server.decode
     parts["server_and_ladder"] = common.process_age_s()
     registries = [server.registry, metrics.REGISTRY]
-    edges = common.Edges(registries,
-                         env["wants"] + [TOKENS, PREFILL_TOKENS])
+    edges = common.Edges(registries, env["wants"] + family.WANTS
+                         + [TOKENS, PREFILL_TOKENS])
     sampler = common.GaugeSampler(registries, env["gauges"])
     clients = Clients(sched, mix, cfg["vocab_size"], args.seed, timeout_s)
     tracer = None
@@ -262,16 +261,18 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
            "requests": good, "setup_s": setup_s, "chips": cell["chips"],
            "memory_peak_bytes": peak, "compiles_in_window": compiles,
            "edges": edges, "gauge_peaks": sampler.peak,
-           "flops": ops_count.serve_flops(cfg, computed,
-                                          attended_keys(rows)),
+           "flops": family.serve_flops(cfg, {
+               "computed_tokens": computed,
+               "attended_keys": attended_keys(rows),
+               "deltas": [edges.delta(w) for w in family.WANTS]}),
            "generated_tokens": generated, "end_to_end": e2e}
 
     sample = draw_sample(rows, args.seed, cfg.get("check_tokens", 400))
     del server, sched, net, clients
     common.free_device_memory()
     t_ref = time.perf_counter()
-    ref = load_reference(cfg)
-    flat = weights.make_weights(cfg, args.seed)
+    ref = common.load_reference(cfg)
+    flat = weights.make_weights(family, cfg, args.seed)
     logits = reference_logits(ref, cfg, flat, sample)
     gaps = [g for z, r in zip(logits, sample)
             for g in compare.token_gaps(z, r["tokens"])]

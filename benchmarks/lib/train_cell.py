@@ -14,48 +14,26 @@ steps from the same seed.
 
 from __future__ import annotations
 
-import importlib.util
-import os
 import time
 
 import numpy as np
 
-from . import common, compare, control, ops_count, traffic, weights
+from . import common, compare, control, traffic, weights
 
 
-def load_reference(cfg: dict):
-    path = os.path.join(common.ROOT, cfg["reference"])
-    spec = importlib.util.spec_from_file_location("cell_reference", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def build_net(cfg: dict, seed: int, max_cache_t=None):
-    """The program's net for ``cfg`` with the benchmark's weights in it."""
+def build_net(family, cfg: dict, seed: int, max_cache_t=None):
+    """The program's net for ``cfg``, as its family configures it, with the
+    benchmark's weights in it."""
     import gc
     import jax
-    from deeplearning4j_tpu.models import transformer_lm
     from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
-    conf = transformer_lm(
-        cfg["vocab_size"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        d_ff=cfg["ffn_dim"], updater=cfg.get("updater", "sgd"),
-        learning_rate=cfg.get("learning_rate", 0.0),
-        seed=int(seed) & 0x7FFFFFFF, dtype=cfg["dtype"], input_ids=True,
-        max_cache_t=max_cache_t)
-    # transformer_lm() leaves the attention layer's activation to the
-    # builder's default, a sigmoid (PERF.md, Open questions); OPT's block
-    # has none after the output projection, so the configuration says so
-    for i in range(cfg["num_hidden_layers"]):
-        conf.vertices[f"blk{i}_attn"].layer.activation = "identity"
-    net = ComputationGraph(conf).init()
+    net = ComputationGraph(family.build_conf(cfg, seed, max_cache_t)).init()
     like = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), net.params)
     net.params = None               # the program's own draw is not used
     gc.collect()
-    net.params = weights.to_program(cfg, weights.make_weights(cfg, seed),
-                                    like)
+    net.params = weights.to_program(
+        family, cfg, weights.make_weights(family, cfg, seed), like)
     return net
 
 
@@ -64,22 +42,23 @@ class Probe:
     norm by leaf (from Adam's first moment after one step: m1 = (1 - b1)
     g1), and the norm by leaf of the parameters' change after the last."""
 
-    def __init__(self, cfg: dict, seed: int, steps: int):
-        self.cfg, self.seed, self.steps = cfg, seed, steps
+    def __init__(self, family, cfg: dict, seed: int, steps: int):
+        self.family, self.cfg, self.seed, self.steps = family, cfg, seed, steps
         self.losses, self.grad_norms, self.change_norms = [], None, None
         self.first_moment = None
 
     def iteration_done(self, model, iteration, score):
         self.losses.append(float(score))
         if iteration == 1:
-            m = weights.from_program(self.cfg, model.updater_state["m"])
+            m = weights.from_program(self.family, self.cfg,
+                                     model.updater_state["m"])
             self.grad_norms = weights.leaf_norms(m)
             # the gradient itself waits on the host for the reference's
             self.first_moment = {k: np.asarray(v) for k, v in m.items()}
         if iteration == self.steps:
             self.change_norms = weights.change_norms(
-                self.cfg, self.seed,
-                weights.from_program(self.cfg, model.params))
+                self.family, self.cfg, self.seed,
+                weights.from_program(self.family, self.cfg, model.params))
 
     def on_epoch_start(self, model, epoch):
         pass
@@ -125,17 +104,17 @@ def drain(net) -> None:
 def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
     from deeplearning4j_tpu.util import metrics
 
-    chips = cell["chips"]
+    family, chips = env["family"], cell["chips"]
     if chips != 1:
         raise SystemExit("benchmark: the data-parallel training cell is not "
                          "built yet (PERF.md, Open questions)")
     check_steps = int(mix.get("check_steps", 3))
     parts = {"imports": common.process_age_s()}
-    net = build_net(cfg, args.seed)
+    net = build_net(family, cfg, args.seed)
     parts["build_net"] = common.process_age_s()
     if env.get("plant") is not None:          # tests plant faults here
         env["plant"](net)
-    probe = Probe(cfg, args.seed, check_steps)
+    probe = Probe(family, cfg, args.seed, check_steps)
     net.set_listeners(probe)
     net.fit(traffic.train_batch(mix, cfg["vocab_size"], args.seed, s, chips)
             for s in range(check_steps))
@@ -174,7 +153,7 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
 
     out = {"attempted": steps, "failed": 0, "window_s": window_s,
            "steps": steps, "tokens": tokens, "setup_s": setup_s,
-           "flops": tokens * ops_count.train_flops_per_token(
+           "flops": tokens * family.train_flops_per_token(
                cfg, mix["seq_len"]),
            "memory_peak_bytes": peak, "compiles_in_window": compiles,
            "edges": edges, "chips": chips,
@@ -187,7 +166,7 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
     common.free_device_memory()
     t_ref = time.perf_counter()
     reference = control.reference_training(
-        load_reference(cfg), cfg, mix, args.seed, chips,
+        common.load_reference(cfg), family, cfg, mix, args.seed, chips,
         first_grads=program.pop("first_grads"))
     out["reference_s"] = time.perf_counter() - t_ref
     verdict = compare.Verdict()

@@ -6,8 +6,12 @@
 The cell names a configuration (``configs/<config>.json``, with its plain
 reference beside it), a traffic mix (``traffic/<traffic>.json``) and, through
 BENCHMARK.json's ``per_layer`` entries, its per-layer metrics
-(``metrics/<metric>.json``). Nothing here names a cell, a configuration, a
-mix or a metric: a later PR adds files and entries, and edits none.
+(``metrics/<metric>.json``, read by a kind of ``lib/readers.py`` or by
+``readers/<kind>.py``). The configuration's ``model_type`` names its family
+(``families/<model_type>.py``): the program's builder, the leaves of the
+weights, the operation counts. Nothing here names a cell, a configuration, a
+mix, a metric, a family or a size of a model: a later PR adds files and
+entries, and edits none.
 
 ``--seed`` makes the weights and the token ids and nothing else; the work is
 in the traffic file. With ``--trace 0`` the last line of standard output
@@ -74,10 +78,16 @@ def metric_value(v: float) -> float:
 
 def main(argv=None, env_extra=None) -> int:
     args = parse(argv)
-    bench = common.load_json("..", "BENCHMARK.json")
+    env_extra = dict(env_extra or {})
+    # tests bring a cell list and directories of their own, laid out like
+    # benchmarks/ and looked into before it, for families and readers
+    dirs = tuple(env_extra.pop("dirs", ())) + (common.BENCH,)
+    with open(env_extra.pop("benchmark", os.path.join(
+            ROOT, "BENCHMARK.json"))) as f:
+        bench = json.load(f)
     cells = {c["name"]: c for c in bench["workloads"]}
     if args.workload not in cells:
-        print(f"benchmark: no cell {args.workload!r} in BENCHMARK.json "
+        print(f"benchmark: no cell {args.workload!r} in the cell list "
               f"(cells: {sorted(cells)})", file=sys.stderr)
         return 2
     cell = cells[args.workload]
@@ -87,13 +97,16 @@ def main(argv=None, env_extra=None) -> int:
                       if c["name"] == cell["config"])
     with open(os.path.join(ROOT, conf_entry["file"])) as f:
         cfg = json.load(f)
+    family = common.load_family(cfg, dirs)
     from lib import traffic
     mix = traffic.load(cell["traffic"])
     e2e_names = [m["name"] for m in bench["end_to_end"]
                  if cell["name"] in m.get("workloads", [cell["name"]])]
-    layer_files = [common.load_json("metrics", f"{m['name']}.json")
-                   for m in bench["per_layer"]
-                   if cell["name"] in m.get("workloads", [cell["name"]])]
+    layer_files = []
+    for m in bench["per_layer"]:
+        if cell["name"] in m.get("workloads", [cell["name"]]):
+            with open(common.find(dirs, "metrics", f"{m['name']}.json")) as f:
+                layer_files.append(json.load(f))
 
     device = common.device_info(cell["chips"], args.rehearse)
     common.use_compile_cache()
@@ -102,7 +115,7 @@ def main(argv=None, env_extra=None) -> int:
     mode = contextlib.nullcontext()
     if args.rehearse:
         cfg, mix = rehearsal_sizes(cfg, mix)
-        cfg.update((env_extra or {}).get("rehearsal_sizes", {}))
+        cfg.update(env_extra.get("rehearsal_sizes", {}))
         os.environ["DL4JTPU_FLASH_ATTENTION"] = "1"
         if device["platform"] != "tpu":
             mode = interpret_kernels()
@@ -112,20 +125,22 @@ def main(argv=None, env_extra=None) -> int:
 
     trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
     shutil.rmtree(trace_dir, ignore_errors=True)
-    env = {"wants": [w for m in layer_files for w in readers.wants(m["reader"])],
+    env = {"wants": [w for m in layer_files
+                     for w in readers.wants(m["reader"], dirs)],
            "gauges": [g for m in layer_files
-                      for g in readers.gauges(m["reader"])],
+                      for g in readers.gauges(m["reader"], dirs)],
+           "family": family,
            "compiles": common.CompileCounter(), "trace_dir": trace_dir,
            # only the process that holds a chip can trace it: a rehearsal
            # off the TPU still reports the per-layer metrics that need none
            "tracing": bool(args.trace) and device["platform"] == "tpu"}
-    env.update(env_extra or {})
+    env.update(env_extra)
     runner = {"train": "train_cell", "serve": "serve_cell"}[cfg["runner"]]
     module = __import__(f"lib.{runner}", fromlist=["run"])
     with mode:
         run = module.run(cell, cfg, mix, args, env)
 
-    run.update(cfg=cfg, mix=mix, peaks=chip_peaks)
+    run.update(cfg=cfg, mix=mix, peaks=chip_peaks, family=family)
     device["memory_peak_bytes"] = run["memory_peak_bytes"]
     result = {"correct": run["verdict"].correct,
               "attempted": run["attempted"], "failed": run["failed"]}
@@ -144,7 +159,7 @@ def main(argv=None, env_extra=None) -> int:
             result["breakdown"] = xplane.breakdown(reduced)
         metrics = {}
         for m in layer_files:
-            v = readers.read(m, run)
+            v = readers.read(m, run, dirs)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": units[m["name"]]}
         result["metrics"] = metrics

@@ -45,16 +45,15 @@ def cell_files(name: str):
 
 
 def train(cell_name: str, seeds) -> None:
-    from lib.train_cell import load_reference
     cell, cfg, mix = cell_files(cell_name)
     common.device_info(cell["chips"], rehearse=False)
     common.use_compile_cache()
-    ref = load_reference(cfg)
+    ref, family = common.load_reference(cfg), common.load_family(cfg)
     for seed in seeds:
         for name, kw in (("fp8", {"mode": "fp8"}),
                          ("half_batch", {"fault": control.HALF_BATCH})):
-            v = control.control_against_reference(ref, cfg, mix, seed,
-                                                  cell["chips"], **kw)
+            v = control.control_against_reference(ref, family, cfg, mix,
+                                                  seed, cell["chips"], **kw)
             print(json.dumps({"cell": cell_name, "seed": seed,
                               "control": name, "correct": v.correct,
                               "compared": v.compared()}), flush=True)

@@ -23,16 +23,15 @@ import pytest
 
 import run as bench_run
 from lib import common, control, traffic
-from lib.train_cell import load_reference
 
 
-def drive(cell, seed, **env_extra):
+def drive(cell, seed, trace=0, **env_extra):
     """One rehearsal run inside this process; its result line."""
     out, err = io.StringIO(), io.StringIO()
     with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
         rc = bench_run.main(["--workload", cell, "--seed", str(seed),
-                             "--seconds", "1", "--rehearse"],
-                            env_extra=env_extra)
+                             "--seconds", "1", "--trace", str(trace),
+                             "--rehearse"], env_extra=env_extra)
     assert rc == 0
     line = json.loads(out.getvalue().strip().splitlines()[-1])
     assert err.getvalue().strip().splitlines()[-1] == \
@@ -50,26 +49,26 @@ def failed(line):
 @pytest.fixture(scope="module")
 def train_sizes():
     cfg = common.load_json("configs", "opt-1.3b-train.json")
-    return bench_run.rehearsal_sizes(cfg, traffic.load("t8192"))
+    cfg, mix = bench_run.rehearsal_sizes(cfg, traffic.load("t8192"))
+    return (common.load_reference(cfg), common.load_family(cfg), cfg, mix)
 
 
 @pytest.mark.parametrize("seed", [2147483659, 7, 8])
 def test_training_control_fails_and_stated_precision_passes(train_sizes,
                                                             seed):
-    cfg, mix = train_sizes
-    ref = load_reference(cfg)
-    stated = control.control_against_reference(ref, cfg, mix, seed,
+    cfg = train_sizes[2]
+    stated = control.control_against_reference(*train_sizes, seed,
                                                mode="bf16")
     assert stated.correct, stated.compared()
-    fp8 = control.control_against_reference(ref, cfg, mix, seed, mode="fp8")
+    fp8 = control.control_against_reference(*train_sizes, seed, mode="fp8")
     assert not fp8.correct
     assert fp8.compared()["grad_diff_worst_leaf"]["value"] > \
         cfg["limits"]["grad_diff"]
 
 
 def test_half_batch_planted_in_the_reference_fails(train_sizes):
-    cfg, mix = train_sizes
-    v = control.control_against_reference(load_reference(cfg), cfg, mix, 9,
+    cfg = train_sizes[2]
+    v = control.control_against_reference(*train_sizes, 9,
                                           fault=control.HALF_BATCH)
     got = v.compared()
     for name, limit in (("grad_norm_worst_leaf_gap", "grad_norm_gap"),

@@ -3,7 +3,9 @@ shape, and the peaks table's refusal of a chip it does not know."""
 
 import pytest
 
-from lib import ops_count, peaks, readers
+from lib import common, ops_count, peaks, readers
+
+opt = common.load_family({"model_type": "opt"})
 
 # d 8, ff 16, 2 layers, vocabulary 32, 2 heads of 4
 CFG = {"hidden_size": 8, "ffn_dim": 16, "num_hidden_layers": 2,
@@ -13,20 +15,21 @@ CFG = {"hidden_size": 8, "ffn_dim": 16, "num_hidden_layers": 2,
 def test_matmul_params_by_hand():
     # a layer: Wqkv 8x24 = 192, Wo 8x8 = 64, W1 8x16 = 128, W2 16x8 = 128:
     # 512; two layers 1024; head 8x32 = 256
-    assert ops_count.matmul_params(CFG) == 1280
+    assert opt.matmul_params(CFG) == 1280
 
 
 def test_train_flops_per_token_by_hand():
     # forward of a token at T = 6: 2 x 1280 in the matrices; attention over
     # T/2 = 3 keys: q.k and p.v are 8 multiply-adds each a key (2 heads x 4)
     # = 2 x 2 x 8 x 3 = 96 a layer, 192 in two; backward twice the forward
-    assert ops_count.train_flops_per_token(CFG, 6) == 3 * (2560 + 192)
+    assert opt.train_flops_per_token(CFG, 6) == 3 * (2560 + 192)
 
 
 def test_serve_flops_by_hand():
     # 5 tokens computed, 9 (token, key) pairs: 5 x 2560 + 9 x (2 layers x
     # 4 x 8 = 64)
-    assert ops_count.serve_flops(CFG, 5, 9) == 12800 + 576
+    assert opt.serve_flops(CFG, {"computed_tokens": 5, "attended_keys": 9,
+                                 "deltas": []}) == 12800 + 576
 
 
 def test_flash_counts_by_hand():
@@ -44,7 +47,7 @@ def test_flash_roofline_reader_by_hand():
     above, on a chip of 1000 op/s and 100 B/s: forward needs max(320/1000,
     256/100) = 2.56 s, backward max(640/1000, 512/100) = 5.12 s; they took
     10 + 22 s, so 24 %."""
-    run = {"cfg": CFG, "mix": {"batch": 1, "seq_len": 4},
+    run = {"cfg": CFG, "mix": {"batch": 1, "seq_len": 4}, "family": opt,
            "peaks": {"flops_bf16": 1000.0, "hbm_bytes_per_s": 100.0},
            "trace": {"chips": 1,
                      "op_seconds": {"jvp__:tpu_custom_call": 10.0,
