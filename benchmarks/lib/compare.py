@@ -126,3 +126,9 @@ def token_gaps(ref_logits, tokens) -> list:
     z = np.asarray(ref_logits, np.float64)
     tok = np.asarray(tokens, np.int64)
     return (z.max(axis=-1) - z[np.arange(len(tok)), tok]).tolist()
+
+
+def outlier_share(gaps, limit: float):
+    """The share of the gaps that lie beyond ``limit`` (one that is not a
+    number lies beyond any); None of no gaps."""
+    return sum(not g <= limit for g in gaps) / len(gaps) if gaps else None

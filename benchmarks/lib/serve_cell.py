@@ -16,7 +16,13 @@ over every request submitted and completed inside the window.
 After the window a sample of the finished requests, drawn from the seed
 with the longest in it, is checked against the plain reference: one forward
 pass over each prompt with its served tokens, and the widest gap by which a
-served token's logit lies below the reference's best.
+served token's logit lies below the reference's best. A configuration that
+states ``limits.logit_gap_outlier_share`` is held to that share of the
+compared tokens lying beyond ``logit_gap``, not to the widest gap: a model
+with a discrete choice inside (the top k of some scores) differs from its
+reference by rounding everywhere and, where two scores lie closer than the
+rounding of their input, by the other choice at a few tokens, which is no
+fault (PERF.md section 2).
 """
 
 from __future__ import annotations
@@ -267,7 +273,8 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
                "deltas": [edges.delta(w) for w in family.WANTS]}),
            "generated_tokens": generated, "end_to_end": e2e}
 
-    sample = draw_sample(rows, args.seed, cfg.get("check_tokens", 400))
+    want_tokens = cfg.get("check_tokens", 400)
+    sample = draw_sample(rows, args.seed, want_tokens)
     del server, sched, net, clients
     common.free_device_memory()
     t_ref = time.perf_counter()
@@ -287,15 +294,27 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
     verdict.require("no_compile_in_window", compiles == 0, f"{compiles}")
     verdict.require("requests_ran_to_length", failed == 0 and len(good) > 0,
                     f"{failed} failed of {len(rows)}")
-    verdict.add("served_token_logit_gap_max",
-                max(gaps) if gaps else None, cfg["limits"]["logit_gap"],
-                f"{len(gaps)} tokens of {len(sample)} requests, "
-                f"{sum(1 for r in sample if r['prefix_covered_tokens'])} "
-                "of them prefix-cache hits")
+    limits = cfg["limits"]
+    note = (f"{len(gaps)} tokens of {len(sample)} requests, "
+            f"{sum(1 for r in sample if r['prefix_covered_tokens'])} "
+            "of them prefix-cache hits")
+    gaps_max = max(gaps) if gaps else None
+    gaps_p99 = float(np.percentile(gaps, 99)) if gaps else None
+    share = compare.outlier_share(gaps, limits["logit_gap"])
+    share_limit = limits.get("logit_gap_outlier_share")
+    if share_limit is None:
+        verdict.add("served_token_logit_gap_max", gaps_max,
+                    limits["logit_gap"], note)
+    else:
+        verdict.add("served_token_gap_outlier_share", share, share_limit,
+                    f"{note}; beyond {limits['logit_gap']}; largest gap "
+                    f"{gaps_max}, 99th percentile {gaps_p99}")
+        # a share over few tokens is noise
+        verdict.require("tokens_compared", len(gaps) >= want_tokens,
+                        f"{len(gaps)} of {want_tokens}")
     out["verdict"] = verdict
-    out["readings"] = {"gaps_max": max(gaps) if gaps else None,
-                       "gaps_p99": float(np.percentile(gaps, 99))
-                       if gaps else None, "n_tokens": len(gaps),
+    out["readings"] = {"gaps_max": gaps_max, "gaps_p99": gaps_p99,
+                       "gaps_outlier_share": share, "n_tokens": len(gaps),
                        "n_requests": len(sample),
                        "completed": len(good), "setup_parts_s": parts,
                        "end_to_end": e2e,
@@ -304,7 +323,9 @@ def run(cell: dict, cfg: dict, mix: dict, args, env: dict) -> dict:
                        "tpot_p50_ms": 1000.0 * percentile(tpot, 50)
                        if tpot else None,
                        "control_gaps_max": max(control_gaps)
-                       if control_gaps else None}
+                       if control_gaps else None,
+                       "control_outlier_share": compare.outlier_share(
+                           control_gaps or [], limits["logit_gap"])}
     out["sample"] = sample
     return out
 

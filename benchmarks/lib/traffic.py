@@ -21,17 +21,18 @@ ids. Two kinds:
 from __future__ import annotations
 
 import json
-import os
 from typing import Iterator, List, Tuple
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import common
+
 _DOC = 1 << 20          # keeps document streams apart from request streams
 
 
-def load(name: str) -> dict:
-    with open(os.path.join(HERE, "traffic", f"{name}.json")) as f:
+def load(name: str, dirs=(common.BENCH,)) -> dict:
+    """``traffic/<name>.json`` of the first of ``dirs`` that has it."""
+    with open(common.find(dirs, "traffic", f"{name}.json")) as f:
         mix = json.load(f)
     mix["name"] = name
     return mix

@@ -80,7 +80,7 @@ def main(argv=None, env_extra=None) -> int:
     args = parse(argv)
     env_extra = dict(env_extra or {})
     # tests bring a cell list and directories of their own, laid out like
-    # benchmarks/ and looked into before it, for families and readers
+    # benchmarks/ and looked into before it, for families, mixes and readers
     dirs = tuple(env_extra.pop("dirs", ())) + (common.BENCH,)
     with open(env_extra.pop("benchmark", os.path.join(
             ROOT, "BENCHMARK.json"))) as f:
@@ -99,7 +99,7 @@ def main(argv=None, env_extra=None) -> int:
         cfg = json.load(f)
     family = common.load_family(cfg, dirs)
     from lib import traffic
-    mix = traffic.load(cell["traffic"])
+    mix = traffic.load(cell["traffic"], dirs)
     e2e_names = [m["name"] for m in bench["end_to_end"]
                  if cell["name"] in m.get("workloads", [cell["name"]])]
     layer_files = []

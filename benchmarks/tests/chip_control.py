@@ -15,9 +15,15 @@ number by number, against the configuration's limits. No program runs.
 ``serve``: one run of the cell with a short window; besides what every run
 compares, the same sampled prompts and served tokens go through the
 reference with fp8 operands, and the gap of the token it puts first at each
-position is read in the float32 reference's logits. Engine arguments
-given here replace the configuration's for that run (how ``prefill_chunk``
-16 and 128 were tried once each, PERF.md).
+position is read in the float32 reference's logits. The line's ``readings``
+hold the largest gap and the share of the gaps beyond ``logit_gap``, of the
+run (``gaps_max``, ``gaps_outlier_share``) and of the control
+(``control_gaps_max``, ``control_outlier_share``): a configuration's
+``logit_gap`` and ``logit_gap_outlier_share`` are set from them. Engine
+arguments given here replace the configuration's for that run (how
+``prefill_chunk`` 16 and 128 were tried once each, PERF.md). A cell that
+BENCHMARK.json lacks is looked for in the tests' own list
+(``data/cells.json``, with the families, mixes and readers beside it).
 
 Each line printed is one JSON object; PERF.md holds what was read (PR 24).
 Not part of a benchmark run, and not a test that pytest collects.
@@ -28,6 +34,7 @@ import os
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+DATA = os.path.join(HERE, "data")
 BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 sys.path.insert(0, os.path.dirname(BENCH))
@@ -62,10 +69,14 @@ def train(cell_name: str, seeds) -> None:
 def serve(cell_name: str, seed: str, seconds: str, engine: str = "{}",
           trace: str = "0") -> None:
     os.environ["BENCH_READINGS"] = "1"
+    own = {}
+    if cell_name not in [c["name"] for c in common.load_json(
+            "..", "BENCHMARK.json")["workloads"]]:
+        own = {"benchmark": os.path.join(DATA, "cells.json"), "dirs": [DATA]}
     bench_run.main(["--workload", cell_name, "--seed", seed, "--seconds",
                     seconds, "--trace", trace],
                    env_extra={"control_mode": "fp8",
-                              "engine_override": json.loads(engine)})
+                              "engine_override": json.loads(engine), **own})
 
 
 if __name__ == "__main__":

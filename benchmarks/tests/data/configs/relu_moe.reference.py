@@ -14,8 +14,13 @@ over a sequence's positions and divided by the number of sequences. Adam
 as in ``opt-1.3b-train.reference.py``.
 
 Weights come as the flat dict ``lib/weights.py`` makes from the family's
-``leaf_shapes``, in the type the configuration stores them in; every
-product here is float32 at ``precision=HIGHEST``.
+``leaf_shapes``, in the type the configuration stores them in. ``mode``
+chooses the arithmetic of every matrix product, the router's and the
+experts' among them: ``f32``, float32 operands at ``precision=HIGHEST``
+(the reference), or, for the forward pass alone, ``fp8``: operands rounded
+to float8_e4m3fn under a per-tensor scale, float32 accumulation (the
+control of a configuration that states ``mixed_bf16``, as in
+``opt-1.3b.reference.py``).
 """
 
 from __future__ import annotations
@@ -31,9 +36,22 @@ B1, B2, EPS = 0.9, 0.999, 1e-8
 HI = jax.lax.Precision.HIGHEST
 
 
-def _mode(mode: str) -> None:
-    if mode != "f32":
-        raise ValueError(f"this reference computes in f32 only, not {mode!r}")
+def _fp8(x):
+    """Round to float8_e4m3fn under a per-tensor scale (largest magnitude
+    at the format's largest number, 448), as fp8 inference does."""
+    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
+    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.bfloat16), scale
+
+
+def ein(spec: str, a, b, mode: str):
+    """``jnp.einsum(spec, a, b)`` in the arithmetic that ``mode`` names."""
+    if mode == "f32":
+        return jnp.einsum(spec, a, b, precision=HI)
+    if mode != "fp8":
+        raise ValueError(f"unknown arithmetic {mode!r}")
+    (qa, sa), (qb, sb) = _fp8(a), _fp8(b)
+    return jnp.einsum(spec, qa, qb,
+                      preferred_element_type=jnp.float32) * (sa * sb)
 
 
 def layer_norm(x, g, b):
@@ -42,39 +60,38 @@ def layer_norm(x, g, b):
     return (x - mean) * jax.lax.rsqrt(var + LN_EPS) * g + b
 
 
-def attention(p, x, n_heads: int):
+def attention(p, x, n_heads: int, mode: str = "f32"):
     """x [b, t, d] -> [b, t, d], causal, all heads at once."""
     b, t, d = x.shape
-    qkv = jnp.matmul(x, p["wqkv"], precision=HI).reshape(
+    qkv = ein("btd,de->bte", x, p["wqkv"], mode).reshape(
         b, t, 3, n_heads, d // n_heads)
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HI) \
+    s = ein("bqhd,bkhd->bhqk", q, k, mode) \
         / jnp.sqrt(jnp.float32(d // n_heads))
     s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
-                   precision=HI)
-    return jnp.matmul(o.reshape(b, t, d), p["wo"], precision=HI) + p["bo"]
+    o = ein("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, mode)
+    return ein("btd,de->bte", o.reshape(b, t, d), p["wo"], mode) + p["bo"]
 
 
-def experts(p, x, top_k: int):
+def experts(p, x, top_k: int, mode: str = "f32"):
     """x [b, t, d] -> (y [b, t, d], the load-balancing sum before its
     weight)."""
     e = p["router"].shape[-1]
-    gates = jax.nn.softmax(jnp.matmul(x, p["router"], precision=HI), axis=-1)
+    gates = jax.nn.softmax(ein("btd,de->bte", x, p["router"], mode), axis=-1)
     _, idx = jax.lax.top_k(gates, top_k)
     kept = jax.nn.one_hot(idx, e).sum(axis=-2) > 0              # [b, t, E]
     w = jnp.where(kept, gates, 0.0)
     w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-    h = jax.nn.relu(jnp.einsum("btd,edh->ebth", x, p["w1"], precision=HI)
+    h = jax.nn.relu(ein("btd,edh->ebth", x, p["w1"], mode)
                     + p["b1"][:, None, None, :])
-    y = (jnp.einsum("ebth,ehd->ebtd", h, p["w2"], precision=HI)
+    y = (ein("ebth,ehd->ebtd", h, p["w2"], mode)
          + p["b2"][:, None, None, :])
     balance = e * jnp.sum(jnp.mean(gates, axis=(0, 1))
                           * jnp.mean(kept.astype(jnp.float32), axis=(0, 1)))
-    return jnp.einsum("bte,ebtd->btd", w, y, precision=HI), balance
+    return ein("bte,ebtd->btd", w, y, mode), balance
 
 
-def hidden(w, ids, cfg: dict):
+def hidden(w, ids, cfg: dict, mode: str = "f32"):
     """Residual stream after the last layer for ids [b, t], and the sum
     over the layers of the load-balancing terms."""
     w = {k: v.astype(jnp.float32) for k, v in w.items()}
@@ -84,17 +101,17 @@ def hidden(w, ids, cfg: dict):
         p = {k.split(".", 1)[1]: v for k, v in w.items()
              if k.startswith(f"l{i}.")}
         x = x + attention(p, layer_norm(x, p["ln1_g"], p["ln1_b"]),
-                          cfg["num_attention_heads"])
+                          cfg["num_attention_heads"], mode)
         y, bal = experts(p, layer_norm(x, p["ln2_g"], p["ln2_b"]),
-                         cfg["num_experts_per_tok"])
+                         cfg["num_experts_per_tok"], mode)
         x, balance = x + y, balance + bal
     return x, balance, w
 
 
-def logits(w, ids, cfg: dict):
-    x, balance, w = hidden(w, ids, cfg)
-    z = jnp.matmul(layer_norm(x, w["lnf_g"], w["lnf_b"]), w["head_w"],
-                   precision=HI) + w["head_b"]
+def logits(w, ids, cfg: dict, mode: str = "f32"):
+    x, balance, w = hidden(w, ids, cfg, mode)
+    z = ein("btd,dv->btv", layer_norm(x, w["lnf_g"], w["lnf_b"]),
+            w["head_w"], mode) + w["head_b"]
     return z, balance
 
 
@@ -103,8 +120,7 @@ def logits_at(weights, ids, positions, *, cfg: dict, mode: str = "f32",
     """Next-token logits ``[len(positions), V]`` at the given positions of
     one sequence of ids ``[t]`` (``q_block`` is a size of references that
     compute in blocks; this one does not)."""
-    _mode(mode)
-    z, _ = logits(weights, jnp.asarray(ids, jnp.int32)[None], cfg)
+    z, _ = logits(weights, jnp.asarray(ids, jnp.int32)[None], cfg, mode)
     return jnp.take(z[0], jnp.asarray(positions, jnp.int32), axis=0)
 
 
@@ -127,7 +143,8 @@ def train_steps(weights, batches, *, cfg: dict, mode: str = "f32",
     """``len(batches)`` Adam steps on batches of ``(ids [b, t], labels
     [b, t])``; the dict ``lib/compare.compare_training`` takes (see
     ``opt-1.3b-train.reference.py``, whose arguments these are)."""
-    _mode(mode)
+    if mode != "f32":
+        raise ValueError(f"this reference trains in f32 only, not {mode!r}")
     grad = jax.jit(jax.value_and_grad(functools.partial(loss_fn, cfg=cfg)))
     p = {k: v.astype(jnp.float32) for k, v in weights.items()}
     start = dict(p)

@@ -25,13 +25,13 @@ import run as bench_run
 from lib import common, control, traffic
 
 
-def drive(cell, seed, trace=0, **env_extra):
+def drive(cell, seed, trace=0, seconds=1, **env_extra):
     """One rehearsal run inside this process; its result line."""
     out, err = io.StringIO(), io.StringIO()
     with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
         rc = bench_run.main(["--workload", cell, "--seed", str(seed),
-                             "--seconds", "1", "--trace", str(trace),
-                             "--rehearse"], env_extra=env_extra)
+                             "--seconds", str(seconds), "--trace",
+                             str(trace), "--rehearse"], env_extra=env_extra)
     assert rc == 0
     line = json.loads(out.getvalue().strip().splitlines()[-1])
     assert err.getvalue().strip().splitlines()[-1] == \
@@ -140,6 +140,8 @@ def test_serving_control_reads_above_the_limit(seed, monkeypatch):
     limit = SERVE_SIZES["limits"]["logit_gap"]
     assert line["compared"]["served_token_logit_gap_max"]["limit"] == limit
     assert line["readings"]["control_gaps_max"] > limit
+    # the share of the control's tokens beyond the limit is read beside it
+    assert 0.0 < line["readings"]["control_outlier_share"] <= 1.0
     assert line["readings"]["n_requests"] >= 3      # hits and a miss
 
 

@@ -53,6 +53,17 @@ def test_last_line(cell, trace):
     for name, pair in line["compared"].items():
         assert set(pair) == {"value", "limit"}
         assert f"compared {name}:" in p.stderr
+    # no configuration of BENCHMARK.json states a share of outliers: a
+    # serving cell compares what PR 28's did, by the same names and limits
+    entry = next(c for c in bench["configs"] if c["name"] == next(
+        w["config"] for w in bench["workloads"] if w["name"] == cell))
+    with open(os.path.join(common.ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    if cfg["runner"] == "serve":
+        assert {k: v["limit"] for k, v in line["compared"].items()} == {
+            "every_client_returned": 0.0, "no_compile_in_window": 0.0,
+            "requests_ran_to_length": 0.0, "served_token_logit_gap_max":
+            cfg["rehearsal"]["limits"]["logit_gap"]}
     assert p.stderr.strip().splitlines()[-1] == "correct: True"
 
 

@@ -33,15 +33,26 @@ def chat(constant: int, clients: int, per_client: int) -> list:
 
 
 def docqa(constant: int, clients: int, docs: int, questions: int) -> list:
+    """Client ``c`` of ``clients`` draws its documents from the c-th equal
+    part of [1024, 1536] and its answers from the c-th part of [16, 32]:
+    the clients' rounds (a document and its questions) then differ in
+    length by about a quarter, so their phase against each other runs
+    through every value several times in a window. With one range for all,
+    the phase stayed for a whole window near where set-up had left it, and
+    the rate followed it (PERF.md section 6, PR 29)."""
     rng = np.random.default_rng(constant)
     out = []
     for c in range(clients):
+        doc_lo, doc_hi = (1024 + 512 * c // clients,
+                          1024 + 512 * (c + 1) // clients)
+        ans_lo, ans_hi = 16 + 16 * c // clients, 16 + 16 * (c + 1) // clients
         rows = []
         for d in range(docs):
-            n_doc = int(rng.integers(1024, 1537))
+            n_doc = int(rng.integers(doc_lo, doc_hi + 1))
             for _ in range(questions):
                 rows.append([n_doc + int(rng.integers(16, 33)),
-                             int(rng.integers(16, 33)), c * docs + d, n_doc])
+                             int(rng.integers(ans_lo, ans_hi + 1)),
+                             c * docs + d, n_doc])
         out.append(rows)
     return out
 
@@ -85,12 +96,16 @@ if __name__ == "__main__":
                            "so two run at once; eight clients return with "
                            "an arena that holds them (PERF.md, Open "
                            "questions)",
-        "distribution": "each client takes a document of uniform [1024, "
-                        "1536] tokens and asks 4 questions of it in a row "
-                        "(question uniform [16, 32] tokens after the "
-                        "document, answer uniform [16, 32]), then the next "
-                        "document; documents are not shared between "
-                        "clients; greedy, no end-of-sequence id",
+        "distribution": "each client takes a document and asks 4 questions "
+                        "of it in a row (question uniform [16, 32] tokens "
+                        "after the document), then the next document; the "
+                        "first client's documents are uniform [1024, 1280] "
+                        "tokens and its answers uniform [16, 24], the "
+                        "second's [1280, 1536] and [24, 32], so that their "
+                        "rounds differ in length and their phase against "
+                        "each other sweeps through every window; documents "
+                        "are not shared between clients; greedy, no "
+                        "end-of-sequence id",
         "generator_constant": 240002, "generator":
             "numpy default_rng(constant), one pass, client by client",
         "schedule": docqa(240002, 2, 24, 4)})
