@@ -8,20 +8,20 @@ topo sort + params), ``fit`` (``:614-760``), ``computeGradientAndScore``
 TPU-native design: the whole topo-ordered DAG forward + loss + ``jax.grad``
 backward + updater apply traces into ONE jitted XLA program (donated params).
 The reference's per-vertex ``doForward``/``doBackward`` dispatch loop has no
-runtime analog — vertex boundaries disappear into XLA fusion.
+runtime analog — vertex boundaries disappear into XLA fusion. That program
+is built by the engine this class inherits (``nn/trainable.py``: the jitted
+step, its scanned and repeated forms, TBPTT, listeners); what is defined
+here is the walk over the DAG and the loss.
 """
 
 from __future__ import annotations
 
-import functools
-import os
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import dtypes as _dtypes
 from .. import losses as _losses
 from .. import rng as _rng
 from ..optimize import updaters as _updaters
@@ -31,6 +31,7 @@ from ..util.netutil import note_streamed_steps as _note_streamed_steps
 from ..util.netutil import precheck_streamed_steps as _precheck_streamed_steps
 from .conf.graph import ComputationGraphConfiguration, LayerVertex
 from .conf.preprocessors import call_preprocessor
+from .trainable import TrainableNetwork
 
 Pytree = Any
 
@@ -39,33 +40,13 @@ def _as_list(v) -> List[Any]:
     return list(v) if isinstance(v, (list, tuple)) else [v]
 
 
-class ComputationGraph:
+class ComputationGraph(TrainableNetwork):
     """Runtime DAG network over a :class:`ComputationGraphConfiguration`."""
 
     def __init__(self, conf: ComputationGraphConfiguration):
         conf.validate()
-        self.conf = conf
-        self.training = conf.training
-        self.policy = _dtypes.policy_from_name(conf.training.dtype)
+        super().__init__(conf)
         self.topo_order = conf.topological_order()
-        self.params: Optional[Dict[str, Dict[str, jax.Array]]] = None
-        self.state: Dict[str, Dict[str, jax.Array]] = {}
-        self.updater_state: Optional[Pytree] = None
-        self.listeners: List[Any] = []
-        self.iteration_count = 0
-        self._update_count = 0
-        self.epoch_count = 0
-        self._score = None
-        self._updater = None
-        self._rnn_state: Optional[Dict[str, Dict[str, jax.Array]]] = None
-        self._rnn_steps_fed = 0    # streaming steps since last cache reset
-        self._jit_cache: Dict[str, Any] = {}
-        # on-device training-health stats (util.health): None = off (the
-        # default; the no-stats trace is untouched), a StatsConfig routes
-        # fit_batch/fit_scan through the stats-collecting step variant
-        self.health_stats: Optional[_health.StatsConfig] = None
-        self._last_health_stats: Optional[_health.DeviceStats] = None
-
         self._output_layer_names = [
             n for n in conf.network_outputs
             if hasattr(self._vertex_layer(n), "compute_score_array")]
@@ -122,11 +103,10 @@ class ComputationGraph:
                                for k in shapes}
         return mults
 
-    def num_params(self) -> int:
-        if self.params is None:
-            raise ValueError("call init() first")
-        return sum(int(np.prod(p.shape))
-                   for p in jax.tree_util.tree_leaves(self.params))
+    def _param_layers(self):
+        """Vertices that hold a layer (the others carry no l1/l2)."""
+        pairs = ((name, self._vertex_layer(name)) for name in self.topo_order)
+        return ((name, layer) for name, layer in pairs if layer is not None)
 
     # ------------------------------------------------------------------
     # functional forward over the DAG
@@ -140,6 +120,8 @@ class ComputationGraph:
                 st.update(rnn_state[n])
             out[n] = st
         return out
+
+    _states = _states_map   # the name the shared engine asks under
 
     def _persist_states(self, new_states: Dict[str, Dict[str, jax.Array]]) -> None:
         for name, keys in self._persistent_keys.items():
@@ -500,283 +482,49 @@ class ComputationGraph:
                       else jnp.float32)
         return total.astype(loss_dtype), new_states
 
-    def _reg_penalty(self, params):
-        if not self.training.regularization:
-            return 0.0
-        acc_dtype = (jnp.float64 if self.policy.param_dtype == jnp.float64
-                     else jnp.float32)
-        total = 0.0
-        for name in self.topo_order:
-            layer = self._vertex_layer(name)
-            if layer is None:
-                continue
-            l1 = float(layer.l1 or 0.0)
-            l2 = float(layer.l2 or 0.0)
-            if l1 == 0.0 and l2 == 0.0:
-                continue
-            lp = params[name]
-            for pname in layer.regularized_params():
-                if pname not in lp:
-                    continue
-                w = lp[pname].astype(acc_dtype)
-                if l1:
-                    total = total + l1 * jnp.sum(jnp.abs(w))
-                if l2:
-                    total = total + 0.5 * l2 * jnp.sum(jnp.square(w))
-        return total
-
     def score_for(self, inputs, labels, masks=None) -> float:
-        inputs = [jnp.asarray(x) for x in _as_list(inputs)]
-        labels = [jnp.asarray(y) for y in _as_list(labels)]
-        if masks is not None:
-            masks = [None if m is None else jnp.asarray(m)
-                     for m in _as_list(masks)]
+        inputs, labels, masks = self._as_batch(inputs, labels, masks)
         loss, _ = self._loss_fn(self.params, self._states_map(), inputs,
                                 labels, masks, None)
         return float(loss)
 
-    def score(self) -> Optional[float]:
-        if self._score is None:
-            return None
-        self._score = float(self._score)
-        return self._score
-
     # ------------------------------------------------------------------
-    # the jitted train step + fit
+    # fit: a call's arguments become the lists-of-arrays batch the loss
+    # takes; the engine (TrainableNetwork) does the rest
     # ------------------------------------------------------------------
 
-    def _make_train_step(self, stats_cfg: Optional[_health.StatsConfig] = None):
-        t = self.training
-        norm_kind = t.gradient_normalization
-        norm_thr = float(t.gradient_normalization_threshold)
-        updater = self._updater
-        collect = stats_cfg is not None
+    @staticmethod
+    def _as_batch(inputs, labels, masks):
+        """Array or list of arrays each (multi-input / multi-output);
+        masks: optional list of feature masks."""
+        return ([jnp.asarray(x) for x in _as_list(inputs)],
+                [jnp.asarray(y) for y in _as_list(labels)],
+                None if masks is None else
+                [None if m is None else jnp.asarray(m)
+                 for m in _as_list(masks)])
 
-        def step(params, opt_state, states, inputs, labels, masks, rng, it):
-            loss, new_states, grads_raw, act_stats = \
-                _health.value_grad_with_stats(
-                    self._loss_fn, stats_cfg, params, states, inputs,
-                    labels, masks, rng)
-            grads = _updaters.normalize_gradients(grads_raw, norm_kind,
-                                                  norm_thr)
-            deltas, opt_state = updater.update(grads, opt_state, it)
-            params = _updaters.apply_updates(params, deltas)
-            if not collect:
-                return params, opt_state, new_states, loss
-            # per-layer health stats in the SAME dispatch: raw (pre-norm)
-            # grads, the applied deltas, and the post-update params
-            stats = _health.model_stats(params, grads_raw, deltas,
-                                        act_stats, stats_cfg, loss=loss)
-            return params, opt_state, new_states, loss, stats
+    @staticmethod
+    def _batch_size(inputs) -> int:
+        return int(inputs[0].shape[0])
 
-        # the XLA module carries the retrace-guard name, so a device
-        # trace tells the train step from any other jit_step
-        step.__name__ = ("ComputationGraph_train_step_stats" if collect
-                         else "ComputationGraph_train_step")
-        return jax.jit(step, donate_argnums=(0, 1),
-                       compiler_options=_xla.train_step_options())
+    def fit(self, data, labels=None, *, epochs: int = 1,
+            coalesce: Optional[int] = None, session=None) -> None:
+        """Train from (inputs, labels), a DataSet/MultiDataSet, or an iterator
+        of either (parity: fit variants :614-760). No ``mask`` keyword: a
+        graph's masks ride in its DataSet batches. ``coalesce``, ``session``
+        and the loop itself: ``TrainableNetwork._fit``."""
+        self._fit(data, labels, None, epochs, coalesce, session)
 
-    def _train_step(self):
-        # explicit override first (ParallelWrapper installs its sharded
-        # SPMD step here; an override is pinned, not trace-env-keyed and
-        # not stats-keyed — sharded steps do not collect health stats)
-        fn = self._jit_cache.get("train_step_override")
-        if fn is not None:
-            return fn
-        cfg = self.health_stats
-        suffix = "" if cfg is None else f"|stats={cfg.trace_key()}"
-        cache_key = f"train_step@{_xla.trace_env_key()}{suffix}"
-        fn = self._jit_cache.get(cache_key)
-        if fn is None:
-            # distinct guard name for the stats variant: the no-stats
-            # trace's retrace pin must not move when stats toggle
-            name = ("ComputationGraph.train_step" if cfg is None
-                    else "ComputationGraph.train_step_stats")
-            fn = _xla.retrace_guard(self._make_train_step(cfg), name)
-            self._jit_cache[cache_key] = fn
-        return fn
-
-    def enable_health_stats(self, config=True) -> None:
-        """Compute per-layer training-health stats (util.health) INSIDE
-        the train dispatch from the next fit call on: the stats-keyed jit
-        cache traces a separate program, so the cached no-stats trace is
-        untouched and toggling back off reuses it without a recompile.
-        Consumers read :func:`util.health.latest_stats` — one host sync
-        per read, the snapshot carries the step loss."""
-        self.health_stats = _health.StatsConfig.coerce(config)
-
-    def disable_health_stats(self) -> None:
-        self.health_stats = None
-
-    def set_listeners(self, *listeners) -> None:
-        # Accept both varargs and a single collection (ref Model.setListeners
-        # has both overloads).
-        if len(listeners) == 1 and isinstance(listeners[0], (list, tuple)):
-            listeners = tuple(listeners[0])
-        self.listeners = list(listeners)
-
-    def add_listener(self, listener) -> None:
-        self.listeners.append(listener)
-
-    def _fire_iteration(self, batch_size, loss):
-        self.iteration_count += 1
-        if not self.listeners:
-            return
-        # LazyScore delivery: the device loss syncs to host only when a
-        # listener actually reads it (host scalars from the fused-scan
-        # replay pass through)
-        from ..util.ingest import as_listener_score
-        score = as_listener_score(loss)
-        for l in self.listeners:
-            if hasattr(l, "record_batch"):
-                l.record_batch(batch_size)
-            l.iteration_done(self, self.iteration_count, score)
-
-    def _make_train_scan(self, stats_cfg: Optional[_health.StatsConfig] = None):
-        """K train steps fused into ONE lax.scan XLA program (same design as
-        MultiLayerNetwork._make_train_scan). With ``stats_cfg`` the scan
-        also emits the health-stats pytree of the LAST step."""
-        t = self.training
-        norm_kind = t.gradient_normalization
-        norm_thr = float(t.gradient_normalization_threshold)
-        updater = self._updater
-        base = _rng.key(t.seed)
-        collect = stats_cfg is not None
-
-        def one(carry, batch):
-            params, opt_state, states, it = carry
-            xs, ys, masks = batch
-            # per-step rng derived from the TRACED counter — computing keys
-            # eagerly from the host-side update count bakes fresh constants
-            # into the program and forces a recompile every call
-            rng = jax.random.fold_in(base, it)
-            loss, new_states, grads_raw, act_stats = \
-                _health.value_grad_with_stats(
-                    self._loss_fn, stats_cfg, params, states, xs, ys,
-                    masks, rng)
-            grads = _updaters.normalize_gradients(grads_raw, norm_kind,
-                                                  norm_thr)
-            deltas, opt_state = updater.update(grads, opt_state, it)
-            params = _updaters.apply_updates(params, deltas)
-            kept = {name: {k: new_states[name].get(k, v)
-                           for k, v in st_old.items()}
-                    for name, st_old in states.items()}
-            if collect:
-                stats = _health.model_stats(params, grads_raw, deltas,
-                                            act_stats, stats_cfg, loss=loss)
-                return (params, opt_state, kept, it + 1), (loss, stats)
-            return (params, opt_state, kept, it + 1), loss
-
-        def scan_steps(params, opt_state, states, xs, ys, masks, it0):
-            (params, opt_state, states, _), ys_out = jax.lax.scan(
-                one, (params, opt_state, states, it0), (xs, ys, masks),
-                unroll=_xla.scan_unroll())
-            if collect:
-                losses, stats_seq = ys_out
-                last_stats = jax.tree_util.tree_map(lambda a: a[-1],
-                                                    stats_seq)
-                return params, opt_state, states, losses, last_stats
-            return params, opt_state, states, ys_out
-
-        return jax.jit(scan_steps, donate_argnums=(0, 1),
-                       compiler_options=_xla.train_step_options())
+    def fit_batch(self, inputs, labels, masks=None):
+        """One update (tbptt-aware). inputs/labels: array or list of arrays
+        (multi-input / multi-output); masks: optional list of feature
+        masks."""
+        return self._fit_batch(*self._as_batch(inputs, labels, masks))
 
     def fit_scan(self, xs, ys, masks=None):
         """Train on K pre-staged batches in one dispatch. xs/ys: [k, b, ...]
         arrays or lists of such (multi-input/multi-output); returns [k] losses."""
-        xs = [jnp.asarray(a) for a in _as_list(xs)]
-        ys = [jnp.asarray(a) for a in _as_list(ys)]
-        self._reject_tbptt([x[0] for x in xs], "fit_scan")
-        k = xs[0].shape[0]
-        if masks is not None:
-            masks = [None if m is None else jnp.asarray(m)
-                     for m in _as_list(masks)]
-        cfg = self.health_stats
-        suffix = "" if cfg is None else f"|stats={cfg.trace_key()}"
-        cache_key = f"train_scan@{_xla.trace_env_key()}{suffix}"
-        fn = self._jit_cache.get(cache_key)
-        if fn is None:
-            name = ("ComputationGraph.train_scan" if cfg is None
-                    else "ComputationGraph.train_scan_stats")
-            fn = _xla.retrace_guard(self._make_train_scan(cfg), name)
-            self._jit_cache[cache_key] = fn
-        it0 = jnp.asarray(self._update_count, jnp.int32)
-        out = fn(
-            self.params, self.updater_state, self._states_map(), xs, ys,
-            masks, it0)
-        if cfg is not None:
-            params, opt_state, new_states, losses, stats = out
-            self._last_health_stats = _health.DeviceStats(
-                stats, iteration=self.iteration_count + k,
-                model="ComputationGraph")
-        else:
-            params, opt_state, new_states, losses = out
-        self.params = params
-        self.updater_state = opt_state
-        self._update_count += k
-        self._persist_states(new_states)
-        self._score = losses[-1]
-        # replay per-step losses so listener/stats semantics (score history,
-        # throughput via record_batch) match fit()/fit_batch for k updates
-        if self.listeners:
-            batch_size = int(xs[0].shape[1])
-            per_step = np.asarray(losses)
-            for i in range(k):
-                self._fire_iteration(batch_size, per_step[i])
-        else:
-            self.iteration_count += k
-        return losses
-
-    def _make_train_repeat(self, stats_cfg: Optional[_health.StatsConfig] = None):
-        """K train steps on ONE closed-over batch via lax.scan over step
-        indices — constant HBM regardless of K. Used by fit_repeated().
-        With ``stats_cfg`` the scan also emits the health-stats pytree of
-        the LAST step (same window semantics as fit_scan)."""
-        t = self.training
-        norm_kind = t.gradient_normalization
-        norm_thr = float(t.gradient_normalization_threshold)
-        updater = self._updater
-        base = _rng.key(t.seed)
-        collect = stats_cfg is not None
-
-        def one(xs, ys, masks, carry, it):
-            params, opt_state, states = carry
-            rng = jax.random.fold_in(base, it)
-            loss, new_states, grads_raw, act_stats = \
-                _health.value_grad_with_stats(
-                    self._loss_fn, stats_cfg, params, states, xs, ys,
-                    masks, rng)
-            grads = _updaters.normalize_gradients(grads_raw, norm_kind,
-                                                  norm_thr)
-            deltas, opt_state = updater.update(grads, opt_state, it)
-            params = _updaters.apply_updates(params, deltas)
-            kept = {name: {k: new_states[name].get(k, v)
-                           for k, v in st_old.items()}
-                    for name, st_old in states.items()}
-            if collect:
-                stats = _health.model_stats(params, grads_raw, deltas,
-                                            act_stats, stats_cfg, loss=loss)
-                return (params, opt_state, kept), (loss, stats)
-            return (params, opt_state, kept), loss
-
-        def repeat_steps(params, opt_state, states, xs, ys, masks, it0, k):
-            # unroll (default 2): XLA removes inter-iteration carry copies
-            # between the paired bodies (measured ~1.2 ms/step on ResNet-50
-            # @ v5e); DL4JTPU_SCAN_UNROLL overrides for tuning
-            (params, opt_state, states), ys_out = jax.lax.scan(
-                functools.partial(one, xs, ys, masks),
-                (params, opt_state, states), it0 + jnp.arange(k),
-                unroll=_xla.scan_unroll())
-            if collect:
-                losses, stats_seq = ys_out
-                last_stats = jax.tree_util.tree_map(lambda a: a[-1],
-                                                    stats_seq)
-                return params, opt_state, states, losses, last_stats
-            return params, opt_state, states, ys_out
-
-        return jax.jit(repeat_steps, donate_argnums=(0, 1, 2),
-                       static_argnums=(7,),
-                       compiler_options=_xla.train_step_options())
+        return self._fit_scan(*self._as_batch(xs, ys, masks))
 
     def fit_repeated(self, inputs, labels, k: int, masks=None):
         """Run K optimizer updates on one pre-staged batch in a single device
@@ -784,73 +532,7 @@ class ComputationGraph:
         ``fit_batch`` K times: same per-update rng folding, iteration counters,
         and listener firing — but one dispatch and one batch of HBM. Used for
         steady-state throughput measurement; returns [k] losses."""
-        inputs = [jnp.asarray(x) for x in _as_list(inputs)]
-        labels = [jnp.asarray(y) for y in _as_list(labels)]
-        self._reject_tbptt(inputs, "fit_repeated")
-        if masks is not None:
-            masks = [None if m is None else jnp.asarray(m)
-                     for m in _as_list(masks)]
-        cfg = self.health_stats
-        suffix = "" if cfg is None else f"|stats={cfg.trace_key()}"
-        cache_key = f"train_repeat@{_xla.trace_env_key()}{suffix}"
-        fn = self._jit_cache.get(cache_key)
-        if fn is None:
-            name = ("ComputationGraph.train_repeat" if cfg is None
-                    else "ComputationGraph.train_repeat_stats")
-            fn = _xla.retrace_guard(self._make_train_repeat(cfg), name)
-            self._jit_cache[cache_key] = fn
-        it0 = jnp.asarray(self._update_count, jnp.int32)
-        out = fn(
-            self.params, self.updater_state, self._states_map(), inputs,
-            labels, masks, it0, int(k))
-        if cfg is not None:
-            params, opt_state, new_states, losses, stats = out
-            self._last_health_stats = _health.DeviceStats(
-                stats, iteration=self.iteration_count + int(k),
-                model="ComputationGraph")
-        else:
-            params, opt_state, new_states, losses = out
-        self.params = params
-        self.updater_state = opt_state
-        self._update_count += int(k)
-        self._persist_states(new_states)
-        self._score = losses[-1]
-        if self.listeners:
-            batch_size = int(inputs[0].shape[0])
-            per_step = np.asarray(losses)
-            for i in range(int(k)):
-                self._fire_iteration(batch_size, per_step[i])
-        else:
-            self.iteration_count += int(k)
-        return losses
-
-    def fit_batch(self, inputs, labels, masks=None):
-        """One update (tbptt-aware). inputs/labels: array or list of arrays
-        (multi-input / multi-output); masks: optional list of feature
-        masks."""
-        inputs = [jnp.asarray(x) for x in _as_list(inputs)]
-        labels = [jnp.asarray(y) for y in _as_list(labels)]
-        if masks is not None:
-            masks = [None if m is None else jnp.asarray(m)
-                     for m in _as_list(masks)]
-        T = self._tbptt_T(inputs)
-        if T is not None and T > self.conf.tbptt_fwd_length:
-            return self._fit_tbptt(inputs, labels, masks, T)
-        loss = self._step_and_update(inputs, labels, masks, None)
-        self._score = loss
-        self._fire_iteration(inputs[0].shape[0], loss)
-        return loss
-
-    def _reject_tbptt(self, inputs, api: str) -> None:
-        """The fused-scan paths run ONE full-sequence BPTT update per batch;
-        silently doing that under a truncated_bptt config would change both
-        memory behavior and optimization semantics — refuse loudly."""
-        T = self._tbptt_T(inputs)
-        if T is not None and T > self.conf.tbptt_fwd_length:
-            raise ValueError(
-                f"{api} does not chunk truncated BPTT (T={T} > "
-                f"tbptt_fwd_length={self.conf.tbptt_fwd_length}); use "
-                "fit()/fit_batch(), or pre-chunk the sequences")
+        return self._fit_repeated(*self._as_batch(inputs, labels, masks), k)
 
     def _tbptt_T(self, inputs):
         """The time-series length for truncated BPTT, scanning ALL inputs
@@ -868,37 +550,16 @@ class ComputationGraph:
                 "across inputs is ambiguous — align or pad them")
         return ts.pop()
 
-    def _fit_tbptt(self, inputs, labels, masks, T):
-        """Truncated BPTT over the DAG: slice [b, t, ...] into fwd-length
-        chunks, carrying every recurrent vertex's h/c across chunks with
-        gradients stopped at the boundary (parity: the reference
-        ComputationGraph's doTruncatedBPTT)."""
-        length = self.conf.tbptt_fwd_length
-        batch = inputs[0].shape[0]
-
-        def _slice(a, start, end):
-            return (a[:, start:end]
-                    if a is not None and a.ndim == 3 and a.shape[1] == T
-                    else a)
-
-        rnn_state = self._zero_rnn_carry(batch)
-        loss = 0.0
-        for start in range(0, T, length):
-            end = min(start + length, T)
-            xs = [_slice(x, start, end) for x in inputs]
-            ys = [_slice(y, start, end) for y in labels]
-            ms = (None if masks is None else
-                  [m[:, start:end] if (m is not None and m.ndim >= 2
-                                       and m.shape[1] == T) else m
-                   for m in masks])
-            loss = self._step_and_update(xs, ys, ms, rnn_state)
-            rnn_state = self._last_rnn_carry
-            # one iteration (and listener firing) per TBPTT segment, matching
-            # the reference's doTruncatedBPTT accounting: listeners see every
-            # iteration number, not one per full-sequence batch.
-            self._score = loss
-            self._fire_iteration(batch, loss)
-        return loss
+    @staticmethod
+    def _tbptt_slice(inputs, labels, masks, T, start, end):
+        """Only what runs the whole length T is cut: a static [b, f] input,
+        a per-sequence label or a mask of another length passes whole."""
+        def cut(a, temporal):
+            return a[:, start:end] if temporal and a.shape[1] == T else a
+        return ([cut(x, x.ndim == 3) for x in inputs],
+                [cut(y, y.ndim == 3) for y in labels],
+                None if masks is None else
+                [cut(m, m is not None and m.ndim >= 2) for m in masks])
 
     def _zero_rnn_carry(self, batch):
         mbs = self._minibatch_map(batch)
@@ -915,55 +576,6 @@ class ComputationGraph:
             else:
                 carry[name] = {}
         return carry
-
-    def _step_and_update(self, inputs, labels, masks, rnn_state):
-        rng = _rng.fold_name(_rng.key(self.training.seed),
-                             f"update_{self._update_count}")
-        it = jnp.asarray(self._update_count, jnp.int32)
-        out = self._train_step()(
-            self.params, self.updater_state, self._states_map(rnn_state),
-            inputs, labels, masks, rng, it)
-        # sharded overrides always return 4 outputs; only the stats
-        # variant of the owned step returns the fifth (the stats pytree)
-        if len(out) == 5:
-            params, opt_state, new_states, loss, stats = out
-            self._last_health_stats = _health.DeviceStats(
-                stats, iteration=self.iteration_count + 1,
-                model="ComputationGraph")
-        else:
-            params, opt_state, new_states, loss = out
-        self.params = params
-        self.updater_state = opt_state
-        self._update_count += 1
-        # stop-gradient boundary for tbptt: carry values, not graph
-        self._last_rnn_carry = jax.tree_util.tree_map(
-            jax.lax.stop_gradient,
-            {name: {k: v for k, v in st.items() if k in ("h", "c")}
-             for name, st in new_states.items()})
-        self._persist_states(new_states)
-        return loss
-
-    def fit(self, data, labels=None, *, epochs: int = 1,
-            coalesce: Optional[int] = None, session=None) -> None:
-        """Train from (inputs, labels), a DataSet/MultiDataSet, or an iterator
-        of either (parity: fit variants :614-760).
-
-        Same async-dispatch loop as ``MultiLayerNetwork.fit``: background
-        device staging for iterator sources, bounded in-flight window,
-        LazyScore listener delivery, lazy epoch-start resets (the final
-        epoch never restarts the producer), optional same-shape
-        coalescing via ``coalesce=K`` / ``DL4JTPU_COALESCE_K``.
-        """
-        from ..util.ingest import run_fit_loop
-        if self.params is None:
-            self.init()
-        run_fit_loop(self, data, labels, None, epochs, coalesce,
-                     model_label="ComputationGraph", session=session)
-
-    @staticmethod
-    def _as_batches(data, labels=None, mask=None):
-        from ..util.batching import iter_batches
-        return iter_batches(data, labels, mask)
 
     # ------------------------------------------------------------------
     # layerwise pretraining (parity: ComputationGraph.pretrain :509-523)
@@ -1056,6 +668,3 @@ class ComputationGraph:
         if hasattr(data, "reset"):
             data.reset()
         return ev
-
-    def clone_params(self):
-        return jax.tree_util.tree_map(lambda p: jnp.array(p), self.params)
