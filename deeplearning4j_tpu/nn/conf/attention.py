@@ -225,8 +225,11 @@ class SelfAttentionLayer(Layer):
     def apply_paged(self, params, x, k_pool, v_pool, page_table,
                     write_slots, rel_pos, *, policy=None):
         """Paged-arena streaming decode (the serving continuous-batching
-        path): K/V live in shared ``[num_pages, page_size, h, d]`` block
-        pools instead of a per-sequence dense cache; each lane's page
+        path): K/V live in shared ``[num_pages, page_size, h*d]`` block
+        pools instead of a per-sequence dense cache (a token's heads in
+        one row: the layout a program's entry parameter keeps through
+        the scatter and the gather, so no pool is copied; nothing here
+        may reshape a pool, ``ops.paged_attention``); each lane's page
         table names its window, which ``ops.paged_attention.
         paged_read_attention`` reads a chunk of pages at a time, as far
         as the furthest live position of the dispatch, under a running

@@ -2,7 +2,7 @@
 
 vLLM-style block cache (PAPERS: PagedAttention/SOSP'23) for the
 continuous-batching decode path: per-layer K/V live in preallocated
-``[num_pages, page_size, heads, head_dim]`` block pools; each sequence
+``[num_pages, page_size, heads * head_dim]`` block pools; each sequence
 owns an ordered *page table* of physical page ids. A decode step scatters
 the new tokens' K/V into the pools at (page, offset) and then reads them
 back through :func:`paged_read_attention`, which walks the page table a
@@ -25,6 +25,20 @@ page here, a token there — and diverge by design).
 Layout conventions (shared with ``serving/kv_cache.py`` and
 ``serving/decode.py``):
 
+- a pool is stored ``[num_pages, page_size, h*d]``: a token's heads lie
+  side by side in one row. A decode program's entry parameter has the
+  default layout, and with a row of ``h*d`` (a whole number of 128-lane
+  tiles) that is the layout the compiler keeps for the scatter and the
+  gather; stored ``[..., h, d]`` with ``d`` = 64 minor, it relaid every
+  donated pool at the program's entry and again before its result (96
+  pool-sized copies a dispatch at 24 layers; PERF.md, PR 32).
+- NO PROGRAM RESHAPES A WHOLE POOL (nor transposes, converts or does
+  arithmetic on one): a pool enters a scatter and a gather and leaves
+  as the scatter's result. Heads are split off the GATHERED pages only,
+  turned so that the keys lie on the minor axis (:func:`paged_gather`:
+  ``[S, keys, h*d]`` → ``[S, h, d, keys]``); a reshape of the pool
+  itself, even back and forth as a "view", brings the copies back
+  twofold. ``tests/test_pool_layout.py`` holds every program to it.
 - page tables are ``[lanes, pages_per_seq]`` int32 of PHYSICAL page ids;
   unallocated entries hold the SENTINEL ``num_pages`` (one past the pool)
   — gathers fill zeros there, scatters drop.
@@ -58,33 +72,39 @@ __all__ = ["paged_write", "paged_gather", "paged_read_attention",
 READ_CHUNK_TOKENS = 128
 
 
+def _write_targets(num_pages, page_size, page_table, write_slots):
+    """``(phys, off)`` of each new token, ``[S, t_new]`` both: padded
+    tokens (slot < 0) and sentinel table entries land out of bounds, so
+    a scatter with ``mode="drop"`` discards them."""
+    p_idx = jnp.clip(write_slots // page_size, 0, page_table.shape[1] - 1)
+    phys = jnp.take_along_axis(page_table, p_idx, axis=1)
+    return (jnp.where(write_slots >= 0, phys, num_pages),
+            write_slots % page_size)
+
+
 @jax.named_scope("attn.paged_write")
 def paged_write(pool, new, page_table, write_slots):
     """Scatter new K (or V) rows into the block pool.
 
-    pool: ``[num_pages, page_size, h, d]`` — or, int8-quantized, a
+    pool: ``[num_pages, page_size, h*d]`` — or, int8-quantized, a
     ``(q_int8, scales)`` tuple (see :func:`_paged_write_q8`); new:
-    ``[S, t_new, h, d]``; page_table: ``[S, P]`` physical page ids;
-    write_slots: ``[S, t_new]`` view-relative slot per token (``-1`` =
-    padded, dropped). Returns the updated pool (same structure as the
-    input). Out-of-range/sentinel targets are dropped, so padded lanes
-    can never corrupt a live page.
+    ``[S, t_new, h, d]``, written as rows of ``h*d``; page_table:
+    ``[S, P]`` physical page ids; write_slots: ``[S, t_new]``
+    view-relative slot per token (``-1`` = padded, dropped). Returns the
+    updated pool (same structure as the input). Out-of-range/sentinel
+    targets are dropped, so padded lanes can never corrupt a live page.
     """
     if isinstance(pool, tuple):
         return _paged_write_q8(pool, new, page_table, write_slots)
-    num_pages, page_size = pool.shape[0], pool.shape[1]
-    p_idx = jnp.clip(write_slots // page_size, 0, page_table.shape[1] - 1)
-    off = write_slots % page_size
-    phys = jnp.take_along_axis(page_table, p_idx, axis=1)
-    # padded tokens (slot < 0) and sentinel table entries both land out of
-    # bounds → mode="drop" discards the write
-    phys = jnp.where(write_slots >= 0, phys, num_pages)
-    return pool.at[phys, off].set(new.astype(pool.dtype), mode="drop")
+    phys, off = _write_targets(pool.shape[0], pool.shape[1], page_table,
+                               write_slots)
+    rows = new.reshape(new.shape[0], new.shape[1], -1)
+    return pool.at[phys, off].set(rows.astype(pool.dtype), mode="drop")
 
 
 def _paged_write_q8(pool, new, page_table, write_slots):
     """int8 write path: ``pool = (q, scales)`` with ``q`` the
-    ``[num_pages, page_size, h, d]`` int8 codes and ``scales`` the
+    ``[num_pages, page_size, h*d]`` int8 codes and ``scales`` the
     per-(page, head) ``[num_pages, h]`` f32 quantization step.
 
     Scales are MONOTONE per page: a write first folds the new rows'
@@ -94,15 +114,15 @@ def _paged_write_q8(pool, new, page_table, write_slots):
     the new rows quantized at the new scale. Monotonicity keeps already
     written tokens valid without tracking per-row scales; the bounded
     requantization drift it costs is covered by the int8 quality gate
-    (logit max-err + greedy divergence, see PERF.md).
+    (logit max-err + greedy divergence, see PERF.md). The per-head
+    rescale works on the GATHERED pages (split into heads there), never
+    on the pool.
     """
     q, scales = pool
     num_pages, page_size = q.shape[0], q.shape[1]
-    h = q.shape[2]
-    p_idx = jnp.clip(write_slots // page_size, 0, page_table.shape[1] - 1)
-    off = write_slots % page_size
-    phys = jnp.take_along_axis(page_table, p_idx, axis=1)
-    phys = jnp.where(write_slots >= 0, phys, num_pages)       # [S, t]
+    s, t, h, d = new.shape
+    phys, off = _write_targets(num_pages, page_size, page_table,
+                               write_slots)                   # [S, t]
     newf = new.astype(jnp.float32)
     # 1) fold the new rows' amax into the touched pages' scales
     amax_tok = jnp.max(jnp.abs(newf), axis=-1)                # [S, t, h]
@@ -115,39 +135,49 @@ def _paged_write_q8(pool, new, page_table, write_slots):
     pages_q = jnp.take(q, flat_phys, axis=0, mode="fill", fill_value=0)
     r = jnp.take(ratio, flat_phys, axis=0,
                  mode="fill", fill_value=0.0)[:, None, :, None]
+    pages_q = pages_q.reshape(-1, page_size, h, d).astype(jnp.float32)
     q = q.at[flat_phys].set(
-        jnp.round(pages_q.astype(jnp.float32) * r).astype(jnp.int8),
+        jnp.round(pages_q * r).astype(jnp.int8).reshape(-1, page_size,
+                                                        h * d),
         mode="drop")
     # 3) quantize the new rows at the new step and scatter them in
     s_tok = jnp.take(new_scales, phys, axis=0,
                      mode="fill", fill_value=0.0)              # [S, t, h]
     rows = jnp.round(newf / jnp.maximum(s_tok[..., None], 1e-30))
     rows = jnp.clip(rows, -127, 127).astype(jnp.int8)
-    q = q.at[phys, off].set(rows, mode="drop")
+    q = q.at[phys, off].set(rows.reshape(s, t, h * d), mode="drop")
     return (q, new_scales)
 
 
 @jax.named_scope("attn.paged_gather")
-def paged_gather(pool, page_table):
-    """Gather pages into a contiguous view: the read loop's gather, one
-    chunk of each lane's table at a time.
+def paged_gather(pool, page_table, heads):
+    """Gather pages into a contiguous view with the KEYS ON THE MINOR
+    AXIS: the read loop's gather, one chunk of each lane's table at a
+    time.
 
-    pool: ``[num_pages, page_size, h, d]`` (or the int8
+    pool: ``[num_pages, page_size, h*d]`` (or the int8
     ``(q, scales)`` tuple — dequantized here, the one place reads
-    happen); page_table: ``[S, P]`` → ``[S, P·page_size, h, d]``.
-    Sentinel entries read as zeros (masked by the causal window in
+    happen); page_table: ``[S, P]`` → ``[S, h, d, P·page_size]`` with
+    ``h = heads``. The GATHERED pages are turned (``[S, keys, h*d]`` →
+    ``[S, h*d, keys]``) and the heads split off the second-minor axis,
+    which costs nothing; the pool is not touched. Splitting them off the
+    minor axis instead (``h*d`` → ``h, d`` with ``d`` = 64, half a lane
+    tile) made the compiler pad and relay every chunk, a fifth of a
+    decode block's device time (PERF.md, PR 32). Sentinel entries read
+    as zeros (masked by the causal window in
     :func:`paged_read_attention` anyway).
     """
+    codes = pool[0] if isinstance(pool, tuple) else pool
+    g = jnp.take(codes, page_table, axis=0, mode="fill", fill_value=0)
+    s, p, page_size, f = g.shape
+    g = jnp.swapaxes(g.reshape(s, p * page_size, f), 1, 2)
+    g = g.reshape(s, heads, f // heads, p, page_size)
     if isinstance(pool, tuple):
-        q, scales = pool
-        g = jnp.take(q, page_table, axis=0, mode="fill", fill_value=0)
-        sc = jnp.take(scales, page_table, axis=0,
+        sc = jnp.take(pool[1], page_table, axis=0,
                       mode="fill", fill_value=0.0)            # [S, P, h]
-        g = g.astype(jnp.float32) * sc[:, :, None, :, None]
-    else:
-        g = jnp.take(pool, page_table, axis=0, mode="fill", fill_value=0)
-    s, p, page_size, h, d = g.shape
-    return g.reshape(s, p * page_size, h, d)
+        g = g.astype(jnp.float32) * jnp.swapaxes(sc, 1, 2)[:, :, None, :,
+                                                            None]
+    return g.reshape(s, heads, f // heads, p * page_size)
 
 
 def read_chunk_pages(page_size: int, pages_per_seq: int) -> int:
@@ -185,7 +215,7 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
     contributed exactly 0 before and is simply not read now.
 
     q: ``[S, t_new, h, d]`` (compute dtype); k_pool/v_pool:
-    ``[num_pages, page_size, h, d]`` (or int8 ``(q, scales)`` tuples);
+    ``[num_pages, page_size, h*d]`` (or int8 ``(q, scales)`` tuples);
     page_table: ``[S, P]``; rel_pos: ``[S]`` view-relative position of
     each lane's FIRST new query (``global_pos - base``). Returns
     ``[S, t_new, h, d]``.
@@ -209,10 +239,10 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
         m, l, acc = carry
         table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
                                                axis=1)
-        k_c = paged_gather(k_pool, table_c)                   # [S, chunk, h, d]
-        v_c = paged_gather(v_pool, table_c)
+        k_c = paged_gather(k_pool, table_c, h)                # [S, h, d, chunk]
+        v_c = paged_gather(v_pool, table_c, h)
         with jax.named_scope("attn.paged_softmax"):
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_c) * scale
+            logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
             key_idx = c * chunk + jnp.arange(chunk)
             allow = key_idx[None, None, :] <= q_idx[:, :, None]
             logits = jnp.where(allow[:, None], logits.astype(jnp.float32),
@@ -223,7 +253,7 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
                           jnp.exp(logits - m_safe))
             alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
             l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v_c)
+            pv = jnp.einsum("bhqk,bhdk->bqhd", p.astype(q.dtype), v_c)
             acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
         return m_new, l, acc
 

@@ -4,7 +4,7 @@ The serving-side memory manager behind continuous-batching decode
 (PAPERS: vLLM/SOSP'23). Instead of a monolithic ``[b, max_t, f]`` cache
 per sequence — whose worst-case length must be reserved up front and
 whose slots idle whenever a sequence is shorter — K/V live in per-layer
-``[num_pages, page_size, heads, head_dim]`` block pools shared by every
+``[num_pages, page_size, heads * head_dim]`` block pools shared by every
 in-flight sequence. Each sequence owns an ordered page table of physical
 page ids; pages are handed out lazily as decode advances and returned to
 the free list the moment the sequence retires, so HBM holds exactly the
@@ -488,8 +488,18 @@ class PagedKVArena:
     one past the pool) marks page-table holes: gathers fill zeros there,
     scatters drop.
 
+    A pool is stored ``[num_pages, page_size, h*d]`` (a token's heads in
+    one row), for every layer, dtype and arena alike: a decode program's
+    entry parameter has the default layout, and for this shape that is
+    the one the compiler keeps for the scatter and the gather, so a
+    donated pool is updated in place. Stored ``[..., h, d]`` every
+    program relaid every pool at its entry and before its result
+    (PERF.md, PR 32). No program reshapes a whole pool; the heads are
+    split off the gathered pages (``ops/paged_attention``, "Layout
+    conventions").
+
     ``kv_dtype="int8"`` swaps each pool for a ``(q_int8, scales)`` tuple
-    — ``q_int8`` is ``[num_pages, page_size, h, d]`` int8, ``scales`` is
+    — ``q_int8`` is ``[num_pages, page_size, h*d]`` int8, ``scales`` is
     ``[num_pages, h]`` f32 per-(page, head) — quantized on write and
     dequantized in ``ops/paged_attention.paged_gather``. Tuples ride the
     engine's donated-pytree dispatch protocol unchanged.
@@ -543,7 +553,7 @@ class PagedKVArena:
         self.k_pools = []
         self.v_pools = []
         for h, d in self._layer_dims.values():
-            shape = (self.num_pages, self.page_size, h, d)
+            shape = (self.num_pages, self.page_size, h * d)
             if self.kv_dtype == "int8":
                 self.k_pools.append((jnp.zeros(shape, jnp.int8),
                                      jnp.zeros((self.num_pages, h),

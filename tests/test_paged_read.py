@@ -26,10 +26,10 @@ H, D = 2, 8
 def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale):
     """What ``apply_paged`` did before the bounded read: gather every
     lane's whole table, then mask (kept here as the test's reference)."""
-    k_view = paged_gather(k_pool, page_table)
-    v_view = paged_gather(v_pool, page_table)
-    t_new, w = q.shape[1], k_view.shape[1]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view) * scale
+    k_view = paged_gather(k_pool, page_table, H)        # [S, h, d, window]
+    v_view = paged_gather(v_pool, page_table, H)
+    t_new, w = q.shape[1], k_view.shape[-1]
+    logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_view) * scale
     q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]
     allow = jnp.arange(w)[None, None, :] <= q_idx[:, :, None]
     logits = jnp.where(allow[:, None], logits.astype(jnp.float32), -jnp.inf)
@@ -37,7 +37,7 @@ def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale):
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
     p = jnp.where(jnp.isneginf(logits), 0.0, jnp.exp(logits - m_safe))
     weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v_view)
+    return jnp.einsum("bhqk,bhdk->bqhd", weights.astype(q.dtype), v_view)
 
 
 def _arena(rng, lanes, page_size, pages_per_seq, live, *, int8=False):
@@ -54,14 +54,24 @@ def _arena(rng, lanes, page_size, pages_per_seq, live, *, int8=False):
         need = -(-int(n) // page_size)
         table[i, :need] = perm[at:at + need]
         at += need
-    if not int8:
-        return jnp.asarray(k), jnp.asarray(v), jnp.asarray(table)
-    pools = []
-    for x in (k, v):
-        scales = np.abs(x).max(axis=(1, 3)) / 127.0          # [pages, h]
-        codes = np.round(x / scales[:, None, :, None]).astype(np.int8)
-        pools.append((jnp.asarray(codes), jnp.asarray(scales)))
-    return pools[0], pools[1], jnp.asarray(table)
+    return (as_pool(k, int8=int8), as_pool(v, int8=int8),
+            jnp.asarray(table))
+
+
+def quantized(x):
+    """``[pages, page_size, h, d]`` floats as int8 codes of that shape
+    and their ``[pages, h]`` scales."""
+    scales = np.abs(x).max(axis=(1, 3)) / 127.0
+    return np.round(x / scales[:, None, :, None]).astype(np.int8), scales
+
+
+def as_pool(x, *, int8=False):
+    """A hand-built ``[pages, page_size, h, d]`` array as the arena stores
+    it: rows of ``h*d`` (int8: codes in that shape and ``[pages, h]``
+    scales). The one place the tests know the stored shape."""
+    x, scales = quantized(np.asarray(x)) if int8 else (np.asarray(x), None)
+    rows = jnp.asarray(x.reshape(x.shape[:2] + (-1,)))
+    return (rows, jnp.asarray(scales)) if int8 else rows
 
 
 # (page_size, pages_per_seq): two chunks of 8 pages; 16 chunks as in the
@@ -233,10 +243,11 @@ def test_fused_block_reads_inside_an_inner_while(fused_text, scope):
 
 def test_fused_block_gathers_a_chunk_never_the_window(fused_text):
     """No gather of the program yields a whole 256-token window of K/V
-    (the chunk is 8 pages of 16): ``tensor<1x16x16x2x8`` would be it."""
+    (the chunk is 8 pages of 16 rows of h*d): ``tensor<1x16x16x16`` would
+    be it."""
     assert "stablehlo.gather" in fused_text
-    assert "tensor<1x16x16x2x8x" not in fused_text
-    assert "tensor<1x8x16x2x8x" in fused_text
+    assert "tensor<1x16x16x16x" not in fused_text
+    assert "tensor<1x8x16x16x" in fused_text
 
 
 def _serve(net, prompts, new_tokens, **kw):
