@@ -45,6 +45,13 @@ FLOAT32 = DtypePolicy()
 MIXED_BF16 = DtypePolicy(param_dtype=jnp.float32, compute_dtype=jnp.bfloat16,
                          output_dtype=jnp.bfloat16)
 FLOAT64 = DtypePolicy(param_dtype=jnp.float64, compute_dtype=jnp.float64)
+# Stored bf16: parameters kept in bfloat16 as a served checkpoint is, bf16
+# compute. Serving only (a bf16 master copy does not train). What must stay
+# float32 does so where it is computed: router scores, softmax and norm
+# statistics, a state-space layer's dt, A and carried state, the K/V pools.
+STORED_BF16 = DtypePolicy(param_dtype=jnp.bfloat16,
+                          compute_dtype=jnp.bfloat16,
+                          output_dtype=jnp.bfloat16)
 
 _default_policy = FLOAT32
 
@@ -64,6 +71,8 @@ def policy_from_name(name: str) -> DtypePolicy:
         return FLOAT32
     if name in ("bfloat16", "bf16", "mixed", "mixed_bf16", "mixed_bfloat16"):
         return MIXED_BF16
+    if name in ("stored_bf16", "bf16_stored", "bfloat16_stored"):
+        return STORED_BF16
     if name in ("float64", "f64", "double"):
         return FLOAT64
     raise ValueError(f"unknown dtype policy {name!r}")

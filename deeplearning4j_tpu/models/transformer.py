@@ -28,6 +28,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+import jax
 import numpy as np
 
 from ..nn.conf.attention import SelfAttentionLayer
@@ -36,6 +37,7 @@ from ..nn.conf.graph import ElementWiseVertex, LayerVertex
 from ..nn.conf.inputs import InputType
 from ..nn.conf.layers import (EmbeddingSequenceLayer, LayerNormalization,
                               RnnOutputLayer)
+from ..nn.conf import ssm as _ssm
 from ..nn.conf.recurrent import TimeDistributedDenseLayer
 
 
@@ -140,6 +142,31 @@ def attention_vertices(net) -> List[str]:
         if isinstance(layer, SelfAttentionLayer) and layer.causal:
             names.append(name)
     return names
+
+
+def stateful_vertices(net) -> List[str]:
+    """Topo-ordered names of the vertices that own state during paged
+    decode, one ordered list for the walker, the arena and the engine:
+    causal attention (a K and a V pool) and state-space mixers (a
+    convolution tail and an SSM state a lane)."""
+    attn = set(attention_vertices(net))
+    return [name for name in net.topo_order
+            if name in attn
+            or isinstance(net._vertex_layer(name), _ssm.Mamba2Mixer)]
+
+
+def state_space_vertices(net) -> List[str]:
+    """Those of :func:`stateful_vertices` whose state is a fixed-size row a
+    lane, not pages."""
+    return [name for name in stateful_vertices(net)
+            if isinstance(net._vertex_layer(name), _ssm.Mamba2Mixer)]
+
+
+def counting_vertices(net) -> List[str]:
+    """Names of the expert layers that report their routing counts and
+    take the dispatch's valid positions as their mask."""
+    return [name for name in net.topo_order
+            if getattr(net._vertex_layer(name), "wants_token_mask", False)]
 
 
 def filtered_probs_host(p: np.ndarray, temperature: float, top_k: int,
@@ -264,42 +291,73 @@ def oracle_stream_probs(net, token_ids) -> np.ndarray:
 
 
 def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
-                         write_slots, rel_pos):
-    """ONE traced forward of an ids-mode ``transformer_lm`` graph in
-    paged-decode mode: every causal attention vertex reads/writes the
-    block pools through the lanes' page tables
-    (``SelfAttentionLayer.apply_paged``); every other vertex applies
-    exactly as in ``output()``. Pure w.r.t. its arguments, so the serving
-    engine jits it once per (lanes, chunk) bucket and admission/
-    retirement only ever change array CONTENTS.
+                         write_slots, rel_pos, lane_ids=None, counts=None):
+    """ONE traced forward of an ids-mode decoder graph in paged-decode
+    mode: every stateful vertex (:func:`stateful_vertices`) reads and
+    writes ITS entry of the two state lists; every other vertex applies
+    exactly as in ``output()``. A causal attention vertex owns a K and a V
+    block pool, read and written through the lanes' page tables
+    (``SelfAttentionLayer.apply_paged``); a state-space vertex owns a
+    ``[lanes, K-1, C]`` convolution tail and a ``[lanes, H, P, N]`` SSM
+    state (``Mamba2Mixer.apply_paged``), of which the dispatch's rows are
+    ``lane_ids``. Pure w.r.t. its arguments, so the serving engine jits it
+    once per (lanes, chunk) bucket and admission/retirement only ever
+    change array CONTENTS.
 
     ids: ``[S, t_new]`` int32 (padded lanes: any value — their writes are
     dropped and their outputs ignored); page_tables: ``[S, P]``;
     write_slots: ``[S, t_new]`` view-relative slots (-1 = dropped);
-    rel_pos: ``[S]``. Returns ``(probs [S, t_new, V], k_pools,
-    v_pools)``.
+    rel_pos: ``[S]``; lane_ids: ``[S]`` (only a net with state-space
+    vertices needs them; a padded slot of the bucket holds an id past the
+    last lane; None where the caller hands the state-space vertices'
+    entries in as the dispatch's own rows, ``Mamba2Mixer.apply_paged``). A
+    position is VALID where its write slot is not -1:
+    padding and retired lanes advance no recurrent state and count in no
+    expert layer; a lane at ``rel_pos`` 0 starts a sequence, so its
+    recurrent state starts from zero inside this program (no dispatch of
+    its own resets a lane). Returns ``(probs [S, t_new, V], k_pools,
+    v_pools)``; ``counts``, a list, receives the sum of the expert layers'
+    routing counts (``nn.conf.moe.MOE_STATS``, int32) where the net has
+    layers that count.
     """
-    attn = attention_vertices(net)
-    if len(attn) != len(k_pools):
+    owners = stateful_vertices(net)
+    if len(owners) != len(k_pools):
         raise ValueError(
-            f"{len(k_pools)} pools for {len(attn)} attention vertices")
-    pool_ix = {n: i for i, n in enumerate(attn)}
+            f"{len(k_pools)} pools for {len(owners)} stateful vertices")
+    pool_ix = {n: i for i, n in enumerate(owners)}
     k_pools, v_pools = list(k_pools), list(v_pools)
     acts = {net.conf.network_inputs[0]: ids[:, :, None]}
     mbs = net._minibatch_map(ids.shape[0])
+    # a position is valid where its write is kept: what a state-space
+    # vertex advances over and an expert layer counts
+    valid = (write_slots >= 0 if state_space_vertices(net)
+             or counting_vertices(net) else None)
+    stats = None
     for name in net.topo_order:
         in_names = net.conf.vertex_inputs[name]
+        layer = net._vertex_layer(name)
         i = pool_ix.get(name)
-        if i is not None:
-            layer = net.conf.vertices[name].layer
+        if i is not None and isinstance(layer, SelfAttentionLayer):
             out, k_pools[i], v_pools[i] = layer.apply_paged(
                 params[name], acts[in_names[0]], k_pools[i], v_pools[i],
                 page_tables, write_slots, rel_pos, policy=net.policy)
+        elif i is not None:
+            out, k_pools[i], v_pools[i] = layer.apply_paged(
+                params[name], acts[in_names[0]], k_pools[i], v_pools[i],
+                lane_ids, valid, rel_pos == 0, policy=net.policy)
+        elif getattr(layer, "wants_token_mask", False):
+            out, st = net._apply_vertex(name, params[name], acts, {}, None,
+                                        train=False, in_masks=[valid],
+                                        minibatch=mbs[in_names[0]])
+            stats = st["moe_stats"] if stats is None \
+                else stats + st["moe_stats"]
         else:
             out, _ = net._apply_vertex(name, params[name], acts, {}, None,
                                        train=False,
                                        minibatch=mbs[in_names[0]])
         acts[name] = out
+    if counts is not None and stats is not None:
+        counts.append(stats)
     return acts[net.conf.network_outputs[0]], k_pools, v_pools
 
 
@@ -326,7 +384,7 @@ def draft_transformer_lm(vocab_size: int, *, d_model: int = 128,
 
 def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
                       page_tables, rel_pos, active, budget, eos_ids,
-                      temperature, top_k, top_p, uniforms):
+                      temperature, top_k, top_p, uniforms, lane_ids=None):
     """N decode steps over the paged arena in ONE dispatch — the
     device-resident inner loop the serving engine jits per lane bucket
     (``uniforms [S, N]`` fixes N at trace time). Each inner step
@@ -351,7 +409,12 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     Returns ``(tokens [S, N], valid [S, N], n_emitted [S], done [S],
     k_pools, v_pools)`` — ``valid`` is a prefix mask; ``n_emitted`` is
     both the number of valid tokens AND the number of K/V slots the lane
-    actually wrote (the host advances its position by exactly this).
+    actually wrote (the host advances its position by exactly this). A
+    net with expert layers that count their routing returns the counts
+    summed over the block's steps after ``done``
+    (:func:`paged_decode_forward`), and the ``while`` carries them, as it
+    carries a state-space vertex's state in the two state lists
+    (``lane_ids``): a retired lane's dropped slot keeps its state still.
 
     Two CPU-harness-measured costs shape the implementation: the loop
     is a ``while_loop`` (not ``scan``) so a block whose every lane
@@ -367,6 +430,17 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     n_steps = uniforms.shape[1]
     s = last_tokens.shape[0]
     any_sampled = jnp.any(temperature > 0)
+    # a state-space vertex's per-lane state: the block's lanes are the
+    # same at every step, so their rows are gathered once here, carried
+    # through the steps as they are, and scattered back once at the end
+    k_pools, v_pools = list(k_pools), list(v_pools)
+    owners = stateful_vertices(net)
+    state_ix = [] if lane_ids is None else [
+        owners.index(name) for name in state_space_vertices(net)]
+    whole = {i: (k_pools[i], v_pools[i]) for i in state_ix}
+    for i in state_ix:
+        k_pools[i], v_pools[i] = _ssm.gather_lanes(k_pools[i], v_pools[i],
+                                                   lane_ids)
 
     def pick(row, u):
         return jax.lax.cond(
@@ -376,15 +450,16 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
             lambda: jnp.argmax(row, axis=-1).astype(jnp.int32))
 
     def cond_fn(st):
-        i, _, _, _, done, _, _, _ = st
+        i, done = st[0], st[4]
         return (i < n_steps) & jnp.logical_not(jnp.all(done))
 
     def body_fn(st):
-        i, k_pools, v_pools, cur, done, n_emitted, toks, valid = st
+        i, k_pools, v_pools, cur, done, n_emitted, toks, valid, *stats = st
         slot = jnp.where(done, jnp.int32(-1), rel_pos + i)
+        step_stats = []
         probs, k_pools, v_pools = paged_decode_forward(
             net, params, k_pools, v_pools, cur[:, None], page_tables,
-            slot[:, None], rel_pos + i)
+            slot[:, None], rel_pos + i, None, step_stats)
         with jax.named_scope("sample"):
             u = jax.lax.dynamic_index_in_dim(uniforms, i, axis=1,
                                              keepdims=False)
@@ -399,15 +474,21 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
         valid = jax.lax.dynamic_update_index_in_dim(valid, emit, i,
                                                     axis=1)
         return (i + 1, k_pools, v_pools, cur, done, n_emitted, toks,
-                valid)
+                valid, *(a + b for a, b in zip(stats, step_stats)))
 
-    st = (jnp.int32(0), list(k_pools), list(v_pools),
+    from ..nn.conf.moe import MOE_STATS
+    counts = counting_vertices(net)
+    st = (jnp.int32(0), k_pools, v_pools,
           last_tokens.astype(jnp.int32), jnp.logical_not(active),
           jnp.zeros(s, jnp.int32), jnp.full((s, n_steps), -1, jnp.int32),
-          jnp.zeros((s, n_steps), bool))
-    (_, k_pools, v_pools, _, done, n_emitted, toks,
-     valid) = jax.lax.while_loop(cond_fn, body_fn, st)
-    return toks, valid, n_emitted, done, k_pools, v_pools
+          jnp.zeros((s, n_steps), bool),
+          *([jnp.zeros(len(MOE_STATS), jnp.int32)] if counts else []))
+    (_, k_pools, v_pools, _, done, n_emitted, toks, valid,
+     *stats) = jax.lax.while_loop(cond_fn, body_fn, st)
+    for i in state_ix:
+        k_pools[i], v_pools[i] = _ssm.scatter_lanes(
+            *whole[i], lane_ids, k_pools[i], v_pools[i])
+    return (toks, valid, n_emitted, done, *stats, k_pools, v_pools)
 
 
 def draft_decode_loop(net, params, k_pools, v_pools, last_tokens,

@@ -61,6 +61,12 @@ def relu(x):
     return jax.nn.relu(x)
 
 
+@register("relu2")
+def relu2(x):
+    """Squared ReLU (Primer; the non-gated experts of ``nemotron_h``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 @register("leakyrelu")
 def leakyrelu(x, alpha: float = 0.01):
     return jnp.where(x >= 0, x, alpha * x)

@@ -35,11 +35,19 @@ class SelfAttentionLayer(Layer):
     Params: fused qkv projection ``Wqkv`` [n_in, 3·n_in], output projection
     ``Wo`` [n_in, n_out], bias ``b`` [n_out]. ``n_in`` must divide by
     ``n_heads``.
+
+    ``n_kv_heads`` < ``n_heads`` is grouped-query attention: query head
+    ``i`` reads K/V head ``i // (n_heads // n_kv_heads)``; ``Wqkv`` is then
+    [n_in, (n_heads + 2·n_kv_heads)·d], columns ``[q | k | v]``, and every
+    cache (dense streaming, paged pools) holds ``n_kv_heads·d`` a token.
+    ``has_bias=False`` leaves ``b`` out.
     """
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None       # defaults to n_in
     n_heads: int = 4
+    n_kv_heads: Optional[int] = None  # None = n_heads (multi-head)
+    has_bias: bool = True
     causal: bool = True
     # streaming decode: K/V cache length for rnn_time_step. None = no
     # cache — rnn_time_step then attends WITHIN each fed chunk only (no
@@ -71,6 +79,42 @@ class SelfAttentionLayer(Layer):
         if self.n_in % self.n_heads:
             raise ValueError(f"n_in={self.n_in} not divisible by "
                              f"n_heads={self.n_heads}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_heads={self.n_heads} not divisible by "
+                             f"n_kv_heads={self.n_kv_heads}")
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.n_in // self.n_heads
+
+    @property
+    def kv_width(self) -> int:
+        """Features a token's K (or V) takes in a cache."""
+        return self.kv_heads * self.head_dim
+
+    def _split_qkv(self, qkv):
+        """``[b, t, (h + 2·kv)·d]`` → q ``[b, t, h, d]``, k and v
+        ``[b, t, kv, d]``. Multi-head keeps the reshape it always had (the
+        lowered text of the served OPT block does not change)."""
+        b, t = qkv.shape[:2]
+        h, kv, d = self.n_heads, self.kv_heads, self.head_dim
+        if kv == h:
+            qkv = qkv.reshape(b, t, 3, h, d)
+            return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = jnp.split(qkv, [h * d, (h + kv) * d], axis=-1)
+        return (q.reshape(b, t, h, d), k.reshape(b, t, kv, d),
+                v.reshape(b, t, kv, d))
+
+    def _repeat_kv(self, x):
+        """K or V ``[b, t, kv, d]`` with each head repeated for the query
+        heads that read it (the dense paths; the paged read groups the
+        queries instead and never copies K/V)."""
+        g = self.n_heads // self.kv_heads
+        return x if g == 1 else jnp.repeat(x, g, axis=2)
 
     def preprocessor_for(self, input_type: InputType):
         # same adapters the recurrent layers insert (BaseRecurrentLayer)
@@ -88,8 +132,11 @@ class SelfAttentionLayer(Layer):
         return True
 
     def param_shapes(self, policy=None) -> Dict[str, Tuple[int, ...]]:
-        return {"Wqkv": (self.n_in, 3 * self.n_in),
-                "Wo": (self.n_in, self.n_out), "b": (self.n_out,)}
+        shapes = {"Wqkv": (self.n_in, self.n_in + 2 * self.kv_width),
+                  "Wo": (self.n_in, self.n_out)}
+        if self.has_bias:
+            shapes["b"] = (self.n_out,)
+        return shapes
 
     def regularized_params(self):
         return ("Wqkv", "Wo")
@@ -98,7 +145,7 @@ class SelfAttentionLayer(Layer):
         policy = policy or _dtypes.default_policy()
         dt = policy.param_dtype
         k1, k2 = jax.random.split(key)
-        wqkv = init_weights(k1, (self.n_in, 3 * self.n_in),
+        wqkv = init_weights(k1, (self.n_in, self.n_in + 2 * self.kv_width),
                             self.weight_init or "XAVIER",
                             fan_in=self.n_in, fan_out=self.n_in,
                             distribution=self.dist, dtype=dt)
@@ -106,9 +153,19 @@ class SelfAttentionLayer(Layer):
                           self.weight_init or "XAVIER",
                           fan_in=self.n_in, fan_out=self.n_out,
                           distribution=self.dist, dtype=dt)
-        return {"Wqkv": wqkv, "Wo": wo,
-                "b": jnp.full((self.n_out,), float(self.bias_init or 0.0),
-                              dt)}
+        params = {"Wqkv": wqkv, "Wo": wo}
+        if self.has_bias:
+            params["b"] = jnp.full((self.n_out,),
+                                   float(self.bias_init or 0.0), dt)
+        return params
+
+    def _project_out(self, params, att):
+        """``att [b, t, n_in]`` through ``Wo`` (+ ``b``) and the
+        activation."""
+        out = att @ params["Wo"].astype(att.dtype)
+        if self.has_bias:
+            out = out + params["b"].astype(att.dtype)
+        return self._act(self.activation or "identity")(out)
 
     def _zero_state(self, batch, policy):
         """Streaming K/V cache (only when ``max_cache_t`` is set): rides
@@ -132,7 +189,7 @@ class SelfAttentionLayer(Layer):
         # (bf16 rounds integers past 256), and cached K/V precision
         # benefits too
         dt = jnp.promote_types(policy.compute_dtype, jnp.float32)
-        shape = (batch, self.max_cache_t + 1, self.n_in)
+        shape = (batch, self.max_cache_t + 1, self.kv_width)
         return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
     def _apply_streaming(self, params, xc, state, policy):
@@ -169,8 +226,8 @@ class SelfAttentionLayer(Layer):
                 f"max_cache_t={max_t}; raise max_cache_t or feed smaller "
                 "chunks")
         wqkv = params["Wqkv"].astype(xc.dtype)
-        qkv = (xc @ wqkv).reshape(b, t_new, 3, h, f // h)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k_new, v_new = self._split_qkv(xc @ wqkv)
+        kw = self.kv_width
         k_cache, v_cache = state["h"], state["c"]
         pos = k_cache[0, -1, 0].astype(jnp.int32)
         # cache slot j holds global position base + j; this call may
@@ -187,15 +244,15 @@ class SelfAttentionLayer(Layer):
                         jnp.roll(kv[1], -shift, axis=1)),
             lambda kv: kv,
             (k_cache[:, :max_t], v_cache[:, :max_t]))
-        k_flat = k_new.reshape(b, t_new, f).astype(k_cache.dtype)
-        v_flat = v_new.reshape(b, t_new, f).astype(v_cache.dtype)
+        k_flat = k_new.reshape(b, t_new, kw).astype(k_cache.dtype)
+        v_flat = v_new.reshape(b, t_new, kw).astype(v_cache.dtype)
         zero = jnp.zeros((), pos.dtype)
         body_k = jax.lax.dynamic_update_slice(body_k, k_flat,
                                               (zero, write_pos, zero))
         body_v = jax.lax.dynamic_update_slice(body_v, v_flat,
                                               (zero, write_pos, zero))
-        kh = body_k.reshape(b, max_t, h, f // h)
-        vh = body_v.reshape(b, max_t, h, f // h)
+        kh = self._repeat_kv(body_k.reshape(b, max_t, self.kv_heads, f // h))
+        vh = self._repeat_kv(body_v.reshape(b, max_t, self.kv_heads, f // h))
         scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, kh) * scale
         # new query i sits at global position pos+i = view slot
@@ -212,9 +269,7 @@ class SelfAttentionLayer(Layer):
         weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True),
                                   1e-30)
         att = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(xc.dtype), vh)
-        wo = params["Wo"].astype(att.dtype)
-        out = att.reshape(b, t_new, f) @ wo + params["b"].astype(att.dtype)
-        out = self._act(self.activation or "identity")(out)
+        out = self._project_out(params, att.reshape(b, t_new, f))
         new_pos = (pos + t_new).astype(k_cache.dtype)
         k_cache = jnp.concatenate(
             [body_k, k_cache[:, max_t:].at[:, 0, 0].set(new_pos)], axis=1)
@@ -255,18 +310,26 @@ class SelfAttentionLayer(Layer):
         b, t_new, f = xc.shape
         h = self.n_heads
         with jax.named_scope("attn.qkv"):
-            qkv = (xc @ wqkv).reshape(b, t_new, 3, h, f // h)
-            q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q, k_new, v_new = self._split_qkv(xc @ wqkv)
         k_pool = paged_write(k_pool, k_new, page_table, write_slots)
         v_pool = paged_write(v_pool, v_new, page_table, write_slots)
         scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
-        att = paged_read_attention(q, k_pool, v_pool, page_table, rel_pos,
-                                   scale)
+        group = h // self.kv_heads
+        if group == 1:
+            att = paged_read_attention(q, k_pool, v_pool, page_table,
+                                       rel_pos, scale)
+        else:
+            # the query heads of one K/V head become further queries of
+            # it: [S, t, kv, g, d] -> [S, t·g, kv, d], so the read's
+            # products have g rows a key and K/V are gathered once
+            kv, d = self.kv_heads, f // h
+            qg = q.reshape(b, t_new, kv, group, d).swapaxes(2, 3)
+            att = paged_read_attention(
+                qg.reshape(b, t_new * group, kv, d), k_pool, v_pool,
+                page_table, rel_pos, scale, group=group)
+            att = att.reshape(b, t_new, group, kv, d).swapaxes(2, 3)
         with jax.named_scope("attn.out"):
-            wo = params["Wo"].astype(att.dtype)
-            out = (att.reshape(b, t_new, f) @ wo
-                   + params["b"].astype(att.dtype))
-            out = self._act(self.activation or "identity")(out)
+            out = self._project_out(params, att.reshape(b, t_new, f))
         return out, k_pool, v_pool
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
@@ -284,8 +347,8 @@ class SelfAttentionLayer(Layer):
         b, t, f = xc.shape
         h = self.n_heads
         with jax.named_scope("attn.qkv"):
-            qkv = (xc @ wqkv).reshape(b, t, 3, h, f // h)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q, k, v = self._split_qkv(xc @ wqkv)
+            k, v = self._repeat_kv(k), self._repeat_kv(v)
         seq_ctx = active_sequence_sharding()
         if seq_ctx is not None and seq_ctx[1] is not None:
             # sequence-parallel route: the time axis is sharded over the
@@ -309,9 +372,7 @@ class SelfAttentionLayer(Layer):
             att = dot_product_attention(q, k, v, causal=self.causal,
                                         mask=mask)
         with jax.named_scope("attn.out"):
-            wo = params["Wo"].astype(att.dtype)
-            out = att.reshape(b, t, f) @ wo + params["b"].astype(att.dtype)
-            out = self._act(self.activation or "identity")(out)
+            out = self._project_out(params, att.reshape(b, t, f))
             if mask is not None:
                 out = out * mask[:, :, None].astype(out.dtype)
         return out, state

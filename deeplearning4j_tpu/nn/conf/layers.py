@@ -175,6 +175,9 @@ class FeedForwardLayer(Layer):
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
+    # False leaves the bias out of the parameters and of the product (the
+    # heads and projections of models published without one)
+    has_bias: bool = True
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.feed_forward(self.n_out)
@@ -197,7 +200,10 @@ class FeedForwardLayer(Layer):
         return True
 
     def param_shapes(self, policy=None):
-        return {"W": (self.n_in, self.n_out), "b": (self.n_out,)}
+        shapes = {"W": (self.n_in, self.n_out)}
+        if self.has_bias:
+            shapes["b"] = (self.n_out,)
+        return shapes
 
     def init_params(self, key, policy=None):
         policy = policy or _dtypes.default_policy()
@@ -206,13 +212,16 @@ class FeedForwardLayer(Layer):
                          self.weight_init or "XAVIER",
                          fan_in=self.n_in, fan_out=self.n_out,
                          distribution=self.dist, dtype=dt)
+        if not self.has_bias:
+            return {"W": w}
         b = jnp.full((self.n_out,), float(self.bias_init or 0.0), dt)
         return {"W": w, "b": b}
 
     def pre_output(self, params, x, *, policy=None):
         policy = policy or _dtypes.default_policy()
         xc, wc = policy.cast_to_compute(x, params["W"])
-        return xc @ wc + params["b"].astype(xc.dtype)
+        z = xc @ wc
+        return z + params["b"].astype(xc.dtype) if self.has_bias else z
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None, policy=None):
@@ -273,7 +282,8 @@ class RnnOutputLayer(BaseOutputLayer):
         # x: [b, t, n_in] — einsum keeps the time axis, one big MXU matmul
         policy = policy or _dtypes.default_policy()
         xc, wc = policy.cast_to_compute(x, params["W"])
-        return jnp.einsum("bti,io->bto", xc, wc) + params["b"].astype(xc.dtype)
+        z = jnp.einsum("bti,io->bto", xc, wc)
+        return z + params["b"].astype(xc.dtype) if self.has_bias else z
 
 
 @register_layer("loss")
@@ -387,18 +397,6 @@ class EmbeddingSequenceLayer(FeedForwardLayer):
 
     def preprocessor_for(self, input_type: InputType):
         return None     # ids are consumed raw — never reshaped/cast
-
-    def param_shapes(self, policy=None):
-        shapes = {"W": (self.n_in, self.n_out)}
-        if self.has_bias:
-            shapes["b"] = (self.n_out,)
-        return shapes
-
-    def init_params(self, key, policy=None):
-        params = super().init_params(key, policy)
-        if not self.has_bias:
-            params.pop("b", None)
-        return params
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None, policy=None):
@@ -728,6 +726,52 @@ class LayerNormalization(Layer):
         y = (xf - mean) * jax.lax.rsqrt(var + self.eps)
         y = y * params["gamma"].astype(cdt) + params["beta"].astype(cdt)
         return y.astype(x.dtype), state
+
+
+@register_layer("rms_norm")
+@dataclasses.dataclass
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the feature (last) axis:
+    ``x / sqrt(mean(x^2) + eps) * gamma`` (Zhang & Sennrich 2019; the norm
+    of ``nemotron_h`` and most of today's decoder blocks). No mean is
+    subtracted and there is no offset. Statistics in at least float32."""
+
+    _trace_scope = "ln"
+
+    n_out: Optional[int] = None          # feature count (inferred)
+    eps: float = 1e-5
+
+    def set_n_in(self, input_type: InputType, override: bool = False) -> None:
+        if self.n_out is None or override:
+            self.n_out = (input_type.size if input_type.kind == "recurrent"
+                          else input_type.flat_size())
+
+    def has_params(self) -> bool:
+        return True
+
+    def regularized_params(self) -> Tuple[str, ...]:
+        return ()
+
+    def param_shapes(self, policy=None):
+        return {"gamma": (self.n_out,)}
+
+    def init_params(self, key, policy=None):
+        policy = policy or _dtypes.default_policy()
+        return {"gamma": jnp.ones((self.n_out,), policy.param_dtype)}
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None, policy=None):
+        return rms_normalize(x, params["gamma"], self.eps), state
+
+
+def rms_normalize(x, gamma, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, computed
+    in at least float32 and returned in ``x``'s type."""
+    cdt = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(cdt)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * gamma.astype(cdt)).astype(x.dtype)
 
 
 @register_layer("lrn")

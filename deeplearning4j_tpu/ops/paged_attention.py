@@ -61,6 +61,8 @@ device trace can tell the paged read from the rest of a decode step.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -198,8 +200,10 @@ def read_trip_count(rel_pos, t_new: int, page_size: int,
                       n_chunks)
 
 
-@jax.jit      # every layer of a program reads at the same shapes: traced once
-def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
+# every layer of a program reads at the same shapes: traced once
+@functools.partial(jax.jit, static_argnames=("group",))
+def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
+                         group: int = 1):
     """Causal attention of new queries over each lane's paged window,
     read only as far as the furthest live position of the dispatch.
 
@@ -219,6 +223,13 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
     page_table: ``[S, P]``; rel_pos: ``[S]`` view-relative position of
     each lane's FIRST new query (``global_pos - base``). Returns
     ``[S, t_new, h, d]``.
+
+    ``group`` > 1 is grouped-query attention as its caller lays it out
+    (``SelfAttentionLayer.apply_paged``): ``h`` counts the K/V heads and
+    the query axis holds ``group`` query heads for each of the
+    ``t_new // group`` positions, position-major, so query row ``j`` sits
+    at position ``rel_pos + j // group``. The pools' rows are ``h*d`` wide
+    either way.
     """
     codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
     num_pages, page_size = codes.shape[0], codes.shape[1]
@@ -232,8 +243,12 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale):
     if pad:     # a last, partial chunk reads sentinel pages: zeros, masked
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)),
                              constant_values=num_pages)
-    trips = read_trip_count(rel_pos, t_new, page_size, pages_per_seq)
-    q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]     # [S, t_new]
+    trips = read_trip_count(rel_pos, t_new // group, page_size,
+                            pages_per_seq)
+    if group == 1:
+        q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]  # [S, t_new]
+    else:
+        q_idx = rel_pos[:, None] + (jnp.arange(t_new) // group)[None, :]
 
     def fold(c, carry):
         m, l, acc = carry

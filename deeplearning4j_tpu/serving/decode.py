@@ -254,7 +254,6 @@ class PagedDecodeEngine:
                  block_len: int = 1, draft_net=None, draft_k: int = 4,
                  prefix_cache: bool = False,
                  kv_dtype: Optional[str] = None):
-        import jax.numpy as jnp
         self._validate_net(net)
         self.net = net
         self.lanes = int(max_batch)
@@ -284,16 +283,13 @@ class PagedDecodeEngine:
         self.registry = registry if registry is not None \
             else _metrics.MetricsRegistry()
         self._check_decode_config(net)
-        attn = _transformer.attention_vertices(net)
-        dims = {}
-        for name in attn:
-            layer = net.conf.vertices[name].layer
-            dims[name] = (layer.n_heads, layer.n_in // layer.n_heads)
-        # same dtype rule as the dense streaming cache (_zero_state):
-        # at least f32, so bf16 compute policies keep exact K/V
-        # (kv_dtype="int8" replaces the pools with quantized
-        # (codes, scales) tuples — dtype then only names the fp fallback)
-        dtype = jnp.promote_types(net.policy.compute_dtype, jnp.float32)
+        # what the net makes the engine carry beside K/V pages: per-lane
+        # recurrent state, and expert layers whose routing is counted
+        self.state_layers = _transformer.state_space_vertices(net)
+        self._counting = bool(_transformer.counting_vertices(net))
+        self._refuse_unsupported(net, prefix_cache=bool(prefix_cache),
+                                 draft_net=draft_net)
+        dims, dtype = self._arena_dims(net)
         self.arena = PagedKVArena(dims, num_pages=int(num_pages),
                                   page_size=self.page_size, dtype=dtype,
                                   registry=self.registry,
@@ -326,12 +322,7 @@ class PagedDecodeEngine:
                     f"draft vocab {self._embed_vocab(draft_net)} != "
                     f"target vocab {self.vocab} — accept/reject compares "
                     "distributions over one vocabulary")
-            ddims = {}
-            for name in _transformer.attention_vertices(draft_net):
-                layer = draft_net.conf.vertices[name].layer
-                ddims[name] = (layer.n_heads, layer.n_in // layer.n_heads)
-            ddtype = jnp.promote_types(draft_net.policy.compute_dtype,
-                                       jnp.float32)
+            ddims, ddtype = self._arena_dims(draft_net)
             self.draft_arena = PagedKVArena(
                 ddims, num_pages=int(num_pages), page_size=self.page_size,
                 dtype=ddtype, with_allocator=False, kv_dtype=kv_dtype)
@@ -393,13 +384,94 @@ class PagedDecodeEngine:
             "Key positions of the same dispatches' whole windows: lanes "
             "of the bucket x window, for every step of a block",
             ("kind",))
+        if self.state_layers:
+            self._m_state_resets = self.registry.counter(
+                "decode_state_resets_total",
+                "Lanes whose recurrent state a new sequence started from "
+                "zero (inside its first prefill program)")
+            state_bytes = float(self.arena.state_nbytes())
+            self.registry.gauge(
+                "decode_state_bytes",
+                "Bytes of per-lane recurrent state (convolution tails and "
+                "SSM states of the state-space layers) the arena holds"
+            ).set_function(lambda: state_bytes)
+        if self._counting:
+            self._m_moe_routed = self.registry.counter(
+                "moe_routed_pairs_total",
+                "(token, expert) pairs the expert layers routed, by where "
+                "the expert lives: held by this chip, or absent (its part "
+                "of the sum is left out)", ("where",))
+            self._m_moe_computed = self.registry.counter(
+                "moe_computed_pairs_total",
+                "Rows the expert layers' grouped product computed, a "
+                "tile's padding included")
+            self._m_moe_peak = self.registry.counter(
+                "moe_expert_load_peak_pairs_total",
+                "Pairs of the most loaded held expert, summed over expert "
+                "layers and decode steps")
+            self._m_moe_steps = self.registry.counter(
+                "moe_expert_load_steps_total",
+                "(expert layer, step) observations behind "
+                "moe_expert_load_peak_pairs_total")
+            self._m_moe_touched = self.registry.counter(
+                "moe_touched_experts_total",
+                "Held experts that got at least one pair, summed over "
+                "expert layers and steps: the expert matrices a step "
+                "has to read")
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
+        self._precompile: Optional[list] = None   # warm-up's first pass
         # why the last acquire_lane() refused: "lanes" | "pages" | None
         self.refused_by: Optional[str] = None
 
     # -- construction-time validation ---------------------------------
+
+    def _arena_dims(self, net):
+        """``(layer_dims, dtype)`` of the arena of ``net``, target or
+        draft: each stateful vertex in the walker's order with what it
+        holds (``PagedKVArena``), and the pools' dtype: the dense
+        streaming cache's rule (``_zero_state``), at least f32, so bf16
+        compute policies keep exact K/V (``kv_dtype="int8"`` replaces the
+        pools with quantized (codes, scales) tuples; the dtype then only
+        names the fp fallback)."""
+        import jax.numpy as jnp
+        dims = {}
+        for name in _transformer.stateful_vertices(net):
+            layer = net._vertex_layer(name)
+            dims[name] = ((layer.kv_heads, layer.head_dim)
+                          if isinstance(layer, SelfAttentionLayer)
+                          else layer.state_shapes(self.lanes))
+        return dims, jnp.promote_types(net.policy.compute_dtype,
+                                       jnp.float32)
+
+    def _refuse_unsupported(self, net, *, prefix_cache: bool,
+                            draft_net) -> None:
+        """What a net with recurrent state or counting expert layers
+        cannot be served with yet, refused here with the reason."""
+        if self.state_layers and prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True with state-space vertices "
+                f"{self.state_layers[:2]}...: a prefix hit maps the K/V "
+                "pages of a shared prompt prefix, but a recurrent state "
+                "after that prefix is kept nowhere (no snapshot at page "
+                "boundaries yet), so the lane would decode from a wrong "
+                "state — serve this net with prefix_cache=False")
+        if self._counting and prefix_cache:
+            raise ValueError(
+                "prefix_cache=True with an expert layer that takes the "
+                "dispatch's valid positions: a prefix hit re-feeds covered "
+                "positions with dropped writes, which the layer would "
+                "take for padding and skip — serve this net with "
+                "prefix_cache=False")
+        if draft_net is not None and (
+                self.state_layers
+                or _transformer.state_space_vertices(draft_net)):
+            raise ValueError(
+                "draft_net with state-space vertices: a rejected draft "
+                "token's K/V is simply overwritten, but a recurrent state "
+                "that advanced over it cannot be rolled back (no state "
+                "roll-back yet) — serve this net without a draft_net")
 
     @staticmethod
     def _validate_net(net) -> None:
@@ -412,9 +484,10 @@ class PagedDecodeEngine:
                 or len(net.conf.network_outputs) != 1):
             raise ValueError("paged decode needs exactly one input and "
                              "one output vertex")
-        if not _transformer.attention_vertices(net):
-            raise ValueError("no causal SelfAttentionLayer vertices — "
-                             "nothing to cache")
+        owners = _transformer.stateful_vertices(net)
+        if not owners:
+            raise ValueError("no causal SelfAttentionLayer or state-space "
+                             "vertices — nothing to cache")
         in_name = net.conf.network_inputs[0]
         consumers = [n for n in net.topo_order
                      if in_name in net.conf.vertex_inputs[n]]
@@ -432,11 +505,15 @@ class PagedDecodeEngine:
                         f"vertex {name!r}: non-causal attention cannot "
                         "decode incrementally")
                 continue
+            if name in owners:
+                continue
             if layer is not None and hasattr(layer, "_zero_state"):
                 raise ValueError(
                     f"vertex {name!r} ({type(layer).__name__}) carries "
-                    "recurrent state — paged decode supports attention-"
-                    "only sequence mixing")
+                    "recurrent state — paged decode supports causal "
+                    "attention (paged K/V) and Mamba-2 state-space mixers "
+                    "(per-lane state) as sequence mixing; an LSTM's carry "
+                    "has no arena")
             if v.init_state(net.policy):
                 raise ValueError(
                     f"vertex {name!r} carries persistent state — "
@@ -518,6 +595,10 @@ class PagedDecodeEngine:
                 self.refused_by = "pages"
                 return None
         lane = self._free_lanes.popleft()
+        if self.state_layers:
+            # the lane's recurrent state starts from zero inside the
+            # sequence's first prefill program (rel_pos 0): no dispatch
+            self._m_state_resets.inc()
         cov = len(covered_pages)
         covered_tokens = cov * ps
         self._base[lane] = 0
@@ -644,8 +725,10 @@ class PagedDecodeEngine:
             if arena is None or arena.kv_dtype != "int8":
                 continue
             for pools in (arena.k_pools, arena.v_pools):
-                for i, (q, s) in enumerate(pools):
-                    pools[i] = (q, s.at[idx].set(0.0))
+                for i, pool in enumerate(pools):
+                    if isinstance(pool, tuple):     # not recurrent state
+                        q, s = pool
+                        pools[i] = (q, s.at[idx].set(0.0))
 
     def advance(self, lane: int, n: int) -> None:
         """Account ``n`` tokens written by the dispatch that just ran."""
@@ -658,7 +741,8 @@ class PagedDecodeEngine:
     # -- the jitted paged step ----------------------------------------
 
     def run(self, ids: np.ndarray, write_slots: np.ndarray,
-            rel_pos: np.ndarray, tables: np.ndarray) -> np.ndarray:
+            rel_pos: np.ndarray, tables: np.ndarray,
+            lanes: Optional[np.ndarray] = None) -> np.ndarray:
         """One paged forward over a COMPACT lane selection (``ids
         [B, t_new]``, ``tables [B, P]`` — the scheduler packs only the
         lanes that actually have work, bucketed to a power of two, so a
@@ -668,20 +752,54 @@ class PagedDecodeEngine:
         the arena costs one copy of HBM. Jitted once per
         ``(B, t_new, P)`` bucket under a retrace guard — the bucket set
         is fixed (≤ log₂(lanes)+1 sizes × two chunk lengths), so
-        steady-state decode never retraces."""
+        steady-state decode never retraces. ``lanes [B]``: the engine
+        lane of each row, which a net with state-space vertices needs
+        (its recurrent state is a row a lane; see
+        :meth:`_state_args`)."""
         b, t_new = ids.shape
         name = f"paged_decode[S{b}xT{t_new}xP{self.pages_per_seq}]"
 
-        def step(params, k_pools, v_pools, ids, tables, wslots, rel):
-            return _transformer.paged_decode_forward(
+        def step(params, k_pools, v_pools, ids, tables, wslots, rel,
+                 *lane_ids):
+            counts = []
+            probs, k_pools, v_pools = _transformer.paged_decode_forward(
                 self.net, params, k_pools, v_pools, ids, tables, wslots,
-                rel)
+                rel, *lane_ids, counts=counts)
+            return (probs, *counts, k_pools, v_pools)
 
-        (probs,) = self._dispatch(name, step, self.arena, self.net.params,
-                                  (ids, tables, write_slots, rel_pos),
-                                  kind="paged")
+        probs, *counts = self._dispatch(
+            name, step, self.arena, self.net.params,
+            (ids, tables, write_slots, rel_pos, *self._state_args(lanes, b)),
+            kind="paged")
         self._note_kv_read("paged", rel_pos, t_new)
+        self._note_routing(counts)
         return probs
+
+    def _state_args(self, lanes: Optional[np.ndarray], b: int) -> tuple:
+        """The lane ids a decode program of a net with state-space
+        vertices takes after its other arguments, none for a net without
+        (whose programs keep the arguments they always had). ``None``
+        (warm-up) is every slot padded: an id one past the last lane
+        reads zeros and writes nothing, like a sentinel page."""
+        if not self.state_layers:
+            return ()
+        if lanes is None:
+            lanes = np.full(b, self.lanes, np.int32)
+        return (np.asarray(lanes, np.int32),)
+
+    def _note_routing(self, counts: list) -> None:
+        """Account the expert layers' routing counts that a dispatch
+        brought back beside its tokens (``nn.conf.moe.MOE_STATS``)."""
+        if not counts or self._warming:
+            return
+        held, absent, computed, peak, steps, touched = (
+            int(v) for v in counts[0])
+        self._m_moe_routed.inc(held, where="held")
+        self._m_moe_routed.inc(absent, where="absent")
+        self._m_moe_computed.inc(computed)
+        self._m_moe_peak.inc(peak)
+        self._m_moe_steps.inc(steps)
+        self._m_moe_touched.inc(touched)
 
     def _dispatch(self, name: str, step, arena, params, args: tuple, *,
                   kind: str, sync: bool = True) -> list:
@@ -704,6 +822,8 @@ class PagedDecodeEngine:
             self._jit_cache, step, extra=name,
             wrap=lambda f: _xla.retrace_guard(f, name, self.registry),
             donate_argnums=(1, 2))
+        if self._precompile is not None:
+            return self._record_program(fn, arena, params, args, sync)
         hist = None if self._warming else self._m_phase
         wall, nbytes = 0.0, 0
         try:
@@ -735,6 +855,19 @@ class PagedDecodeEngine:
             raise
         self._note_dispatch(wall, kind, nbytes, sync=sync)
         return outputs
+
+    def _record_program(self, fn, arena, params, args: tuple,
+                        sync: bool) -> list:
+        """Warm-up's first pass (:meth:`warmup`): note the program and
+        what it would be called with, dispatch nothing, and hand back
+        zeros in the shapes its outputs will have, so that the ladder's
+        own code walks on as if it had run."""
+        jitted = getattr(fn, "__wrapped__", fn)     # under the retrace guard
+        call = (params, arena.k_pools, arena.v_pools, *args)
+        self._precompile.append((jitted, call))
+        *outputs, _, _ = jitted.eval_shape(*call)
+        zeros = np.zeros if sync else jax.numpy.zeros
+        return [zeros(o.shape, o.dtype) for o in outputs]
 
     def _reset_all_pools(self) -> None:
         self.arena.reset_pools()
@@ -792,7 +925,8 @@ class PagedDecodeEngine:
     def run_fused(self, last: np.ndarray, tables: np.ndarray,
                   rel: np.ndarray, active: np.ndarray, budget: np.ndarray,
                   eos: np.ndarray, temps: np.ndarray, top_k: np.ndarray,
-                  top_p: np.ndarray, uniforms: np.ndarray
+                  top_p: np.ndarray, uniforms: np.ndarray,
+                  lanes: Optional[np.ndarray] = None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One fused block: ``uniforms.shape[1]`` decode steps in ONE
         dispatch through ``models.transformer.fused_decode_loop`` —
@@ -804,17 +938,18 @@ class PagedDecodeEngine:
         name = f"fused_decode[S{b}xN{n}xP{self.pages_per_seq}]"
 
         def step(params, k_pools, v_pools, last, tables, rel, active,
-                 budget, eos, temps, tk, tp, u):
+                 budget, eos, temps, tk, tp, u, *lane_ids):
             return _transformer.fused_decode_loop(
                 self.net, params, k_pools, v_pools, last, tables, rel,
-                active, budget, eos, temps, tk, tp, u)
+                active, budget, eos, temps, tk, tp, u, *lane_ids)
 
-        toks, valid, n_emitted, _done = self._dispatch(
+        toks, valid, n_emitted, _done, *counts = self._dispatch(
             name, step, self.arena, self.net.params,
             (last, tables, rel, active, budget, eos, temps, top_k, top_p,
-             uniforms), kind="fused")
+             uniforms, *self._state_args(lanes, b)), kind="fused")
         # the block's loop ends with its last live lane
         self._note_kv_read("fused", rel, 1, steps=int(n_emitted.max()))
+        self._note_routing(counts)
         return toks, valid, n_emitted
 
     # -- speculative draft / verify -----------------------------------
@@ -900,9 +1035,29 @@ class PagedDecodeEngine:
         front, so serving cold-start pays compilation here instead of on
         the first live requests. Warmup dispatches carry all-sentinel
         tables and dropped write slots, so they cannot perturb the
-        arena."""
+        arena.
+
+        The ladder is walked twice. The first pass dispatches nothing: it
+        notes each program with its arguments (:meth:`_record_program`),
+        and the programs are then lowered and compiled CONCURRENTLY, one
+        a thread (XLA compiles outside the interpreter's lock; a fused
+        block's compile is 25 s, most of it the sampler's sort over the
+        vocabulary, and a ladder of twelve took 200 s one after another).
+        The second pass is the ladder as it always ran: each program's
+        first call finds its executable compiled (an ahead-of-time compile
+        and the call share JAX's compilation cache) and runs once."""
         self._warming = True
         try:
+            self._precompile = []
+            try:
+                self._warmup_ladder()
+            finally:
+                programs, self._precompile = self._precompile, None
+            if len(programs) > 1:
+                from concurrent.futures import ThreadPoolExecutor
+                with ThreadPoolExecutor(min(len(programs), 12)) as pool:
+                    list(pool.map(
+                        lambda p: p[0].lower(*p[1]).compile(), programs))
             self._warmup_ladder()
         finally:
             self._warming = False
@@ -1151,6 +1306,14 @@ class DecodeScheduler:
                     else self.default_max_new_tokens)
         if n_new < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if (self.engine.state_layers
+                and prompt.size + n_new > self.engine.window):
+            raise ValueError(
+                f"request of {prompt.size} + {n_new} tokens exceeds the "
+                f"window of {self.engine.window}: past it the attention "
+                "layers' pages slide while the state-space layers' state "
+                "has no window, so the two would see different histories "
+                "— size page_size x pages_per_seq to the longest request")
         if int(top_k) < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
         if not (0.0 < float(top_p) <= 1.0):
@@ -1310,7 +1473,7 @@ class DecodeScheduler:
 
     def _compact(self, seqs: List[_Sequence], t_new: int
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                            np.ndarray]:
+                            np.ndarray, np.ndarray]:
         """Pack the lanes that actually have work into a power-of-two
         batch bucket: a lone admission prefills at [1, C] cost, not a
         full-width padded dispatch, and the tail of a draining batch
@@ -1325,9 +1488,11 @@ class DecodeScheduler:
         rel = np.zeros(b, np.int32)
         tables = np.full((b, eng.pages_per_seq), eng.arena.sentinel,
                          np.int32)
+        lanes = np.full(b, eng.lanes, np.int32)     # padded slot: no lane
         for i, seq in enumerate(seqs):
             tables[i] = eng._tables[seq.lane]
-        return ids, wslots, rel, tables
+            lanes[i] = seq.lane
+        return ids, wslots, rel, tables, lanes
 
     def _prefill_tick(self) -> bool:
         seqs = [s for s in self._active.values() if s.state == _PREFILL]
@@ -1352,7 +1517,7 @@ class DecodeScheduler:
         # in every mode when the cache is on, so the retrace pin holds)
         t_feed = (1 if (eng.arena.prefix_index is not None
                         and max(chunk_len) <= 1) else c)
-        ids, wslots, rel, tables = self._compact(seqs, t_feed)
+        ids, wslots, rel, tables, lanes = self._compact(seqs, t_feed)
         for i, seq in enumerate(seqs):
             n = chunk_len[i]
             r = eng.rel_pos(seq.lane)
@@ -1368,7 +1533,7 @@ class DecodeScheduler:
         _faults.check("serving.decode_step",
                       {"phase": "prefill", "lanes": len(seqs)})
         w0, c0 = eng._tick_dispatch_wall, eng._compile_wall()
-        probs = eng.run(ids, wslots, rel, tables)   # [B, C, V]
+        probs = eng.run(ids, wslots, rel, tables, lanes)   # [B, C, V]
         if eng.draft_net is not None:
             # shadow prefill: the draft cache must hold the same prompt
             # context before its first drafting block (same ids, same
@@ -1386,7 +1551,8 @@ class DecodeScheduler:
                     "prefill_chunk", d_wall, parent=seq.req.span,
                     attributes={"lane": seq.lane, "bucket": ids.shape[0],
                                 "tokens": int(chunk_len[i]),
-                                "compile_s": round(d_compile, 6)})
+                                "compile_s": round(d_compile, 6),
+                                "state_layers": len(eng.state_layers)})
         self._m_tokens.inc(sum(chunk_len), phase="prefill")
         for i, seq in enumerate(seqs):
             n = chunk_len[i]
@@ -1421,7 +1587,7 @@ class DecodeScheduler:
         eng = self.engine
         for seq in seqs:
             eng.ensure_pages(seq.lane, 1)
-        ids, wslots, rel, tables = self._compact(seqs, 1)
+        ids, wslots, rel, tables, lanes = self._compact(seqs, 1)
         for i, seq in enumerate(seqs):
             r = eng.rel_pos(seq.lane)
             ids[i, 0] = seq.last_token
@@ -1430,7 +1596,7 @@ class DecodeScheduler:
         _faults.check("serving.decode_step",
                       {"phase": "decode", "lanes": len(seqs)})
         w0 = eng._tick_dispatch_wall
-        probs = eng.run(ids, wslots, rel, tables)   # [B, 1, V]
+        probs = eng.run(ids, wslots, rel, tables, lanes)   # [B, 1, V]
         self._record_block_spans(seqs, "ticked", ids.shape[0],
                                  [1] * len(seqs),
                                  eng._tick_dispatch_wall - w0)
@@ -1469,10 +1635,12 @@ class DecodeScheduler:
             "u": np.zeros((b, n_uniform), np.float32),
             "tables": np.full((b, eng.pages_per_seq), eng.arena.sentinel,
                               np.int32),
+            "lanes": np.full(b, eng.lanes, np.int32),
         }
         for i, seq in enumerate(seqs):
             req = seq.req
             arr["tables"][i] = eng._tables[seq.lane]
+            arr["lanes"][i] = seq.lane
             arr["last"][i] = seq.last_token
             arr["rel"][i] = eng.rel_pos(seq.lane)
             arr["active"][i] = True
@@ -1505,7 +1673,8 @@ class DecodeScheduler:
         w0 = eng._tick_dispatch_wall
         toks, valid, n_emitted = eng.run_fused(
             a["last"], a["tables"], a["rel"], a["active"], budget,
-            a["eos"], a["temps"], a["top_k"], a["top_p"], a["u"])
+            a["eos"], a["temps"], a["top_k"], a["top_p"], a["u"],
+            a["lanes"])
         self._record_block_spans(
             seqs, "fused", a["last"].shape[0],
             [int(n_emitted[i]) for i in range(len(seqs))],
@@ -1611,7 +1780,9 @@ class DecodeScheduler:
                     "decode_block", seconds, parent=seq.req.span,
                     attributes={"kind": kind, "lane": seq.lane,
                                 "bucket": int(bucket),
-                                "tokens": int(tokens[i])})
+                                "tokens": int(tokens[i]),
+                                "state_layers": len(
+                                    self.engine.state_layers)})
 
     def _emit_token(self, seq: _Sequence, probs: np.ndarray, *,
                     greedy_tok: Optional[int] = None) -> None:

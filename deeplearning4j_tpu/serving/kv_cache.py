@@ -481,12 +481,22 @@ class PrefixIndex:
 
 
 class PagedKVArena:
-    """Per-attention-layer K/V block pools + the shared allocator.
+    """What the stateful vertices of a decode graph own, in the order the
+    walker visits them, + the shared page allocator: one manager for two
+    kinds of state.
 
-    ``layer_dims`` maps attention vertex name → ``(heads, head_dim)`` in
-    the order the decode walker visits them. ``SENTINEL`` (= num_pages,
+    ``layer_dims`` maps vertex name → what it holds. A causal attention
+    vertex gives ``(kv heads, head_dim)`` and gets a K and a V block pool
+    (``k_pools[i]``, ``v_pools[i]``), paged: ``SENTINEL`` (= num_pages,
     one past the pool) marks page-table holes: gathers fill zeros there,
-    scatters drop.
+    scatters drop. A state-space vertex gives its two per-lane shapes
+    (``Mamba2Mixer.state_shapes(lanes)``) and gets a convolution tail
+    ``[lanes, K-1, C]`` in ``k_pools[i]`` and an SSM state ``[lanes, H, P,
+    N]`` in ``v_pools[i]``: fixed-size whatever the sequence's length, so
+    it takes no page; a lane's row is zeroed inside the first prefill
+    program of the sequence it is given to, never by the arena. Both
+    kinds ride the engine's donated-pytree dispatch protocol alike and
+    are rebuilt together after a failed dispatch (:meth:`reset_pools`).
 
     A pool is stored ``[num_pages, page_size, h*d]`` (a token's heads in
     one row), for every layer, dtype and arena alike: a decode program's
@@ -502,7 +512,8 @@ class PagedKVArena:
     — ``q_int8`` is ``[num_pages, page_size, h*d]`` int8, ``scales`` is
     ``[num_pages, h]`` f32 per-(page, head) — quantized on write and
     dequantized in ``ops/paged_attention.paged_gather``. Tuples ride the
-    engine's donated-pytree dispatch protocol unchanged.
+    engine's donated-pytree dispatch protocol unchanged. Recurrent state
+    is never quantized.
     """
 
     def __init__(self, layer_dims: Dict[str, Tuple[int, int]], *,
@@ -522,7 +533,7 @@ class PagedKVArena:
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if not layer_dims:
-            raise ValueError("arena needs at least one attention layer")
+            raise ValueError("arena needs at least one stateful layer")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"kv_dtype must be None or 'int8', "
                              f"got {kv_dtype!r}")
@@ -553,6 +564,10 @@ class PagedKVArena:
         self.k_pools = []
         self.v_pools = []
         for h, d in self._layer_dims.values():
+            if isinstance(h, tuple):       # a state-space vertex's shapes
+                self.k_pools.append(jnp.zeros(h, jnp.float32))
+                self.v_pools.append(jnp.zeros(d, jnp.float32))
+                continue
             shape = (self.num_pages, self.page_size, h * d)
             if self.kv_dtype == "int8":
                 self.k_pools.append((jnp.zeros(shape, jnp.int8),
@@ -568,6 +583,13 @@ class PagedKVArena:
     def pages_for(self, n_tokens: int) -> int:
         """Pages needed to hold ``n_tokens`` (ceil)."""
         return -(-int(n_tokens) // self.page_size)
+
+    def state_nbytes(self) -> int:
+        """Bytes of the per-lane recurrent state (no pool among them)."""
+        return sum(int(k.nbytes) + int(v.nbytes)
+                   for (h, _), k, v in zip(self._layer_dims.values(),
+                                           self.k_pools, self.v_pools)
+                   if isinstance(h, tuple))
 
     def nbytes(self) -> int:
         total = 0

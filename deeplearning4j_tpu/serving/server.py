@@ -92,6 +92,7 @@ import json
 import queue
 import threading
 import time
+import weakref
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
@@ -232,10 +233,27 @@ class InferenceServer:
         # queue emptiness (an item leaves the queue before it is answered)
         self._pending = 0
         self._pending_lock = threading.Lock()
-        self._m_queue_depth.set_function(lambda: float(self._queue.qsize()))
-        self._m_pending.set_function(lambda: float(self._pending))
+        # weakly bound, like the scheduler's and the allocator's gauges: a
+        # stopped server (and through it the net, its parameters and the
+        # decode arena) must stay collectable while its registry lives on
+        # in a caller's hands (a 9 GB model served and then compared with
+        # a reference on the same chip has no room for both); a dead ref
+        # raises, dropping the series at exposition
+        ref = weakref.ref(self)
+
+        def _sample(get):
+            def fn():
+                server = ref()
+                if server is None:
+                    raise LookupError("server retired")
+                return float(get(server))
+            return fn
+
+        self._m_queue_depth.set_function(
+            _sample(lambda s: s._queue.qsize()))
+        self._m_pending.set_function(_sample(lambda s: s._pending))
         self._m_breaker_state.set_function(
-            lambda: STATE_VALUES.get(self.breaker.state, -1.0))
+            _sample(lambda s: STATE_VALUES.get(s.breaker.state, -1.0)))
         self._batcher = threading.Thread(target=self._batch_loop, daemon=True)
         self._batcher.start()
 

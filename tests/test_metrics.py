@@ -1067,6 +1067,22 @@ class TestMetricsConventions:
                                    prefix_cache=True, kv_dtype="int8")
         sched = DecodeScheduler(engine, registry=reg,
                                 start_thread=False)
+        # a hybrid net registers what OPT's engine has no use for: the
+        # state arena's gauge and reset counter, the expert layers'
+        # routing counts (ISSUE 33); `where` has two values
+        from deeplearning4j_tpu.models import nemotron_h_lm
+        hybrid = ComputationGraph(nemotron_h_lm(
+            8, pattern="ME*", d_model=8, n_heads=2, n_kv_heads=1,
+            mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=4,
+            n_experts=4, top_k=2, d_latent=4, d_expert=4, d_shared=4,
+            experts_held=2, max_cache_t=16)).init()
+        hybrid_engine = PagedDecodeEngine(hybrid, max_batch=2, page_size=4,
+                                          pages_per_seq=4, registry=reg)
+        hybrid_engine.run(np.zeros((1, 4), np.int32),
+                          np.arange(4, dtype=np.int32)[None],
+                          np.zeros(1, np.int32),
+                          np.full((1, 4), hybrid_engine.arena.sentinel,
+                                  np.int32), np.zeros(1, np.int32))
         # the serving-fleet tier (ISSUE 20): router/agent families plus
         # the drain-outcome counter on the replica side
         from deeplearning4j_tpu.serving import fleet as _fleet
@@ -1086,6 +1102,14 @@ class TestMetricsConventions:
                     "kv_pages_shared", "kv_page_refcount",
                     "kv_pages_cow_total"):
             assert reg.get(fam) is not None, fam
+        for fam in ("moe_routed_pairs_total", "moe_computed_pairs_total",
+                    "moe_expert_load_peak_pairs_total",
+                    "moe_expert_load_steps_total",
+                    "decode_state_resets_total", "decode_state_bytes"):
+            assert reg.get(fam) is not None, fam
+        routed = reg.get("moe_routed_pairs_total").snapshot()["series"]
+        assert {s["labels"]["where"] for s in routed} == {"held", "absent"}
+        assert sum(s["value"] for s in routed) == 4 * 2    # tokens x top_k
         for fam in ("fleet_requests_total", "fleet_failovers_total",
                     "fleet_heartbeats_total",
                     "fleet_request_latency_seconds",
