@@ -291,7 +291,8 @@ def oracle_stream_probs(net, token_ids) -> np.ndarray:
 
 
 def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
-                         write_slots, rel_pos, lane_ids=None, counts=None):
+                         write_slots, rel_pos, lane_ids=None, counts=None,
+                         out_rows=None):
     """ONE traced forward of an ids-mode decoder graph in paged-decode
     mode: every stateful vertex (:func:`stateful_vertices`) reads and
     writes ITS entry of the two state lists; every other vertex applies
@@ -319,7 +320,17 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     v_pools)``; ``counts``, a list, receives the sum of the expert layers'
     routing counts (``nn.conf.moe.MOE_STATS``, int32) where the net has
     layers that count.
+
+    out_rows: ``[S]`` int32, the one position of each lane whose
+    distribution the caller will read (the serving engine: the last
+    prompt token of the chunk). The output vertex then sees that row of
+    its input alone, so the head's product and its softmax have ``S`` rows
+    and ``probs`` is ``[S, V]``; every vertex before it still runs over
+    all ``t_new`` positions (they write K/V, carry state, count routed
+    pairs). ``None``: every position, as a verify pass needs.
     """
+    import jax.numpy as jnp
+
     owners = stateful_vertices(net)
     if len(owners) != len(k_pools):
         raise ValueError(
@@ -333,10 +344,16 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     valid = (write_slots >= 0 if state_space_vertices(net)
              or counting_vertices(net) else None)
     stats = None
+    head = net.conf.network_outputs[0]
     for name in net.topo_order:
         in_names = net.conf.vertex_inputs[name]
         layer = net._vertex_layer(name)
         i = pool_ix.get(name)
+        if name == head and out_rows is not None:
+            # the head at the wanted position only: [S, t_new, d] -> [S, 1, d]
+            acts = dict(acts, **{n: jnp.take_along_axis(
+                acts[n], out_rows[:, None, None], axis=1, mode="clip")
+                for n in in_names})
         if i is not None and isinstance(layer, SelfAttentionLayer):
             out, k_pools[i], v_pools[i] = layer.apply_paged(
                 params[name], acts[in_names[0]], k_pools[i], v_pools[i],
@@ -358,7 +375,8 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
         acts[name] = out
     if counts is not None and stats is not None:
         counts.append(stats)
-    return acts[net.conf.network_outputs[0]], k_pools, v_pools
+    probs = acts[head]
+    return (probs if out_rows is None else probs[:, 0, :]), k_pools, v_pools
 
 
 # --------------------------------------------------------------------------

@@ -741,14 +741,18 @@ class PagedDecodeEngine:
     # -- the jitted paged step ----------------------------------------
 
     def run(self, ids: np.ndarray, write_slots: np.ndarray,
-            rel_pos: np.ndarray, tables: np.ndarray,
+            rel_pos: np.ndarray, tables: np.ndarray, out_rows: np.ndarray,
             lanes: Optional[np.ndarray] = None) -> np.ndarray:
         """One paged forward over a COMPACT lane selection (``ids
         [B, t_new]``, ``tables [B, P]`` — the scheduler packs only the
         lanes that actually have work, bucketed to a power of two, so a
         single admitting sequence does not pay a full-width prefill):
         scatter the new tokens' K/V, gather, attend, return probs
-        ``[B, t_new, V]`` on host. Pools are donated and replaced, so
+        ``[B, V]`` on host: lane ``i``'s distribution at position
+        ``out_rows[i]`` of its chunk, the one row the scheduler samples
+        from (the head is computed at that position only, and a prefill
+        chunk of 128 fetches 1/128 of what all its positions would be).
+        Pools are donated and replaced, so
         the arena costs one copy of HBM. Jitted once per
         ``(B, t_new, P)`` bucket under a retrace guard — the bucket set
         is fixed (≤ log₂(lanes)+1 sizes × two chunk lengths), so
@@ -759,17 +763,18 @@ class PagedDecodeEngine:
         b, t_new = ids.shape
         name = f"paged_decode[S{b}xT{t_new}xP{self.pages_per_seq}]"
 
-        def step(params, k_pools, v_pools, ids, tables, wslots, rel,
+        def step(params, k_pools, v_pools, ids, tables, wslots, rel, rows,
                  *lane_ids):
             counts = []
             probs, k_pools, v_pools = _transformer.paged_decode_forward(
                 self.net, params, k_pools, v_pools, ids, tables, wslots,
-                rel, *lane_ids, counts=counts)
+                rel, *lane_ids, counts=counts, out_rows=rows)
             return (probs, *counts, k_pools, v_pools)
 
         probs, *counts = self._dispatch(
             name, step, self.arena, self.net.params,
-            (ids, tables, write_slots, rel_pos, *self._state_args(lanes, b)),
+            (ids, tables, write_slots, rel_pos,
+             np.asarray(out_rows, np.int32), *self._state_args(lanes, b)),
             kind="paged")
         self._note_kv_read("paged", rel_pos, t_new)
         self._note_routing(counts)
@@ -955,22 +960,26 @@ class PagedDecodeEngine:
     # -- speculative draft / verify -----------------------------------
 
     def run_draft_prefill(self, ids: np.ndarray, write_slots: np.ndarray,
-                          rel_pos: np.ndarray, tables: np.ndarray) -> None:
+                          rel_pos: np.ndarray, tables: np.ndarray,
+                          out_rows: np.ndarray) -> None:
         """Shadow prefill: the draft model processes the SAME prompt
         chunk into its own pools (same tables, same slots), so its first
         drafting block sees the full context. Output discarded — no host
-        sync; an async failure surfaces at the block's verify sync."""
+        sync; an async failure surfaces at the block's verify sync.
+        ``out_rows`` as in :meth:`run`: the program builds no
+        ``[B, t, V]`` for an output nobody reads."""
         b, t = ids.shape
         name = f"draft_prefill[S{b}xT{t}xP{self.pages_per_seq}]"
 
-        def step(params, k_pools, v_pools, ids, tables, wslots, rel):
+        def step(params, k_pools, v_pools, ids, tables, wslots, rel, rows):
             return _transformer.paged_decode_forward(
                 self.draft_net, params, k_pools, v_pools, ids, tables,
-                wslots, rel)
+                wslots, rel, out_rows=rows)
 
         self._dispatch(name, step, self.draft_arena,
                        self.draft_net.params,
-                       (ids, tables, write_slots, rel_pos),
+                       (ids, tables, write_slots, rel_pos,
+                        np.asarray(out_rows, np.int32)),
                        kind="draft_prefill", sync=False)
         self._note_kv_read("draft_prefill", rel_pos, t)
 
@@ -1068,12 +1077,12 @@ class PagedDecodeEngine:
             c = self.prefill_chunk
             sentinel_tables = np.full((b, self.pages_per_seq),
                                       self.arena.sentinel, np.int32)
-            self.run(np.zeros((b, c), np.int32),
-                     np.full((b, c), -1, np.int32),
-                     np.zeros(b, np.int32), sentinel_tables)
             inactive = np.zeros(b, bool)
             zeros_f = np.zeros(b, np.float32)
             zeros_i = np.zeros(b, np.int32)
+            self.run(np.zeros((b, c), np.int32),
+                     np.full((b, c), -1, np.int32),
+                     zeros_i, sentinel_tables, zeros_i)
             if self.arena.prefix_index is not None and c > 1:
                 # prefix-cache hit ticks re-feed at t=1 (the scheduler
                 # collapses an all-≤1-token prefill tick to the decode
@@ -1081,17 +1090,15 @@ class PagedDecodeEngine:
                 # pays a mid-serve trace
                 self.run(np.zeros((b, 1), np.int32),
                          np.full((b, 1), -1, np.int32),
-                         np.zeros(b, np.int32), sentinel_tables)
+                         zeros_i, sentinel_tables, zeros_i)
                 if self.draft_net is not None:
                     self.run_draft_prefill(np.zeros((b, 1), np.int32),
                                            np.full((b, 1), -1, np.int32),
-                                           np.zeros(b, np.int32),
-                                           sentinel_tables)
+                                           zeros_i, sentinel_tables, zeros_i)
             if self.draft_net is not None:
                 self.run_draft_prefill(np.zeros((b, c), np.int32),
                                        np.full((b, c), -1, np.int32),
-                                       np.zeros(b, np.int32),
-                                       sentinel_tables)
+                                       zeros_i, sentinel_tables, zeros_i)
                 d_toks, d_dists = self.run_draft(
                     zeros_i, sentinel_tables, zeros_i, inactive, zeros_i,
                     zeros_f, zeros_i, np.ones(b, np.float32),
@@ -1111,7 +1118,7 @@ class PagedDecodeEngine:
             else:
                 self.run(np.zeros((b, 1), np.int32),
                          np.full((b, 1), -1, np.int32),
-                         np.zeros(b, np.int32), sentinel_tables)
+                         zeros_i, sentinel_tables, zeros_i)
             if b >= self.lanes:
                 break
             b <<= 1           # same ladder _compact produces
@@ -1518,6 +1525,7 @@ class DecodeScheduler:
         t_feed = (1 if (eng.arena.prefix_index is not None
                         and max(chunk_len) <= 1) else c)
         ids, wslots, rel, tables, lanes = self._compact(seqs, t_feed)
+        rows = np.zeros(len(rel), np.int32)
         for i, seq in enumerate(seqs):
             n = chunk_len[i]
             r = eng.rel_pos(seq.lane)
@@ -1530,15 +1538,18 @@ class DecodeScheduler:
                 slots[:seq.covered - seq.cursor] = -1
             wslots[i, :n] = slots
             rel[i] = r
+            # the one position whose distribution may be sampled from:
+            # the chunk's last prompt token (a padded slot keeps row 0)
+            rows[i] = n - 1
         _faults.check("serving.decode_step",
                       {"phase": "prefill", "lanes": len(seqs)})
         w0, c0 = eng._tick_dispatch_wall, eng._compile_wall()
-        probs = eng.run(ids, wslots, rel, tables, lanes)   # [B, C, V]
+        probs = eng.run(ids, wslots, rel, tables, rows, lanes)   # [B, V]
         if eng.draft_net is not None:
             # shadow prefill: the draft cache must hold the same prompt
             # context before its first drafting block (same ids, same
             # slots, its own pools)
-            eng.run_draft_prefill(ids, wslots, rel, tables)
+            eng.run_draft_prefill(ids, wslots, rel, tables, rows)
         # TTFT attribution: this chunk's dispatch wall (compile split
         # out) is charged to every sequence it prefilled
         d_wall = eng._tick_dispatch_wall - w0
@@ -1565,7 +1576,7 @@ class DecodeScheduler:
                 eng.register_prefix(seq.lane, seq.req.prompt)
                 # the last prompt position's distribution yields the
                 # FIRST generated token (TTFT lands here)
-                self._emit_token(seq, probs[i, n - 1])
+                self._emit_token(seq, probs[i])
                 if seq.lane in self._active:
                     seq.state = _DECODE
 
@@ -1596,7 +1607,8 @@ class DecodeScheduler:
         _faults.check("serving.decode_step",
                       {"phase": "decode", "lanes": len(seqs)})
         w0 = eng._tick_dispatch_wall
-        probs = eng.run(ids, wslots, rel, tables, lanes)   # [B, 1, V]
+        probs = eng.run(ids, wslots, rel, tables, np.zeros_like(rel),
+                        lanes)                             # [B, V]
         self._record_block_spans(seqs, "ticked", ids.shape[0],
                                  [1] * len(seqs),
                                  eng._tick_dispatch_wall - w0)
@@ -1607,11 +1619,10 @@ class DecodeScheduler:
         # python round-trip — this loop runs once per generated token
         # across the whole batch (identical result: argmax is invariant
         # under sample_token's monotone float64 cast)
-        greedy = np.argmax(probs[:, 0, :], axis=-1)
+        greedy = np.argmax(probs, axis=-1)
         for i, seq in enumerate(seqs):
             eng.advance(seq.lane, 1)
-            self._emit_token(seq, probs[i, 0],
-                             greedy_tok=int(greedy[i]))
+            self._emit_token(seq, probs[i], greedy_tok=int(greedy[i]))
 
     def _block_arrays(self, seqs: List[_Sequence], n_uniform: int):
         """Per-lane arrays for a fused/speculative block over a

@@ -338,7 +338,7 @@ class TestSchedulerPolicy:
                     np.full((1, 1), -1, np.int32),
                     np.zeros(1, np.int32),
                     np.full((1, eng.pages_per_seq), eng.arena.sentinel,
-                            np.int32))
+                            np.int32), np.zeros(1, np.int32))
         assert [tuple(p.shape) for p in eng.arena.k_pools] == shapes
         monkeypatch.undo()
         req = sched.submit([1, 2], 3)

@@ -1082,7 +1082,8 @@ class TestMetricsConventions:
                           np.arange(4, dtype=np.int32)[None],
                           np.zeros(1, np.int32),
                           np.full((1, 4), hybrid_engine.arena.sentinel,
-                                  np.int32), np.zeros(1, np.int32))
+                                  np.int32), np.full(1, 3, np.int32),
+                          np.zeros(1, np.int32))
         # the serving-fleet tier (ISSUE 20): router/agent families plus
         # the drain-outcome counter on the replica side
         from deeplearning4j_tpu.serving import fleet as _fleet

@@ -67,10 +67,30 @@ def reference_logprobs(reference, family, cfg, ids):
     return np.asarray(jax.nn.log_softmax(z, axis=-1))
 
 
+def all_positions(eng, ids, slots, rel, tables, lanes=None):
+    """The engine's program with no row named (``out_rows=None``, the form
+    a verify pass uses): the distribution at every position, and the two
+    state lists as this dispatch would leave them. The arena is read, not
+    donated and not written: ``eng.run`` on the same arguments comes
+    after, and has to find what this call found."""
+    fwd = getattr(eng, "_all_positions", None)
+    if fwd is None:                 # one jit an engine: its shapes' cache
+        fwd = eng._all_positions = jax.jit(
+            lambda params, k, v, *args: _transformer.paged_decode_forward(
+                eng.net, params, k, v, *args))
+    probs, k_pools, v_pools = fwd(
+        eng.net.params, eng.arena.k_pools, eng.arena.v_pools, ids, tables,
+        slots, rel, *eng._state_args(lanes, len(rel)))
+    return np.asarray(probs), k_pools, v_pools
+
+
 def engine_logprobs(net, ids, n_prefill, chunk=8):
     """Teacher-forced through the paged engine: the first ``n_prefill``
     tokens in prefill chunks (the last one partly padding), the rest as
-    one-token steps; log-probabilities at every position."""
+    one-token steps; log-probabilities at every position. A dispatch
+    returns one row a lane (the chunk's last token here), so the other
+    positions come from the same program with no row named, and the row
+    the engine did return is held to it."""
     eng = PagedDecodeEngine(net, **dict(ENGINE, prefill_chunk=chunk))
     lane = eng.acquire_lane(len(ids) + 1)
     out, pos = [], 0
@@ -82,8 +102,12 @@ def engine_logprobs(net, ids, n_prefill, chunk=8):
         fed[0, :n] = ids[pos:pos + n]
         slots = np.full((1, t), -1, np.int32)
         slots[0, :n] = eng.rel_pos(lane) + np.arange(n)
-        probs = eng.run(fed, slots, np.array([eng.rel_pos(lane)], np.int32),
-                        eng._tables[lane][None], np.array([lane], np.int32))
+        args = (fed, slots, np.array([eng.rel_pos(lane)], np.int32),
+                eng._tables[lane][None])
+        probs, _, _ = all_positions(eng, *args, np.array([lane], np.int32))
+        row = eng.run(*args, np.array([n - 1], np.int32),
+                      np.array([lane], np.int32))
+        np.testing.assert_allclose(row[0], probs[0, n - 1], rtol=1e-5)
         out.append(np.asarray(probs[0, :n], np.float32))
         eng.advance(lane, n)
         pos += n
@@ -215,7 +239,7 @@ def test_failed_dispatch_rebuilds_the_state_arrays_too(net, ids, monkeypatch):
     eng.ensure_pages(lane, 8)
     args = (ids[None, :8], np.arange(8, dtype=np.int32)[None],
             np.zeros(1, np.int32), eng._tables[lane][None],
-            np.array([lane], np.int32))
+            np.full(1, 7, np.int32), np.array([lane], np.int32))
     eng.run(*args)
     owners = _transformer.stateful_vertices(net)
     mamba = [i for i, n in enumerate(owners) if n in eng.state_layers]

@@ -294,10 +294,10 @@ def test_a_lane_at_the_windows_end_reads_all_of_it(net):
     window = eng.registry.get("decode_kv_window_tokens_total")
     tables = np.full((1, 16), eng.arena.sentinel, np.int32)
     eng.run(np.zeros((1, 1), np.int32), np.full((1, 1), -1, np.int32),
-            np.array([255], np.int32), tables)
+            np.array([255], np.int32), tables, np.zeros(1, np.int32))
     assert read.value(kind="paged") == window.value(kind="paged") == 256
     eng.run(np.zeros((1, 1), np.int32), np.full((1, 1), -1, np.int32),
-            np.array([5], np.int32), tables)
+            np.array([5], np.int32), tables, np.zeros(1, np.int32))
     assert read.value(kind="paged") == 256 + 128
     assert window.value(kind="paged") == 512
 

@@ -203,10 +203,11 @@ def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
 
     tables, i32 = arg((lanes, pages_per_seq), jnp.int32), jnp.int32
     if program == "prefill":
-        def step(params, k, v, *a):
-            return _transformer.paged_decode_forward(net, params, k, v, *a)
+        def step(params, k, v, *a):             # the last: the rows named
+            return _transformer.paged_decode_forward(
+                net, params, k, v, *a[:-1], out_rows=a[-1])
         args = (arg((lanes, chunk), i32), tables, arg((lanes, chunk), i32),
-                arg((lanes,), i32))
+                arg((lanes,), i32), arg((lanes,), i32))
     else:
         def step(params, k, v, *a):
             return _transformer.fused_decode_loop(net, params, k, v, *a)

@@ -440,7 +440,7 @@ class TestStaleness:
                     np.full((2, 1), -1, np.int32),
                     np.zeros(2, np.int32),
                     np.full((2, eng.pages_per_seq), eng.arena.sentinel,
-                            np.int32))
+                            np.int32), np.zeros(2, np.int32))
         monkeypatch.undo()
         assert eng.arena.prefix_index.cached_pages == 0
         assert eng.arena.allocator.pages_in_use == 0

@@ -197,9 +197,10 @@ def test_warmup_dispatches_stay_out_of_every_series(net):
 
 
 def test_d2h_bytes_equal_nbytes_of_what_came_back(net):
-    """One prefill chunk returns probs [B, chunk, V]; one fused block
-    returns tokens, valid, n_emitted and done: the counter moves by
-    exactly the bytes of the arrays the host got."""
+    """One prefill chunk returns probs [B, V], the one row a lane that the
+    caller named; one fused block returns tokens, valid, n_emitted and
+    done: the counter moves by exactly the bytes of the arrays the host
+    got."""
     eng = _engine(net, block_len=4)
     d2h = eng.registry.get("decode_d2h_bytes_total")
     lane = eng.acquire_lane(12, prompt=None)
@@ -207,8 +208,8 @@ def test_d2h_bytes_equal_nbytes_of_what_came_back(net):
     tables = eng._tables[lane][None, :]
     probs = eng.run(np.array([[1, 2, 3, 4]], np.int32),
                     np.arange(4, dtype=np.int32)[None, :],
-                    np.zeros(1, np.int32), tables)
-    assert probs.shape == (1, 4, VOCAB)
+                    np.zeros(1, np.int32), tables, np.full(1, 3, np.int32))
+    assert probs.shape == (1, VOCAB)
     assert d2h.value(kind="paged") == probs.nbytes
     eng.advance(lane, 4)
     eng.ensure_pages(lane, 4)
@@ -221,7 +222,7 @@ def test_d2h_bytes_equal_nbytes_of_what_came_back(net):
     done_bytes = np.zeros(1, bool).nbytes
     assert d2h.value(kind="fused") == (toks.nbytes + valid.nbytes
                                        + n_emitted.nbytes + done_bytes)
-    # a fused block hands back ids, not distributions
+    # a fused block hands back ids, not a distribution
     assert d2h.value(kind="fused") < d2h.value(kind="paged")
 
 
@@ -236,7 +237,8 @@ def test_failed_dispatch_is_not_a_sample(net, monkeypatch):
     eng.ensure_pages(lane, 4)
     with pytest.raises(RuntimeError):
         eng.run(np.zeros((1, 4), np.int32), np.full((1, 4), -1, np.int32),
-                np.zeros(1, np.int32), eng._tables[lane][None, :])
+                np.zeros(1, np.int32), eng._tables[lane][None, :],
+                np.zeros(1, np.int32))
     phases = eng.registry.get("decode_dispatch_phase_seconds")
     assert phases.count(kind="paged", phase="enqueue") == 1
     assert phases.count(kind="paged", phase="device_wait") == 0
@@ -412,7 +414,7 @@ def lowered(net):
     zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
     texts["prefill"] = texts.pop("paged_decode[S1xT4xP8]").lower(
         net.params, k, v, np.zeros((1, 4), np.int32), tables,
-        np.full((1, 4), -1, np.int32), zi).as_text(debug_info=True)
+        np.full((1, 4), -1, np.int32), zi, zi).as_text(debug_info=True)
     texts["fused"] = texts.pop("fused_decode[S1xN4xP8]").lower(
         net.params, k, v, zi, tables, zi, np.zeros(1, bool), zi,
         np.full(1, -1, np.int32), zf, zi, np.ones(1, np.float32),
