@@ -14,7 +14,9 @@ from .char_rnn import char_rnn_lstm
 from .classic import alexnet, deep_autoencoder, vgg16
 from .transformer import draft_transformer_lm, generate, transformer_lm
 from .nemotron_h import nemotron_h_lm
+from .pangu import pangu_ultra_moe_lm
 
 __all__ = ["lenet", "resnet", "resnet50", "resnet_tiny", "char_rnn_lstm",
            "alexnet", "vgg16", "deep_autoencoder", "transformer_lm",
-           "draft_transformer_lm", "generate", "nemotron_h_lm"]
+           "draft_transformer_lm", "generate", "nemotron_h_lm",
+           "pangu_ultra_moe_lm"]

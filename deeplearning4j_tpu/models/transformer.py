@@ -32,6 +32,7 @@ import jax
 import numpy as np
 
 from ..nn.conf.attention import SelfAttentionLayer
+from ..nn.conf.mla import MLAttentionLayer
 from ..nn.conf.builders import NeuralNetConfiguration
 from ..nn.conf.graph import ElementWiseVertex, LayerVertex
 from ..nn.conf.inputs import InputType
@@ -131,15 +132,19 @@ def transformer_lm(vocab_size: int, *, n_layers: int = 4,
 # --------------------------------------------------------------------------
 
 
+# the layers that own a K/V cache (dense or paged) during decode
+ATTENTION_LAYERS = (SelfAttentionLayer, MLAttentionLayer)
+
+
 def attention_vertices(net) -> List[str]:
-    """Topo-ordered names of the net's causal ``SelfAttentionLayer``
-    vertices — the layers that own a K/V cache (dense or paged) during
-    decode."""
+    """Topo-ordered names of the net's causal attention vertices
+    (:data:`ATTENTION_LAYERS`) — the layers that own a K/V cache (dense
+    or paged) during decode."""
     names = []
     for name in net.topo_order:
         v = net.conf.vertices[name]
         layer = v.layer if isinstance(v, LayerVertex) else None
-        if isinstance(layer, SelfAttentionLayer) and layer.causal:
+        if isinstance(layer, ATTENTION_LAYERS) and layer.causal:
             names.append(name)
     return names
 
@@ -147,8 +152,8 @@ def attention_vertices(net) -> List[str]:
 def stateful_vertices(net) -> List[str]:
     """Topo-ordered names of the vertices that own state during paged
     decode, one ordered list for the walker, the arena and the engine:
-    causal attention (a K and a V pool) and state-space mixers (a
-    convolution tail and an SSM state a lane)."""
+    causal attention (a K and a V pool; a latent layer one pool) and
+    state-space mixers (a convolution tail and an SSM state a lane)."""
     attn = set(attention_vertices(net))
     return [name for name in net.topo_order
             if name in attn
@@ -160,6 +165,13 @@ def state_space_vertices(net) -> List[str]:
     lane, not pages."""
     return [name for name in stateful_vertices(net)
             if isinstance(net._vertex_layer(name), _ssm.Mamba2Mixer)]
+
+
+def position_vertices(net) -> List[str]:
+    """Names of the vertices that take each lane's ABSOLUTE position (a
+    rotary term): the walkers hand it to no other."""
+    return [name for name in net.topo_order
+            if getattr(net._vertex_layer(name), "wants_positions", False)]
 
 
 def counting_vertices(net) -> List[str]:
@@ -292,7 +304,7 @@ def oracle_stream_probs(net, token_ids) -> np.ndarray:
 
 def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
                          write_slots, rel_pos, lane_ids=None, counts=None,
-                         out_rows=None):
+                         out_rows=None, positions=None, fed=None):
     """ONE traced forward of an ids-mode decoder graph in paged-decode
     mode: every stateful vertex (:func:`stateful_vertices`) reads and
     writes ITS entry of the two state lists; every other vertex applies
@@ -311,10 +323,19 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     rel_pos: ``[S]``; lane_ids: ``[S]`` (only a net with state-space
     vertices needs them; a padded slot of the bucket holds an id past the
     last lane; None where the caller hands the state-space vertices'
-    entries in as the dispatch's own rows, ``Mamba2Mixer.apply_paged``). A
-    position is VALID where its write slot is not -1:
-    padding and retired lanes advance no recurrent state and count in no
-    expert layer; a lane at ``rel_pos`` 0 starts a sequence, so its
+    entries in as the dispatch's own rows, ``Mamba2Mixer.apply_paged``).
+
+    Two masks over the ``[S, t_new]`` positions. A position's WRITE IS
+    KEPT where its write slot is not -1: a recurrent state advances over
+    those. A position is COMPUTED where it is no padding: an expert layer
+    routes, computes and counts those. They differ only under a prefix
+    hit, which re-feeds covered positions with dropped writes (their K/V
+    is resident) for their distribution: ``fed [S]`` then gives each lane's
+    number of fed positions and "computed" is ``column < fed``; without it
+    the two masks are one. ``positions [S]`` (a net with
+    :func:`position_vertices` only): the absolute position of each lane's
+    first new token, which is ``rel_pos`` plus what the lane's window has
+    evicted. A lane at ``rel_pos`` 0 starts a sequence, so its
     recurrent state starts from zero inside this program (no dispatch of
     its own resets a lane). Returns ``(probs [S, t_new, V], k_pools,
     v_pools)``; ``counts``, a list, receives the sum of the expert layers'
@@ -343,6 +364,8 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     # vertex advances over and an expert layer counts
     valid = (write_slots >= 0 if state_space_vertices(net)
              or counting_vertices(net) else None)
+    computed = valid if fed is None else (
+        jnp.arange(ids.shape[1], dtype=fed.dtype)[None, :] < fed[:, None])
     stats = None
     head = net.conf.network_outputs[0]
     for name in net.topo_order:
@@ -354,7 +377,11 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
             acts = dict(acts, **{n: jnp.take_along_axis(
                 acts[n], out_rows[:, None, None], axis=1, mode="clip")
                 for n in in_names})
-        if i is not None and isinstance(layer, SelfAttentionLayer):
+        if i is not None and isinstance(layer, MLAttentionLayer):
+            out, k_pools[i] = layer.apply_paged(
+                params[name], acts[in_names[0]], k_pools[i], page_tables,
+                write_slots, rel_pos, positions, policy=net.policy)
+        elif i is not None and isinstance(layer, SelfAttentionLayer):
             out, k_pools[i], v_pools[i] = layer.apply_paged(
                 params[name], acts[in_names[0]], k_pools[i], v_pools[i],
                 page_tables, write_slots, rel_pos, policy=net.policy)
@@ -364,7 +391,7 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
                 lane_ids, valid, rel_pos == 0, policy=net.policy)
         elif getattr(layer, "wants_token_mask", False):
             out, st = net._apply_vertex(name, params[name], acts, {}, None,
-                                        train=False, in_masks=[valid],
+                                        train=False, in_masks=[computed],
                                         minibatch=mbs[in_names[0]])
             stats = st["moe_stats"] if stats is None \
                 else stats + st["moe_stats"]
@@ -402,7 +429,8 @@ def draft_transformer_lm(vocab_size: int, *, d_model: int = 128,
 
 def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
                       page_tables, rel_pos, active, budget, eos_ids,
-                      temperature, top_k, top_p, uniforms, lane_ids=None):
+                      temperature, top_k, top_p, uniforms, lane_ids=None,
+                      positions=None):
     """N decode steps over the paged arena in ONE dispatch — the
     device-resident inner loop the serving engine jits per lane bucket
     (``uniforms [S, N]`` fixes N at trace time). Each inner step
@@ -433,6 +461,9 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     (:func:`paged_decode_forward`), and the ``while`` carries them, as it
     carries a state-space vertex's state in the two state lists
     (``lane_ids``): a retired lane's dropped slot keeps its state still.
+    ``positions [S]`` (a net with :func:`position_vertices`): each lane's
+    absolute position at the block's first step; step ``i`` is at
+    ``positions + i``.
 
     Two CPU-harness-measured costs shape the implementation: the loop
     is a ``while_loop`` (not ``scan``) so a block whose every lane
@@ -477,7 +508,8 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
         step_stats = []
         probs, k_pools, v_pools = paged_decode_forward(
             net, params, k_pools, v_pools, cur[:, None], page_tables,
-            slot[:, None], rel_pos + i, None, step_stats)
+            slot[:, None], rel_pos + i, None, step_stats,
+            positions=None if positions is None else positions + i)
         with jax.named_scope("sample"):
             u = jax.lax.dynamic_index_in_dim(uniforms, i, axis=1,
                                              keepdims=False)

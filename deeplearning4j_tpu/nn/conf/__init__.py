@@ -9,6 +9,7 @@ from .inputs import InputType
 from .builders import NeuralNetConfiguration, ListBuilder
 from .multi_layer import MultiLayerConfiguration
 from . import attention as _attention  # noqa: F401  (serde registration)
+from . import mla as _mla  # noqa: F401  (serde registration)
 from . import moe as _moe  # noqa: F401  (serde registration)
 from . import ssm as _ssm  # noqa: F401  (serde registration)
 

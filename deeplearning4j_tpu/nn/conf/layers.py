@@ -774,6 +774,68 @@ def rms_normalize(x, gamma, eps: float):
     return (y * gamma.astype(cdt)).astype(x.dtype)
 
 
+def gated_ffn(x, w_gate, w_up, w_down):
+    """``(silu(x @ w_gate) * (x @ w_up)) @ w_down`` over the last axis, the
+    matrices cast to ``x``'s type: the gated feed-forward of today's
+    decoder blocks (SwiGLU), a dense layer's and a shared expert's alike.
+    Scopes ``ffn.gate_up`` and ``ffn.down``."""
+    with jax.named_scope("ffn.gate_up"):
+        h = (jax.nn.silu(x @ w_gate.astype(x.dtype))
+             * (x @ w_up.astype(x.dtype)))
+    with jax.named_scope("ffn.down"):
+        return h @ w_down.astype(x.dtype)
+
+
+@register_layer("gated_ffn")
+@dataclasses.dataclass
+class GatedFFNLayer(Layer):
+    """Position-wise gated feed-forward, [b, t, n_in] -> [b, t, n_out]:
+    ``(silu(x @ W_gate) * (x @ W_up)) @ W_down`` with ``d_hidden`` columns
+    in the middle and no bias (:func:`gated_ffn`)."""
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None          # defaults to n_in
+    d_hidden: int = 256
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_out or self.n_in,
+                                   input_type.timesteps)
+
+    def set_n_in(self, input_type: InputType, override: bool = False) -> None:
+        if self.n_in is None or override:
+            self.n_in = input_type.flat_size()
+        if self.n_out is None:
+            self.n_out = self.n_in
+
+    def has_params(self) -> bool:
+        return True
+
+    def param_shapes(self, policy=None):
+        return {"W_gate": (self.n_in, self.d_hidden),
+                "W_up": (self.n_in, self.d_hidden),
+                "W_down": (self.d_hidden, self.n_out)}
+
+    def regularized_params(self) -> Tuple[str, ...]:
+        return ("W_gate", "W_up", "W_down")
+
+    def init_params(self, key, policy=None):
+        policy = policy or _dtypes.default_policy()
+        return {name: init_weights(
+            jax.random.fold_in(key, n), shape, self.weight_init or "XAVIER",
+            fan_in=shape[0], fan_out=shape[1], distribution=self.dist,
+            dtype=policy.param_dtype)
+            for n, (name, shape) in enumerate(
+                sorted(self.param_shapes().items()))}
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None, policy=None):
+        policy = policy or _dtypes.default_policy()
+        xc = policy.cast_to_compute(self._dropout_in(x, train, rng))
+        out = gated_ffn(xc, params["W_gate"], params["W_up"],
+                        params["W_down"])
+        return self._act(self.activation or "identity")(out), state
+
+
 @register_layer("lrn")
 @dataclasses.dataclass
 class LocalResponseNormalization(Layer):

@@ -185,15 +185,18 @@ def tile_rows(pairs: int, n_published: int) -> int:
     return int(min(256, max(2, 1 << (math.ceil(mean) - 1).bit_length())))
 
 
-def sparse_expert_ffn(lat, idx, weights, w1, w2, *, offset: int,
+def sparse_expert_ffn(lat, idx, weights, *stacks, offset: int,
                       n_published: int, token_mask=None):
     """The routed part of an expert layer by sparse dispatch: for each
     token the sum over its chosen experts HELD HERE (``offset <= e <
     offset + E``) of ``w_e · relu(lat @ w1[e])^2 @ w2[e]``; what an absent
     expert would add is left out. ``lat [T, L]``, ``idx [T, k]`` int32
-    expert ids over all ``n_published``, ``weights [T, k]`` float32, ``w1
-    [E, L, F]``, ``w2 [E, F, L]``; ``token_mask [T]`` (or None) leaves a
-    padded token's pairs out of the work and of the counts.
+    expert ids over all ``n_published``, ``weights [T, k]`` float32;
+    ``stacks``: ``w1 [E, L, F]``, ``w2 [E, F, L]``, or three of a GATED
+    expert, ``wg``, ``wu [E, L, F]`` and ``wd [E, F, L]``, whose term is
+    ``w_e · (silu(lat @ wg[e]) * (lat @ wu[e])) @ wd[e]``; ``token_mask
+    [T]`` (or None) leaves a padded token's pairs out of the work and of
+    the counts.
 
     The (token, expert) pairs are sorted by expert, so each held expert's
     rows lie together, and cut into tiles of :func:`tile_rows` rows, one
@@ -210,7 +213,7 @@ def sparse_expert_ffn(lat, idx, weights, w1, w2, *, offset: int,
     """
     from ...ops.grouped_ffn import tile_ffn
     t, k = idx.shape
-    e_held = w1.shape[0]
+    e_held = stacks[0].shape[0]
     m = t * k
     rows = tile_rows(m, n_published)
     local = idx - offset
@@ -251,7 +254,7 @@ def sparse_expert_ffn(lat, idx, weights, w1, w2, *, offset: int,
     in_tile = jnp.arange(rows)[None, :] < tile_n[:, None]
     tok = jnp.take(order // k, jnp.clip(pos, 0, m - 1))
     x_tiles = jnp.where(in_tile[:, :, None], jnp.take(lat, tok, axis=0), 0)
-    out_tiles = tile_ffn(x_tiles, tile_e, n_tiles.astype(jnp.int32), w1, w2)
+    out_tiles = tile_ffn(x_tiles, tile_e, n_tiles.astype(jnp.int32), *stacks)
     # back to each pair's own slot: pair j is the rank-th of its expert's
     # sorted run, so row rank % rows of that expert's tile rank // rows
     g = jnp.minimum(gid, e_held - 1)
@@ -266,14 +269,16 @@ def sparse_expert_ffn(lat, idx, weights, w1, w2, *, offset: int,
     return out, stats
 
 
-def dense_expert_ffn(lat, idx, weights, w1, w2, *, offset: int):
+def dense_expert_ffn(lat, idx, weights, *stacks, offset: int):
     """The same sum by dense dispatch: every held expert computes every
     token and a mask keeps what was routed. The test oracle of
     :func:`sparse_expert_ffn`; no program calls it."""
-    e_held = w1.shape[0]
-    h = jnp.square(jax.nn.relu(jnp.einsum("tl,elf->etf", lat,
-                                          w1.astype(lat.dtype))))
-    y = jnp.einsum("etf,efl->etl", h, w2.astype(lat.dtype))
+    e_held = stacks[0].shape[0]
+    up = [jnp.einsum("tl,elf->etf", lat, w.astype(lat.dtype))
+          for w in stacks[:-1]]
+    h = (jnp.square(jax.nn.relu(up[0])) if len(stacks) == 2
+         else jax.nn.silu(up[0]) * up[1])
+    y = jnp.einsum("etf,efl->etl", h, stacks[-1].astype(lat.dtype))
     chosen = (idx[None] == (offset + jnp.arange(e_held))[:, None, None])
     w = jnp.sum(jnp.where(chosen, weights[None].astype(jnp.float32), 0.0),
                 axis=-1)                                  # [E, T]
@@ -410,6 +415,64 @@ class LatentMoELayer(Layer):
         with jax.named_scope("moe.shared"):
             h = jnp.square(jax.nn.relu(xc @ params["ws1"].astype(xc.dtype)))
             out = out + h @ params["ws2"].astype(xc.dtype)
+        out = self._act(self.activation or "identity")(out)
+        out_state = dict(state or {})
+        out_state["moe_stats"] = stats
+        return out.reshape(b, t, self.n_out), out_state
+
+
+@register_layer("gated_moe")
+@dataclasses.dataclass
+class GatedMoELayer(LatentMoELayer):
+    """Routed GATED experts in the hidden width with a gated shared expert
+    beside them (the expert layer of the DeepSeek-V3 family, which
+    ``pangu_ultra_moe`` shares): [b, t, f] -> [b, t, f].
+
+        idx, w = LatentMoELayer.route          the one routing rule
+        routed = sum over chosen e of w_e · (silu(u @ wg[e]) * (u @ wu[e])) @ wd[e]
+        shared = (silu(u @ sg) * (u @ su)) @ sd
+        out    = routed + shared
+
+    No latent projections (``d_latent`` is not used): ``wg``, ``wu`` are
+    ``[held, n_in, d_hidden]`` and ``wd`` ``[held, d_hidden, n_out]``. It
+    holds a share of its experts, dispatches, counts and masks exactly as
+    its parent does: one sparse dispatch (:func:`sparse_expert_ffn`), one
+    kernel file (``ops/grouped_ffn``), one set of :data:`MOE_STATS`.
+
+    In the hidden width the dispatch's pair-level arrays (tokens x top_k
+    rows of ``n_in``, sized for the worst routing: every pair held) are
+    the largest temporaries of a prefill program (1 GB at 32 lanes x 128
+    tokens x 8 a token x 7680 in float32).
+
+    Scopes: ``moe.router``, ``moe.experts``, ``moe.shared``.
+    """
+
+    def param_shapes(self, policy=None) -> Dict[str, Tuple[int, ...]]:
+        d, f, fs = self.n_in, self.d_hidden, self.d_shared
+        return {"router": (d, self.n_experts), "e_bias": (self.n_experts,),
+                "wg": (self.held, d, f), "wu": (self.held, d, f),
+                "wd": (self.held, f, self.n_out),
+                "sg": (d, fs), "su": (d, fs), "sd": (fs, self.n_out)}
+
+    def regularized_params(self) -> Tuple[str, ...]:
+        return ("wg", "wu", "wd", "sg", "su", "sd")
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None, policy=None):
+        from .layers import gated_ffn
+        policy = policy or _dtypes.default_policy()
+        x = self._dropout_in(x, train, rng)
+        b, t, d = x.shape
+        xc = policy.cast_to_compute(x).reshape(b * t, d)
+        idx, weights = self.route(params, xc)
+        with jax.named_scope("moe.experts"):
+            routed, stats = sparse_expert_ffn(
+                xc, idx, weights, params["wg"], params["wu"], params["wd"],
+                offset=self.expert_offset, n_published=self.n_experts,
+                token_mask=None if mask is None else mask.reshape(b * t))
+        with jax.named_scope("moe.shared"):
+            out = routed.astype(xc.dtype) + gated_ffn(
+                xc, params["sg"], params["su"], params["sd"])
         out = self._act(self.activation or "identity")(out)
         out_state = dict(state or {})
         out_state["moe_stats"] = stats

@@ -32,6 +32,12 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
   gather; stored ``[..., h, d]`` with ``d`` = 64 minor, it relaid every
   donated pool at the program's entry and again before its result (96
   pool-sized copies a dispatch at 24 layers; PERF.md, PR 32).
+- a LATENT attention vertex (``nn/conf/mla``) owns one pool, ``[num_pages,
+  page_size, row]``: a token's key row, whose first columns are also its
+  value (:func:`paged_read_attention`, ``v_width``). The row is rounded up
+  to a whole number of 128-lane tiles with zeros (576 numbers in 640
+  columns): at 576 the compiler stored the pool pages-minor and relaid it
+  at every program's entry and before its result (TPU compiler, PR 35).
 - NO PROGRAM RESHAPES A WHOLE POOL (nor transposes, converts or does
   arithmetic on one): a pool enters a scatter and a gather and leaves
   as the scatter's result. Heads are split off the GATHERED pages only,
@@ -201,9 +207,9 @@ def read_trip_count(rel_pos, t_new: int, page_size: int,
 
 
 # every layer of a program reads at the same shapes: traced once
-@functools.partial(jax.jit, static_argnames=("group",))
+@functools.partial(jax.jit, static_argnames=("group", "v_width"))
 def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
-                         group: int = 1):
+                         group: int = 1, v_width=None):
     """Causal attention of new queries over each lane's paged window,
     read only as far as the furthest live position of the dispatch.
 
@@ -230,12 +236,18 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
     ``t_new // group`` positions, position-major, so query row ``j`` sits
     at position ``rel_pos + j // group``. The pools' rows are ``h*d`` wide
     either way.
+
+    ``v_width`` (with ``v_pool`` None) is the read over a LATENT pool
+    (``nn/conf/mla.MLAttentionLayer``): one pool holds a token's key row
+    and the value is that row's first ``v_width`` columns, so a chunk of
+    pages is gathered once and sliced; returns ``[S, t_new, h, v_width]``.
     """
     codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
     num_pages, page_size = codes.shape[0], codes.shape[1]
     kv_dtype = jnp.float32 if isinstance(k_pool, tuple) else codes.dtype
     out_dtype = jnp.result_type(q.dtype, kv_dtype)
     s, t_new, h, d = q.shape
+    d_v = d if v_width is None else v_width
     pages_per_seq = page_table.shape[1]
     cp = read_chunk_pages(page_size, pages_per_seq)
     chunk = cp * page_size
@@ -255,7 +267,8 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
         table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
                                                axis=1)
         k_c = paged_gather(k_pool, table_c, h)                # [S, h, d, chunk]
-        v_c = paged_gather(v_pool, table_c, h)
+        v_c = (paged_gather(v_pool, table_c, h) if v_width is None
+               else k_c[:, :, :v_width])
         with jax.named_scope("attn.paged_softmax"):
             logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
             key_idx = c * chunk + jnp.arange(chunk)
@@ -274,7 +287,7 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
 
     init = (jnp.full((s, h, t_new, 1), -jnp.inf, jnp.float32),
             jnp.zeros((s, h, t_new, 1), jnp.float32),
-            jnp.zeros((s, t_new, h, d),
+            jnp.zeros((s, t_new, h, d_v),
                       jnp.promote_types(out_dtype, jnp.float32)))
     _, l, acc = jax.lax.fori_loop(0, trips, fold, init)
     with jax.named_scope("attn.paged_softmax"):

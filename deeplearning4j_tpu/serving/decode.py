@@ -83,6 +83,16 @@ paged read of each dispatch went is counted beside what its lanes'
 whole windows hold: ``decode_kv_read_tokens_total{kind}`` over
 ``decode_kv_window_tokens_total{kind}``.
 
+What a net makes the engine carry beside K/V pages, each found on the
+net and handed to the decode programs as one more argument, so that a net
+without it keeps the programs it had: per-lane recurrent state
+(``lane_ids``; state-space vertices), each lane's ABSOLUTE position
+(``positions``; a rotary term, ``nn/conf/mla``: the view-relative position
+indexes the cache, the absolute one turns the keys), and under the prefix
+cache the positions each lane fed (``fed``; an expert layer computes a
+re-fed position whose write is dropped, and skips padding). A latent
+attention vertex owns one pool, not two.
+
 Fault seam: ``"serving.decode_step"`` before every prefill/decode
 dispatch (chaos tests script outages at exact step boundaries).
 """
@@ -100,8 +110,8 @@ import jax
 import numpy as np
 
 from ..models import transformer as _transformer
-from ..nn.conf.attention import SelfAttentionLayer
 from ..nn.conf.layers import EmbeddingSequenceLayer
+from ..nn.conf.mla import MLAttentionLayer
 from ..ops import paged_attention as _paged
 from ..util import faults as _faults
 from ..util import flightrecorder as _flight
@@ -286,9 +296,23 @@ class PagedDecodeEngine:
         # what the net makes the engine carry beside K/V pages: per-lane
         # recurrent state, and expert layers whose routing is counted
         self.state_layers = _transformer.state_space_vertices(net)
-        self._counting = bool(_transformer.counting_vertices(net))
+        self._counting_layers = len(_transformer.counting_vertices(net))
+        self._counting = bool(self._counting_layers)
+        # vertices with a rotary term take each lane's absolute position
+        self.position_layers = _transformer.position_vertices(net)
         self._refuse_unsupported(net, prefix_cache=bool(prefix_cache),
                                  draft_net=draft_net)
+        # what a decode program takes after its other arguments, by what
+        # the net needs (none for a net that needs none: its programs keep
+        # the arguments they always had): the lanes' ids (recurrent state
+        # is a row a lane), their absolute positions, and, in a paged
+        # dispatch under the prefix cache, how many positions each lane
+        # fed (an expert layer computes a re-fed position whose write is
+        # dropped, and skips padding)
+        self._extra = (("lane_ids",) * bool(self.state_layers)
+                       + ("positions",) * bool(self.position_layers))
+        self._extra_paged = self._extra + ("fed",) * bool(
+            self._counting and prefix_cache)
         dims, dtype = self._arena_dims(net)
         self.arena = PagedKVArena(dims, num_pages=int(num_pages),
                                   page_size=self.page_size, dtype=dtype,
@@ -384,6 +408,12 @@ class PagedDecodeEngine:
             "Key positions of the same dispatches' whole windows: lanes "
             "of the bucket x window, for every step of a block",
             ("kind",))
+        token_bytes = float(self.arena.token_nbytes())
+        self.registry.gauge(
+            "decode_kv_bytes_per_token",
+            "Bytes one cached token takes over all the paged pools (K and "
+            "V rows of every attention vertex; a latent vertex's one row)"
+        ).set_function(lambda: token_bytes)
         if self.state_layers:
             self._m_state_resets = self.registry.counter(
                 "decode_state_resets_total",
@@ -418,6 +448,10 @@ class PagedDecodeEngine:
                 "Held experts that got at least one pair, summed over "
                 "expert layers and steps: the expert matrices a step "
                 "has to read")
+            self._m_moe_calls = self.registry.counter(
+                "moe_grouped_calls_total",
+                "Calls of the expert layers' grouped product: dispatches "
+                "x expert layers x steps, counted on the host")
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
@@ -430,7 +464,9 @@ class PagedDecodeEngine:
     def _arena_dims(self, net):
         """``(layer_dims, dtype)`` of the arena of ``net``, target or
         draft: each stateful vertex in the walker's order with what it
-        holds (``PagedKVArena``), and the pools' dtype: the dense
+        holds (``PagedKVArena``: K/V heads and their size, a latent
+        vertex's row width, a state-space vertex's two shapes), and the
+        pools' dtype: the dense
         streaming cache's rule (``_zero_state``), at least f32, so bf16
         compute policies keep exact K/V (``kv_dtype="int8"`` replaces the
         pools with quantized (codes, scales) tuples; the dtype then only
@@ -439,16 +475,19 @@ class PagedDecodeEngine:
         dims = {}
         for name in _transformer.stateful_vertices(net):
             layer = net._vertex_layer(name)
-            dims[name] = ((layer.kv_heads, layer.head_dim)
-                          if isinstance(layer, SelfAttentionLayer)
-                          else layer.state_shapes(self.lanes))
+            if isinstance(layer, MLAttentionLayer):
+                dims[name] = (layer.pool_width, None)    # one latent pool
+            elif hasattr(layer, "state_shapes"):         # per-lane state
+                dims[name] = layer.state_shapes(self.lanes)
+            else:
+                dims[name] = (layer.kv_heads, layer.head_dim)
         return dims, jnp.promote_types(net.policy.compute_dtype,
                                        jnp.float32)
 
     def _refuse_unsupported(self, net, *, prefix_cache: bool,
                             draft_net) -> None:
-        """What a net with recurrent state or counting expert layers
-        cannot be served with yet, refused here with the reason."""
+        """What a net with recurrent state or a rotary term cannot be
+        served with yet, refused here with the reason."""
         if self.state_layers and prefix_cache:
             raise ValueError(
                 f"prefix_cache=True with state-space vertices "
@@ -457,13 +496,16 @@ class PagedDecodeEngine:
                 "after that prefix is kept nowhere (no snapshot at page "
                 "boundaries yet), so the lane would decode from a wrong "
                 "state — serve this net with prefix_cache=False")
-        if self._counting and prefix_cache:
+        if draft_net is not None and (
+                self.position_layers
+                or _transformer.position_vertices(draft_net)):
             raise ValueError(
-                "prefix_cache=True with an expert layer that takes the "
-                "dispatch's valid positions: a prefix hit re-feeds covered "
-                "positions with dropped writes, which the layer would "
-                "take for padding and skip — serve this net with "
-                "prefix_cache=False")
+                "draft_net with vertices that take absolute positions "
+                f"{(self.position_layers or ['the draft net'])[:2]}...: the "
+                "speculative programs (draft scan, verify chunk) hand a "
+                "layer view-relative positions only, and a rotary term "
+                "turned by those is wrong once a window slides or a "
+                "prefix is mapped — serve this net without a draft_net")
         if draft_net is not None and (
                 self.state_layers
                 or _transformer.state_space_vertices(draft_net)):
@@ -499,7 +541,7 @@ class PagedDecodeEngine:
         for name in net.topo_order:
             v = net.conf.vertices[name]
             layer = getattr(v, "layer", None)
-            if isinstance(layer, SelfAttentionLayer):
+            if isinstance(layer, _transformer.ATTENTION_LAYERS):
                 if not layer.causal:
                     raise ValueError(
                         f"vertex {name!r}: non-causal attention cannot "
@@ -742,7 +784,8 @@ class PagedDecodeEngine:
 
     def run(self, ids: np.ndarray, write_slots: np.ndarray,
             rel_pos: np.ndarray, tables: np.ndarray, out_rows: np.ndarray,
-            lanes: Optional[np.ndarray] = None) -> np.ndarray:
+            lanes: Optional[np.ndarray] = None,
+            fed: Optional[np.ndarray] = None) -> np.ndarray:
         """One paged forward over a COMPACT lane selection (``ids
         [B, t_new]``, ``tables [B, P]`` — the scheduler packs only the
         lanes that actually have work, bucketed to a power of two, so a
@@ -759,44 +802,57 @@ class PagedDecodeEngine:
         steady-state decode never retraces. ``lanes [B]``: the engine
         lane of each row, which a net with state-space vertices needs
         (its recurrent state is a row a lane; see
-        :meth:`_state_args`)."""
+        :meth:`_extra_args`); ``fed [B]``: the positions each lane fed
+        (its chunk's length; a padded slot 0), which only an expert
+        layer under the prefix cache is told."""
         b, t_new = ids.shape
         name = f"paged_decode[S{b}xT{t_new}xP{self.pages_per_seq}]"
+        names = self._extra_paged
+        if fed is None:         # no prefix hit among them: what is written
+            fed = (write_slots >= 0).sum(axis=1)
 
         def step(params, k_pools, v_pools, ids, tables, wslots, rel, rows,
-                 *lane_ids):
+                 *extra):
             counts = []
             probs, k_pools, v_pools = _transformer.paged_decode_forward(
                 self.net, params, k_pools, v_pools, ids, tables, wslots,
-                rel, *lane_ids, counts=counts, out_rows=rows)
+                rel, counts=counts, out_rows=rows, **dict(zip(names, extra)))
             return (probs, *counts, k_pools, v_pools)
 
         probs, *counts = self._dispatch(
             name, step, self.arena, self.net.params,
             (ids, tables, write_slots, rel_pos,
-             np.asarray(out_rows, np.int32), *self._state_args(lanes, b)),
+             np.asarray(out_rows, np.int32),
+             *self._extra_args(names, lanes, rel_pos, fed)),
             kind="paged")
         self._note_kv_read("paged", rel_pos, t_new)
-        self._note_routing(counts)
+        self._note_routing(counts, steps=1)
         return probs
 
-    def _state_args(self, lanes: Optional[np.ndarray], b: int) -> tuple:
-        """The lane ids a decode program of a net with state-space
-        vertices takes after its other arguments, none for a net without
-        (whose programs keep the arguments they always had). ``None``
-        (warm-up) is every slot padded: an id one past the last lane
-        reads zeros and writes nothing, like a sentinel page."""
-        if not self.state_layers:
-            return ()
+    def _extra_args(self, names: tuple, lanes: Optional[np.ndarray],
+                    rel: np.ndarray,
+                    fed: Optional[np.ndarray] = None) -> tuple:
+        """The arrays ``names`` (``_extra`` or ``_extra_paged``) stand
+        for, in their order. ``lanes`` None (warm-up) is every slot
+        padded: a lane id one past the last lane reads zeros and writes
+        nothing, like a sentinel page, and sits at position ``rel``. A
+        lane's absolute position is its view-relative one plus what its
+        window has evicted."""
         if lanes is None:
-            lanes = np.full(b, self.lanes, np.int32)
-        return (np.asarray(lanes, np.int32),)
+            lanes = np.full(len(rel), self.lanes, np.int32)
+        lanes = np.asarray(lanes, np.int32)
+        base = np.append(self._base, 0)[np.minimum(lanes, self.lanes)]
+        by_name = {"lane_ids": lanes, "fed": fed,
+                   "positions": np.asarray(rel, np.int64) + base}
+        return tuple(np.asarray(by_name[name], np.int32) for name in names)
 
-    def _note_routing(self, counts: list) -> None:
-        """Account the expert layers' routing counts that a dispatch
-        brought back beside its tokens (``nn.conf.moe.MOE_STATS``)."""
+    def _note_routing(self, counts: list, steps: int) -> None:
+        """Account the expert layers' routing counts that a dispatch of
+        ``steps`` decode steps brought back beside its tokens
+        (``nn.conf.moe.MOE_STATS``)."""
         if not counts or self._warming:
             return
+        self._m_moe_calls.inc(self._counting_layers * steps)
         held, absent, computed, peak, steps, touched = (
             int(v) for v in counts[0])
         self._m_moe_routed.inc(held, where="held")
@@ -942,19 +998,23 @@ class PagedDecodeEngine:
         b, n = uniforms.shape
         name = f"fused_decode[S{b}xN{n}xP{self.pages_per_seq}]"
 
+        names = self._extra
+
         def step(params, k_pools, v_pools, last, tables, rel, active,
-                 budget, eos, temps, tk, tp, u, *lane_ids):
+                 budget, eos, temps, tk, tp, u, *extra):
             return _transformer.fused_decode_loop(
                 self.net, params, k_pools, v_pools, last, tables, rel,
-                active, budget, eos, temps, tk, tp, u, *lane_ids)
+                active, budget, eos, temps, tk, tp, u,
+                **dict(zip(names, extra)))
 
         toks, valid, n_emitted, _done, *counts = self._dispatch(
             name, step, self.arena, self.net.params,
             (last, tables, rel, active, budget, eos, temps, top_k, top_p,
-             uniforms, *self._state_args(lanes, b)), kind="fused")
+             uniforms, *self._extra_args(names, lanes, rel)), kind="fused")
         # the block's loop ends with its last live lane
-        self._note_kv_read("fused", rel, 1, steps=int(n_emitted.max()))
-        self._note_routing(counts)
+        steps = int(n_emitted.max())
+        self._note_kv_read("fused", rel, 1, steps=steps)
+        self._note_routing(counts, steps=steps)
         return toks, valid, n_emitted
 
     # -- speculative draft / verify -----------------------------------
@@ -1321,6 +1381,16 @@ class DecodeScheduler:
                 "layers' pages slide while the state-space layers' state "
                 "has no window, so the two would see different histories "
                 "— size page_size x pages_per_seq to the longest request")
+        if (self.engine.position_layers
+                and prompt.size + n_new > self.engine.window):
+            raise ValueError(
+                f"request of {prompt.size} + {n_new} tokens exceeds the "
+                f"window of {self.engine.window}: past it the pages slide "
+                "and the first tokens leave the attention, but a rotary "
+                "model is trained, and its reference computed, with every "
+                "earlier position in view, so what it would generate past "
+                "the window is no longer the model's — size page_size x "
+                "pages_per_seq to the longest request")
         if int(top_k) < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
         if not (0.0 < float(top_p) <= 1.0):
@@ -1544,7 +1614,9 @@ class DecodeScheduler:
         _faults.check("serving.decode_step",
                       {"phase": "prefill", "lanes": len(seqs)})
         w0, c0 = eng._tick_dispatch_wall, eng._compile_wall()
-        probs = eng.run(ids, wslots, rel, tables, rows, lanes)   # [B, V]
+        fed = np.zeros(len(rel), np.int32)
+        fed[:len(seqs)] = chunk_len
+        probs = eng.run(ids, wslots, rel, tables, rows, lanes, fed)  # [B, V]
         if eng.draft_net is not None:
             # shadow prefill: the draft cache must hold the same prompt
             # context before its first drafting block (same ids, same

@@ -482,14 +482,18 @@ class PrefixIndex:
 
 class PagedKVArena:
     """What the stateful vertices of a decode graph own, in the order the
-    walker visits them, + the shared page allocator: one manager for two
+    walker visits them, + the shared page allocator: one manager for three
     kinds of state.
 
     ``layer_dims`` maps vertex name → what it holds. A causal attention
     vertex gives ``(kv heads, head_dim)`` and gets a K and a V block pool
     (``k_pools[i]``, ``v_pools[i]``), paged: ``SENTINEL`` (= num_pages,
     one past the pool) marks page-table holes: gathers fill zeros there,
-    scatters drop. A state-space vertex gives its two per-lane shapes
+    scatters drop. A LATENT attention vertex (``nn/conf/mla``) gives
+    ``(row width, None)`` and gets ONE block pool ``[num_pages, page_size,
+    row width]`` in ``k_pools[i]``, paged, written and read as the others
+    are; ``v_pools[i]`` is None (an empty pytree: nothing is donated,
+    returned or counted for it). A state-space vertex gives its two per-lane shapes
     (``Mamba2Mixer.state_shapes(lanes)``) and gets a convolution tail
     ``[lanes, K-1, C]`` in ``k_pools[i]`` and an SSM state ``[lanes, H, P,
     N]`` in ``v_pools[i]``: fixed-size whatever the sequence's length, so
@@ -544,6 +548,13 @@ class PagedKVArena:
         self.kv_dtype = kv_dtype
         self.layer_names = list(layer_dims)
         self._layer_dims = dict(layer_dims)
+        if kv_dtype == "int8" and any(d is None
+                                      for _, d in layer_dims.values()):
+            raise ValueError(
+                "kv_dtype='int8' with a latent attention vertex: the int8 "
+                "pools keep one scale a (page, head), and a latent row has "
+                "no heads: its normalised latent and its rotary key differ "
+                "in scale by columns — serve this net with float pools")
         self.k_pools: List = []
         self.v_pools: List = []
         self.reset_pools()
@@ -568,6 +579,11 @@ class PagedKVArena:
                 self.k_pools.append(jnp.zeros(h, jnp.float32))
                 self.v_pools.append(jnp.zeros(d, jnp.float32))
                 continue
+            if d is None:                  # a latent vertex: one pool
+                self.k_pools.append(jnp.zeros(
+                    (self.num_pages, self.page_size, h), self.dtype))
+                self.v_pools.append(None)
+                continue
             shape = (self.num_pages, self.page_size, h * d)
             if self.kv_dtype == "int8":
                 self.k_pools.append((jnp.zeros(shape, jnp.int8),
@@ -591,9 +607,26 @@ class PagedKVArena:
                                            self.k_pools, self.v_pools)
                    if isinstance(h, tuple))
 
+    def token_nbytes(self) -> int:
+        """Bytes ONE cached token takes over all the paged pools (a
+        quantized pool's per-page scales and the per-lane recurrent state,
+        which no token adds to, left out)."""
+        total = 0
+        for (h, _), k, v in zip(self._layer_dims.values(), self.k_pools,
+                                self.v_pools):
+            if isinstance(h, tuple):
+                continue
+            for p in (k, v):
+                if p is not None:
+                    codes = p[0] if isinstance(p, tuple) else p
+                    total += codes.shape[-1] * codes.dtype.itemsize
+        return total
+
     def nbytes(self) -> int:
         total = 0
         for p in self.k_pools + self.v_pools:
+            if p is None:                  # a latent vertex's second slot
+                continue
             if isinstance(p, tuple):
                 total += sum(int(x.nbytes) for x in p)
             else:

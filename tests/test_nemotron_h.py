@@ -76,11 +76,13 @@ def all_positions(eng, ids, slots, rel, tables, lanes=None):
     fwd = getattr(eng, "_all_positions", None)
     if fwd is None:                 # one jit an engine: its shapes' cache
         fwd = eng._all_positions = jax.jit(
-            lambda params, k, v, *args: _transformer.paged_decode_forward(
-                eng.net, params, k, v, *args))
+            lambda params, k, v, *args, **extra:
+            _transformer.paged_decode_forward(eng.net, params, k, v, *args,
+                                              **extra))
     probs, k_pools, v_pools = fwd(
         eng.net.params, eng.arena.k_pools, eng.arena.v_pools, ids, tables,
-        slots, rel, *eng._state_args(lanes, len(rel)))
+        slots, rel, **dict(zip(eng._extra,
+                               eng._extra_args(eng._extra, lanes, rel))))
     return np.asarray(probs), k_pools, v_pools
 
 
@@ -214,14 +216,15 @@ def test_what_the_engine_refuses(net, family):
                             start_thread=False)
     with pytest.raises(ValueError, match="has no window"):
         sched.submit(np.arange(100) % 7, 40)       # 140 > the window of 128
-    # an expert layer that takes the valid positions, attention-only net
-    with pytest.raises(ValueError, match="take for padding and skip"):
-        moe_only = ComputationGraph(nemotron_h_lm(
-            32, pattern="*E", d_model=16, n_heads=2, n_kv_heads=1,
-            mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=4,
-            n_experts=4, top_k=2, d_latent=8, d_expert=8, d_shared=8,
-            max_cache_t=WINDOW)).init()
-        PagedDecodeEngine(moe_only, **ENGINE, prefix_cache=True)
+    # an expert layer over an attention-only net takes the prefix cache
+    # since the walker tells "computed" from "write kept" (tests/test_pangu)
+    moe_only = ComputationGraph(nemotron_h_lm(
+        32, pattern="*E", d_model=16, n_heads=2, n_kv_heads=1,
+        mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=4,
+        n_experts=4, top_k=2, d_latent=8, d_expert=8, d_shared=8,
+        max_cache_t=WINDOW)).init()
+    eng = PagedDecodeEngine(moe_only, **ENGINE, prefix_cache=True)
+    assert eng._extra == () and eng._extra_paged == ("fed",)
     # and an LSTM's carry still has no arena: the message names what has
     from deeplearning4j_tpu.models import char_rnn_lstm
     with pytest.raises(ValueError):

@@ -349,6 +349,7 @@ def test_benchmark_metric_names_counters_the_registry_has(net):
         entry = [m for m in json.load(f)["per_layer"]
                  if m["name"] == metric["name"]]
     assert len(entry) == 1
-    assert entry[0]["workloads"] == ["serve-opt-chat", "serve-opt-docqa"]
+    assert entry[0]["workloads"] == ["serve-opt-chat", "serve-opt-docqa",
+                                     "serve-pangu-longdoc"]
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[0][key] == metric[key]
