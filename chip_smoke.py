@@ -7,7 +7,8 @@ from a seed) through the entry points a user calls, in one process that owns
 the chip from start to end:
 
   kernel  the Pallas flash forward/backward against the XLA path on a small
-          input at the flagship head shape
+          input at the flagship head shape, and at the shape of the
+          benchmark's training cell ([1, 8192, 32, 64], one backward call)
   train   ``ComputationGraph(...).init()`` then ``net.fit(iterator)`` at
           T=4096, where attention routes itself to the Pallas kernel
   serve   ``InferenceServer(net, decode={...})`` (fused block path) answering
@@ -48,11 +49,13 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 SEED = 21
 FULL = dict(vocab=32768, d_model=768, n_layers=12, n_heads=12, d_ff=3072,
             train_t=4096, train_batch=2, train_steps=6, kernel_t=1024,
+            kernel_cell=(1, 8192, 32, 64),
             page_size=16, pages_per_seq=32, lanes=8, block_len=8,
             prompt_lens=(9, 20, 47, 100, 180, 300), new_tokens=24,
             sp_t=4096)
 SMALL = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, d_ff=64,
              train_t=128, train_batch=2, train_steps=4, kernel_t=128,
+             kernel_cell=(1, 256, 4, 32),
              page_size=4, pages_per_seq=8, lanes=2, block_len=4,
              prompt_lens=(3, 5, 9, 14, 3, 7), new_tokens=6, sp_t=512)
 
@@ -86,17 +89,18 @@ def retraces(registry, fn=None) -> float:
 # --------------------------------------------------------------------------
 
 
-def leg_kernel(cfg) -> dict:
-    """Pallas flash fwd + bwd vs the XLA path, bf16, flagship head shape."""
+def flash_parity(shape, what: str) -> dict:
+    """Pallas flash fwd + bwd against the XLA path on one [b, t, h, d]
+    bf16 causal input; the three gradients' gaps are printed and held to
+    a few bf16 ulps at the reference's largest magnitude."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops.attention import dot_product_attention
     from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
-    t, h = cfg["kernel_t"], cfg["n_heads"]
-    d = cfg["d_model"] // h
+    b, t, h, d = shape
     keys = jax.random.split(jax.random.PRNGKey(SEED), 3)
-    q, k, v = (jax.random.normal(kk, (2, t, h, d), jnp.float32)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32)
                .astype(jnp.bfloat16) for kk in keys)
 
     def grads(attn):
@@ -105,32 +109,58 @@ def leg_kernel(cfg) -> dict:
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
 
     # the reference is the XLA path in float32 on the same (bf16-rounded)
-    # values: t is below the auto-route length and the flag is lifted, so
-    # dot_product_attention cannot route to the kernel; flash_attention()
-    # IS the kernel, compiled unless --small asked for interpret mode
+    # values, a few heads at a call so that its [b, heads, t, t] scores
+    # stay under half a GiB (the loss is a sum over heads); the flag says
+    # 0 while it is traced, so at no length can it route to the kernel.
+    # flash_attention() IS the kernel, compiled unless --small asked for
+    # interpret mode
+    group = max(1, min(h, (1 << 27) // (b * t * t)))
+    while h % group:
+        group -= 1
+    ref_fn = grads(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True))
     prior = os.environ.pop("DL4JTPU_FLASH_ATTENTION", None)
+    os.environ["DL4JTPU_FLASH_ATTENTION"] = "0"
     try:
-        ref_loss, ref = grads(lambda q, k, v: dot_product_attention(
-            q, k, v, causal=True))(*(a.astype(jnp.float32)
-                                     for a in (q, k, v)))
+        parts = [ref_fn(*(a[:, :, g:g + group].astype(jnp.float32)
+                          for a in (q, k, v)))
+                 for g in range(0, h, group)]
     finally:
+        del os.environ["DL4JTPU_FLASH_ATTENTION"]
         if prior is not None:
             os.environ["DL4JTPU_FLASH_ATTENTION"] = prior
-    fl_loss, fl = grads(lambda q, k, v: flash_attention(
-        q, k, v, True))(q, k, v)
-    out = {"t": t, "heads": h, "head_dim": d}
+    ref_loss = sum(float(p[0]) for p in parts)
+    ref = [jnp.concatenate([p[1][i] for p in parts], axis=2)
+           for i in range(3)]
+    fl_fn = grads(lambda q, k, v: flash_attention(q, k, v, True))
+    # forward and ONE backward call: each tile's P and dS computed once
+    n = kernel_calls(fl_fn.trace(q, k, v), f"{what}: flash forward+grad")
+    check(n == 2, f"{what}: one forward and one backward kernel ({n})")
+    fl_loss, fl = fl_fn(q, k, v)
+    out = {"shape": list(shape)}
     for name, a, r in zip("qkv", fl, ref):
         err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - r)))
         scale = float(jnp.max(jnp.abs(r)))
         out[f"d{name}_max_err"] = err
         # bf16 results: a few ulps at the largest magnitude
         check(math.isfinite(err) and err <= scale / 64.0,
-              f"flash d{name} matches XLA (max err {err:.3g} vs "
+              f"{what}: flash d{name} matches XLA (max err {err:.3g} vs "
               f"scale {scale:.3g})")
-    rel = abs(float(fl_loss) - float(ref_loss)) / max(1.0,
-                                                      abs(float(ref_loss)))
-    check(rel < 1e-2, f"flash forward loss matches XLA (rel {rel:.2g})")
+    rel = abs(float(fl_loss) - ref_loss) / max(1.0, abs(ref_loss))
+    check(rel < 1e-2, f"{what}: flash forward loss matches XLA "
+                      f"(rel {rel:.2g})")
     return out
+
+
+def leg_kernel(cfg) -> dict:
+    """Pallas flash fwd + bwd vs the XLA path, bf16: at the flagship head
+    shape, and at the shape of the benchmark's training cell (one
+    sequence of ``cell_t`` tokens, 32 heads of 64), where the backward is
+    one call with the head's whole dq in VMEM."""
+    h = cfg["n_heads"]
+    return {"flagship": flash_parity(
+                (2, cfg["kernel_t"], h, cfg["d_model"] // h), "flagship"),
+            "cell": flash_parity(cfg["kernel_cell"], "cell")}
 
 
 def build_net(cfg):

@@ -13,20 +13,28 @@ NEG_INF logits, and rows with NO attendable keys (leading padding under a
 causal mask, all-zero mask rows) output 0 — same semantics as the guarded
 XLA path in ``ops.attention``.
 
-Backward: Pallas kernels for both passes — P is recomputed per tile from
-the saved lse; the dq pass streams K/V blocks while the dq tile
-accumulates in VMEM scratch; the fused dk/dv pass recomputes each tile's
-P/dS once for both grads. Peak memory is O(t·block + t·d), so TRAINING
-runs at sequence lengths where XLA's attention cannot even compile.
-Gradients match the dense path (CPU interpret + on-chip parity tests).
-A JAX-blockwise fallback backward remains behind ``DL4JTPU_FLASH_BWD=jax``.
+Backward: ONE Pallas call a layer (``_bwd_kernel``). On the
+grid (heads, key blocks, query blocks) each [block_q, block_k] tile's P and
+dS are recomputed once from the saved lse and feed all three gradients:
+dk and dv accumulate in a [block_k, d] VMEM scratch over a key block's
+steps, dq in a float32 scratch that holds the head's WHOLE [t, d] dq (2 MiB
+at t 8192, d 64), zeroed at the head's first step and written at its last
+to an output block that stays resident in between. That call runs where
+the head's dq fits ``_VMEM_DQ_LIMIT`` (t·d·4 bytes, compared at trace time;
+every shape the tests, examples and the benchmark run does); a longer
+sequence takes two calls: the same kernel without its dq for dk/dv, and
+``_bwd_dq_kernel``, which computes every tile again.
+Peak memory is O(t·block + t·d), so TRAINING runs at sequence lengths
+where XLA's attention cannot even compile. Gradients match the dense path
+(CPU interpret + on-chip parity, ``chip_smoke.py``). A JAX-blockwise
+fallback backward remains behind ``DL4JTPU_FLASH_BWD=jax``.
 
-What is measured lives in PERF.md, with the installation each figure
-was taken on: the kernels compile under the installed TPU compiler and
-agree with the XLA path on the v5e (``chip_smoke.py``, PR 21); their
-speed-up over XLA (about 2–3× fwd+grad at t ≥ 4096, t=16384 running
-where XLA ran out of memory) dates from before PR 1 on an installation
-that no longer exists and has not been re-measured.
+What is measured lives in PERF.md, with the installation each figure was
+taken on: the kernels compile under the installed TPU compiler and agree
+with the XLA path on the v5e (``chip_smoke.py``); the one-call backward's
+times against the two calls, tile by tile, are PR 36's (PERF.md section
+6). The kernels' speed-up over XLA dates from before PR 1 on an
+installation that no longer exists and has not been re-measured.
 
 Routing (``ops.attention.dot_product_attention``): auto at t ≥ 4096 on
 the TPU backend; ``DL4JTPU_FLASH_ATTENTION=1`` forces it on (any length),
@@ -50,6 +58,14 @@ _HALF_NEG = NEG_INF / 2
 # whole-K/V-in-VMEM variant above this size switches to the grid-streamed
 # kernel (module constant so tests can force the streamed path)
 _VMEM_KV_LIMIT = 4 * 1024 * 1024
+# the backward computes dq, dk and dv in ONE call while a head's float32
+# dq, t * d * 4 bytes, fits this budget (it stays in a VMEM scratch for the
+# head's whole sweep); above it the two-call backward runs
+_VMEM_DQ_LIMIT = 4 * 1024 * 1024
+# scoped-VMEM limit of every backward call: 1024 x 1024 tiles need more
+# than the default 16 MiB, and the one call's dq scratch and resident
+# output block come on top (a v5e core has 128 MiB)
+_BWD_VMEM_BYTES = 64 * 1024 * 1024
 
 
 def _masked_update(q, k, v, valid, m_prev, num, den, *, scale, causal,
@@ -333,7 +349,8 @@ def _flash_bwd_btd(q, k, v, mk, out, lse, dout, *, scale, causal, block_q,
 
 
 # --------------------------------------------------------------------------
-# Pallas backward kernels: dq pass + fused dk/dv pass
+# Pallas backward kernels: one call for dq, dk and dv; above the dq budget
+# the dk/dv pass and a dq pass
 # --------------------------------------------------------------------------
 
 
@@ -362,8 +379,10 @@ def _bwd_p_ds(q, k, v, do, lse, delta, valid, *, scale, causal,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mk_ref, lse_ref, dl_ref, do_ref,
                    dq_ref, dq_acc, *, scale, causal, block_q, block_k, nk):
-    """dq pass: grid (bh, nq, nk), k sequential — the dq tile accumulates
-    in VMEM scratch while Pallas streams (double-buffers) K/V blocks."""
+    """dq pass of the two-call backward (a head whose dq is beyond
+    ``_VMEM_DQ_LIMIT``): grid (bh, nq, nk), k sequential — the dq tile
+    accumulates in VMEM scratch while Pallas streams (double-buffers) K/V
+    blocks."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -392,11 +411,20 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mk_ref, lse_ref, dl_ref, do_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(k_ref, v_ref, mk_ref, q_ref, lse_ref, dl_ref, do_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    block_q, block_k, nq):
-    """Fused dk/dv pass: grid (bh, nk, nq), q sequential — P and dS are
-    recomputed ONCE per tile and feed both dk (dSᵀ·q) and dv (Pᵀ·dout)."""
+def _bwd_kernel(k_ref, v_ref, mk_ref, q_ref, lse_ref, dl_ref, do_ref, *refs,
+                scale, causal, block_q, block_k, nq, nk, with_dq):
+    """The backward of one head: grid (bh, nk, nq), q sequential — P and
+    dS are recomputed ONCE per tile and feed dk (dSᵀ·q), dv (Pᵀ·dout) and,
+    ``with_dq``, dq (dS·k). dk/dv accumulate in a [block_k, d] scratch
+    over a key block's steps; dq accumulates in a scratch that holds the
+    head's WHOLE [t, d] dq, zeroed at the head's first step and written at
+    its last to an output block that stays resident in between. Without
+    ``with_dq`` (a head whose dq does not fit ``_VMEM_DQ_LIMIT``) this is
+    the dk/dv pass and ``_bwd_dq_kernel`` computes every tile again."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
     kj = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -405,15 +433,21 @@ def _bwd_dkv_kernel(k_ref, v_ref, mk_ref, q_ref, lse_ref, dl_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    if with_dq:
+        @pl.when((kj == 0) & (qi == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
     relevant = (qi * block_q + block_q - 1 >= kj * block_k) if causal \
         else (qi >= 0)
 
     @pl.when(relevant)
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         p, ds = _bwd_p_ds(
-            q, k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
+            q, k, v_ref[0].astype(jnp.float32),
             do, lse_ref[0, :, 0], dl_ref[0, :, 0],
             mk_ref[0, pl.ds(kj, 1), :] > 0,
             scale=scale, causal=causal, q_offset=qi * block_q,
@@ -424,60 +458,44 @@ def _bwd_dkv_kernel(k_ref, v_ref, mk_ref, q_ref, lse_ref, dl_ref, do_ref,
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
     def _write():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _write_dq():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
 
 def _flash_bwd_btd_pallas(q, k, v, mk, out, lse, dout, *, scale, causal,
                           block_q, block_k, interpret, n_heads):
-    """[bh, t, d] grads via the two Pallas passes. Same math as
-    ``_flash_bwd_btd`` (the JAX-blockwise fallback, kept for
-    ``DL4JTPU_FLASH_BWD=jax``) with the tile loops lowered to Mosaic:
-    measured ≥1.5× over the XLA backward at bf16 t=8192 (PERF.md)."""
+    """[bh, t, d] grads in Pallas. Same math as ``_flash_bwd_btd`` (the
+    JAX-blockwise fallback, kept for ``DL4JTPU_FLASH_BWD=jax``) with the
+    tile loops lowered to Mosaic. ONE call (``_bwd_kernel``) where a
+    head's float32 dq fits ``_VMEM_DQ_LIMIT``: every tile's P and dS are
+    computed once. Above it two calls: the same kernel for dk/dv, and
+    ``_bwd_dq_kernel`` with a [block_q, d] accumulator."""
     bh, t, d = q.shape
     if t % block_k:
         block_k = block_q
     nq, nk = t // block_q, t // block_k
     h_ = n_heads
+    fused = t * d * 4 <= _VMEM_DQ_LIMIT
     # delta = rowsum(dout * out): one cheap fused elementwise pass in XLA
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[..., None]                       # [bh, t, 1]
     lse3 = lse[..., None]                                     # [bh, t, 1]
     mkt = mk.astype(jnp.float32).reshape(-1, nk, block_k)
 
-    i_spec = lambda name: pl.BlockSpec((1, block_q, d),
-                                       lambda b, i, j: (b, i, 0))
-    i_col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    if causal:
-        # clamp the streamed K/V index map at the causal diagonal: the
-        # grid still visits post-diagonal steps (compute is pl.when-gated
-        # off), but a repeated block index lets Pallas elide the DMA —
-        # the backward analog of the forward kernel's loads-and-compute
-        # skip, halving streamed traffic at large t
-        def _kv_map(b, i, j):
-            return (b, jnp.minimum(
-                j, (i * block_q + block_q - 1) // block_k), 0)
-        j_spec = pl.BlockSpec((1, block_k, d), _kv_map)
-    else:
-        j_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    mk_spec = pl.BlockSpec((1, nk, block_k), lambda b, i, j: (b // h_, 0, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nk=nk),
-        grid=(bh, nq, nk),
-        in_specs=[i_spec("q"), j_spec, j_spec, mk_spec, i_col, i_col,
-                  i_spec("do")],
-        out_specs=i_spec("dq"),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, mkt, lse3, delta, dout)
-
-    # dk/dv pass: i (q-blocks) is the SEQUENTIAL (last) grid dim
+    # grid (bh, nk, nq): i (q-blocks) is the SEQUENTIAL (last) grid dim
     jk_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     if causal:
         # pre-diagonal q blocks contribute nothing to this k block —
@@ -485,29 +503,63 @@ def _flash_bwd_btd_pallas(q, k, v, mk, out, lse, dout, *, scale, causal,
         # once, then reused) so the skipped steps cost no DMA
         def _q_map(b, j, i):
             return (b, jnp.maximum(i, (j * block_k) // block_q), 0)
-
-        def _q_col_map(b, j, i):
-            return (b, jnp.maximum(i, (j * block_k) // block_q), 0)
-        iq_spec = pl.BlockSpec((1, block_q, d), _q_map)
-        iq_col = pl.BlockSpec((1, block_q, 1), _q_col_map)
     else:
-        iq_spec = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-        iq_col = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
-    mk2_spec = pl.BlockSpec((1, nk, block_k),
-                            lambda b, j, i: (b // h_, 0, 0))
+        def _q_map(b, j, i):
+            return (b, i, 0)
+    iq_spec = pl.BlockSpec((1, block_q, d), _q_map)
+    iq_col = pl.BlockSpec((1, block_q, 1), _q_map)
+    # the batch row's whole mask, whichever block axis the grid names first
+    mk_spec = pl.BlockSpec((1, nk, block_k), lambda b, *_: (b // h_, 0, 0))
+    dkv_shape = (jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+                 jax.ShapeDtypeStruct((bh, t, d), v.dtype))
+    dkv_acc = [pltpu.VMEM((block_k, d), jnp.float32),
+               pltpu.VMEM((block_k, d), jnp.float32)]
+    dq_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
+    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k, nq=nq,
+                               nk=nk, with_dq=fused)
+    in_specs = [jk_spec, jk_spec, mk_spec, iq_spec, iq_col, iq_col, iq_spec]
+    vmem = pltpu.CompilerParams(vmem_limit_bytes=_BWD_VMEM_BYTES)
+    if fused:
+        # the head's whole dq: one block, resident across the head's
+        # steps, written back once when the head changes
+        return pl.pallas_call(
+            kernel, grid=(bh, nk, nq), in_specs=in_specs,
+            out_specs=(pl.BlockSpec((1, t, d), lambda b, j, i: (b, 0, 0)),
+                       jk_spec, jk_spec),
+            out_shape=(dq_shape,) + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)] + dkv_acc,
+            compiler_params=vmem, interpret=interpret,
+        )(k, v, mkt, q, lse3, delta, dout)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq=nq),
-        grid=(bh, nk, nq),
-        in_specs=[jk_spec, jk_spec, mk2_spec, iq_spec, iq_col, iq_col,
-                  iq_spec],
-        out_specs=(jk_spec, jk_spec),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
+        kernel, grid=(bh, nk, nq), in_specs=in_specs,
+        out_specs=(jk_spec, jk_spec), out_shape=dkv_shape,
+        scratch_shapes=dkv_acc, compiler_params=vmem, interpret=interpret,
     )(k, v, mkt, q, lse3, delta, dout)
+
+    # dq pass: grid (bh, nq, nk), j (k-blocks) sequential
+    i_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    i_col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    if causal:
+        # clamp the streamed K/V index map at the causal diagonal: the
+        # grid still visits post-diagonal steps (compute is pl.when-gated
+        # off), but a repeated block index lets Pallas elide the DMA
+        def _kv_map(b, i, j):
+            return (b, jnp.minimum(
+                j, (i * block_q + block_q - 1) // block_k), 0)
+    else:
+        def _kv_map(b, i, j):
+            return (b, j, 0)
+    j_spec = pl.BlockSpec((1, block_k, d), _kv_map)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, nk=nk),
+        grid=(bh, nq, nk),
+        in_specs=[i_spec, j_spec, j_spec, mk_spec, i_col, i_col, i_spec],
+        out_specs=i_spec, out_shape=dq_shape,
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=vmem, interpret=interpret,
+    )(q, k, v, mkt, lse3, delta, dout)
     return dq, dk, dv
 
 
@@ -636,16 +688,26 @@ def _resolve_scale(scale, d):
 
 
 def _bwd_tiles(t, block_q, pallas):
-    """Backward tile choice — ONE copy of the PERF.md sweep rationale for
-    both the monolithic VJP and the ring's per-hop backward. Pallas
-    kernels take 512×1024 when t allows (fastest point that fits the
-    16MB scoped-VMEM limit; 1024² OOMs, 256² is ~2× slower); the
-    lax.scan fallback has no VMEM ceiling, so it takes square 1024
-    tiles. ``block_q`` is the FALLBACK tile for non-divisible t (the
-    caller's forward/padding granule), not an override of the tuned
-    table."""
+    """Backward tile choice — ONE table for the monolithic VJP and the
+    ring's per-hop backward, whichever backward runs: the one Pallas call,
+    the two calls above ``_VMEM_DQ_LIMIT`` and the lax.scan fallback.
+
+    1024 × 1024 where t allows, from a sweep on the v5e (my chip runs,
+    PR 36; PERF.md section 6). At the training cell's shape,
+    [32, 8192, 64] bf16 causal, the one call takes 11.90 ms at 1024²,
+    12.15 at 256 × 2048, 12.25 at 512 × 1024, 12.42 at 512 × 2048, 12.61 at
+    1024 × 2048, 12.79 at 1024 × 512, 13.2 at 2048 × 1024, 13.5 at 512²,
+    15.7 at 256 × 512, 16.1 at 128 × 1024; the two calls take 16.61 ms at
+    512 × 1024. 1024² also wins at [16, 8192, 128]
+    (5.49 against 5.65 ms at 512 × 1024; the two calls 7.67), at
+    [8, 16384, 64] (10.58, 10.96; 14.41) and, for the two calls above the
+    budget, at [8, 16384, 128] (13.25 against 13.98). A tile beyond
+    512 × 1024 needs more than the default 16 MiB of scoped VMEM, which
+    is why every backward call states ``_BWD_VMEM_BYTES``. ``block_q`` is
+    the FALLBACK tile for non-divisible t (the caller's forward/padding
+    granule), not an override of the tuned table."""
     if t % 1024 == 0:
-        return (512, 1024) if pallas else (1024, 1024)
+        return 1024, 1024
     if t % 512 == 0:
         return 512, 512
     if pallas and t % 256 == 0:

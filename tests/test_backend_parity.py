@@ -208,9 +208,11 @@ print(json.dumps({"platform": plat, "diffs": diffs, "scale": scale}))
 
 class TestFlashBackwardOnChip:
     def test_pallas_backward_matches_xla_on_chip(self, rng, tmp_path):
-        """The Pallas dq/dkv kernels vs XLA autodiff ON THE REAL CHIP at a
-        size that engages the 512x1024 tile dispatch (the CPU interpret
-        tests can't see Mosaic lowering bugs). f32, causal."""
+        """The Pallas backward (one call for dq, dk and dv) vs XLA autodiff
+        ON THE REAL CHIP at a size that engages the 1024x1024 tile
+        dispatch and two key blocks, so the dq scratch is summed into
+        across them (the CPU interpret tests can't see Mosaic lowering
+        bugs). f32, causal."""
         q = rng.normal(size=(1, 2048, 2, 64)).astype(np.float32)
         k = rng.normal(size=(1, 2048, 2, 64)).astype(np.float32)
         v = rng.normal(size=(1, 2048, 2, 64)).astype(np.float32)

@@ -155,6 +155,128 @@ class TestFlashAttention:
                                        rtol=2e-4, atol=2e-4)
 
 
+def _count_pallas_calls(jaxpr):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_pallas_calls(sub)
+    return n
+
+
+def _dense_attention_btd(q, k, v, mask_bt, causal, scale):
+    """The XLA path on one batch row's [h, t, d] heads (a row with no
+    attendable key gives 0 there as in the kernels)."""
+    to_bthd = lambda a: a.transpose(1, 0, 2)[None]
+    out = dot_product_attention(to_bthd(q), to_bthd(k), to_bthd(v),
+                                causal=causal, mask=mask_bt, scale=scale)
+    return out[0].transpose(1, 0, 2)
+
+
+class TestPallasBackward:
+    """``_flash_bwd_btd_pallas``: ONE call where a head's float32 dq fits
+    ``_VMEM_DQ_LIMIT`` (each tile's P and dS computed once, dq summed in a
+    scratch that holds the head's whole dq), two above it. t 512 in tiles
+    of at most 128 is at least 4 x 4 tiles, so steps before the diagonal
+    are skipped and their index maps clamped."""
+
+    B, H, T, D = 1, 2, 512, 32
+
+    def _case(self, rng, causal, masked):
+        bh = self.B * self.H
+        mk = lambda: jnp.asarray(rng.normal(size=(bh, self.T, self.D))
+                                 .astype(np.float32))
+        q, k, v, dout = mk(), mk(), mk(), mk()
+        mask = np.ones((self.B, self.T), np.float32)
+        if masked:                  # rows 0-4 see no key at all under causal
+            mask[:, :5] = 0.0
+            mask[:, 300:317] = 0.0
+        mask = jnp.asarray(mask)
+        scale = self.D ** -0.5
+        out, lse = fa._flash_fwd_btd(q, k, v, mask, n_heads=self.H,
+                                     scale=scale, causal=causal, block_q=128,
+                                     interpret=True)
+        if masked and causal:
+            assert np.all(np.asarray(lse)[:, :5] == fa.NEG_INF)
+        return q, k, v, dout, mask, out, lse, scale
+
+    @pytest.mark.parametrize("limit", [None, 0], ids=["fused", "two_pass"])
+    @pytest.mark.parametrize("tiles", [(128, 128), (64, 128), (128, 64)],
+                             ids=lambda bt: f"{bt[0]}x{bt[1]}")
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["nomask", "keymask"])
+    @pytest.mark.parametrize("causal", [False, True],
+                             ids=["full", "causal"])
+    def test_matches_dense_and_jax_blockwise(self, rng, monkeypatch, causal,
+                                             masked, tiles, limit):
+        if limit is not None:
+            monkeypatch.setattr(fa, "_VMEM_DQ_LIMIT", limit)
+        q, k, v, dout, mask, out, lse, scale = self._case(rng, causal, masked)
+        got = fa._flash_bwd_btd_pallas(
+            q, k, v, mask, out, lse, dout, scale=scale, causal=causal,
+            block_q=tiles[0], block_k=tiles[1], interpret=True,
+            n_heads=self.H)
+        _, vjp = jax.vjp(lambda q, k, v: _dense_attention_btd(
+            q, k, v, mask, causal, scale), q, k, v)
+        blockwise = fa._flash_bwd_btd(
+            q, k, v, jnp.repeat(mask, self.H, axis=0), out, lse, dout,
+            scale=scale, causal=causal, block_q=tiles[0], block_k=tiles[1])
+        for name, g, dense, jx in zip("qkv", got, vjp(dout), blockwise):
+            assert np.all(np.isfinite(np.asarray(g))), name
+            np.testing.assert_allclose(np.asarray(g), np.asarray(dense),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+            np.testing.assert_allclose(np.asarray(g), np.asarray(jx),
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+    def test_one_call_equals_two_calls_bit_for_bit(self, rng, monkeypatch,
+                                                   causal):
+        # the same products on the same operands, dq summed over the key
+        # blocks in the same order: float32 results are equal, not close
+        q, k, v, dout, mask, out, lse, scale = self._case(rng, causal, True)
+        run = lambda: fa._flash_bwd_btd_pallas(
+            q, k, v, mask, out, lse, dout, scale=scale, causal=causal,
+            block_q=128, block_k=64, interpret=True, n_heads=self.H)
+        fused = run()
+        monkeypatch.setattr(fa, "_VMEM_DQ_LIMIT", 0)
+        for a, b in zip(fused, run()):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_backward_is_one_pallas_call_under_the_budget(self, monkeypatch,
+                                                          dtype):
+        def calls(t, d):
+            a = jax.ShapeDtypeStruct((2, t, d), dtype)
+            f = lambda q, k, v, out, dout: fa._flash_bwd_btd_pallas(
+                q, k, v, jnp.ones((1, t)), out, jnp.zeros((2, t)), dout,
+                scale=1.0, causal=True, block_q=128, block_k=128,
+                interpret=True, n_heads=2)
+            return _count_pallas_calls(jax.make_jaxpr(f)(a, a, a, a, a).jaxpr)
+        # the budget is the float32 accumulator's size, whatever the dtype
+        assert fa._VMEM_DQ_LIMIT == 4 * 1024 * 1024
+        assert calls(16384, 64) == 1 and calls(8192, 128) == 1
+        assert calls(16384, 128) == 2 and calls(32768, 64) == 2
+
+    def test_grad_of_the_public_op_runs_one_backward_call(self, rng,
+                                                          monkeypatch):
+        q, k, v = _qkv(rng, b=1, t=256, h=1, d=32)
+        loss = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, True, None, 128, True) ** 2)
+
+        def calls():
+            jax.clear_caches()      # the route is chosen at trace time
+            return _count_pallas_calls(jax.make_jaxpr(jax.grad(
+                loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+        # one forward and one backward; the jax-blockwise route has none
+        assert calls() == 2
+        monkeypatch.setenv("DL4JTPU_FLASH_BWD", "jax")
+        assert calls() == 1
+        monkeypatch.delenv("DL4JTPU_FLASH_BWD")
+        monkeypatch.setattr(fa, "_VMEM_DQ_LIMIT", 0)
+        assert calls() == 3
+
+
 class TestTraceTimeFlagRouting:
     """VERDICT r5 item 9: ``DL4JTPU_FLASH_ATTENTION`` / ``DL4JTPU_FLASH_BWD``
     are read at TRACE time, so historically a toggle only took effect

@@ -222,6 +222,38 @@ def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
     assert re.findall(r"= \w+\[" + shape + r"\]\{[^}]*\} copy\(", text) == []
 
 
+@pytest.mark.parametrize("bh, t, d, calls", [
+    (32, 8192, 64, 1),       # the training cell: [1, 8192, 32, 64]
+    (4, 16384, 64, 1),       # a head's float32 dq at the budget, 4 MiB
+    (4, 8192, 128, 1),
+    (4, 32768, 64, 2),       # above it: the dq pass and the dk/dv pass
+])
+def test_compiled_for_v5e_the_flash_backward_fits_vmem(one_chip, bh, t, d,
+                                                       calls):
+    """Kept beside the pools' compiles because one file alone may
+    describe a topology. The one-call backward holds a head's whole dq in
+    a VMEM scratch beside a resident output block: the TPU compiler has to
+    take its tiles (``_bwd_tiles``) under the kernel's own limit, at the
+    cell's shape and at the budget's edge, and a shape above the budget
+    has to lower to the two-call backward."""
+    from deeplearning4j_tpu.ops import flash_attention as fa
+    bq, bk = fa._bwd_tiles(t, None, pallas=True)
+
+    def bwd(q, k, v, mask, out, lse, dout):
+        return fa._flash_bwd_btd_pallas(
+            q, k, v, mask, out, lse, dout, scale=d ** -0.5, causal=True,
+            block_q=bq, block_k=bk, interpret=False, n_heads=bh)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    a = arg((bh, t, d))
+    with jax.enable_x64(False):     # as on the chip; the suite runs with x64
+        text = jax.jit(bwd).lower(
+            a, a, a, arg((1, t), jnp.float32), a, arg((bh, t), jnp.float32),
+            a).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+
 # ---------------------------------------------------------------------------
 # 3. equal, bit for bit, to the [num_pages, page_size, h, d] formulation
 # ---------------------------------------------------------------------------
