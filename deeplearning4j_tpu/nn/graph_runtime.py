@@ -60,22 +60,24 @@ class ComputationGraph(TrainableNetwork):
     # ------------------------------------------------------------------
 
     def init(self, key: Optional[jax.Array] = None) -> "ComputationGraph":
-        if key is None:
-            key = _rng.key(self.training.seed)
-        params, state = {}, {}
-        for name in self.topo_order:
-            v = self.conf.vertices[name]
-            vk = _rng.fold_name(key, name)
-            params[name] = v.init_params(vk, self.policy)
-            state[name] = v.init_state(self.policy)
-        self.params = params
-        self.state = state
-        self._persistent_keys = {
-            name: tuple(self.conf.vertices[name].init_state(self.policy).keys())
-            for name in self.topo_order}
-        self._updater = _updaters.make_updater(
-            self.training, self._lr_multipliers())
-        self.updater_state = self._updater.init(params)
+        with _xla.init_region(self):
+            if key is None:
+                key = _rng.key(self.training.seed)
+            params, state = {}, {}
+            for name in self.topo_order:
+                v = self.conf.vertices[name]
+                vk = _rng.fold_name(key, name)
+                params[name] = v.init_params(vk, self.policy)
+                state[name] = v.init_state(self.policy)
+            self.params = params
+            self.state = state
+            self._persistent_keys = {
+                name: tuple(
+                    self.conf.vertices[name].init_state(self.policy).keys())
+                for name in self.topo_order}
+            self._updater = _updaters.make_updater(
+                self.training, self._lr_multipliers())
+            self.updater_state = self._updater.init(params)
         return self
 
     def _lr_multipliers(self) -> Pytree:

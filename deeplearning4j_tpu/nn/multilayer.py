@@ -62,22 +62,25 @@ class MultiLayerNetwork(TrainableNetwork):
     # ------------------------------------------------------------------
 
     def init(self, key: Optional[jax.Array] = None) -> "MultiLayerNetwork":
-        if key is None:
-            key = _rng.key(self.training.seed)
-        params, state = {}, {}
-        for i, layer in enumerate(self.layers):
-            lk = _rng.fold_name(key, _layer_key(i))
-            params[_layer_key(i)] = layer.init_params(lk, self.policy)
-            state[_layer_key(i)] = layer.init_state(self.policy)
-        self.params = params
-        self.state = state
-        # persistent-state keys per layer (e.g. BN running stats), cached so
-        # the hot fit loop never re-calls init_state just to read key names
-        self._persistent_keys = [
-            tuple(layer.init_state(self.policy).keys()) for layer in self.layers]
-        self._updater = _updaters.make_updater(
-            self.training, self._lr_multipliers())
-        self.updater_state = self._updater.init(params)
+        with _xla.init_region(self):
+            if key is None:
+                key = _rng.key(self.training.seed)
+            params, state = {}, {}
+            for i, layer in enumerate(self.layers):
+                lk = _rng.fold_name(key, _layer_key(i))
+                params[_layer_key(i)] = layer.init_params(lk, self.policy)
+                state[_layer_key(i)] = layer.init_state(self.policy)
+            self.params = params
+            self.state = state
+            # persistent-state keys per layer (e.g. BN running stats), cached
+            # so the hot fit loop never re-calls init_state just to read key
+            # names
+            self._persistent_keys = [
+                tuple(layer.init_state(self.policy).keys())
+                for layer in self.layers]
+            self._updater = _updaters.make_updater(
+                self.training, self._lr_multipliers())
+            self.updater_state = self._updater.init(params)
         return self
 
     def _lr_multipliers(self) -> Pytree:

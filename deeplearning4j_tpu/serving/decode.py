@@ -313,19 +313,9 @@ class PagedDecodeEngine:
                        + ("positions",) * bool(self.position_layers))
         self._extra_paged = self._extra + ("fed",) * bool(
             self._counting and prefix_cache)
-        dims, dtype = self._arena_dims(net)
-        self.arena = PagedKVArena(dims, num_pages=int(num_pages),
-                                  page_size=self.page_size, dtype=dtype,
-                                  registry=self.registry,
-                                  kv_dtype=kv_dtype,
-                                  prefix_cache=bool(prefix_cache))
         self.vocab = self._embed_vocab(net)
-        # speculative decoding: the draft model's K/V lives in a
-        # pools-only SHADOW arena indexed by the same page tables (one
-        # admission/eviction decision covers both models)
         self.draft_net = draft_net
         self.draft_k = int(draft_k)
-        self.draft_arena = None
         if draft_net is not None:
             if int(block_len) != 1:
                 raise ValueError(
@@ -346,10 +336,9 @@ class PagedDecodeEngine:
                     f"draft vocab {self._embed_vocab(draft_net)} != "
                     f"target vocab {self.vocab} — accept/reject compares "
                     "distributions over one vocabulary")
-            ddims, ddtype = self._arena_dims(draft_net)
-            self.draft_arena = PagedKVArena(
-                ddims, num_pages=int(num_pages), page_size=self.page_size,
-                dtype=ddtype, with_allocator=False, kv_dtype=kv_dtype)
+        with _xla.startup_region("startup.engine_build", self.registry):
+            self._build_arenas(net, int(num_pages), bool(prefix_cache),
+                               kv_dtype)
         # per-lane host state
         s, p = self.lanes, self.pages_per_seq
         self._tables = np.full((s, p), self.arena.sentinel, np.int32)
@@ -455,9 +444,32 @@ class PagedDecodeEngine:
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
-        self._precompile: Optional[list] = None   # warm-up's first pass
+        self._precompile: Optional[dict] = None   # warm-up's first pass
         # why the last acquire_lane() refused: "lanes" | "pages" | None
         self.refused_by: Optional[str] = None
+
+    def _build_arenas(self, net, num_pages: int, prefix_cache: bool,
+                      kv_dtype) -> None:
+        """Allocate what lives on the device beside the weights, and wait
+        for it: the arena's pools and per-lane state and, for speculative
+        decoding, the draft model's K/V in a pools-only SHADOW arena
+        indexed by the same page tables (one admission/eviction decision
+        covers both models)."""
+        dims, dtype = self._arena_dims(net)
+        self.arena = PagedKVArena(dims, num_pages=num_pages,
+                                  page_size=self.page_size, dtype=dtype,
+                                  registry=self.registry,
+                                  kv_dtype=kv_dtype,
+                                  prefix_cache=prefix_cache)
+        self.draft_arena = None
+        if self.draft_net is not None:
+            ddims, ddtype = self._arena_dims(self.draft_net)
+            self.draft_arena = PagedKVArena(
+                ddims, num_pages=num_pages, page_size=self.page_size,
+                dtype=ddtype, with_allocator=False, kv_dtype=kv_dtype)
+        jax.block_until_ready([
+            (a.k_pools, a.v_pools)
+            for a in (self.arena, self.draft_arena) if a is not None])
 
     # -- construction-time validation ---------------------------------
 
@@ -884,7 +896,7 @@ class PagedDecodeEngine:
             wrap=lambda f: _xla.retrace_guard(f, name, self.registry),
             donate_argnums=(1, 2))
         if self._precompile is not None:
-            return self._record_program(fn, arena, params, args, sync)
+            return self._record_program(name, fn, arena, params, args, sync)
         hist = None if self._warming else self._m_phase
         wall, nbytes = 0.0, 0
         try:
@@ -917,16 +929,15 @@ class PagedDecodeEngine:
         self._note_dispatch(wall, kind, nbytes, sync=sync)
         return outputs
 
-    def _record_program(self, fn, arena, params, args: tuple,
+    def _record_program(self, name: str, fn, arena, params, args: tuple,
                         sync: bool) -> list:
-        """Warm-up's first pass (:meth:`warmup`): note the program and
-        what it would be called with, dispatch nothing, and hand back
-        zeros in the shapes its outputs will have, so that the ladder's
-        own code walks on as if it had run."""
-        jitted = getattr(fn, "__wrapped__", fn)     # under the retrace guard
+        """Warm-up's first pass (:meth:`warmup`): note the program under
+        its ladder key with what it would be called with, dispatch
+        nothing, and hand back zeros in the shapes its outputs will
+        have, so that the ladder's own code walks on as if it had run."""
         call = (params, arena.k_pools, arena.v_pools, *args)
-        self._precompile.append((jitted, call))
-        *outputs, _, _ = jitted.eval_shape(*call)
+        self._precompile[name] = (fn, call)
+        *outputs, _, _ = fn.__wrapped__.eval_shape(*call)   # the jit itself
         zeros = np.zeros if sync else jax.numpy.zeros
         return [zeros(o.shape, o.dtype) for o in outputs]
 
@@ -1114,20 +1125,34 @@ class PagedDecodeEngine:
         vocabulary, and a ladder of twelve took 200 s one after another).
         The second pass is the ladder as it always ran: each program's
         first call finds its executable compiled (an ahead-of-time compile
-        and the call share JAX's compilation cache) and runs once."""
+        and the call share JAX's compilation cache) and runs once.
+
+        Timed as the ``warmup`` phase of ``startup_phase_seconds`` and its
+        three parts, ``warmup.plan``, ``warmup.compile`` (the pool's wall)
+        and ``warmup.run``; each task of the pool is the program's sample
+        in ``xla_compile_seconds{fn=<ladder key>}``
+        (``retrace_guard``'s ``precompile``)."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        def phase(name):
+            return _xla.startup_region(name, self.registry)
+
         self._warming = True
         try:
-            self._precompile = []
-            try:
-                self._warmup_ladder()
-            finally:
-                programs, self._precompile = self._precompile, None
-            if len(programs) > 1:
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(min(len(programs), 12)) as pool:
+            with phase("startup.warmup"):
+                self._precompile = {}
+                try:
+                    with phase("warmup.plan"):
+                        self._warmup_ladder()
+                finally:
+                    programs, self._precompile = self._precompile, None
+                with phase("warmup.compile") as compiling, \
+                        ThreadPoolExecutor(min(len(programs), 12)) as pool:
                     list(pool.map(
-                        lambda p: p[0].lower(*p[1]).compile(), programs))
-            self._warmup_ladder()
+                        lambda p: p[0].precompile(p[1], compiling.span),
+                        programs.values()))
+                with phase("warmup.run"):
+                    self._warmup_ladder()
         finally:
             self._warming = False
 

@@ -197,13 +197,16 @@ class InferenceServer:
                 # served engines (production traffic repeats system
                 # prompts); pass prefix_cache=False to opt out
                 cfg.setdefault("prefix_cache", True)
-                engine = PagedDecodeEngine(model, registry=self.registry,
-                                           **cfg)
-                # compile the whole bucket ladder before the loop starts:
-                # server START pays it, not the first live requests'
-                # SLO deadlines
-                if not warmup_background:
-                    engine.warmup()
+                # the root of a start-up's spans (the engine's phases and
+                # every compilation join the trace open on their thread)
+                with _tracing.region("startup", tracer=tracer) as startup:
+                    engine = PagedDecodeEngine(
+                        model, registry=self.registry, **cfg)
+                    # compile the whole bucket ladder before the loop
+                    # starts: server START pays it, not the first live
+                    # requests' SLO deadlines
+                    if not warmup_background:
+                        engine.warmup()
                 self.decode = DecodeScheduler(
                     engine, clock=clock, registry=self.registry,
                     tracer=tracer, **sched_kw)
@@ -217,7 +220,9 @@ class InferenceServer:
 
                     def _warm(sched=self.decode, eng=engine):
                         try:
-                            with sched._dispatch_lock:
+                            with sched._dispatch_lock, _tracing.region(
+                                    "startup.background",
+                                    **_tracing.joining(startup.span)):
                                 eng.warmup()
                         finally:
                             self._warming = False

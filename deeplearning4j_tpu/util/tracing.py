@@ -465,6 +465,16 @@ def active_span() -> Optional[Span]:
     return None
 
 
+def joining(parent: Optional[Span] = None) -> Dict[str, Any]:
+    """``tracer=`` and ``parent=`` for a :class:`region` that joins the
+    trace of ``parent`` (a span made on another thread names its cause so)
+    or, without one, the trace open on this thread; nothing when there is
+    neither, and the region then makes no span. For code below the seam
+    that owns the tracer: start-up phases, compilations."""
+    span = parent if parent is not None else active_span()
+    return {} if span is None else {"tracer": span._tracer, "parent": span}
+
+
 # The process-default tracer: components take ``tracer=None`` and fall
 # back to it, so one export shows the whole process.
 TRACER = Tracer()

@@ -20,7 +20,7 @@ import contextlib
 import functools
 import logging
 import os
-import time
+import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
 logger = logging.getLogger("deeplearning4j_tpu")
@@ -203,8 +203,46 @@ def _reg(registry=None):
 def compile_seconds_histogram(registry=None):
     return _reg(registry).histogram(
         "xla_compile_seconds",
-        "Wall time of each fresh compilation (trace + XLA compile) per "
-        "guarded jitted function", ("fn",), buckets=_COMPILE_BUCKETS)
+        "Wall time of each fresh compilation per guarded jitted function: "
+        "the ahead-of-time lower().compile() of a program compiled before "
+        "its first call (the decode ladder), else the compiling first call "
+        "(trace + XLA compile + the launch)", ("fn",),
+        buckets=_COMPILE_BUCKETS)
+
+
+def compile_stage_counter():
+    return _reg().counter(
+        "xla_compile_stage_seconds_total",
+        "Seconds JAX reports for each stage of every compilation of the "
+        "process, summed over threads (work, not wall): trace and lower "
+        "run under the interpreter's lock, backend is the XLA compile or, "
+        "on a persistent-cache hit, the look-up, whose own seconds are "
+        "cache_retrieval", ("stage",))
+
+
+def compile_cache_counter():
+    return _reg().counter(
+        "xla_compile_cache_total",
+        "Compilations that asked the persistent cache (request) and those "
+        "it served (hit); a miss is a request that was no hit",
+        ("result",))
+
+
+def slowest_compile_gauge():
+    return _reg().gauge(
+        "xla_compile_slowest_seconds",
+        "The largest single sample of xla_compile_seconds so far, whatever "
+        "registry took it: the floor of a concurrent warm-up's wall")
+
+
+def startup_phase_histogram(registry=None):
+    return _reg(registry).histogram(
+        "startup_phase_seconds",
+        "Wall time of each phase of start-up: pre_init (process start to "
+        "the first init()), init, engine_build, warmup and its parts "
+        "warmup.plan, warmup.compile, warmup.run, and cost_analysis (the "
+        "second lowering behind compiled_flops / compiled_bytes)",
+        ("phase",), buckets=_COMPILE_BUCKETS)
 
 
 def compiled_flops_gauge(registry=None):
@@ -259,6 +297,102 @@ def compiled_costs(fn: Callable, *args, **kwargs) -> Optional[Dict[str, float]]:
 
 
 # ----------------------------------------------------------------------
+# start-up, timed from inside
+# ----------------------------------------------------------------------
+
+_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+# JAX's own cache_misses event fires only when an entry is WRITTEN, under
+# size and time thresholds: a miss is a request that was no hit
+_CACHE_RESULT_OF_EVENT = {
+    "/jax/compilation_cache/compile_requests_use_cache": "request",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_lock = threading.Lock()
+_done_once = set()
+
+
+def _first_time(what: str) -> bool:
+    with _lock:
+        first = what not in _done_once
+        _done_once.add(what)
+    return first
+
+
+def listen_to_compiles() -> None:
+    """Tell every compilation of the process apart, by stage and by what
+    the persistent cache said: ``jax.monitoring`` listeners, registered
+    once a process (a second call adds nothing), that keep
+    ``xla_compile_stage_seconds_total{stage}`` and
+    ``xla_compile_cache_total{result}`` in the process registry. Called
+    where a guarded jit site is built and where a net is initialised, so
+    the counts start before the first program compiles."""
+    if not _first_time("listen"):
+        return
+    import jax
+    stages, cache = compile_stage_counter(), compile_cache_counter()
+
+    def on_duration(event, duration_secs, **_):
+        stage = _STAGE_OF_EVENT.get(event)
+        if stage is not None:
+            stages.inc(duration_secs, stage=stage)
+
+    def on_event(event, **_):
+        result = _CACHE_RESULT_OF_EVENT.get(event)
+        if result is not None:
+            cache.inc(result=result)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since the kernel started this process (its record in
+    ``/proc/self/stat``), or None where there is no ``/proc``."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+    except OSError:
+        return None
+    return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+
+
+def startup_region(name: str, registry=None, parent=None):
+    """The :class:`~.tracing.region` of one start-up phase: observed into
+    ``startup_phase_seconds{phase}`` (the phase is the region's name
+    without a leading ``startup.``), a host span on the profiler's clock,
+    and a span of whatever trace is open on this thread (or of
+    ``parent``'s, for a phase on a worker thread)."""
+    from . import tracing as _tracing
+    return _tracing.region(name, startup_phase_histogram(registry),
+                           **_tracing.joining(parent),
+                           phase=name.removeprefix("startup."))
+
+
+@contextlib.contextmanager
+def init_region(net):
+    """What both runtimes' ``init()`` run inside: the ``startup.init``
+    phase, which ends once the parameters and the updater's state are on
+    the device. The first one of the process also stamps ``pre_init``:
+    interpreter, imports and configuration, from the process's start."""
+    import jax
+    listen_to_compiles()
+    if _first_time("pre_init"):
+        age = process_age_s()
+        if age is not None:
+            startup_phase_histogram().observe(age, phase="pre_init")
+    with startup_region("startup.init"):
+        yield
+        jax.block_until_ready((net.params, net.state, net.updater_state))
+
+
+# ----------------------------------------------------------------------
 # retrace guard
 # ----------------------------------------------------------------------
 
@@ -293,11 +427,16 @@ def retrace_guard(fn: Callable, name: str, registry=None) -> Callable:
     A fresh signature additionally records:
 
     - ``xla_compile_seconds{fn}`` — wall time of the compiling call
-      (trace + XLA compile; dispatch is async, so execution is excluded);
+      (trace + XLA compile; dispatch is async, so execution is excluded),
+      a ``compile.program`` region. A program compiled ahead of its first
+      call (``wrapped.precompile(args)``, the decode ladder's warm-up)
+      observes its ``lower().compile()`` there instead, and its first
+      call, a look-up and a launch, observes nothing;
     - ``compiled_flops{fn}`` / ``compiled_bytes{fn}`` — the lowered
       program's HLO cost analysis (:func:`compiled_costs`), the MEASURED
       counterpart of the analytic formulas in bench.py — plus the latest
-      analysis on ``wrapped.compiled_costs``;
+      analysis on ``wrapped.compiled_costs``; the second lowering it
+      takes is the ``cost_analysis`` phase of ``startup_phase_seconds``;
     - a ``compile`` flight-recorder event (retraces after the first carry
       the differing signature, so a post-mortem dump names the churning
       input).
@@ -308,12 +447,43 @@ def retrace_guard(fn: Callable, name: str, registry=None) -> Callable:
     """
     from . import flightrecorder as _flight
     from . import ingest as _ingest
+    from . import tracing as _tracing
+    listen_to_compiles()
     counter = _ingest.retrace_counter(registry)
     compile_hist = compile_seconds_histogram(registry)
     flops_gauge = compiled_flops_gauge(registry)
     bytes_gauge = compiled_bytes_gauge(registry)
+    slowest = slowest_compile_gauge()
     seen: Dict[Tuple, int] = {}
     last: list = []
+    ahead: Dict[Tuple, float] = {}    # signature -> its precompile's seconds
+
+    def compile_region(parent=None):
+        """The region a compilation of this program is timed by. Entered
+        with ``with``, never through a helper that makes the call: a
+        Python frame between the guard and the jitted call is part of
+        what is lowered (the locations of the program's operations, which
+        a Mosaic kernel's body keeps), and two of them cost the train
+        step 0.9 s of lowering and a cache entry of its own (PR 37)."""
+        return _tracing.region("compile.program", compile_hist,
+                               attributes={"fn": name},
+                               **_tracing.joining(parent), fn=name)
+
+    def compiled_in(seconds: float) -> float:
+        with _lock:
+            if seconds > slowest.value():
+                slowest.set(seconds)
+        return seconds
+
+    def precompile(args: tuple, parent=None) -> None:
+        """Lower and compile the program for ``args`` now, on the calling
+        thread (XLA compiles outside the interpreter's lock, so a pool of
+        these overlaps): THIS is the sample ``xla_compile_seconds{fn}``
+        gets for the signature. ``parent`` is the span that caused it,
+        for a task on a worker thread."""
+        with compile_region(parent) as r:
+            fn.lower(*args).compile()
+        ahead[_abstract_signature(args, {})] = compiled_in(r.seconds)
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
@@ -329,17 +499,22 @@ def retrace_guard(fn: Callable, name: str, registry=None) -> Callable:
                 last[0][1] if last else "?")
         prev = last[0][1] if last else None
         last[:] = [key]
-        # the compiling call: trace + compile happen synchronously inside
-        # it, execution is dispatched async — so the wall time here IS
-        # the compile cost the caller paid
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        compile_hist.observe(dt, fn=name)
+        if key in ahead:
+            # compiled ahead of time: this call finds the executable
+            out, dt = fn(*args, **kwargs), ahead[key]
+        else:
+            # the compiling call: trace + compile happen synchronously
+            # inside it, execution is dispatched async — so the wall time
+            # here IS the compile cost the caller paid
+            with compile_region() as r:
+                out = fn(*args, **kwargs)
+            dt = compiled_in(r.seconds)
         event = {"fn": name, "signature_idx": idx,
                  "compile_seconds": round(dt, 4)}
-        costs = (compiled_costs(fn, *args, **kwargs)
-                 if cost_analysis_enabled() else None)
+        costs = None
+        if cost_analysis_enabled():
+            with startup_region("startup.cost_analysis", registry):
+                costs = compiled_costs(fn, *args, **kwargs)
         if costs is not None:
             wrapped.compiled_costs = costs
             if "flops" in costs:
@@ -355,4 +530,5 @@ def retrace_guard(fn: Callable, name: str, registry=None) -> Callable:
 
     wrapped.signatures_seen = seen
     wrapped.compiled_costs = None
+    wrapped.precompile = precompile
     return wrapped
