@@ -45,6 +45,23 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
   ``[S, keys, h*d]`` → ``[S, h, d, keys]``); a reshape of the pool
   itself, even back and forth as a "view", brings the copies back
   twofold. ``tests/test_pool_layout.py`` holds every program to it.
+- That rule is about the jaxpr. THE COMPILED TEXT broke it until PR 38:
+  with a bfloat16 query over a float32 pool both products of the read
+  are float32 matrix products at default precision, which the MXU feeds
+  with bfloat16 operands; the TPU compiler's bfloat16 propagation saw
+  that the chunk is needed only to bfloat16, walked back through the
+  select, the reshape and the gather (which pass values through
+  unchanged) and put the rounding on the whole pool, before the read
+  loop: a pool-sized ``convert`` a layer in every such program (a sixth
+  of the latent cell's device time; PERF.md, PR 38). So the read rounds
+  the chunk ITSELF, right after the gather
+  (``jax.lax.reduce_precision`` to the query's exponent and mantissa
+  bits, the dtype unchanged), exactly where the products would round
+  (:func:`read_rounds_chunk`); the products see the very numbers they
+  saw, and the compiler has nothing left to push onto the pool. An
+  ``optimization_barrier`` on the chunk, a bitcast of the chunk to
+  ``uint32`` and back, and a gather from a ``uint32`` view of the pool
+  do NOT hold it (PR 35, PR 38).
 - page tables are ``[lanes, pages_per_seq]`` int32 of PHYSICAL page ids;
   unallocated entries hold the SENTINEL ``num_pages`` (one past the pool)
   — gathers fill zeros there, scatters drop.
@@ -73,7 +90,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_gather", "paged_read_attention",
-           "read_chunk_pages", "read_trip_count", "READ_CHUNK_TOKENS"]
+           "read_chunk_pages", "read_trip_count", "read_rounds_chunk",
+           "READ_CHUNK_TOKENS"]
 
 # tokens of K/V one trip of the read loop gathers (a whole number of
 # pages; the whole table where the window is shorter)
@@ -157,8 +175,27 @@ def _paged_write_q8(pool, new, page_table, write_slots):
     return (q, new_scales)
 
 
+def read_rounds_chunk(q_dtype, pool_dtype, rows: int) -> bool:
+    """Whether the read's products round their K/V operand to the
+    query's dtype, so that :func:`paged_read_attention` rounds the chunk
+    it gathered: the query is a float narrower than the pool's, and the
+    products are matrix products, ``rows`` > 1 query rows a K/V head
+    (``t_new`` times the grouped query heads). With one row the TPU
+    compiler forms both products on the vector unit in float32 and uses
+    K/V unrounded, and so does the read. ``pool_dtype`` is None for an
+    int8 pool (dequantized to float32 inside the gather, never rounded).
+    The engine's ``decode_kv_chunk_rounded_tokens_total`` asks here too.
+    """
+    if pool_dtype is None or rows <= 1:
+        return False
+    q_dtype, pool_dtype = jnp.dtype(q_dtype), jnp.dtype(pool_dtype)
+    return (jnp.issubdtype(q_dtype, jnp.floating)
+            and jnp.issubdtype(pool_dtype, jnp.floating)
+            and q_dtype.itemsize < pool_dtype.itemsize)
+
+
 @jax.named_scope("attn.paged_gather")
-def paged_gather(pool, page_table, heads):
+def paged_gather(pool, page_table, heads, round_to=None):
     """Gather pages into a contiguous view with the KEYS ON THE MINOR
     AXIS: the read loop's gather, one chunk of each lane's table at a
     time.
@@ -174,9 +211,19 @@ def paged_gather(pool, page_table, heads):
     decode block's device time (PERF.md, PR 32). Sentinel entries read
     as zeros (masked by the causal window in
     :func:`paged_read_attention` anyway).
+
+    ``round_to`` (a float dtype; an array pool only) rounds the gathered
+    pages to that dtype's exponent and mantissa bits and keeps the
+    pool's dtype: the rounding the read's products would do, done on the
+    chunk before the reshape and the turn, so that the compiler does not
+    do it on the pool (module docstring).
     """
     codes = pool[0] if isinstance(pool, tuple) else pool
     g = jnp.take(codes, page_table, axis=0, mode="fill", fill_value=0)
+    if round_to is not None:
+        to = jnp.finfo(round_to)
+        g = jax.lax.reduce_precision(g, exponent_bits=to.nexp,
+                                     mantissa_bits=to.nmant)
     s, p, page_size, f = g.shape
     g = jnp.swapaxes(g.reshape(s, p * page_size, f), 1, 2)
     g = g.reshape(s, heads, f // heads, p, page_size)
@@ -241,12 +288,22 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
     (``nn/conf/mla.MLAttentionLayer``): one pool holds a token's key row
     and the value is that row's first ``v_width`` columns, so a chunk of
     pages is gathered once and sliced; returns ``[S, t_new, h, v_width]``.
+
+    Where the products round K/V to ``q``'s dtype
+    (:func:`read_rounds_chunk`: a bfloat16 query over float32 pools, more
+    than one query row a K/V head), each gathered chunk is rounded so,
+    in the pools' dtype, before it is turned: the same numbers reach the
+    products, and no rounding is left for the compiler to place on a
+    whole pool.
     """
     codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
     num_pages, page_size = codes.shape[0], codes.shape[1]
     kv_dtype = jnp.float32 if isinstance(k_pool, tuple) else codes.dtype
     out_dtype = jnp.result_type(q.dtype, kv_dtype)
     s, t_new, h, d = q.shape
+    round_to = q.dtype if read_rounds_chunk(
+        q.dtype, None if isinstance(k_pool, tuple) else codes.dtype,
+        t_new) else None
     d_v = d if v_width is None else v_width
     pages_per_seq = page_table.shape[1]
     cp = read_chunk_pages(page_size, pages_per_seq)
@@ -266,9 +323,9 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
         m, l, acc = carry
         table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
                                                axis=1)
-        k_c = paged_gather(k_pool, table_c, h)                # [S, h, d, chunk]
-        v_c = (paged_gather(v_pool, table_c, h) if v_width is None
-               else k_c[:, :, :v_width])
+        k_c = paged_gather(k_pool, table_c, h, round_to)      # [S, h, d, chunk]
+        v_c = (paged_gather(v_pool, table_c, h, round_to)
+               if v_width is None else k_c[:, :, :v_width])
         with jax.named_scope("attn.paged_softmax"):
             logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
             key_idx = c * chunk + jnp.arange(chunk)

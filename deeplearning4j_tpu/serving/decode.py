@@ -397,6 +397,23 @@ class PagedDecodeEngine:
             "Key positions of the same dispatches' whole windows: lanes "
             "of the bucket x window, for every step of a block",
             ("kind",))
+        self._m_kv_rounded = self.registry.counter(
+            "decode_kv_chunk_rounded_tokens_total",
+            "The part of decode_kv_read_tokens_total read by dispatches "
+            "whose paged read rounds each gathered chunk to the query's "
+            "dtype (ops.paged_attention.read_rounds_chunk: a query "
+            "narrower than the pools, more than one query row a K/V "
+            "head)", ("kind",))
+        # query heads a K/V head of each attention vertex's paged read (a
+        # grouped-query vertex reads its group's heads, a latent one all
+        # its heads, as further rows of one K/V head), target and draft
+        self._query_groups = {
+            draft: {layer.n_heads if isinstance(layer, MLAttentionLayer)
+                    else layer.n_heads // layer.kv_heads
+                    for layer in map(n._vertex_layer,
+                                     _transformer.attention_vertices(n))}
+            for draft, n in ((False, net), (True, draft_net))
+            if n is not None}
         token_bytes = float(self.arena.token_nbytes())
         self.registry.gauge(
             "decode_kv_bytes_per_token",
@@ -975,12 +992,24 @@ class PagedDecodeEngine:
             self._m_tick.observe(wall, component="dispatch")
             self._m_d2h.inc(nbytes, kind=kind)
 
+    def _read_rounds_chunk(self, t_new: int, draft: bool) -> bool:
+        """Whether the paged reads of a dispatch of ``t_new`` new tokens
+        a lane round their chunks: the read's own helper, asked with each
+        attention vertex's query rows a K/V head."""
+        net, arena = ((self.draft_net, self.draft_arena) if draft
+                      else (self.net, self.arena))
+        pool_dtype = None if arena.kv_dtype == "int8" else arena.dtype
+        return all(_paged.read_rounds_chunk(
+            net.policy.compute_dtype, pool_dtype, t_new * g)
+            for g in self._query_groups[draft])
+
     def _note_kv_read(self, kind: str, rel: np.ndarray, t_new: int,
-                      steps: int = 1) -> None:
+                      steps: int = 1, draft: bool = False) -> None:
         """Account how far the paged read of one dispatch went: its
         trip count (the very helper the program's loop bound comes from,
         on the same ``rel``) for each of the block's ``steps``, beside
-        the whole windows the lanes hold."""
+        the whole windows the lanes hold, and whether the read rounded
+        the chunks it gathered (``draft``: the draft net's read)."""
         if self._warming:
             return
         chunk = self.page_size * _paged.read_chunk_pages(
@@ -991,6 +1020,8 @@ class PagedDecodeEngine:
             for i in range(steps))
         self._m_kv_read.inc(len(rel) * visited, kind=kind)
         self._m_kv_window.inc(len(rel) * self.window * steps, kind=kind)
+        if self._read_rounds_chunk(t_new, draft):
+            self._m_kv_rounded.inc(len(rel) * visited, kind=kind)
 
     # -- fused multi-token block --------------------------------------
 
@@ -1052,7 +1083,7 @@ class PagedDecodeEngine:
                        (ids, tables, write_slots, rel_pos,
                         np.asarray(out_rows, np.int32)),
                        kind="draft_prefill", sync=False)
-        self._note_kv_read("draft_prefill", rel_pos, t)
+        self._note_kv_read("draft_prefill", rel_pos, t, draft=True)
 
     def run_draft(self, last: np.ndarray, tables: np.ndarray,
                   rel: np.ndarray, active: np.ndarray,
@@ -1076,7 +1107,7 @@ class PagedDecodeEngine:
             name, step, self.draft_arena, self.draft_net.params,
             (last, tables, rel, active, write_budget, temps, top_k,
              top_p, uniforms), kind="draft", sync=False)
-        self._note_kv_read("draft", rel, 1, steps=k1)
+        self._note_kv_read("draft", rel, 1, steps=k1, draft=True)
         return d_toks, d_dists
 
     def run_verify(self, last: np.ndarray, tables: np.ndarray,

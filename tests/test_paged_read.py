@@ -2,7 +2,9 @@
 chunked, bounded read against the full-window gather + masked softmax it
 replaced, the trip count on host and device, the inner ``while`` in the
 lowered fused block, and the two counters that say how much of the window
-a dispatch visits (``paged_read_window_share`` of the benchmark)."""
+a dispatch visits (``paged_read_window_share`` of the benchmark), and
+the rounding of the gathered chunk where the products would round K/V
+(``paged_read_chunk_rounded_share``)."""
 
 import json
 import os
@@ -12,11 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.models import nemotron_h_lm
 from deeplearning4j_tpu.models.transformer import transformer_lm
 from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
 from deeplearning4j_tpu.ops.paged_attention import (
     READ_CHUNK_TOKENS, paged_gather, paged_read_attention, paged_write,
-    read_chunk_pages, read_trip_count)
+    read_chunk_pages, read_rounds_chunk, read_trip_count)
 from deeplearning4j_tpu.serving.decode import (DecodeScheduler,
                                                PagedDecodeEngine)
 
@@ -194,7 +197,101 @@ def test_trip_count_same_on_host_and_device(rel, t_new, page_size,
 
 
 # ---------------------------------------------------------------------------
-# the engine: the loop in the lowered programs, and the two counters
+# the chunk rounded where the products would round it (PR 38)
+# ---------------------------------------------------------------------------
+
+# form -> (query heads a K/V head, value width of a latent row or None)
+ROUNDED_FORMS = {"plain": (1, None), "grouped": (4, None),
+                 "latent": (4, 8)}
+
+
+def _rounding_case(form, rng, *, positions=5, q_dtype=jnp.bfloat16,
+                   int8=False):
+    """Arguments of a read of ``positions`` new tokens a lane in one of
+    the three forms its callers use, over float32 (or int8) pools."""
+    group, v_width = ROUNDED_FORMS[form]
+    page_size, pages_per_seq = 16, 20
+    rel = np.array([0, 150, page_size * pages_per_seq - positions], np.int32)
+    k, v, table = _arena(rng, 3, page_size, pages_per_seq, rel + positions,
+                         int8=int8)
+    if v_width is not None:     # one key head as wide as the row, no V pool
+        q_shape, v = (3, positions * group, 1, H * D), None
+    else:
+        q_shape = (3, positions * group, H, D)
+    q = jnp.asarray(rng.standard_normal(q_shape), q_dtype)
+    return (q, k, v, table, jnp.asarray(rel), jnp.asarray(0.3, q_dtype)), {
+        "group": group, "v_width": v_width}
+
+
+def primitives(jaxpr):
+    """The name of every primitive of a jaxpr and of the jaxprs inside."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield from primitives(x)
+
+
+@pytest.mark.parametrize("form", sorted(ROUNDED_FORMS))
+def test_rounded_read_equals_the_read_over_a_rounded_pool(form):
+    """A bfloat16 query, float32 pools, more than one row a K/V head:
+    the read gives, bit for bit, what it gives over the pools rounded to
+    bfloat16 as a whole (what the TPU compiler made of the pools before
+    PR 38), and something else than the unrounded products the CPU
+    would form."""
+    args, kw = _rounding_case(form, np.random.default_rng(len(form)))
+    q, k, v, *rest = args
+
+    def rounded(pool):
+        return None if pool is None else \
+            pool.astype(jnp.bfloat16).astype(jnp.float32)
+
+    assert read_rounds_chunk(q.dtype, k.dtype, q.shape[1])
+    got = paged_read_attention(*args, **kw)
+    want = paged_read_attention(q, rounded(k), rounded(v), *rest, **kw)
+    assert got.dtype == want.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert "reduce_precision" in set(primitives(jax.make_jaxpr(
+        lambda *a: paged_read_attention(*a, **kw))(*args).jaxpr))
+    # the pools themselves are not bfloat16 numbers: rounding did something
+    assert not np.array_equal(np.asarray(k), np.asarray(rounded(k)))
+
+
+@pytest.mark.parametrize("case", ["one_row", "f32_query", "int8_pools"])
+def test_the_read_does_not_round_where_the_products_do_not(case):
+    """One row a K/V head (the vector unit's float32 products use K/V
+    unrounded), a float32 query, int8 pools (dequantized in the gather):
+    the read's jaxpr is what it was, no ``reduce_precision`` in it."""
+    rng = np.random.default_rng(len(case))
+    args, kw = _rounding_case(
+        "plain", rng, positions=1 if case == "one_row" else 5,
+        q_dtype=jnp.float32 if case == "f32_query" else jnp.bfloat16,
+        int8=case == "int8_pools")
+    q, k = args[0], args[1]
+    assert not read_rounds_chunk(
+        q.dtype, None if isinstance(k, tuple) else k.dtype, q.shape[1])
+    assert "reduce_precision" not in set(primitives(jax.make_jaxpr(
+        lambda *a: paged_read_attention(*a, **kw))(*args).jaxpr))
+
+
+@pytest.mark.parametrize("q_dtype,pool_dtype,rows,rounds", [
+    ("bfloat16", "float32", 2, True),        # spec_verify at K = 1
+    ("bfloat16", "float32", 128, True),      # a prefill chunk; the latent read
+    ("bfloat16", "float32", 16, True),       # the hybrid's grouped read
+    ("float16", "float32", 4, True),
+    ("bfloat16", "float32", 1, False),       # OPT's fused block, ticked step
+    ("float32", "float32", 128, False),
+    ("bfloat16", "bfloat16", 128, False),    # nothing narrower to round to
+    ("bfloat16", None, 128, False),          # int8 pools
+])
+def test_read_rounds_chunk(q_dtype, pool_dtype, rows, rounds):
+    assert read_rounds_chunk(q_dtype, pool_dtype, rows) is rounds
+
+
+# ---------------------------------------------------------------------------
+# the engine: the loop in the lowered programs, and the counters
 # ---------------------------------------------------------------------------
 
 VOCAB = 48
@@ -353,3 +450,59 @@ def test_benchmark_metric_names_counters_the_registry_has(net):
                                      "serve-pangu-longdoc"]
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[0][key] == metric[key]
+
+
+@pytest.fixture(scope="module")
+def bf16_net():
+    return ComputationGraph(transformer_lm(
+        VOCAB, n_layers=2, d_model=16, n_heads=2, d_ff=32, seed=3,
+        input_ids=True, dtype="mixed_bf16")).init()
+
+
+def _rounded(eng, kind):
+    read = eng.registry.get("decode_kv_read_tokens_total").value(kind=kind)
+    return read, eng.registry.get(
+        "decode_kv_chunk_rounded_tokens_total").value(kind=kind)
+
+
+@pytest.mark.parametrize("case", ["bf16_prefill", "bf16_one_row_block",
+                                  "f32_prefill", "bf16_int8_prefill",
+                                  "bf16_grouped_one_token"])
+def test_rounded_counter_counts_the_dispatches_whose_read_rounds(
+        net, bf16_net, case):
+    """``decode_kv_chunk_rounded_tokens_total`` is all of
+    ``decode_kv_read_tokens_total`` for a ``mixed_bf16`` prefill dispatch
+    and for a grouped-query net's one-token step (``group`` rows a K/V
+    head), and stays 0 for a one-row block, a float32 policy and int8
+    pools."""
+    if case == "bf16_grouped_one_token":
+        served = ComputationGraph(nemotron_h_lm(     # one attention layer
+            VOCAB, pattern="*", d_model=16, n_heads=4, n_kv_heads=2,
+            mamba_heads=1, mamba_head_dim=8, mamba_groups=1, state_size=8,
+            n_experts=1, top_k=1, d_latent=8, d_expert=8, d_shared=8,
+            dtype="mixed_bf16")).init()
+    else:
+        served = net if case.startswith("f32") else bf16_net
+    eng = _engine(served, max_batch=1,
+                  kv_dtype="int8" if "int8" in case else None)
+    tables = np.full((1, 16), eng.arena.sentinel, np.int32)
+    zi = np.zeros(1, np.int32)
+    if case.endswith("prefill"):
+        eng.run(np.zeros((1, 8), np.int32), np.full((1, 8), -1, np.int32),
+                np.array([130], np.int32), tables, zi)
+        read, rounded = _rounded(eng, "paged")
+        assert read == 256
+    elif case == "bf16_grouped_one_token":
+        eng.run(np.zeros((1, 1), np.int32), np.full((1, 1), -1, np.int32),
+                np.array([5], np.int32), tables, zi)
+        read, rounded = _rounded(eng, "paged")
+        assert read == 128
+    else:
+        eng.run_fused(zi, tables, np.array([5], np.int32), np.ones(1, bool),
+                      np.full(1, 4, np.int32), np.full(1, -1, np.int32),
+                      np.zeros(1, np.float32), zi, np.ones(1, np.float32),
+                      np.zeros((1, 4), np.float32))
+        read, rounded = _rounded(eng, "fused")
+        assert read == 4 * 128
+    assert rounded == (read if case in ("bf16_prefill",
+                                        "bf16_grouped_one_token") else 0)

@@ -5,8 +5,9 @@
   as a scatter's operand and result, a gather's operand, a loop carry or
   a call's argument (its jaxpr, on the CPU);
 - compiled for a described TPU v5e, the prefill and the fused program
-  hold no copy whose shape is a pool's (skipped where no TPU topology
-  can be described);
+  hold no copy and no convert whose shape is a pool's, and the read at
+  each serving cell's decode shape carries its pools in float32 (skipped
+  where no TPU topology can be described);
 - ``paged_write``, ``paged_gather`` and ``paged_read_attention`` give,
   bit for bit, what the ``[num_pages, page_size, h, d]`` formulation they
   replaced gave (kept below as the reference; the gather's result has
@@ -30,7 +31,7 @@ from deeplearning4j_tpu.ops.paged_attention import (paged_gather,
                                                     paged_write)
 from deeplearning4j_tpu.serving.decode import PagedDecodeEngine
 from deeplearning4j_tpu.serving.kv_cache import PagedKVArena
-from test_paged_read import as_pool, quantized
+from test_paged_read import as_pool, primitives, quantized
 
 # ---------------------------------------------------------------------------
 # 1. the programs' jaxprs: a pool is scattered into, gathered from, carried
@@ -78,11 +79,13 @@ def pool_faults(jaxpr, pools):
     return faults
 
 
-@pytest.fixture(scope="module")
-def nets():
+def _nets(dtype):
     net = ComputationGraph(transformer_lm(
         VOCAB, n_layers=2, d_model=16, n_heads=2, d_ff=32, seed=3,
-        input_ids=True)).init()
+        input_ids=True, dtype=dtype)).init()
+    # the draft stays float32: its one-row loop would not round anyway,
+    # and under ``mixed_bf16`` ``draft_decode_loop`` does not trace (its
+    # greedy branch returns the row's dtype, the sampled one float32)
     draft = ComputationGraph(draft_transformer_lm(
         VOCAB, d_model=8, n_heads=2, d_ff=16, seed=5)).init()
     return net, draft
@@ -114,17 +117,25 @@ def _recorded_programs(kv_dtype, nets, monkeypatch):
     return seen
 
 
-@pytest.fixture(scope="module", params=[None, "int8"], ids=["f32", "int8"])
-def programs(request, nets):
+# bf16: a bfloat16 query over float32 pools, where the read rounds the
+# chunks it gathers (and nothing else: not the pool)
+@pytest.fixture(scope="module", params=["f32", "int8", "bf16"])
+def programs(request):
+    nets = _nets("mixed_bf16" if request.param == "bf16" else "float32")
     with pytest.MonkeyPatch.context() as mp:
-        return _recorded_programs(request.param, nets, mp)
+        return request.param, _recorded_programs(
+            "int8" if request.param == "int8" else None, nets, mp)
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_no_program_works_on_a_whole_pool(programs, kind):
     """In the program's jaxpr a pool-shaped value is an input, a scatter's
     operand or result, a gather's operand or a loop's carry: no reshape,
-    transpose, convert or arithmetic has one as operand or result."""
+    transpose, convert or arithmetic has one as operand or result. The
+    rounding of the read (several query rows under ``mixed_bf16``: the
+    prefill chunk and the verify chunk) is in the program and is none of
+    those: its operand is the gathered chunk."""
+    policy, programs = programs
     found = [n for n in programs if n.startswith(KINDS[kind])]
     assert found, (kind, sorted(programs))
     for name in found:
@@ -134,22 +145,38 @@ def test_no_program_works_on_a_whole_pool(programs, kind):
         assert any(tuple(v.aval.shape) == s for v in jaxpr.invars
                    for s, _ in pools)       # the walker sees the pools
         assert pool_faults(jaxpr, pools) == [], name
+        assert ("reduce_precision" in set(primitives(jaxpr))) == (
+            policy == "bf16" and kind in ("prefill_chunk", "verify")), name
 
 
-def test_the_walker_finds_a_reshaped_pool():
-    """The guard guards: a view of the pool around a 4-D primitive (what
-    ISSUE 32 warns of) is reported."""
-    def viewed(pool, table):
-        return jnp.take(pool.reshape(NUM_PAGES, 4, 2, 8), table, axis=0)
-    jaxpr = jax.make_jaxpr(viewed)(jnp.zeros((NUM_PAGES, 4, 16), jnp.float32),
-                                   jnp.zeros((1, 2), jnp.int32)).jaxpr
+def _viewed(pool, table):          # what ISSUE 32 warns of
+    return jnp.take(pool.reshape(NUM_PAGES, 4, 2, 8), table, axis=0)
+
+
+def _rounded_whole(pool, table):    # what the compiler did until PR 38
+    return jnp.take(jax.lax.reduce_precision(pool, 8, 7), table, axis=0)
+
+
+def _rounded_chunk(pool, table):    # what the read does
+    return jax.lax.reduce_precision(jnp.take(pool, table, axis=0), 8, 7)
+
+
+@pytest.mark.parametrize("fn,fault", [
+    (_viewed, "reshape"), (_rounded_whole, "reduce_precision"),
+    (_rounded_chunk, None)])
+def test_the_walker_finds_a_pool_worked_on(fn, fault):
+    """The guard guards: a view of the pool around a 4-D primitive and a
+    rounding of the whole pool are reported; a rounding of the gathered
+    pages, the read's, is none of its business."""
+    jaxpr = jax.make_jaxpr(fn)(jnp.zeros((NUM_PAGES, 4, 16), jnp.float32),
+                               jnp.zeros((1, 2), jnp.int32)).jaxpr
     faults = pool_faults(jaxpr, {((NUM_PAGES, 4, 16), "float32")})
-    assert len(faults) == 1 and faults[0].startswith("reshape")
+    assert [f.split(":")[0] for f in faults] == ([fault] if fault else [])
 
 
 # ---------------------------------------------------------------------------
-# 2. compiled for a described v5e: no copy of a pool (this file alone
-#    describes a topology: one worker loads the TPU's library)
+# 2. compiled for a described v5e: no copy and no rounding of a pool (this
+#    file alone describes a topology: one worker loads the TPU's library)
 # ---------------------------------------------------------------------------
 
 
@@ -178,12 +205,25 @@ def _described(tree, sharding):
         tree)
 
 
+def _pool_shaped(text, pool_shape, ops):
+    """``(dtype, op)`` of every instruction of ``ops`` in the compiled
+    text whose result has a pool's shape."""
+    shape = ",".join(str(n) for n in pool_shape)
+    return re.findall(r"= (\w+)\[" + shape + r"\]\{[^}]*\} ("
+                      + "|".join(map(re.escape, ops)) + r")\(", text)
+
+
 @pytest.mark.parametrize("program", ["prefill", "fused"])
 def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
     """Heads of 64 (half a 128-lane tile) and 224 pages, as in the
     benchmark's OPT: stored ``[.., h, d]``, each of the two programs
     relaid every donated pool at entry and before its result (8 copies
-    for these 4 pools; 40 pages show none, so the sizes matter)."""
+    for these 4 pools; 40 pages show none, so the sizes matter). Nor a
+    ``convert``: until PR 38 the prefill program's text held four
+    ``bf16[224,16,256] convert``, the compiler's rounding of each whole
+    float32 pool for the read's matrix products, and its read loop
+    carried the bfloat16 copies (``ops/paged_attention``, module
+    docstring)."""
     lanes, chunk, block, page_size, pages_per_seq = 2, 128, 4, 16, 16
     conf = transformer_lm(512, n_layers=2, d_model=256, n_heads=4,
                           d_ff=512, seed=3, input_ids=True,
@@ -218,8 +258,64 @@ def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
     text = jax.jit(step, donate_argnums=(1, 2)).lower(
         params, k, v, *args).compile().as_text()
     assert "scatter" in text and "gather" in text
-    shape = ",".join(str(n) for n in k[0].shape)
-    assert re.findall(r"= \w+\[" + shape + r"\]\{[^}]*\} copy\(", text) == []
+    assert _pool_shaped(text, k[0].shape, ["copy", "convert"]) == []
+
+
+# the paged write and read of one attention vertex at each serving cell's
+# decode shape: lanes, query rows a K/V head, K/V heads, head size, pages,
+# pages a lane, and the latent read's value width (`benchmarks/configs`)
+READ_SHAPES = {
+    # 128 heads on the one latent row of 576 numbers in 640 columns
+    "latent": (32, 128, 1, 640, 12288, 512, 512),
+    # the hybrid's grouped-query layer: 16 heads on each of 2 K/V heads
+    "hybrid": (32, 16, 2, 128, 4096, 128, None),
+    # OPT's fused block and ticked step: one row a head
+    "opt_one_row": (8, 1, 32, 64, 224, 128, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(READ_SHAPES))
+def test_compiled_for_v5e_the_read_rounds_no_pool(one_chip, cell):
+    """A bfloat16 query over float32 pools: where the read's products
+    are matrix products (more than one row a K/V head) the compiler
+    rounded each WHOLE pool to bfloat16 before the read loop (one
+    ``bf16[12288,16,640] convert`` at the latent shape, two
+    ``bf16[4096,16,256]`` and two pool-shaped ``copy-done`` at the
+    hybrid's; none with one row, where the products are the vector
+    unit's, in float32). The read rounds the gathered chunk itself, so
+    no pool-shaped ``convert``, ``copy`` or ``bitcast-convert`` is left
+    and the read's ``while`` carries every pool in float32."""
+    lanes, rows, h, d, num_pages, pages_per_seq, v_width = READ_SHAPES[cell]
+    group = rows if rows > 1 else 1
+    page_size, latent = 16, v_width is not None
+
+    def step(k_pool, v_pool, q, new, table, slots, rel):
+        k_pool = paged_write(k_pool, new, table, slots)
+        if not latent:
+            v_pool = paged_write(v_pool, new, table, slots)
+        out = paged_read_attention(
+            q, k_pool, None if latent else v_pool, table, rel,
+            jnp.asarray(0.125, q.dtype), group=group, v_width=v_width)
+        return out, k_pool, v_pool
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((num_pages, page_size, h * d), jnp.float32)
+    with jax.enable_x64(False):     # as on the chip; the suite runs with x64
+        text = jax.jit(step, donate_argnums=(0, 1)).lower(
+            pool, pool, arg((lanes, rows, h, d), jnp.bfloat16),
+            arg((lanes, 1, h, d), jnp.bfloat16),
+            arg((lanes, pages_per_seq), jnp.int32),
+            arg((lanes, 1), jnp.int32),
+            arg((lanes,), jnp.int32)).compile().as_text()
+    assert "scatter" in text and "gather" in text
+    assert _pool_shaped(text, pool.shape, [
+        "convert", "copy", "bitcast-convert", "copy-done"]) == []
+    shape = ",".join(str(n) for n in pool.shape)
+    carried = [dt for carry in re.findall(r"= (\(.*?\)) while\(", text)
+               for dt in re.findall(r"(\w+)\[" + shape + r"\]", carry)]
+    assert carried == ["f32"] * (1 if latent else 2)
 
 
 @pytest.mark.parametrize("bh, t, d, calls", [
