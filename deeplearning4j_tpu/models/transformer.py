@@ -32,6 +32,7 @@ import jax
 import numpy as np
 
 from ..nn.conf.attention import SelfAttentionLayer
+from ..nn.conf.eva import EvaAttentionLayer
 from ..nn.conf.mla import MLAttentionLayer
 from ..nn.conf.builders import NeuralNetConfiguration
 from ..nn.conf.graph import ElementWiseVertex, LayerVertex
@@ -133,7 +134,7 @@ def transformer_lm(vocab_size: int, *, n_layers: int = 4,
 
 
 # the layers that own a K/V cache (dense or paged) during decode
-ATTENTION_LAYERS = (SelfAttentionLayer, MLAttentionLayer)
+ATTENTION_LAYERS = (SelfAttentionLayer, MLAttentionLayer, EvaAttentionLayer)
 
 
 def attention_vertices(net) -> List[str]:
@@ -172,6 +173,13 @@ def position_vertices(net) -> List[str]:
     rotary term): the walkers hand it to no other."""
     return [name for name in net.topo_order
             if getattr(net._vertex_layer(name), "wants_positions", False)]
+
+
+def eva_vertices(net) -> List[str]:
+    """Names of the EVA attention vertices (``nn/conf/eva``): a window of
+    raw K/V pages and summary pages a lane, in one table."""
+    return [name for name in attention_vertices(net)
+            if isinstance(net._vertex_layer(name), EvaAttentionLayer)]
 
 
 def counting_vertices(net) -> List[str]:
@@ -310,7 +318,9 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     writes ITS entry of the two state lists; every other vertex applies
     exactly as in ``output()``. A causal attention vertex owns a K and a V
     block pool, read and written through the lanes' page tables
-    (``SelfAttentionLayer.apply_paged``); a state-space vertex owns a
+    (``SelfAttentionLayer.apply_paged``; an EVA vertex's table holds its
+    window pages and then its summary pages, ``EvaAttentionLayer.
+    apply_paged``); a state-space vertex owns a
     ``[lanes, K-1, C]`` convolution tail and a ``[lanes, H, P, N]`` SSM
     state (``Mamba2Mixer.apply_paged``), of which the dispatch's rows are
     ``lane_ids``. Pure w.r.t. its arguments, so the serving engine jits it
@@ -381,6 +391,11 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
             out, k_pools[i] = layer.apply_paged(
                 params[name], acts[in_names[0]], k_pools[i], page_tables,
                 write_slots, rel_pos, positions, policy=net.policy)
+        elif i is not None and isinstance(layer, EvaAttentionLayer):
+            out, k_pools[i], v_pools[i] = layer.apply_paged(
+                params[name], acts[in_names[0]], k_pools[i], v_pools[i],
+                page_tables, write_slots, rel_pos, positions,
+                policy=net.policy)
         elif i is not None and isinstance(layer, SelfAttentionLayer):
             out, k_pools[i], v_pools[i] = layer.apply_paged(
                 params[name], acts[in_names[0]], k_pools[i], v_pools[i],

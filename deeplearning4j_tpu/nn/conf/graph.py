@@ -221,6 +221,9 @@ class ElementWiseVertex(GraphVertex):
     building block of ResNet)."""
 
     op: str = "add"   # add | subtract | product | average | max
+    # every input cast to float32 first: a float32 residual stream under a
+    # bfloat16 policy (EvaByte's ``fp32_skip_add``)
+    float32: bool = False
 
     def output_type(self, input_types):
         return input_types[0]
@@ -228,6 +231,8 @@ class ElementWiseVertex(GraphVertex):
     def apply(self, params, xs, *, state=None, train=False, rng=None,
               masks=None, policy=None, minibatch=None):
         op = self.op.lower()
+        if self.float32:
+            xs = [x.astype(jnp.float32) for x in xs]
         if op == "add":
             out = xs[0]
             for x in xs[1:]:

@@ -278,11 +278,20 @@ class RnnOutputLayer(BaseOutputLayer):
             return FeedForwardToRnnPreProcessor()
         return None
 
+    # the head's product in float32 at full precision whatever the policy
+    # (EvaByte's ``fp32_logits``)
+    float32_logits: bool = False
+
     def pre_output(self, params, x, *, policy=None):
         # x: [b, t, n_in] — einsum keeps the time axis, one big MXU matmul
         policy = policy or _dtypes.default_policy()
-        xc, wc = policy.cast_to_compute(x, params["W"])
-        z = jnp.einsum("bti,io->bto", xc, wc)
+        if self.float32_logits:
+            xc, wc = x.astype(jnp.float32), params["W"].astype(jnp.float32)
+            z = jnp.einsum("bti,io->bto", xc, wc,
+                           precision=jax.lax.Precision.HIGHEST)
+        else:
+            xc, wc = policy.cast_to_compute(x, params["W"])
+            z = jnp.einsum("bti,io->bto", xc, wc)
         return z + params["b"].astype(xc.dtype) if self.has_bias else z
 
 
@@ -734,12 +743,16 @@ class RMSNorm(Layer):
     """Root-mean-square normalization over the feature (last) axis:
     ``x / sqrt(mean(x^2) + eps) * gamma`` (Zhang & Sennrich 2019; the norm
     of ``nemotron_h`` and most of today's decoder blocks). No mean is
-    subtracted and there is no offset. Statistics in at least float32."""
+    subtracted and there is no offset. Statistics in at least float32.
+    ``unit_offset`` stores the gain less one (``gamma`` 0 is the identity,
+    EvaByte's ``norm_add_unit_offset``), so that a stored bfloat16 gain
+    keeps its small departures from 1."""
 
     _trace_scope = "ln"
 
     n_out: Optional[int] = None          # feature count (inferred)
     eps: float = 1e-5
+    unit_offset: bool = False
 
     def set_n_in(self, input_type: InputType, override: bool = False) -> None:
         if self.n_out is None or override:
@@ -757,11 +770,16 @@ class RMSNorm(Layer):
 
     def init_params(self, key, policy=None):
         policy = policy or _dtypes.default_policy()
-        return {"gamma": jnp.ones((self.n_out,), policy.param_dtype)}
+        make = jnp.zeros if self.unit_offset else jnp.ones
+        return {"gamma": make((self.n_out,), policy.param_dtype)}
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None, policy=None):
-        return rms_normalize(x, params["gamma"], self.eps), state
+        gamma = params["gamma"]
+        if self.unit_offset:
+            gamma = gamma.astype(jnp.promote_types(gamma.dtype,
+                                                   jnp.float32)) + 1.0
+        return rms_normalize(x, gamma, self.eps), state
 
 
 def rms_normalize(x, gamma, eps: float):

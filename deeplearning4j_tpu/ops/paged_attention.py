@@ -91,7 +91,7 @@ import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_gather", "paged_read_attention",
            "read_chunk_pages", "read_trip_count", "read_rounds_chunk",
-           "READ_CHUNK_TOKENS"]
+           "eva_read_attention", "eva_read_trips", "READ_CHUNK_TOKENS"]
 
 # tokens of K/V one trip of the read loop gathers (a whole number of
 # pages; the whole table where the window is shorter)
@@ -320,33 +320,145 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
         q_idx = rel_pos[:, None] + (jnp.arange(t_new) // group)[None, :]
 
     def fold(c, carry):
-        m, l, acc = carry
         table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
                                                axis=1)
         k_c = paged_gather(k_pool, table_c, h, round_to)      # [S, h, d, chunk]
         v_c = (paged_gather(v_pool, table_c, h, round_to)
                if v_width is None else k_c[:, :, :v_width])
-        with jax.named_scope("attn.paged_softmax"):
-            logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
-            key_idx = c * chunk + jnp.arange(chunk)
-            allow = key_idx[None, None, :] <= q_idx[:, :, None]
-            logits = jnp.where(allow[:, None], logits.astype(jnp.float32),
-                               -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-            m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-            p = jnp.where(jnp.isneginf(logits), 0.0,
-                          jnp.exp(logits - m_safe))
-            alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
-            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jnp.einsum("bhqk,bhdk->bqhd", p.astype(q.dtype), v_c)
-            acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
-        return m_new, l, acc
+        return _fold_chunk(
+            carry, q, k_c, v_c, c, scale,
+            lambda key_idx: key_idx[None, None, :] <= q_idx[:, :, None])
 
-    init = (jnp.full((s, h, t_new, 1), -jnp.inf, jnp.float32),
+    carry = jax.lax.fori_loop(0, trips, fold,
+                              _softmax_start(s, t_new, h, d_v, out_dtype))
+    return _softmax_end(carry, out_dtype)
+
+
+def _softmax_start(s, t_new, h, d_v, out_dtype):
+    """The running softmax's carry before any key: max, sum, accumulator."""
+    return (jnp.full((s, h, t_new, 1), -jnp.inf, jnp.float32),
             jnp.zeros((s, h, t_new, 1), jnp.float32),
             jnp.zeros((s, t_new, h, d_v),
                       jnp.promote_types(out_dtype, jnp.float32)))
-    _, l, acc = jax.lax.fori_loop(0, trips, fold, init)
+
+
+def _fold_chunk(carry, q, k_c, v_c, c, scale, allow):
+    """Fold one gathered chunk (``k_c [S, h, d, keys]``, ``v_c [S, h, d_v,
+    keys]``, keys on the minor axis) into the running softmax of ``q [S,
+    t_new, h, d]``: logits in float32, max-subtracted exp, the sum and the
+    accumulator rescaled by the max's move. The chunk is trip ``c`` of its
+    table, and ``allow(key_idx) [S, t_new, keys]`` says which of its keys
+    (numbered by their place in the table) each query row takes."""
+    m, l, acc = carry
+    with jax.named_scope("attn.paged_softmax"):
+        logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
+        chunk = k_c.shape[-1]
+        key_idx = c * chunk + jnp.arange(chunk)
+        logits = jnp.where(allow(key_idx)[:, None],
+                           logits.astype(jnp.float32), -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.where(jnp.isneginf(logits), 0.0,
+                      jnp.exp(logits - m_safe))
+        alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("bhqk,bhdk->bqhd", p.astype(q.dtype), v_c)
+        acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
+    return m_new, l, acc
+
+
+def _softmax_end(carry, out_dtype):
+    """The running softmax's output: the accumulator over the sum (floored
+    at 1e-30, so a row that took no key reads 0)."""
+    _, l, acc = carry
     with jax.named_scope("attn.paged_softmax"):
         out = acc / jnp.swapaxes(jnp.maximum(l, 1e-30), 1, 2)
         return out.astype(out_dtype)
+
+
+def eva_read_trips(rel_pos, positions, t_new: int, window: int,
+                   chunk_size: int, page_size: int, window_pages: int,
+                   summary_pages: int, xp=jnp):
+    """The bounds of the EVA read of one dispatch (:func:`eva_read_attention`),
+    in trips of :func:`read_chunk_pages` pages: ``(first, end)`` over the
+    window pages, from the chunk of the earliest window start any lane's
+    first query has to the furthest live position, and the trips over the
+    summary pages, up to the most summary rows any query sees. ``rel_pos``
+    view-relative (slot 0 is the lane's window start), ``positions``
+    absolute, both ``[S]``; ``xp`` as for :func:`read_trip_count`, so that
+    the engine counts on the host what the program reads."""
+    chunk = read_chunk_pages(page_size, window_pages) * page_size
+    start = positions // window * window - (positions - rel_pos)
+    seen = xp.max((positions + t_new - 1) // window * window // chunk_size)
+    cs = read_chunk_pages(page_size, summary_pages)
+    return (xp.min(start) // chunk,
+            read_trip_count(rel_pos, t_new, page_size, window_pages, xp),
+            xp.minimum((seen + cs * page_size - 1) // (cs * page_size),
+                       -(-summary_pages // cs)))
+
+
+@functools.partial(jax.jit, static_argnames=("window", "chunk_size"))
+def eva_read_attention(q, k_pool, v_pool, page_table, rel_pos, positions,
+                       scale, window: int, chunk_size: int):
+    """EVA attention of new queries (``nn/conf/eva``) over each lane's
+    paged window and its summary rows, merged in ONE running softmax.
+
+    ``page_table [S, P]``: the window's ``window / page_size + 1`` pages
+    (slot 0 the window's first position, so ``rel_pos`` indexes it as the
+    plain read's view does), then the summary pages, ``page_size``
+    summaries a page (summary ``c`` of chunk ``c``: row ``c % page_size`` of
+    summary page ``c // page_size``), in the same K and V pools. Query ``j``
+    of a lane, at absolute position ``t = positions + j``, takes the window
+    rows from its own window's start ``window * floor(t / window)`` to
+    ``t`` and the summary rows ``c < window * floor(t / window) /
+    chunk_size``; nothing else is allowed into its softmax. The summary
+    part is read first (scope ``eva.read_summary``), the window part after
+    (``eva.read_window``), each a ``while`` over chunks of
+    :func:`read_chunk_pages` pages bounded by :func:`eva_read_trips`; the
+    window part starts at the chunk of the earliest window start, and a
+    lane's pages before its first query's window start read as holes, so
+    no lane gathers a page of a window it has left (the one fused block
+    that crosses an edge still holds the last window's pages for its first
+    steps). Logits, exp and sums in float32; K/V rounded per chunk as
+    :func:`paged_read_attention` does. Returns ``[S, t_new, h, d]``."""
+    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    s, t_new, h, d = q.shape
+    out_dtype = jnp.result_type(q.dtype, k_pool.dtype)
+    round_to = q.dtype if read_rounds_chunk(q.dtype, k_pool.dtype,
+                                            t_new) else None
+    win = window // page_size + 1
+    q_abs = positions[:, None] + jnp.arange(t_new)              # [S, t_new]
+    start = q_abs // window * window
+    seen = start // chunk_size                  # summary rows a query sees
+    start_rel = start - (positions - rel_pos)[:, None]
+    q_rel = rel_pos[:, None] + jnp.arange(t_new)
+    first, end, trips = eva_read_trips(
+        rel_pos, positions, t_new, window, chunk_size, page_size, win,
+        page_table.shape[1] - win)
+    own = jnp.where(jnp.arange(win)[None, :] * page_size < start_rel[:, :1],
+                    num_pages, page_table[:, :win])
+
+    def loop(table, lo, hi, allow):
+        cp = read_chunk_pages(page_size, table.shape[1])
+        pad = -table.shape[1] % cp
+        if pad:     # a last, partial chunk reads sentinel pages: zeros, masked
+            table = jnp.pad(table, ((0, 0), (0, pad)),
+                            constant_values=num_pages)
+
+        def fold(c, carry):
+            table_c = jax.lax.dynamic_slice_in_dim(table, c * cp, cp, axis=1)
+            return _fold_chunk(carry, q, paged_gather(k_pool, table_c, h,
+                                                      round_to),
+                               paged_gather(v_pool, table_c, h, round_to),
+                               c, scale, allow)
+        return lambda carry: jax.lax.fori_loop(lo, hi, fold, carry)
+
+    carry = _softmax_start(s, t_new, h, d, out_dtype)
+    with jax.named_scope("eva.read_summary"):
+        carry = loop(page_table[:, win:], 0, trips,
+                     lambda key: key[None, None, :] < seen[:, :, None])(carry)
+    with jax.named_scope("eva.read_window"):
+        carry = loop(own, first, end,
+                     lambda key: (key[None, None, :] >= start_rel[:, :, None])
+                     & (key[None, None, :] <= q_rel[:, :, None]))(carry)
+    return _softmax_end(carry, out_dtype)

@@ -91,7 +91,10 @@ without it keeps the programs it had: per-lane recurrent state
 indexes the cache, the absolute one turns the keys), and under the prefix
 cache the positions each lane fed (``fed``; an expert layer computes a
 re-fed position whose write is dropped, and skips padding). A latent
-attention vertex owns one pool, not two.
+attention vertex owns one pool, not two. An EVA vertex (``nn/conf/eva``)
+owns a K and a V pool as a plain one does, but its lane's table is its
+current window's pages and then its summary pages: at each window edge the
+finished window goes back to the allocator (``_ensure_eva_pages``).
 
 Fault seam: ``"serving.decode_step"`` before every prefill/decode
 dispatch (chaos tests script outages at exact step boundaries).
@@ -300,8 +303,14 @@ class PagedDecodeEngine:
         self._counting = bool(self._counting_layers)
         # vertices with a rotary term take each lane's absolute position
         self.position_layers = _transformer.position_vertices(net)
+        # EVA vertices hold a window of raw K/V pages and summary pages a
+        # lane, both in the lane's one table (its width below)
+        self.eva_layers = _transformer.eva_vertices(net)
         self._refuse_unsupported(net, prefix_cache=bool(prefix_cache),
-                                 draft_net=draft_net)
+                                 draft_net=draft_net, kv_dtype=kv_dtype)
+        self.table_pages = self.pages_per_seq
+        if self.eva_layers:
+            self._eva_geometry(net, num_pages)
         # what a decode program takes after its other arguments, by what
         # the net needs (none for a net that needs none: its programs keep
         # the arguments they always had): the lanes' ids (recurrent state
@@ -340,11 +349,12 @@ class PagedDecodeEngine:
             self._build_arenas(net, int(num_pages), bool(prefix_cache),
                                kv_dtype)
         # per-lane host state
-        s, p = self.lanes, self.pages_per_seq
+        s, p = self.lanes, self.table_pages
         self._tables = np.full((s, p), self.arena.sentinel, np.int32)
         self._pos = np.zeros(s, np.int64)       # global fed positions
         self._base = np.zeros(s, np.int64)      # evicted positions
         self._held: List[List[int]] = [[] for _ in range(s)]
+        self._sum_held: List[List[int]] = [[] for _ in range(s)]  # EVA
         self._reserve_left = np.zeros(s, np.int64)
         self._covered = np.zeros(s, np.int64)   # prefix-hit tokens/lane
         self._free_lanes = deque(range(s))
@@ -458,6 +468,8 @@ class PagedDecodeEngine:
                 "moe_grouped_calls_total",
                 "Calls of the expert layers' grouped product: dispatches "
                 "x expert layers x steps, counted on the host")
+        if self.eva_layers:
+            self._init_eva_metrics()
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
@@ -514,9 +526,35 @@ class PagedDecodeEngine:
                                        jnp.float32)
 
     def _refuse_unsupported(self, net, *, prefix_cache: bool,
-                            draft_net) -> None:
-        """What a net with recurrent state or a rotary term cannot be
-        served with yet, refused here with the reason."""
+                            draft_net, kv_dtype=None) -> None:
+        """What a net with recurrent state, a rotary term or EVA attention
+        cannot be served with yet, refused here with the reason."""
+        if self.eva_layers and prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True with EVA vertices "
+                f"{self.eva_layers[:2]}...: a prefix hit maps the pages of a "
+                "shared prompt prefix, but an EVA lane gives its finished "
+                "windows' pages back at each window edge and keeps their "
+                "chunks only as summary rows in pages of its own, so a "
+                "long prompt's early pages are gone before any other lane "
+                "could map them — serve this net with prefix_cache=False")
+        if self.eva_layers and draft_net is not None:
+            raise ValueError(
+                f"draft_net with EVA vertices {self.eva_layers[:2]}...: a "
+                "verify chunk writes every draft token's K/V and pools a "
+                "chunk its drafts complete into a summary row, and gives "
+                "the window's pages back when the drafts cross its edge; a "
+                "rejected draft would leave a summary row built from rows "
+                "that were never served and a window that cannot come back "
+                "(no roll-back of either yet) — serve this net without a "
+                "draft_net")
+        if self.eva_layers and kv_dtype is not None:
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} with EVA vertices: a summary row "
+                "lands in a page beside fifteen others written at other "
+                "times, and the int8 pools keep one scale a (page, head) "
+                "that a later write rescales — serve this net with float "
+                "pools")
         if self.state_layers and prefix_cache:
             raise ValueError(
                 f"prefix_cache=True with state-space vertices "
@@ -543,6 +581,53 @@ class PagedDecodeEngine:
                 "token's K/V is simply overwritten, but a recurrent state "
                 "that advanced over it cannot be rolled back (no state "
                 "roll-back yet) — serve this net without a draft_net")
+
+    def _eva_geometry(self, net, num_pages: int) -> None:
+        """An EVA net's lane table: the window's pages (``window /
+        page_size``, and one more, which a fused block that crosses an edge
+        draws before the last window's pages go back) and then the summary
+        pages of the longest sequence (``page_size`` chunks a page). One
+        table serves every EVA vertex, so they must agree, and no other
+        kind of attention may share it."""
+        layers = [net._vertex_layer(n) for n in self.eva_layers]
+        others = sorted(set(_transformer.attention_vertices(net))
+                        - set(self.eva_layers))
+        if others:
+            raise ValueError(
+                f"EVA vertices beside other attention vertices {others[:2]}: "
+                "an EVA lane's table holds one window and its summaries, "
+                "where a full attention vertex needs every page of the "
+                "sequence (no tables per kind of layer yet)")
+        geometry = {(lay.window_size, lay.chunk_size) for lay in layers}
+        if len(geometry) > 1:
+            raise ValueError(f"EVA vertices with different windows or chunks "
+                             f"{sorted(geometry)}: one table serves them all")
+        (window, chunk), = geometry
+        ps = self.page_size
+        if ps != chunk:
+            raise ValueError(
+                f"page_size={ps} with EVA chunks of {chunk}: a page holds "
+                "one chunk, which is pooled into its summary row when it "
+                "fills — set page_size to the chunk size")
+        if window % self.prefill_chunk:
+            raise ValueError(
+                f"prefill_chunk={self.prefill_chunk} does not divide the EVA "
+                f"window of {window}: a prefill chunk must not cross a "
+                "window edge (the lane's table holds one window and the "
+                "next window's first page)")
+        if self.block_len > ps:
+            raise ValueError(
+                f"block_len={self.block_len} exceeds the page of {ps}: a "
+                "fused block crosses an EVA window edge into one page of "
+                "the next window at most")
+        self._eva_window, self._eva_chunk = window, chunk
+        self._eva_win_pages = window // ps + 1
+        self._eva_sum_pages = -(-(self.window // chunk) // ps)
+        self.table_pages = self._eva_win_pages + self._eva_sum_pages
+        if self.table_pages > num_pages:
+            raise ValueError(
+                f"an EVA lane may hold {self.table_pages} pages, more than "
+                f"the arena's {num_pages}: one sequence could never run")
 
     @staticmethod
     def _validate_net(net) -> None:
@@ -661,7 +746,8 @@ class PagedDecodeEngine:
                     self.refused_by = "pages"
                     return None
         else:
-            need = min(self.pages_per_seq, worst)
+            need = (self._eva_pages(total_tokens) if self.eva_layers
+                    else min(self.pages_per_seq, worst))
             if not alloc.reserve(need):
                 self.refused_by = "pages"
                 return None
@@ -715,10 +801,11 @@ class PagedDecodeEngine:
         returns to the free list at refcount 0 — prefix-cached pages
         stay resident under the index's reference), unused reservation
         returned, the lane reusable by the next admission."""
-        self.arena.allocator.free(self._held[lane])
+        self.arena.allocator.free(self._held[lane] + self._sum_held[lane])
         if self._reserve_left[lane]:
             self.arena.allocator.unreserve(int(self._reserve_left[lane]))
         self._held[lane] = []
+        self._sum_held[lane] = []
         self._reserve_left[lane] = 0
         self._covered[lane] = 0
         self._tables[lane, :] = self.arena.sentinel
@@ -735,6 +822,8 @@ class PagedDecodeEngine:
         if n_new > self.window:
             raise ValueError(f"chunk of {n_new} exceeds the "
                              f"window ({self.window})")
+        if self.eva_layers:
+            return self._ensure_eva_pages(lane, n_new)
         pos, base = int(self._pos[lane]), int(self._base[lane])
         ps = self.page_size
         held = self._held[lane]
@@ -759,8 +848,7 @@ class PagedDecodeEngine:
                 # (admission reserved pages_per_seq for window-sliding
                 # sequences precisely so these draws cannot fail).
                 alloc.free([oldest])
-                replacement = alloc.draw()
-                self._reserve_left[lane] -= 1
+                replacement = self._draw(lane)
                 fresh.append(replacement)
                 alloc.note_cow()
             else:
@@ -774,14 +862,59 @@ class PagedDecodeEngine:
             alloc.note_eviction()
         last_idx = (pos + n_new - 1 - base) // ps
         while len(held) <= last_idx:
-            page = alloc.draw()
-            self._reserve_left[lane] -= 1
+            page = self._draw(lane)
             self._tables[lane, len(held)] = page
             held.append(page)
             fresh.append(page)
         self._base[lane] = base
         if fresh:
             self._reset_page_scales(fresh)
+
+    def _eva_pages(self, total_tokens: int) -> int:
+        """Pages an EVA lane of ``total_tokens`` may hold at once: its
+        window's, one more while a block crosses an edge, and the summary
+        pages of every chunk it can complete."""
+        return (min(self._eva_win_pages, self.arena.pages_for(total_tokens))
+                + -(-(total_tokens // self._eva_chunk) // self.page_size))
+
+    def _ensure_eva_pages(self, lane: int, n_new: int) -> None:
+        """:meth:`ensure_pages` for an EVA lane, whose table is its
+        window's pages and then its summary pages. A lane whose next
+        position lies past a window edge gives the finished window's pages
+        back to the allocator (their chunks are summary rows by now) and
+        keeps them reserved for its next window; ``base`` is the window's
+        first position, so view-relative slots index the window pages as
+        they index a plain lane's view. Then the window pages and the
+        summary pages for positions ``pos .. pos+n_new-1`` are drawn."""
+        pos, base = int(self._pos[lane]), int(self._base[lane])
+        ps, held, alloc = self.page_size, self._held[lane], \
+            self.arena.allocator
+        start = pos // self._eva_window * self._eva_window
+        if start > base:
+            with region("sched.eva_retire"):
+                n = min(len(held), (start - base) // ps)
+                done, held[:] = held[:n], held[n:]
+                alloc.recycle(done)
+                self._reserve_left[lane] += len(done)
+                self._tables[lane, :self._eva_win_pages] = self.arena.sentinel
+                self._tables[lane, :len(held)] = held
+                self._m_eva_retired.inc(len(done))
+                base = self._base[lane] = start
+        last = pos + n_new - 1
+        while len(held) <= (last - base) // ps:
+            page = self._draw(lane)
+            self._tables[lane, len(held)] = page
+            held.append(page)
+        sums = self._sum_held[lane]
+        while len(sums) * ps < (last + 1) // self._eva_chunk:
+            page = self._draw(lane)
+            self._tables[lane, self._eva_win_pages + len(sums)] = page
+            sums.append(page)
+
+    def _draw(self, lane: int) -> int:
+        """One page for ``lane``, against its reservation."""
+        self._reserve_left[lane] -= 1
+        return self.arena.allocator.draw()
 
     def _reset_page_scales(self, pages: List[int]) -> None:
         """int8 arenas: zero the quantization scales of freshly drawn
@@ -835,7 +968,7 @@ class PagedDecodeEngine:
         (its chunk's length; a padded slot 0), which only an expert
         layer under the prefix cache is told."""
         b, t_new = ids.shape
-        name = f"paged_decode[S{b}xT{t_new}xP{self.pages_per_seq}]"
+        name = f"paged_decode[S{b}xT{t_new}xP{self.table_pages}]"
         names = self._extra_paged
         if fed is None:         # no prefix hit among them: what is written
             fed = (write_slots >= 0).sum(axis=1)
@@ -854,7 +987,11 @@ class PagedDecodeEngine:
              np.asarray(out_rows, np.int32),
              *self._extra_args(names, lanes, rel_pos, fed)),
             kind="paged")
-        self._note_kv_read("paged", rel_pos, t_new)
+        if self.eva_layers:
+            self._note_eva("paged", rel_pos, lanes, t_new,
+                           (write_slots >= 0).sum(axis=1))
+        else:
+            self._note_kv_read("paged", rel_pos, t_new)
         self._note_routing(counts, steps=1)
         return probs
 
@@ -1023,6 +1160,103 @@ class PagedDecodeEngine:
         if self._read_rounds_chunk(t_new, draft):
             self._m_kv_rounded.inc(len(rel) * visited, kind=kind)
 
+    def _init_eva_metrics(self) -> None:
+        """What the EVA vertices' reads and pages are counted in: on the
+        host, from what the engine dispatches, as
+        ``decode_kv_read_tokens_total`` is (:meth:`_note_eva`)."""
+        reg = self.registry
+        self._m_eva_rows = reg.counter(
+            "eva_read_rows_total",
+            "K/V rows the EVA reads of the dispatches visited, summed over "
+            "the EVA vertices, by part: the window's raw rows and the "
+            "summary rows (live lanes x trips x chunk rows, for every step "
+            "of a block; ops.paged_attention.eva_read_trips)", ("part",))
+        self._m_eva_bytes = reg.counter(
+            "eva_read_bytes_total",
+            "Bytes of K and V rows the same reads gathered")
+        self._m_eva_written = reg.counter(
+            "eva_summary_rows_written_total",
+            "Summary rows written: chunks the dispatches completed, summed "
+            "over the EVA vertices")
+        self._m_eva_attended = reg.counter(
+            "eva_attended_rows_total",
+            "Rows one EVA vertex's softmax takes, summed over the written "
+            "positions: the own window's rows up to the position and the "
+            "summary rows of every chunk before that window")
+        self._m_eva_retired = reg.counter(
+            "eva_window_pages_retired_total",
+            "Window pages EVA lanes gave back to the allocator at a window "
+            "edge")
+        self._m_eva_held = reg.counter(
+            "eva_held_rows_total",
+            "Rows of the pages the dispatched EVA lanes held (window and "
+            "summary pages x page size), summed over dispatches")
+        self._m_eva_context = reg.counter(
+            "eva_context_tokens_total",
+            "Positions the same lanes had fed after the dispatch, summed "
+            "over dispatches")
+        ref = weakref.ref(self)
+
+        def held_per_token():
+            eng = ref()
+            if eng is None:
+                raise LookupError("engine retired")
+            live = [i for i in range(eng.lanes) if eng._pos[i] > 0]
+            tokens = sum(int(eng._pos[i]) for i in live)
+            return (sum(len(eng._held[i]) + len(eng._sum_held[i])
+                        for i in live) * eng.page_size / tokens
+                    if tokens else 0.0)
+        reg.gauge(
+            "eva_held_rows_per_context_token",
+            "Rows of the pages the live EVA lanes hold over the positions "
+            "they have fed (1 and more if windows were never given back)"
+        ).set_function(held_per_token)
+
+    def _note_eva(self, kind: str, rel: np.ndarray,
+                  lanes: Optional[np.ndarray], t_new: int,
+                  written: np.ndarray, steps: int = 1) -> None:
+        """Account an EVA dispatch: the rows its reads visited by part
+        (the bounds the program's loops take, from the same helper and the
+        same arrays, for each of a block's ``steps``), the summary rows its
+        lanes' ``written`` positions completed, the rows their softmaxes
+        took, and the pages they hold against the positions fed."""
+        if self._warming:
+            return
+        if lanes is None:               # every slot padded, as _extra_args
+            lanes = np.full(len(rel), self.lanes, np.int32)
+        live = np.asarray(lanes) < self.lanes
+        base = np.append(self._base, 0)[np.minimum(lanes, self.lanes)]
+        rel = np.asarray(rel, np.int64)
+        pos, n = rel + base, np.asarray(written, np.int64)
+        ps, chunk, window = self.page_size, self._eva_chunk, self._eva_window
+        layers, lanes_live = len(self.eva_layers), int(live.sum())
+        rows_w = ps * _paged.read_chunk_pages(ps, self._eva_win_pages)
+        rows_s = ps * _paged.read_chunk_pages(ps, self._eva_sum_pages)
+        window_rows = summary_rows = 0
+        for i in range(steps):
+            first, end, trips = _paged.eva_read_trips(
+                rel + i, pos + i, t_new, window, chunk, ps,
+                self._eva_win_pages, self._eva_sum_pages, xp=np)
+            window_rows += lanes_live * max(0, int(end - first)) * rows_w
+            summary_rows += lanes_live * int(trips) * rows_s
+        self._m_eva_rows.inc(window_rows * layers, part="window")
+        self._m_eva_rows.inc(summary_rows * layers, part="summary")
+        row_bytes = 2 * int(self.arena.k_pools[0].shape[-1]) \
+            * self.arena.k_pools[0].dtype.itemsize
+        self._m_eva_bytes.inc((window_rows + summary_rows) * layers
+                              * row_bytes)
+        pos, n, idx = pos[live], n[live], np.asarray(lanes)[live]
+        self._m_eva_written.inc(
+            int(((pos + n) // chunk - pos // chunk).sum()) * layers)
+        fed = np.concatenate([np.arange(p, p + m) for p, m in zip(pos, n)]
+                             + [np.zeros(0, np.int64)])
+        start = fed // window * window
+        self._m_eva_attended.inc(int((fed - start + 1 + start // chunk)
+                                     .sum()))
+        self._m_eva_held.inc(ps * sum(len(self._held[i])
+                                      + len(self._sum_held[i]) for i in idx))
+        self._m_eva_context.inc(int((pos + n).sum()))
+
     # -- fused multi-token block --------------------------------------
 
     def run_fused(self, last: np.ndarray, tables: np.ndarray,
@@ -1038,7 +1272,7 @@ class PagedDecodeEngine:
         measures). Returns host ``(tokens [B, N], valid [B, N],
         n_emitted [B])``."""
         b, n = uniforms.shape
-        name = f"fused_decode[S{b}xN{n}xP{self.pages_per_seq}]"
+        name = f"fused_decode[S{b}xN{n}xP{self.table_pages}]"
 
         names = self._extra
 
@@ -1055,7 +1289,10 @@ class PagedDecodeEngine:
              uniforms, *self._extra_args(names, lanes, rel)), kind="fused")
         # the block's loop ends with its last live lane
         steps = int(n_emitted.max())
-        self._note_kv_read("fused", rel, 1, steps=steps)
+        if self.eva_layers:
+            self._note_eva("fused", rel, lanes, 1, n_emitted, steps=steps)
+        else:
+            self._note_kv_read("fused", rel, 1, steps=steps)
         self._note_routing(counts, steps=steps)
         return toks, valid, n_emitted
 
@@ -1071,7 +1308,7 @@ class PagedDecodeEngine:
         ``out_rows`` as in :meth:`run`: the program builds no
         ``[B, t, V]`` for an output nobody reads."""
         b, t = ids.shape
-        name = f"draft_prefill[S{b}xT{t}xP{self.pages_per_seq}]"
+        name = f"draft_prefill[S{b}xT{t}xP{self.table_pages}]"
 
         def step(params, k_pools, v_pools, ids, tables, wslots, rel, rows):
             return _transformer.paged_decode_forward(
@@ -1095,7 +1332,7 @@ class PagedDecodeEngine:
         ``(draft_tokens [B, K], draft_dists [B, K, V])`` — they feed
         straight into :meth:`run_verify` with no host sync between."""
         b, k1 = uniforms.shape
-        name = f"spec_draft[S{b}xK{k1 - 1}xP{self.pages_per_seq}]"
+        name = f"spec_draft[S{b}xK{k1 - 1}xP{self.table_pages}]"
 
         def step(params, k_pools, v_pools, last, tables, rel, active,
                  wbudget, temps, tk, tp, u):
@@ -1123,7 +1360,7 @@ class PagedDecodeEngine:
         dispatch). Returns host ``(emitted [B, K+1], valid [B, K+1],
         accepts [B])``."""
         b, k = u_accept.shape
-        name = f"spec_verify[S{b}xK{k}xP{self.pages_per_seq}]"
+        name = f"spec_verify[S{b}xK{k}xP{self.table_pages}]"
 
         def step(params, k_pools, v_pools, last, tables, rel, active,
                  wbudget, d_toks, d_dists, temps, tk, tp, ua, uf):
@@ -1191,7 +1428,7 @@ class PagedDecodeEngine:
         b = 1
         while True:
             c = self.prefill_chunk
-            sentinel_tables = np.full((b, self.pages_per_seq),
+            sentinel_tables = np.full((b, self.table_pages),
                                       self.arena.sentinel, np.int32)
             inactive = np.zeros(b, bool)
             zeros_f = np.zeros(b, np.float32)
@@ -1619,7 +1856,7 @@ class DecodeScheduler:
         ids = np.zeros((b, t_new), np.int32)
         wslots = np.full((b, t_new), -1, np.int32)
         rel = np.zeros(b, np.int32)
-        tables = np.full((b, eng.pages_per_seq), eng.arena.sentinel,
+        tables = np.full((b, eng.table_pages), eng.arena.sentinel,
                          np.int32)
         lanes = np.full(b, eng.lanes, np.int32)     # padded slot: no lane
         for i, seq in enumerate(seqs):
@@ -1772,7 +2009,7 @@ class DecodeScheduler:
             "top_k": np.zeros(b, np.int32),
             "top_p": np.ones(b, np.float32),
             "u": np.zeros((b, n_uniform), np.float32),
-            "tables": np.full((b, eng.pages_per_seq), eng.arena.sentinel,
+            "tables": np.full((b, eng.table_pages), eng.arena.sentinel,
                               np.int32),
             "lanes": np.full(b, eng.lanes, np.int32),
         }

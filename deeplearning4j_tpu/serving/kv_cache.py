@@ -276,6 +276,15 @@ class PageAllocator:
         with self._lock:
             self._release_locked(pages)
 
+    def recycle(self, pages: Sequence[int]) -> None:
+        """Give an owner's pages back to the free list and keep as many
+        reserved for it: an EVA lane's finished window, whose next window
+        draws its pages against the same reservation (``reserved <= free +
+        reclaimable`` holds: both grow by the same count)."""
+        with self._lock:
+            self._release_locked(pages)
+            self._reserved += len(pages)
+
     def note_eviction(self, n: int = 1) -> None:
         self._m_evicted.inc(n)
 
@@ -493,7 +502,10 @@ class PagedKVArena:
     ``(row width, None)`` and gets ONE block pool ``[num_pages, page_size,
     row width]`` in ``k_pools[i]``, paged, written and read as the others
     are; ``v_pools[i]`` is None (an empty pytree: nothing is donated,
-    returned or counted for it). A state-space vertex gives its two per-lane shapes
+    returned or counted for it). An EVA vertex (``nn/conf/eva``) gets a K
+    and a V pool as a causal one does; its lanes' pages are a window that
+    goes back to the allocator at each edge (:meth:`PageAllocator.recycle`)
+    and summary pages (``serving/decode``). A state-space vertex gives its two per-lane shapes
     (``Mamba2Mixer.state_shapes(lanes)``) and gets a convolution tail
     ``[lanes, K-1, C]`` in ``k_pools[i]`` and an SSM state ``[lanes, H, P,
     N]`` in ``v_pools[i]``: fixed-size whatever the sequence's length, so

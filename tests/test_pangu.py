@@ -28,7 +28,8 @@ import pytest
 from deeplearning4j_tpu.models import generate, transformer_lm
 from deeplearning4j_tpu.models import transformer as _transformer
 from deeplearning4j_tpu.nn.conf.layers import gated_ffn
-from deeplearning4j_tpu.nn.conf.mla import MLAttentionLayer, rotary
+from deeplearning4j_tpu.nn.conf.mla import MLAttentionLayer
+from deeplearning4j_tpu.nn.conf.rotary import rotary
 from deeplearning4j_tpu.nn.conf.moe import (GatedMoELayer, dense_expert_ffn,
                                             sparse_expert_ffn)
 from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
