@@ -72,10 +72,23 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
   (the page table shifts, ``base`` advances) — positions stay global, and
   the causal mask below automatically hides a recycled page's stale tail.
 
-Every SHAPE is static (tables stay ``[lanes, pages_per_seq]``, a chunk
-is :data:`READ_CHUNK_TOKENS` tokens), so the scheduler admits and
-retires sequences every step without retracing; only the read loop's
-trip count is data (:func:`read_trip_count`, a ``while`` on the device).
+Every SHAPE is static (tables stay ``[lanes, pages_per_seq]``, a trip
+of the read takes :func:`read_trip_tokens` keys, a rule on the read's
+static shapes), so the scheduler admits and retires sequences every step
+without retracing; only the read loop's trip count is data
+(:func:`read_trip_count`, a ``while`` on the device).
+
+THE TRIP FOLLOWS THE VALUE WIDTH. Each trip rescales the whole float32
+accumulator (rows x ``d_v``) and forms the trip's logits (rows x keys):
+with the latent read's 512 value columns over 128-key trips the rescale
+moved four times the logits' bytes, three quarters of what a prefill
+read moved (PERF.md, PR 40). A trip takes the largest power-of-two
+multiple of :data:`READ_CHUNK_TOKENS` keys not above ``d_v``, so the
+rescale is no larger than the logits; the products, the keys and the
+precision are the same. A trip whose float32 logits would pass
+:data:`READ_TRIP_LOGIT_BYTES` is halved (the latent cell's 32-lane
+prefill chunk: 256 keys). OPT, the grouped-query read and EVA (``d_v``
+64 and 128) take 128, as before.
 
 Each primitive runs under a ``jax.named_scope`` (``attn.paged_write``,
 ``attn.paged_gather``, ``attn.paged_softmax``): metadata only, so that a
@@ -90,12 +103,15 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_gather", "paged_read_attention",
-           "read_chunk_pages", "read_trip_count", "read_rounds_chunk",
-           "eva_read_attention", "eva_read_trips", "READ_CHUNK_TOKENS"]
+           "read_trip_tokens", "read_chunk_pages", "read_trip_count",
+           "read_rounds_chunk", "eva_read_attention", "eva_read_trips",
+           "READ_CHUNK_TOKENS", "READ_TRIP_LOGIT_BYTES"]
 
-# tokens of K/V one trip of the read loop gathers (a whole number of
-# pages; the whole table where the window is shorter)
+# tokens of K/V one trip of the read loop gathers at the least (a whole
+# number of pages; the whole table where the window is shorter)
 READ_CHUNK_TOKENS = 128
+# the float32 logits one trip may hold before its keys are halved
+READ_TRIP_LOGIT_BYTES = 512 << 20
 
 
 def _write_targets(num_pages, page_size, page_table, write_slots):
@@ -235,18 +251,40 @@ def paged_gather(pool, page_table, heads, round_to=None):
     return g.reshape(s, heads, f // heads, p * page_size)
 
 
-def read_chunk_pages(page_size: int, pages_per_seq: int) -> int:
-    """Pages one trip of the read loop gathers."""
-    return min(max(1, READ_CHUNK_TOKENS // page_size), pages_per_seq)
+def read_trip_tokens(d_v: int = READ_CHUNK_TOKENS,
+                     logit_rows: int = 1) -> int:
+    """Keys one trip of a read whose value is ``d_v`` wide takes: the
+    largest power-of-two multiple of :data:`READ_CHUNK_TOKENS` not above
+    ``d_v`` (at least :data:`READ_CHUNK_TOKENS`), so that the rescale of
+    the accumulator, rows x ``d_v`` a trip, is no larger than the trip's
+    logits, rows x keys; and no more keys than keep the trip's float32
+    logits, ``logit_rows`` (lanes x K/V heads x query rows a head) x keys
+    x 4 B, within :data:`READ_TRIP_LOGIT_BYTES`."""
+    trip = READ_CHUNK_TOKENS
+    while (2 * trip <= d_v
+           and logit_rows * 2 * trip * 4 <= READ_TRIP_LOGIT_BYTES):
+        trip *= 2
+    return trip
+
+
+def read_chunk_pages(page_size: int, pages_per_seq: int,
+                     d_v: int = READ_CHUNK_TOKENS,
+                     logit_rows: int = 1) -> int:
+    """Pages one trip of the read loop gathers (:func:`read_trip_tokens`
+    keys, the whole table where it is shorter)."""
+    return min(max(1, read_trip_tokens(d_v, logit_rows) // page_size),
+               pages_per_seq)
 
 
 def read_trip_count(rel_pos, t_new: int, page_size: int,
-                    pages_per_seq: int, xp=jnp):
+                    pages_per_seq: int, xp=jnp,
+                    d_v: int = READ_CHUNK_TOKENS, logit_rows: int = 1):
     """Chunks the read of one dispatch visits: up to the furthest live
     position ``max(rel_pos) + t_new`` over its lanes, clamped to the
-    table. ``xp`` is ``jnp`` inside the program and ``numpy`` where the
-    engine counts the same number on the host."""
-    cp = read_chunk_pages(page_size, pages_per_seq)
+    table, in trips of :func:`read_chunk_pages` for ``d_v`` and
+    ``logit_rows``. ``xp`` is ``jnp`` inside the program and ``numpy``
+    where the engine counts the same number on the host."""
+    cp = read_chunk_pages(page_size, pages_per_seq, d_v, logit_rows)
     chunk = cp * page_size
     n_chunks = -(-pages_per_seq // cp)
     return xp.minimum((xp.max(rel_pos) + t_new + chunk - 1) // chunk,
@@ -263,7 +301,8 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
     The streaming-decode softmax of
     ``SelfAttentionLayer._apply_streaming`` (max-subtraction in f32,
     masked exp, 1e-30 denominator floor) as a running softmax over
-    chunks of :func:`read_chunk_pages` pages: trip ``c`` gathers
+    chunks of :func:`read_chunk_pages` pages (as many keys as the value
+    is wide, :func:`read_trip_tokens`): trip ``c`` gathers
     ``page_table[:, c·cp:(c+1)·cp]`` of K and of V, masks that chunk's
     logits by ``key_idx <= rel_pos + query offset`` and folds them into
     the running max ``m``, sum ``l`` and accumulator. The trip count is
@@ -306,14 +345,14 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
         t_new) else None
     d_v = d if v_width is None else v_width
     pages_per_seq = page_table.shape[1]
-    cp = read_chunk_pages(page_size, pages_per_seq)
-    chunk = cp * page_size
+    cp = read_chunk_pages(page_size, pages_per_seq, d_v, s * h * t_new)
     pad = -pages_per_seq % cp
     if pad:     # a last, partial chunk reads sentinel pages: zeros, masked
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)),
                              constant_values=num_pages)
     trips = read_trip_count(rel_pos, t_new // group, page_size,
-                            pages_per_seq)
+                            pages_per_seq, d_v=d_v,
+                            logit_rows=s * h * t_new)
     if group == 1:
         q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]  # [S, t_new]
     else:
@@ -378,21 +417,25 @@ def _softmax_end(carry, out_dtype):
 
 def eva_read_trips(rel_pos, positions, t_new: int, window: int,
                    chunk_size: int, page_size: int, window_pages: int,
-                   summary_pages: int, xp=jnp):
+                   summary_pages: int, xp=jnp, d_v: int = READ_CHUNK_TOKENS,
+                   logit_rows: int = 1):
     """The bounds of the EVA read of one dispatch (:func:`eva_read_attention`),
-    in trips of :func:`read_chunk_pages` pages: ``(first, end)`` over the
+    in trips of :func:`read_chunk_pages` pages (for ``d_v`` and
+    ``logit_rows``): ``(first, end)`` over the
     window pages, from the chunk of the earliest window start any lane's
     first query has to the furthest live position, and the trips over the
     summary pages, up to the most summary rows any query sees. ``rel_pos``
     view-relative (slot 0 is the lane's window start), ``positions``
     absolute, both ``[S]``; ``xp`` as for :func:`read_trip_count`, so that
     the engine counts on the host what the program reads."""
-    chunk = read_chunk_pages(page_size, window_pages) * page_size
+    chunk = read_chunk_pages(page_size, window_pages, d_v,
+                             logit_rows) * page_size
     start = positions // window * window - (positions - rel_pos)
     seen = xp.max((positions + t_new - 1) // window * window // chunk_size)
-    cs = read_chunk_pages(page_size, summary_pages)
+    cs = read_chunk_pages(page_size, summary_pages, d_v, logit_rows)
     return (xp.min(start) // chunk,
-            read_trip_count(rel_pos, t_new, page_size, window_pages, xp),
+            read_trip_count(rel_pos, t_new, page_size, window_pages, xp,
+                            d_v, logit_rows),
             xp.minimum((seen + cs * page_size - 1) // (cs * page_size),
                        -(-summary_pages // cs)))
 
@@ -434,12 +477,12 @@ def eva_read_attention(q, k_pool, v_pool, page_table, rel_pos, positions,
     q_rel = rel_pos[:, None] + jnp.arange(t_new)
     first, end, trips = eva_read_trips(
         rel_pos, positions, t_new, window, chunk_size, page_size, win,
-        page_table.shape[1] - win)
+        page_table.shape[1] - win, d_v=d, logit_rows=s * h * t_new)
     own = jnp.where(jnp.arange(win)[None, :] * page_size < start_rel[:, :1],
                     num_pages, page_table[:, :win])
 
     def loop(table, lo, hi, allow):
-        cp = read_chunk_pages(page_size, table.shape[1])
+        cp = read_chunk_pages(page_size, table.shape[1], d, s * h * t_new)
         pad = -table.shape[1] % cp
         if pad:     # a last, partial chunk reads sentinel pages: zeros, masked
             table = jnp.pad(table, ((0, 0), (0, pad)),

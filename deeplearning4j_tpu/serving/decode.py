@@ -81,7 +81,10 @@ in the loop (``decode_sched_wait_seconds{why}``); under a profiler
 session they are host spans on the device trace's clock. How far the
 paged read of each dispatch went is counted beside what its lanes'
 whole windows hold: ``decode_kv_read_tokens_total{kind}`` over
-``decode_kv_window_tokens_total{kind}``.
+``decode_kv_window_tokens_total{kind}``, each attention vertex with its
+own trip (``ops.paged_attention.read_trip_tokens``), and the part of it
+read in trips longer than 128 keys,
+``decode_kv_long_trip_tokens_total{kind}``.
 
 What a net makes the engine carry beside K/V pages, each found on the
 net and handed to the decode programs as one more argument, so that a net
@@ -106,7 +109,7 @@ import contextlib
 import threading
 import time
 import weakref
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -222,6 +225,18 @@ _PREFILL, _DECODE = "prefill", "decode"
 # a dispatch and its parts span 0.1 ms (an enqueue) to 1 s (a fused block)
 _TICK_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 1.0)
+
+
+def _read_shape(layer) -> Tuple[int, int, int]:
+    """``(group, kv_heads, d_v)`` of an attention vertex's paged read:
+    query heads a K/V head (a grouped-query vertex its group's, a latent
+    one all its heads, read as further rows of one K/V head), K/V heads,
+    and the value's width (a latent vertex's: its row's first ``kv_rank``
+    columns), which sets the read's trip
+    (``ops.paged_attention.read_trip_tokens``)."""
+    if isinstance(layer, MLAttentionLayer):
+        return layer.n_heads, 1, layer.kv_rank
+    return layer.n_heads // layer.kv_heads, layer.kv_heads, layer.head_dim
 
 
 def _module_name(ladder_key: str) -> str:
@@ -414,14 +429,16 @@ class PagedDecodeEngine:
             "dtype (ops.paged_attention.read_rounds_chunk: a query "
             "narrower than the pools, more than one query row a K/V "
             "head)", ("kind",))
-        # query heads a K/V head of each attention vertex's paged read (a
-        # grouped-query vertex reads its group's heads, a latent one all
-        # its heads, as further rows of one K/V head), target and draft
-        self._query_groups = {
-            draft: {layer.n_heads if isinstance(layer, MLAttentionLayer)
-                    else layer.n_heads // layer.kv_heads
-                    for layer in map(n._vertex_layer,
-                                     _transformer.attention_vertices(n))}
+        self._m_kv_long_trip = self.registry.counter(
+            "decode_kv_long_trip_tokens_total",
+            "The part of decode_kv_read_tokens_total read in trips longer "
+            "than ops.paged_attention.READ_CHUNK_TOKENS keys (a read whose "
+            "value is wider than that: read_trip_tokens)", ("kind",))
+        # the paged read of each attention vertex, (group, kv_heads, d_v)
+        # -> vertices that read so, target and draft
+        self._reads = {
+            draft: Counter(_read_shape(n._vertex_layer(v))
+                           for v in _transformer.attention_vertices(n))
             for draft, n in ((False, net), (True, draft_net))
             if n is not None}
         token_bytes = float(self.arena.token_nbytes())
@@ -1138,24 +1155,36 @@ class PagedDecodeEngine:
         pool_dtype = None if arena.kv_dtype == "int8" else arena.dtype
         return all(_paged.read_rounds_chunk(
             net.policy.compute_dtype, pool_dtype, t_new * g)
-            for g in self._query_groups[draft])
+            for g, _, _ in self._reads[draft])
 
     def _note_kv_read(self, kind: str, rel: np.ndarray, t_new: int,
                       steps: int = 1, draft: bool = False) -> None:
         """Account how far the paged read of one dispatch went: its
         trip count (the very helper the program's loop bound comes from,
-        on the same ``rel``) for each of the block's ``steps``, beside
-        the whole windows the lanes hold, and whether the read rounded
-        the chunks it gathered (``draft``: the draft net's read)."""
+        on the same ``rel``, with each vertex's own trip) for each of the
+        block's ``steps``, beside the whole windows the lanes hold; which
+        part of it went in trips longer than ``READ_CHUNK_TOKENS``, and
+        whether the read rounded the chunks it gathered (``draft``: the
+        draft net's read). Where vertices take different trips the counts
+        are their mean: the read of one vertex."""
         if self._warming:
             return
-        chunk = self.page_size * _paged.read_chunk_pages(
-            self.page_size, self.pages_per_seq)
-        visited = sum(
-            min(self.window, chunk * int(_paged.read_trip_count(
-                rel + i, t_new, self.page_size, self.pages_per_seq, xp=np)))
-            for i in range(steps))
+        ps, pps = self.page_size, self.pages_per_seq
+        reads = self._reads[draft]
+        visited = long_trip = 0
+        for (g, h, d_v), count in reads.items():
+            rows = len(rel) * h * t_new * g
+            chunk = ps * _paged.read_chunk_pages(ps, pps, d_v, rows)
+            seen = count * sum(
+                min(self.window, chunk * int(_paged.read_trip_count(
+                    rel + i, t_new, ps, pps, np, d_v, rows)))
+                for i in range(steps))
+            visited += seen
+            long_trip += seen if chunk > _paged.READ_CHUNK_TOKENS else 0
+        vertices = max(1, sum(reads.values()))  # state-space only: 0
+        visited, long_trip = visited / vertices, long_trip / vertices
         self._m_kv_read.inc(len(rel) * visited, kind=kind)
+        self._m_kv_long_trip.inc(len(rel) * long_trip, kind=kind)
         self._m_kv_window.inc(len(rel) * self.window * steps, kind=kind)
         if self._read_rounds_chunk(t_new, draft):
             self._m_kv_rounded.inc(len(rel) * visited, kind=kind)
@@ -1230,21 +1259,25 @@ class PagedDecodeEngine:
         pos, n = rel + base, np.asarray(written, np.int64)
         ps, chunk, window = self.page_size, self._eva_chunk, self._eva_window
         layers, lanes_live = len(self.eva_layers), int(live.sum())
-        rows_w = ps * _paged.read_chunk_pages(ps, self._eva_win_pages)
-        rows_s = ps * _paged.read_chunk_pages(ps, self._eva_sum_pages)
         window_rows = summary_rows = 0
-        for i in range(steps):
-            first, end, trips = _paged.eva_read_trips(
-                rel + i, pos + i, t_new, window, chunk, ps,
-                self._eva_win_pages, self._eva_sum_pages, xp=np)
-            window_rows += lanes_live * max(0, int(end - first)) * rows_w
-            summary_rows += lanes_live * int(trips) * rows_s
-        self._m_eva_rows.inc(window_rows * layers, part="window")
-        self._m_eva_rows.inc(summary_rows * layers, part="summary")
+        for (_, h, d_v), count in self._reads[False].items():
+            rows = len(rel) * h * t_new
+            rows_w = ps * _paged.read_chunk_pages(
+                ps, self._eva_win_pages, d_v, rows)
+            rows_s = ps * _paged.read_chunk_pages(
+                ps, self._eva_sum_pages, d_v, rows)
+            for i in range(steps):
+                first, end, trips = _paged.eva_read_trips(
+                    rel + i, pos + i, t_new, window, chunk, ps,
+                    self._eva_win_pages, self._eva_sum_pages, np, d_v, rows)
+                window_rows += (count * lanes_live
+                                * max(0, int(end - first)) * rows_w)
+                summary_rows += count * lanes_live * int(trips) * rows_s
+        self._m_eva_rows.inc(window_rows, part="window")
+        self._m_eva_rows.inc(summary_rows, part="summary")
         row_bytes = 2 * int(self.arena.k_pools[0].shape[-1]) \
             * self.arena.k_pools[0].dtype.itemsize
-        self._m_eva_bytes.inc((window_rows + summary_rows) * layers
-                              * row_bytes)
+        self._m_eva_bytes.inc((window_rows + summary_rows) * row_bytes)
         pos, n, idx = pos[live], n[live], np.asarray(lanes)[live]
         self._m_eva_written.inc(
             int(((pos + n) // chunk - pos // chunk).sum()) * layers)

@@ -419,7 +419,9 @@ def test_prefix_reuse_and_speculative_decoding_are_refused(net):
 # latent engines on the commit before ISSUE 39 (e8e41ba), from the same
 # construction run in this suite on that checkout (interpreted kernels:
 # the expert layers' grouped products are Pallas calls in these texts); the
-# EVA layer must change none of them
+# EVA layer must change none of them. The ``@`` entries, at windows of
+# several read trips, on the commit before ISSUE 40 (184276e): a trip that
+# follows the value width changes no read whose value is 128 wide or less
 PARENT_TEXT = {
     "nemotron_h-small": {
         "fused_decode[S1xN4xP32]":
@@ -459,12 +461,47 @@ PARENT_TEXT = {
         "paged_decode[S2xT1xP32]":
             "21e192bb0c211aaaaa8e19a4de2baea6f064d73e4fd2efed1f517731c86e2adc",
     },
+    "evabyte-small@2048": {
+        "fused_decode[S1xN4xP13]":
+            "f3983d396707cc69db0ee0a5fe73e5a886e6298e4afe221a1b87344b7813f735",
+        "fused_decode[S2xN4xP13]":
+            "a4fbcda3fb4215fc7a05de5346e2450905f0815395650ea6682960983be2eb37",
+        "paged_decode[S1xT16xP13]":
+            "434bcd7b18fbf9e9d1c7dfc662f2c3095530b3a39f46260ae5c2dfef70fa6591",
+        "paged_decode[S2xT16xP13]":
+            "da675cf35fc0cf3629baa75602bc51d727feec7ff4c439dde74e913b77ed4de3",
+    },
+    "nemotron_h-small@1024": {
+        "fused_decode[S1xN4xP256]":
+            "5dc56f7b6eb8b2792267d48ca53b89918e6e389f7d6fdc08e9b58749369742c0",
+        "fused_decode[S2xN4xP256]":
+            "4e7b573264acfa5e5b49be6e1b902606a9c9752d51e7151c908479c264215484",
+        "paged_decode[S1xT16xP256]":
+            "ba57cc089cf2f8900d695e1338a3d03002ff27f5b01a3e2880ddc948653dcc1a",
+        "paged_decode[S2xT16xP256]":
+            "9ae7171bb48144da38e7e3f58b2b9d0745f96e44d7cc0a8eea790e73a3e78ffc",
+    },
+    "opt@1024": {
+        "fused_decode[S1xN4xP256]":
+            "4ad580adbd2c9c263410002c15761d8a644d0ba4fe4bf361cffe4b2b9a91d6a4",
+        "fused_decode[S2xN4xP256]":
+            "5546002870032187de02601a566c11d5de5a734e7f7a482388d323282890f632",
+        "paged_decode[S1xT16xP256]":
+            "740b963f7ab70b10ffb1ec8f094e3f356438bc6dfd012df1c5f4193f6f7625bd",
+        "paged_decode[S1xT1xP256]":
+            "67a100a5b6304b194c67f63411949148b83d2d3a26d755ba184dc6ece30920be",
+        "paged_decode[S2xT16xP256]":
+            "ea08bc51d4efc8ca200e74fe83339134de5ffd7b67d48533cce0cc85aa9197fb",
+        "paged_decode[S2xT1xP256]":
+            "0d071b5e616d5b6b95599bb439074c24167df6eaa4c773fb07501a85a80850b8",
+    },
 }
 
 
 def _programs(tag):
     """name -> sha256 of the lowered text of every program the warm-up of
-    the ``tag`` engine dispatches."""
+    the ``tag`` engine dispatches: ``<net>`` at a window of 128 tokens,
+    ``<net>@<window>`` at a longer one (a read of several trips)."""
     seen = {}
     real = PagedDecodeEngine._dispatch
 
@@ -475,25 +512,26 @@ def _programs(tag):
             seen[name] = hashlib.sha256(text.encode()).hexdigest()
         return real(self, name, step, arena, params, args, **kw)
 
-    window = 128
-    engine = dict(max_batch=2, page_size=4, pages_per_seq=window // 4,
-                  prefill_chunk=16, block_len=4)
-    if tag == "opt":
+    kind, _, window = tag.partition("@")
+    window = int(window or 128)
+    page_size = C if kind == "evabyte-small" else 4
+    engine = dict(max_batch=2, page_size=page_size,
+                  pages_per_seq=window // page_size, prefill_chunk=16,
+                  block_len=4)
+    if kind == "opt":
         net = ComputationGraph(transformer_lm(
             53, n_layers=2, d_model=16, n_heads=2, d_ff=32, seed=3,
             input_ids=True, max_cache_t=window)).init()
         engine["prefix_cache"] = True
     else:
-        with open(os.path.join(DATA, f"{tag}.json")) as f:
+        with open(os.path.join(DATA, f"{kind}.json")) as f:
             cfg = json.load(f)
         net = build_net(common.load_family(cfg), cfg, 3, max_cache_t=window)
-        engine["prefix_cache"] = tag == "pangu-small"
+        engine["prefix_cache"] = kind == "pangu-small"
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(PagedDecodeEngine, "_dispatch", recording)
         PagedDecodeEngine(net, **engine).warmup()
     return seen
-
-
 
 
 @pytest.mark.parametrize("tag", sorted(PARENT_TEXT))

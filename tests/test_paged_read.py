@@ -4,7 +4,10 @@ replaced, the trip count on host and device, the inner ``while`` in the
 lowered fused block, and the two counters that say how much of the window
 a dispatch visits (``paged_read_window_share`` of the benchmark), and
 the rounding of the gathered chunk where the products would round K/V
-(``paged_read_chunk_rounded_share``)."""
+(``paged_read_chunk_rounded_share``); the trip that follows the value
+width (``read_trip_tokens``: a latent read's 512 keys, the guard's
+halving) and the counter of the keys read in such trips
+(``paged_read_long_trip_share``)."""
 
 import json
 import os
@@ -17,23 +20,28 @@ import pytest
 from deeplearning4j_tpu.models import nemotron_h_lm
 from deeplearning4j_tpu.models.transformer import transformer_lm
 from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
+from deeplearning4j_tpu.ops import paged_attention as _paged
 from deeplearning4j_tpu.ops.paged_attention import (
     READ_CHUNK_TOKENS, paged_gather, paged_read_attention, paged_write,
-    read_chunk_pages, read_rounds_chunk, read_trip_count)
+    read_chunk_pages, read_rounds_chunk, read_trip_count, read_trip_tokens)
 from deeplearning4j_tpu.serving.decode import (DecodeScheduler,
                                                PagedDecodeEngine)
 
 H, D = 2, 8
 
 
-def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale):
+def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale,
+                     group=1, v_width=None):
     """What ``apply_paged`` did before the bounded read: gather every
-    lane's whole table, then mask (kept here as the test's reference)."""
-    k_view = paged_gather(k_pool, page_table, H)        # [S, h, d, window]
-    v_view = paged_gather(v_pool, page_table, H)
+    lane's whole table, then mask (kept here as the test's reference).
+    ``group`` and ``v_width`` as for ``paged_read_attention``."""
+    heads = q.shape[2]
+    k_view = paged_gather(k_pool, page_table, heads)    # [S, h, d, window]
+    v_view = (paged_gather(v_pool, page_table, heads) if v_width is None
+              else k_view[:, :, :v_width])
     t_new, w = q.shape[1], k_view.shape[-1]
     logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_view) * scale
-    q_idx = rel_pos[:, None] + jnp.arange(t_new)[None, :]
+    q_idx = rel_pos[:, None] + (jnp.arange(t_new) // group)[None, :]
     allow = jnp.arange(w)[None, None, :] <= q_idx[:, :, None]
     logits = jnp.where(allow[:, None], logits.astype(jnp.float32), -jnp.inf)
     m = jnp.max(logits, axis=-1, keepdims=True)
@@ -43,13 +51,14 @@ def full_window_read(q, k_pool, v_pool, page_table, rel_pos, scale):
     return jnp.einsum("bhqk,bhdk->bqhd", weights.astype(q.dtype), v_view)
 
 
-def _arena(rng, lanes, page_size, pages_per_seq, live, *, int8=False):
+def _arena(rng, lanes, page_size, pages_per_seq, live, *, int8=False,
+           h=H, d=D):
     """Random pools and a table whose lane ``i`` holds ``live[i]`` tokens
     (the pages beyond are sentinels); a stale tail is left in each last
     page, as a recycled page would have."""
     num_pages = lanes * pages_per_seq + 3
-    k = rng.standard_normal((num_pages, page_size, H, D)).astype(np.float32)
-    v = rng.standard_normal((num_pages, page_size, H, D)).astype(np.float32)
+    k = rng.standard_normal((num_pages, page_size, h, d)).astype(np.float32)
+    v = rng.standard_normal((num_pages, page_size, h, d)).astype(np.float32)
     table = np.full((lanes, pages_per_seq), num_pages, np.int32)
     perm = rng.permutation(num_pages)
     at = 0
@@ -183,17 +192,101 @@ TRIP_CASES = [
     ([31], 1, 4, 8, 1),                # window under one chunk
     ([600], 1, 256, 3, 3),             # a page larger than the chunk
 ]
+# the same, with the read's value width and float32 logit rows (lanes x
+# K/V heads x query rows a head): the latent cell's window of 512 pages
+LONG_TRIP_CASES = [
+    # rel_pos, t_new, page_size, pages_per_seq, trips, d_v, logit_rows
+    ([600], 1, 16, 512, 2, 512, 32 * 128),           # decode: 512 keys
+    ([6000], 128, 16, 512, 12, 512, 16 * 16384),     # prefill, 16 lanes
+    ([6000], 128, 16, 512, 24, 512, 32 * 16384),     # 32 lanes: 256 keys
+    ([1279], 1, 16, 80, 3, 512, 4),                  # 80 pages: 32, 32, 16
+    ([4000], 1, 16, 80, 3, 512, 4),                  # clamped to the table
+    ([600], 1, 16, 512, 5, 128, 32 * 32),            # a value of 128
+]
 
 
-@pytest.mark.parametrize("rel,t_new,page_size,pages_per_seq,trips",
-                         TRIP_CASES)
+@pytest.mark.parametrize(
+    "rel,t_new,page_size,pages_per_seq,trips,d_v,logit_rows",
+    [case + (READ_CHUNK_TOKENS, 1) for case in TRIP_CASES] + LONG_TRIP_CASES)
 def test_trip_count_same_on_host_and_device(rel, t_new, page_size,
-                                            pages_per_seq, trips):
+                                            pages_per_seq, trips, d_v,
+                                            logit_rows):
     rel = np.asarray(rel, np.int32)
-    host = read_trip_count(rel, t_new, page_size, pages_per_seq, xp=np)
+    host = read_trip_count(rel, t_new, page_size, pages_per_seq, np, d_v,
+                           logit_rows)
     dev = jax.jit(lambda r: read_trip_count(r, t_new, page_size,
-                                            pages_per_seq))(rel)
+                                            pages_per_seq, d_v=d_v,
+                                            logit_rows=logit_rows))(rel)
     assert int(host) == int(dev) == trips
+
+
+# the trip of each serving cell's read (`benchmarks/configs`): value width,
+# float32 logit rows (lanes x K/V heads x query rows a head), keys a trip
+READ_TRIPS = {
+    "opt_prefill": (64, 8 * 32 * 128, 128),
+    "opt_fused_step": (64, 8 * 32, 128),
+    "hybrid_prefill": (128, 32 * 2 * 16 * 128, 128),
+    "eva_prefill": (128, 8 * 32 * 128, 128),
+    "latent_fused_step": (512, 32 * 128, 512),
+    "latent_prefill_16_lanes": (512, 16 * 128 * 128, 512),
+    # 32 lanes x 128 tokens x 128 heads: 1 GiB of logits at 512 keys
+    "latent_prefill_32_lanes": (512, 32 * 128 * 128, 256),
+    "value_640": (640, 1, 512),
+    "value_1024": (1024, 1, 1024),
+    "value_200": (200, 1, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(READ_TRIPS))
+def test_the_trip_follows_the_value_width(case):
+    """The largest power-of-two multiple of 128 keys not above the value
+    width, halved while the trip's float32 logits pass 512 MiB."""
+    d_v, rows, keys = READ_TRIPS[case]
+    assert read_trip_tokens(d_v, rows) == keys
+    assert rows * keys * 4 <= _paged.READ_TRIP_LOGIT_BYTES or keys == 128
+    assert read_chunk_pages(16, 512, d_v, rows) == keys // 16
+
+
+@pytest.fixture
+def short_trips(monkeypatch):
+    """The read held to 128-key trips: no logits fit the guard."""
+    paged_read_attention.clear_cache()
+    monkeypatch.setattr(_paged, "READ_TRIP_LOGIT_BYTES", 0)
+    yield
+    paged_read_attention.clear_cache()
+
+
+@pytest.mark.parametrize("t_new", [1, 8])
+def test_a_latent_read_in_512_key_trips(t_new, request):
+    """The latent read's shape (4 query heads on one K/V head of 640
+    columns, the value its first 512) over 80 pages: trips of 32 pages
+    and a last of 16, lanes at the start, at the end and inside. Against
+    the whole window read at once, and against the same read made in
+    128-key trips."""
+    group, d, v_width, page_size, pages_per_seq = 4, 640, 512, 16, 80
+    window = page_size * pages_per_seq
+    rng = np.random.default_rng(11 + t_new)
+    rel = np.array([0, window - t_new, 700], np.int32)
+    k, _, table = _arena(rng, 3, page_size, pages_per_seq, rel + t_new,
+                         h=1, d=d)
+    q = jnp.asarray(rng.standard_normal((3, t_new * group, 1, d)),
+                    jnp.float32)
+    scale = jnp.float32(1.0 / np.sqrt(d))
+    assert read_chunk_pages(page_size, pages_per_seq, v_width,
+                            3 * t_new * group) == 32
+
+    def read():
+        return np.asarray(paged_read_attention(
+            q, k, None, table, jnp.asarray(rel), scale, group=group,
+            v_width=v_width))
+    got = read()
+    want = full_window_read(q, k, None, table, jnp.asarray(rel), scale,
+                            group=group, v_width=v_width)
+    assert got.shape == (3, t_new * group, 1, v_width)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-6)
+    request.getfixturevalue("short_trips")
+    assert read_chunk_pages(page_size, pages_per_seq, v_width, 1) == 8
+    np.testing.assert_allclose(got, read(), rtol=2e-5, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -420,16 +513,24 @@ def test_speculative_kinds_are_counted(net):
     assert window["draft"] == 3 * window["verify"]
 
 
-def test_benchmark_metric_names_counters_the_registry_has(net):
+METRIC_CELLS = {
+    "paged_read_window_share": ["serve-opt-chat", "serve-opt-docqa",
+                                "serve-pangu-longdoc"],
+    "paged_read_long_trip_share": ["serve-opt-chat", "serve-opt-docqa",
+                                   "serve-nemotron3-reason",
+                                   "serve-pangu-longdoc"],
+}
+
+
+def _metric(name):
     path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "metrics", "paged_read_window_share.json")
+                        "metrics", f"{name}.json")
     with open(path) as f:
-        metric = json.load(f)
-    assert metric["name"] == "paged_read_window_share"
-    assert metric["layer"] == "paged read"
-    assert metric["moves"] == "serve_tokens_per_s"
-    assert metric["reader"]["kind"] == "counter_ratio"
-    eng = _serve(net, [[1, 2, 3]], [4])
+        return json.load(f)
+
+
+def _metric_share(metric, eng):
+    """The metric's ``counter_ratio`` over the engine's registry."""
     values = {}
     for side in ("num", "den"):
         spec = metric["reader"][side]
@@ -439,17 +540,64 @@ def test_benchmark_metric_names_counters_the_registry_has(net):
         values[side] = sum(
             s["value"] for s in family.snapshot()["series"]
             if all(s["labels"].get(k) == x for k, x in want.items()))
-    share = metric["reader"]["scale"] * values["num"] / values["den"]
-    assert 0 < share < 100
-    with open(os.path.join(os.path.dirname(path), "..", "..",
+    return metric["reader"]["scale"] * values["num"] / values["den"]
+
+
+@pytest.mark.parametrize("name", sorted(METRIC_CELLS))
+def test_benchmark_metric_names_counters_the_registry_has(net, name):
+    metric = _metric(name)
+    assert metric["name"] == name
+    assert metric["layer"] == "paged read"
+    assert metric["moves"] == "serve_tokens_per_s"
+    assert metric["reader"]["kind"] == "counter_ratio"
+    share = _metric_share(metric, _serve(net, [[1, 2, 3]], [4]))
+    if name == "paged_read_long_trip_share":
+        assert share == 0               # OPT-like heads of 8: 128-key trips
+    else:
+        assert 0 < share < 100
+    with open(os.path.join(os.path.dirname(__file__), "..",
                            "BENCHMARK.json")) as f:
         entry = [m for m in json.load(f)["per_layer"]
                  if m["name"] == metric["name"]]
     assert len(entry) == 1
-    assert entry[0]["workloads"] == ["serve-opt-chat", "serve-opt-docqa",
-                                     "serve-pangu-longdoc"]
+    assert entry[0]["workloads"] == METRIC_CELLS[name]
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[0][key] == metric[key]
+
+
+@pytest.fixture(scope="module")
+def latent_net():
+    """The small latent-attention net of ``test_pangu.py`` with a value
+    512 wide, as the latent cell's (``kv_lora_rank``)."""
+    from test_pangu import SMALL, build_net, common
+    cfg = dict(SMALL, kv_lora_rank=512)
+    return build_net(common.load_family(cfg), cfg, 3, max_cache_t=1024)
+
+
+def test_a_latent_engine_counts_its_512_key_trips(latent_net, net):
+    """``_note_kv_read`` counts with the vertex's own trip: lanes x 512 x
+    trips, all of it in long trips; an OPT-like engine's long-trip counter
+    stays 0."""
+    eng = PagedDecodeEngine(latent_net, max_batch=2, page_size=16,
+                            pages_per_seq=64, prefill_chunk=16,
+                            block_len=1, prefix_cache=False)
+    read = eng.registry.get("decode_kv_read_tokens_total")
+    long_trip = eng.registry.get("decode_kv_long_trip_tokens_total")
+    tables = np.full((2, 64), eng.arena.sentinel, np.int32)
+    zi = np.zeros(2, np.int32)
+    eng.run(np.zeros((2, 1), np.int32), np.full((2, 1), -1, np.int32),
+            np.array([600, 10], np.int32), tables, zi)
+    assert read.value(kind="paged") == 2 * 512 * 2           # 601 keys
+    eng.run(np.zeros((2, 16), np.int32), np.full((2, 16), -1, np.int32),
+            np.array([0, 100], np.int32), tables, zi)
+    assert read.value(kind="paged") == 2 * 512 * 2 + 2 * 512 * 1
+    assert long_trip.value(kind="paged") == read.value(kind="paged")
+    assert _metric_share(_metric("paged_read_long_trip_share"), eng) == 100
+    opt = _serve(net, [[1, 2, 3, 4, 5]], [6])
+    assert opt.registry.get("decode_kv_read_tokens_total").value(
+        kind="paged") > 0
+    assert opt.registry.get("decode_kv_long_trip_tokens_total").value(
+        kind="paged") == 0
 
 
 @pytest.fixture(scope="module")

@@ -6,8 +6,9 @@
   a call's argument (its jaxpr, on the CPU);
 - compiled for a described TPU v5e, the prefill and the fused program
   hold no copy and no convert whose shape is a pool's, and the read at
-  each serving cell's decode shape carries its pools in float32 (skipped
-  where no TPU topology can be described);
+  each serving cell's decode shape carries its pools in float32, and the
+  latent cell's largest prefill and fused programs fit the chip with
+  their read's trips (skipped where no TPU topology can be described);
 - ``paged_write``, ``paged_gather`` and ``paged_read_attention`` give,
   bit for bit, what the ``[num_pages, page_size, h, d]`` formulation they
   replaced gave (kept below as the reference; the gather's result has
@@ -316,6 +317,88 @@ def test_compiled_for_v5e_the_read_rounds_no_pool(one_chip, cell):
     carried = [dt for carry in re.findall(r"= (\(.*?\)) while\(", text)
                for dt in re.findall(r"(\w+)\[" + shape + r"\]", carry)]
     assert carried == ["f32"] * (1 if latent else 2)
+
+
+# the latent cell's largest buckets (`benchmarks/configs/openpangu-ultra-
+# moe-718b.json`): program, lanes, tokens a lane (a prefill chunk; a fused
+# block's steps), the read's float32 logit cap (None: the program's own)
+LATENT_BUCKETS = {
+    "prefill_S32xT128": ("prefill", 32, 128, None),
+    "fused_S32xN8": ("fused", 32, 8, None),
+    # the same prefill with the read's guard lifted: 512-key trips
+    "prefill_S32xT128_unguarded": ("prefill", 32, 128, 1 << 40),
+}
+LATENT_PEAK_GIB = 14.9
+
+
+@pytest.mark.parametrize("bucket", sorted(LATENT_BUCKETS))
+def test_compiled_for_v5e_the_latent_programs_fit(one_chip, bucket, request,
+                                                  monkeypatch):
+    """The latent cell's whole 5-layer programs at its sizes (weights in
+    bfloat16, five float32 latent pools of 12,288 pages, 32 lanes over
+    512 pages; the expert layers' Pallas kernel compiled, as on the chip):
+    arguments plus temporaries stay at or under 14.9 GiB of the chip's
+    15.75. A trip that follows the value width (512 keys) widens the
+    trip's logits to 1 GiB in the 32-lane prefill chunk, whose peak then
+    reads 15.00 GiB (13.58 with 128-key trips; TPU compiler here, PR 40):
+    the guard halves that bucket's trip (256 keys: 13.96 GiB, temporaries
+    2.45 GiB; the fused block's 0.53)."""
+    from test_pangu import ROOT, common
+    import json
+    import os
+    from deeplearning4j_tpu.util import xla as _xla
+    kind, lanes, t, cap = LATENT_BUCKETS[bucket]
+    monkeypatch.setattr(_xla, "kernel_mode", lambda: "mosaic")
+    if cap is not None:             # a trace of its own, and none left
+        paged_read_attention.clear_cache()
+        monkeypatch.setattr(_paged, "READ_TRIP_LOGIT_BYTES", cap)
+        request.addfinalizer(paged_read_attention.clear_cache)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "openpangu-ultra-moe-718b.json")) as f:
+        cfg = json.load(f)
+    eng = cfg["engine"]
+    pps, ps = eng["pages_per_seq"], eng["page_size"]
+    conf = common.load_family(cfg).build_conf(cfg, 0, max_cache_t=pps * ps)
+    net = ComputationGraph(conf)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, lane = jnp.int32, lambda dt: arg((lanes,), dt)      # noqa: E731
+    with jax.enable_x64(False):     # as on the chip; the suite runs with x64
+        params = jax.tree_util.tree_map(
+            lambda a: arg(a.shape, jnp.bfloat16 if a.dtype == jnp.float32
+                          else a.dtype),
+            jax.eval_shape(lambda: ComputationGraph(conf).init().params))
+        pools = [arg((eng["num_pages"], ps,
+                      net._vertex_layer(v).pool_width), jnp.float32)
+                 for v in _transformer.attention_vertices(net)]
+        if kind == "prefill":
+            def step(params, k, v, ids, tables, ws, rel, rows, pos, fed):
+                return _transformer.paged_decode_forward(
+                    net, params, k, v, ids, tables, ws, rel, counts=[],
+                    out_rows=rows, positions=pos, fed=fed)
+            args = (arg((lanes, t), i32), arg((lanes, pps), i32),
+                    arg((lanes, t), i32), lane(i32), lane(i32), lane(i32),
+                    lane(i32))
+        else:
+            def step(params, k, v, *a):
+                *a, pos = a
+                return _transformer.fused_decode_loop(net, params, k, v, *a,
+                                                      positions=pos)
+            args = (lane(i32), arg((lanes, pps), i32), lane(i32),
+                    lane(bool), lane(i32), lane(i32), lane(jnp.float32),
+                    lane(i32), lane(jnp.float32),
+                    arg((lanes, t), jnp.float32), lane(i32))
+        mem = jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, pools, [None] * len(pools), *args).compile(
+            ).memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    if cap is None:
+        assert peak <= LATENT_PEAK_GIB, (bucket, peak)
+    else:
+        assert peak > LATENT_PEAK_GIB, (bucket, peak)
 
 
 @pytest.mark.parametrize("bh, t, d, calls", [

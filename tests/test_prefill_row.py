@@ -266,6 +266,14 @@ PARENT_TEXT = {
         "dea99889da15cbec38d2f9e69ca86e91f8bd7bb88687d500b2a59f04e0dcdb16",
     "spec_draft[S2xK2xP32]":
         "cca4afce2026abaa1014f9085044ad46639705726db6797b48eb3eb03e27595d",
+    # the paged programs, on the commit before ISSUE 40 (184276e): the
+    # read's trip follows the value width, and OPT's 64 keeps 128 keys
+    "draft_prefill[S2xT16xP32]":
+        "e6ba8741fbbf7e5641477a2b1a6f3f3a188ba9b148b99270e9de7be4b7a6b022",
+    "paged_decode[S2xT16xP32]":
+        "bd652579f0dfb776e6e5d6508d6cd87d6e8f7bc95612c0130f6cbb0097d637f4",
+    "paged_decode[S2xT1xP32]":
+        "e37fd422415ea27593f0afbfddb008ec5d06ed36728123af762e72f0f60118f5",
 }
 
 
