@@ -16,8 +16,9 @@ from .transformer import draft_transformer_lm, generate, transformer_lm
 from .nemotron_h import nemotron_h_lm
 from .pangu import pangu_ultra_moe_lm
 from .evabyte import evabyte_lm
+from .solar_open2 import solar_open2_lm
 
 __all__ = ["lenet", "resnet", "resnet50", "resnet_tiny", "char_rnn_lstm",
            "alexnet", "vgg16", "deep_autoencoder", "transformer_lm",
            "draft_transformer_lm", "generate", "nemotron_h_lm",
-           "pangu_ultra_moe_lm", "evabyte_lm"]
+           "pangu_ultra_moe_lm", "evabyte_lm", "solar_open2_lm"]

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..nn.conf.attention import SelfAttentionLayer
 from ..nn.conf.eva import EvaAttentionLayer
+from ..nn.conf.kda import KimiDeltaAttention
 from ..nn.conf.mla import MLAttentionLayer
 from ..nn.conf.builders import NeuralNetConfiguration
 from ..nn.conf.graph import ElementWiseVertex, LayerVertex
@@ -153,19 +154,27 @@ def attention_vertices(net) -> List[str]:
 def stateful_vertices(net) -> List[str]:
     """Topo-ordered names of the vertices that own state during paged
     decode, one ordered list for the walker, the arena and the engine:
-    causal attention (a K and a V pool; a latent layer one pool) and
-    state-space mixers (a convolution tail and an SSM state a lane)."""
+    causal attention (a K and a V pool; a latent layer one pool) and the
+    layers that say they hold a fixed-size row of state a lane
+    (``holds_lane_state``: Mamba-2's convolution tail and SSM state, Kimi
+    delta attention's convolution tail and delta-rule state)."""
     attn = set(attention_vertices(net))
     return [name for name in net.topo_order
             if name in attn
-            or isinstance(net._vertex_layer(name), _ssm.Mamba2Mixer)]
+            or getattr(net._vertex_layer(name), "holds_lane_state", False)]
 
 
-def state_space_vertices(net) -> List[str]:
+def lane_state_vertices(net) -> List[str]:
     """Those of :func:`stateful_vertices` whose state is a fixed-size row a
     lane, not pages."""
     return [name for name in stateful_vertices(net)
-            if isinstance(net._vertex_layer(name), _ssm.Mamba2Mixer)]
+            if getattr(net._vertex_layer(name), "holds_lane_state", False)]
+
+
+def kda_vertices(net) -> List[str]:
+    """Names of the Kimi delta attention vertices (``nn/conf/kda``)."""
+    return [name for name in lane_state_vertices(net)
+            if isinstance(net._vertex_layer(name), KimiDeltaAttention)]
 
 
 def position_vertices(net) -> List[str]:
@@ -320,9 +329,10 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     block pool, read and written through the lanes' page tables
     (``SelfAttentionLayer.apply_paged``; an EVA vertex's table holds its
     window pages and then its summary pages, ``EvaAttentionLayer.
-    apply_paged``); a state-space vertex owns a
-    ``[lanes, K-1, C]`` convolution tail and a ``[lanes, H, P, N]`` SSM
-    state (``Mamba2Mixer.apply_paged``), of which the dispatch's rows are
+    apply_paged``); a vertex with per-lane state owns a ``[lanes, K-1,
+    C]`` convolution tail and a ``[lanes, ...]`` recurrent state (an SSM
+    state, ``Mamba2Mixer.apply_paged``; a delta-rule state,
+    ``KimiDeltaAttention.apply_paged``), of which the dispatch's rows are
     ``lane_ids``. Pure w.r.t. its arguments, so the serving engine jits it
     once per (lanes, chunk) bucket and admission/retirement only ever
     change array CONTENTS.
@@ -330,10 +340,10 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     ids: ``[S, t_new]`` int32 (padded lanes: any value — their writes are
     dropped and their outputs ignored); page_tables: ``[S, P]``;
     write_slots: ``[S, t_new]`` view-relative slots (-1 = dropped);
-    rel_pos: ``[S]``; lane_ids: ``[S]`` (only a net with state-space
-    vertices needs them; a padded slot of the bucket holds an id past the
-    last lane; None where the caller hands the state-space vertices'
-    entries in as the dispatch's own rows, ``Mamba2Mixer.apply_paged``).
+    rel_pos: ``[S]``; lane_ids: ``[S]`` (only a net with per-lane state
+    needs them; a padded slot of the bucket holds an id past the last
+    lane; None where the caller hands those vertices' entries in as the
+    dispatch's own rows, ``Mamba2Mixer.apply_paged``).
 
     Two masks over the ``[S, t_new]`` positions. A position's WRITE IS
     KEPT where its write slot is not -1: a recurrent state advances over
@@ -370,9 +380,9 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     k_pools, v_pools = list(k_pools), list(v_pools)
     acts = {net.conf.network_inputs[0]: ids[:, :, None]}
     mbs = net._minibatch_map(ids.shape[0])
-    # a position is valid where its write is kept: what a state-space
+    # a position is valid where its write is kept: what a per-lane state
     # vertex advances over and an expert layer counts
-    valid = (write_slots >= 0 if state_space_vertices(net)
+    valid = (write_slots >= 0 if lane_state_vertices(net)
              or counting_vertices(net) else None)
     computed = valid if fed is None else (
         jnp.arange(ids.shape[1], dtype=fed.dtype)[None, :] < fed[:, None])
@@ -474,7 +484,7 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     net with expert layers that count their routing returns the counts
     summed over the block's steps after ``done``
     (:func:`paged_decode_forward`), and the ``while`` carries them, as it
-    carries a state-space vertex's state in the two state lists
+    carries a per-lane state vertex's state in the two state lists
     (``lane_ids``): a retired lane's dropped slot keeps its state still.
     ``positions [S]`` (a net with :func:`position_vertices`): each lane's
     absolute position at the block's first step; step ``i`` is at
@@ -494,13 +504,13 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     n_steps = uniforms.shape[1]
     s = last_tokens.shape[0]
     any_sampled = jnp.any(temperature > 0)
-    # a state-space vertex's per-lane state: the block's lanes are the
+    # a vertex's per-lane state: the block's lanes are the
     # same at every step, so their rows are gathered once here, carried
     # through the steps as they are, and scattered back once at the end
     k_pools, v_pools = list(k_pools), list(v_pools)
     owners = stateful_vertices(net)
     state_ix = [] if lane_ids is None else [
-        owners.index(name) for name in state_space_vertices(net)]
+        owners.index(name) for name in lane_state_vertices(net)]
     whole = {i: (k_pools[i], v_pools[i]) for i in state_ix}
     for i in state_ix:
         k_pools[i], v_pools[i] = _ssm.gather_lanes(k_pools[i], v_pools[i],

@@ -11,6 +11,7 @@ from .multi_layer import MultiLayerConfiguration
 from . import attention as _attention  # noqa: F401  (serde registration)
 from . import mla as _mla  # noqa: F401  (serde registration)
 from . import eva as _eva  # noqa: F401  (serde registration)
+from . import kda as _kda  # noqa: F401  (serde registration)
 from . import moe as _moe  # noqa: F401  (serde registration)
 from . import ssm as _ssm  # noqa: F401  (serde registration)
 

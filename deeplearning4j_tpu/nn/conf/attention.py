@@ -32,21 +32,27 @@ from ..weights import init_weights
 class SelfAttentionLayer(Layer):
     """Causal/bidirectional multi-head self-attention with output projection.
 
-    Params: fused qkv projection ``Wqkv`` [n_in, 3·n_in], output projection
-    ``Wo`` [n_in, n_out], bias ``b`` [n_out]. ``n_in`` must divide by
-    ``n_heads``.
+    Params: fused qkv projection ``Wqkv`` [n_in, 3·h·d], output projection
+    ``Wo`` [h·d, n_out], bias ``b`` [n_out]. A head is ``d = head_size``
+    wide; without ``head_size`` it is ``n_in / n_heads``, and ``n_in``
+    must divide by ``n_heads``.
 
     ``n_kv_heads`` < ``n_heads`` is grouped-query attention: query head
     ``i`` reads K/V head ``i // (n_heads // n_kv_heads)``; ``Wqkv`` is then
     [n_in, (n_heads + 2·n_kv_heads)·d], columns ``[q | k | v]``, and every
     cache (dense streaming, paged pools) holds ``n_kv_heads·d`` a token.
-    ``has_bias=False`` leaves ``b`` out.
+    ``has_bias=False`` leaves ``b`` out. ``output_gate=True`` adds
+    ``Wgate`` [n_in, h·d]: the heads' output, column by column, is
+    multiplied by ``sigmoid(u @ Wgate)`` of the layer's input ``u`` before
+    ``Wo`` (the gated attention of arXiv:2505.06708; scope ``attn.gate``).
     """
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None       # defaults to n_in
     n_heads: int = 4
     n_kv_heads: Optional[int] = None  # None = n_heads (multi-head)
+    head_size: Optional[int] = None   # None = n_in // n_heads
+    output_gate: bool = False
     has_bias: bool = True
     causal: bool = True
     # streaming decode: K/V cache length for rnn_time_step. None = no
@@ -76,7 +82,7 @@ class SelfAttentionLayer(Layer):
             self.n_in = input_type.flat_size()
         if self.n_out is None:
             self.n_out = self.n_in
-        if self.n_in % self.n_heads:
+        if self.head_size is None and self.n_in % self.n_heads:
             raise ValueError(f"n_in={self.n_in} not divisible by "
                              f"n_heads={self.n_heads}")
         if self.n_heads % self.kv_heads:
@@ -89,7 +95,12 @@ class SelfAttentionLayer(Layer):
 
     @property
     def head_dim(self) -> int:
-        return self.n_in // self.n_heads
+        return self.head_size or self.n_in // self.n_heads
+
+    @property
+    def q_width(self) -> int:
+        """Features of a token's queries (and of the heads' output)."""
+        return self.n_heads * self.head_dim
 
     @property
     def kv_width(self) -> int:
@@ -132,8 +143,10 @@ class SelfAttentionLayer(Layer):
         return True
 
     def param_shapes(self, policy=None) -> Dict[str, Tuple[int, ...]]:
-        shapes = {"Wqkv": (self.n_in, self.n_in + 2 * self.kv_width),
-                  "Wo": (self.n_in, self.n_out)}
+        shapes = {"Wqkv": (self.n_in, self.q_width + 2 * self.kv_width),
+                  "Wo": (self.q_width, self.n_out)}
+        if self.output_gate:
+            shapes["Wgate"] = (self.n_in, self.q_width)
         if self.has_bias:
             shapes["b"] = (self.n_out,)
         return shapes
@@ -145,23 +158,32 @@ class SelfAttentionLayer(Layer):
         policy = policy or _dtypes.default_policy()
         dt = policy.param_dtype
         k1, k2 = jax.random.split(key)
-        wqkv = init_weights(k1, (self.n_in, self.n_in + 2 * self.kv_width),
+        wqkv = init_weights(k1, (self.n_in, self.q_width + 2 * self.kv_width),
                             self.weight_init or "XAVIER",
-                            fan_in=self.n_in, fan_out=self.n_in,
+                            fan_in=self.n_in, fan_out=self.q_width,
                             distribution=self.dist, dtype=dt)
-        wo = init_weights(k2, (self.n_in, self.n_out),
+        wo = init_weights(k2, (self.q_width, self.n_out),
                           self.weight_init or "XAVIER",
-                          fan_in=self.n_in, fan_out=self.n_out,
+                          fan_in=self.q_width, fan_out=self.n_out,
                           distribution=self.dist, dtype=dt)
         params = {"Wqkv": wqkv, "Wo": wo}
+        if self.output_gate:
+            params["Wgate"] = init_weights(
+                jax.random.fold_in(key, 2), (self.n_in, self.q_width),
+                self.weight_init or "XAVIER", fan_in=self.n_in,
+                fan_out=self.q_width, distribution=self.dist, dtype=dt)
         if self.has_bias:
             params["b"] = jnp.full((self.n_out,),
                                    float(self.bias_init or 0.0), dt)
         return params
 
-    def _project_out(self, params, att):
-        """``att [b, t, n_in]`` through ``Wo`` (+ ``b``) and the
-        activation."""
+    def _project_out(self, params, att, xc):
+        """``att [b, t, h·d]`` through the output gate (of the layer's
+        input ``xc``), ``Wo`` (+ ``b``) and the activation."""
+        if self.output_gate:
+            with jax.named_scope("attn.gate"):
+                att = att * jax.nn.sigmoid(
+                    xc @ params["Wgate"].astype(xc.dtype))
         out = att @ params["Wo"].astype(att.dtype)
         if self.has_bias:
             out = out + params["b"].astype(att.dtype)
@@ -217,8 +239,8 @@ class SelfAttentionLayer(Layer):
         choice at page granularity. Below the window this is a no-op
         (shift 0) and the math is bit-identical to the pre-eviction
         path."""
-        b, t_new, f = xc.shape
-        h = self.n_heads
+        b, t_new, _ = xc.shape
+        h, d = self.n_heads, self.head_dim
         max_t = self.max_cache_t
         if t_new > max_t:   # shapes are static: fail at trace, not silently
             raise ValueError(
@@ -251,9 +273,9 @@ class SelfAttentionLayer(Layer):
                                               (zero, write_pos, zero))
         body_v = jax.lax.dynamic_update_slice(body_v, v_flat,
                                               (zero, write_pos, zero))
-        kh = self._repeat_kv(body_k.reshape(b, max_t, self.kv_heads, f // h))
-        vh = self._repeat_kv(body_v.reshape(b, max_t, self.kv_heads, f // h))
-        scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
+        kh = self._repeat_kv(body_k.reshape(b, max_t, self.kv_heads, d))
+        vh = self._repeat_kv(body_v.reshape(b, max_t, self.kv_heads, d))
+        scale = 1.0 / jnp.sqrt(d).astype(xc.dtype)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, kh) * scale
         # new query i sits at global position pos+i = view slot
         # write_pos+i: attend view slots <= write_pos+i (evicted
@@ -269,7 +291,7 @@ class SelfAttentionLayer(Layer):
         weights = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True),
                                   1e-30)
         att = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(xc.dtype), vh)
-        out = self._project_out(params, att.reshape(b, t_new, f))
+        out = self._project_out(params, att.reshape(b, t_new, h * d), xc)
         new_pos = (pos + t_new).astype(k_cache.dtype)
         k_cache = jnp.concatenate(
             [body_k, k_cache[:, max_t:].at[:, 0, 0].set(new_pos)], axis=1)
@@ -307,13 +329,13 @@ class SelfAttentionLayer(Layer):
         from ...ops.paged_attention import paged_read_attention, paged_write
         policy = policy or _dtypes.default_policy()
         xc, wqkv = policy.cast_to_compute(x, params["Wqkv"])
-        b, t_new, f = xc.shape
-        h = self.n_heads
+        b, t_new, _ = xc.shape
+        h, d = self.n_heads, self.head_dim
         with jax.named_scope("attn.qkv"):
             q, k_new, v_new = self._split_qkv(xc @ wqkv)
         k_pool = paged_write(k_pool, k_new, page_table, write_slots)
         v_pool = paged_write(v_pool, v_new, page_table, write_slots)
-        scale = 1.0 / jnp.sqrt(f // h).astype(xc.dtype)
+        scale = 1.0 / jnp.sqrt(d).astype(xc.dtype)
         group = h // self.kv_heads
         if group == 1:
             att = paged_read_attention(q, k_pool, v_pool, page_table,
@@ -322,14 +344,14 @@ class SelfAttentionLayer(Layer):
             # the query heads of one K/V head become further queries of
             # it: [S, t, kv, g, d] -> [S, t·g, kv, d], so the read's
             # products have g rows a key and K/V are gathered once
-            kv, d = self.kv_heads, f // h
+            kv = self.kv_heads
             qg = q.reshape(b, t_new, kv, group, d).swapaxes(2, 3)
             att = paged_read_attention(
                 qg.reshape(b, t_new * group, kv, d), k_pool, v_pool,
                 page_table, rel_pos, scale, group=group)
             att = att.reshape(b, t_new, group, kv, d).swapaxes(2, 3)
         with jax.named_scope("attn.out"):
-            out = self._project_out(params, att.reshape(b, t_new, f))
+            out = self._project_out(params, att.reshape(b, t_new, h * d), xc)
         return out, k_pool, v_pool
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
@@ -344,8 +366,7 @@ class SelfAttentionLayer(Layer):
                 and state is not None and "h" in state):
             # streaming decode with the carried K/V cache (rnn_time_step)
             return self._apply_streaming(params, xc, state, policy)
-        b, t, f = xc.shape
-        h = self.n_heads
+        b, t, _ = xc.shape
         with jax.named_scope("attn.qkv"):
             q, k, v = self._split_qkv(xc @ wqkv)
             k, v = self._repeat_kv(k), self._repeat_kv(v)
@@ -372,7 +393,8 @@ class SelfAttentionLayer(Layer):
             att = dot_product_attention(q, k, v, causal=self.causal,
                                         mask=mask)
         with jax.named_scope("attn.out"):
-            out = self._project_out(params, att.reshape(b, t, f))
+            out = self._project_out(params, att.reshape(b, t, self.q_width),
+                                    xc)
             if mask is not None:
                 out = out * mask[:, :, None].astype(out.dtype)
         return out, state

@@ -75,6 +75,7 @@ class Mamba2Mixer(BaseRecurrentLayer):
     norm_eps: float = 1e-5
     dt_min: float = 0.001
     dt_max: float = 0.1
+    holds_lane_state = True       # the engine's arena keeps a row a lane
 
     # ---- shapes ------------------------------------------------------
 

@@ -84,12 +84,17 @@ whole windows hold: ``decode_kv_read_tokens_total{kind}`` over
 ``decode_kv_window_tokens_total{kind}``, each attention vertex with its
 own trip (``ops.paged_attention.read_trip_tokens``), and the part of it
 read in trips longer than 128 keys,
-``decode_kv_long_trip_tokens_total{kind}``.
+``decode_kv_long_trip_tokens_total{kind}``. What the Kimi delta attention
+vertices compute and carry is counted the same way:
+``kda_positions_total{form, kind}`` (chunked or step; a real lane's
+written position or padding) and ``kda_state_bytes_total`` (the per-lane
+state read and written, ``_note_kda``).
 
 What a net makes the engine carry beside K/V pages, each found on the
 net and handed to the decode programs as one more argument, so that a net
 without it keeps the programs it had: per-lane recurrent state
-(``lane_ids``; state-space vertices), each lane's ABSOLUTE position
+(``lane_ids``; Mamba-2 and Kimi delta attention vertices, a row of the
+arena's state entry a lane each), each lane's ABSOLUTE position
 (``positions``; a rotary term, ``nn/conf/mla``: the view-relative position
 indexes the cache, the absolute one turns the keys), and under the prefix
 cache the positions each lane fed (``fed``; an expert layer computes a
@@ -313,7 +318,8 @@ class PagedDecodeEngine:
         self._check_decode_config(net)
         # what the net makes the engine carry beside K/V pages: per-lane
         # recurrent state, and expert layers whose routing is counted
-        self.state_layers = _transformer.state_space_vertices(net)
+        self.state_layers = _transformer.lane_state_vertices(net)
+        self.kda_layers = _transformer.kda_vertices(net)
         self._counting_layers = len(_transformer.counting_vertices(net))
         self._counting = bool(self._counting_layers)
         # vertices with a rotary term take each lane's absolute position
@@ -455,8 +461,9 @@ class PagedDecodeEngine:
             state_bytes = float(self.arena.state_nbytes())
             self.registry.gauge(
                 "decode_state_bytes",
-                "Bytes of per-lane recurrent state (convolution tails and "
-                "SSM states of the state-space layers) the arena holds"
+                "Bytes of per-lane recurrent state (convolution tails, SSM "
+                "states of the Mamba-2 layers and delta-rule states of the "
+                "Kimi delta attention layers) the arena holds"
             ).set_function(lambda: state_bytes)
         if self._counting:
             self._m_moe_routed = self.registry.counter(
@@ -487,6 +494,8 @@ class PagedDecodeEngine:
                 "x expert layers x steps, counted on the host")
         if self.eva_layers:
             self._init_eva_metrics()
+        if self.kda_layers:
+            self._init_kda_metrics()
         self._tick_dispatch_wall = 0.0
         self._tick_dispatches = 0
         self._warming = False
@@ -523,7 +532,7 @@ class PagedDecodeEngine:
         """``(layer_dims, dtype)`` of the arena of ``net``, target or
         draft: each stateful vertex in the walker's order with what it
         holds (``PagedKVArena``: K/V heads and their size, a latent
-        vertex's row width, a state-space vertex's two shapes), and the
+        vertex's row width, a per-lane state vertex's two shapes), and the
         pools' dtype: the dense
         streaming cache's rule (``_zero_state``), at least f32, so bf16
         compute policies keep exact K/V (``kv_dtype="int8"`` replaces the
@@ -574,8 +583,9 @@ class PagedDecodeEngine:
                 "pools")
         if self.state_layers and prefix_cache:
             raise ValueError(
-                f"prefix_cache=True with state-space vertices "
-                f"{self.state_layers[:2]}...: a prefix hit maps the K/V "
+                f"prefix_cache=True with per-lane recurrent state (Mamba-2 "
+                f"or Kimi delta attention vertices "
+                f"{self.state_layers[:2]}...): a prefix hit maps the K/V "
                 "pages of a shared prompt prefix, but a recurrent state "
                 "after that prefix is kept nowhere (no snapshot at page "
                 "boundaries yet), so the lane would decode from a wrong "
@@ -592,9 +602,10 @@ class PagedDecodeEngine:
                 "prefix is mapped — serve this net without a draft_net")
         if draft_net is not None and (
                 self.state_layers
-                or _transformer.state_space_vertices(draft_net)):
+                or _transformer.lane_state_vertices(draft_net)):
             raise ValueError(
-                "draft_net with state-space vertices: a rejected draft "
+                "draft_net with per-lane recurrent state (Mamba-2 or Kimi "
+                "delta attention vertices): a rejected draft "
                 "token's K/V is simply overwritten, but a recurrent state "
                 "that advanced over it cannot be rolled back (no state "
                 "roll-back yet) — serve this net without a draft_net")
@@ -659,7 +670,7 @@ class PagedDecodeEngine:
                              "one output vertex")
         owners = _transformer.stateful_vertices(net)
         if not owners:
-            raise ValueError("no causal SelfAttentionLayer or state-space "
+            raise ValueError("no causal attention or per-lane state "
                              "vertices — nothing to cache")
         in_name = net.conf.network_inputs[0]
         consumers = [n for n in net.topo_order
@@ -684,9 +695,9 @@ class PagedDecodeEngine:
                 raise ValueError(
                     f"vertex {name!r} ({type(layer).__name__}) carries "
                     "recurrent state — paged decode supports causal "
-                    "attention (paged K/V) and Mamba-2 state-space mixers "
-                    "(per-lane state) as sequence mixing; an LSTM's carry "
-                    "has no arena")
+                    "attention (paged K/V) and the layers that hold a row "
+                    "of state a lane (Mamba-2, Kimi delta attention) as "
+                    "sequence mixing; an LSTM's carry has no arena")
             if v.init_state(net.policy):
                 raise ValueError(
                     f"vertex {name!r} carries persistent state — "
@@ -979,7 +990,7 @@ class PagedDecodeEngine:
         ``(B, t_new, P)`` bucket under a retrace guard — the bucket set
         is fixed (≤ log₂(lanes)+1 sizes × two chunk lengths), so
         steady-state decode never retraces. ``lanes [B]``: the engine
-        lane of each row, which a net with state-space vertices needs
+        lane of each row, which a net with per-lane state needs
         (its recurrent state is a row a lane; see
         :meth:`_extra_args`); ``fed [B]``: the positions each lane fed
         (its chunk's length; a padded slot 0), which only an expert
@@ -1010,6 +1021,8 @@ class PagedDecodeEngine:
         else:
             self._note_kv_read("paged", rel_pos, t_new)
         self._note_routing(counts, steps=1)
+        if self.kda_layers:
+            self._note_kda(lanes, t_new, (write_slots >= 0).sum(axis=1))
         return probs
 
     def _extra_args(self, names: tuple, lanes: Optional[np.ndarray],
@@ -1181,7 +1194,7 @@ class PagedDecodeEngine:
                 for i in range(steps))
             visited += seen
             long_trip += seen if chunk > _paged.READ_CHUNK_TOKENS else 0
-        vertices = max(1, sum(reads.values()))  # state-space only: 0
+        vertices = max(1, sum(reads.values()))  # per-lane state only: 0
         visited, long_trip = visited / vertices, long_trip / vertices
         self._m_kv_read.inc(len(rel) * visited, kind=kind)
         self._m_kv_long_trip.inc(len(rel) * long_trip, kind=kind)
@@ -1290,6 +1303,50 @@ class PagedDecodeEngine:
                                       + len(self._sum_held[i]) for i in idx))
         self._m_eva_context.inc(int((pos + n).sum()))
 
+    def _init_kda_metrics(self) -> None:
+        """What the Kimi delta attention vertices compute and carry: on
+        the host, from what the engine dispatches (:meth:`_note_kda`)."""
+        self._m_kda_positions = self.registry.counter(
+            "kda_positions_total",
+            "Positions the Kimi delta attention vertices computed, summed "
+            "over them, by form (chunked: a dispatch of more than one new "
+            "token a lane, in chunks; step: the recurrence step of a "
+            "one-token dispatch or of a fused block) and kind (valid: a "
+            "position a real lane wrote; pad: a bucket's empty slot, a "
+            "chunk's tail beyond a lane's last valid token, a finished "
+            "lane's step in a block)", ("form", "kind"))
+        self._m_kda_state = self.registry.counter(
+            "kda_state_bytes_total",
+            "Bytes of per-lane state (delta-rule state and convolution "
+            "tail) the Kimi delta attention vertices read and wrote: twice "
+            "a lane's state for every real lane of a dispatch, every step "
+            "and every such vertex")
+        # a lane's state over all the KDA vertices, float32
+        self._kda_lane_bytes = 4 * sum(
+            int(np.prod(shape))
+            for name in self.kda_layers
+            for shape in self.net._vertex_layer(name).state_shapes(1))
+
+    def _note_kda(self, lanes: Optional[np.ndarray], t_new: int,
+                  written: np.ndarray, steps: int = 1) -> None:
+        """Account a dispatch of ``steps`` steps of ``t_new`` positions
+        over a bucket of ``len(written)`` slots for the KDA vertices:
+        the positions computed (every slot, every step), of which
+        ``written`` a lane were a real lane's, and the state the real
+        lanes read and wrote at every step."""
+        if self._warming:
+            return
+        layers = len(self.kda_layers)
+        form = "step" if t_new == 1 else "chunked"
+        valid = int(np.sum(written))
+        self._m_kda_positions.inc(layers * valid, form=form, kind="valid")
+        self._m_kda_positions.inc(
+            layers * (len(written) * t_new * steps - valid), form=form,
+            kind="pad")
+        real = 0 if lanes is None else int(
+            np.sum(np.asarray(lanes) < self.lanes))
+        self._m_kda_state.inc(2 * real * steps * self._kda_lane_bytes)
+
     # -- fused multi-token block --------------------------------------
 
     def run_fused(self, last: np.ndarray, tables: np.ndarray,
@@ -1327,6 +1384,8 @@ class PagedDecodeEngine:
         else:
             self._note_kv_read("fused", rel, 1, steps=steps)
         self._note_routing(counts, steps=steps)
+        if self.kda_layers:
+            self._note_kda(lanes, 1, n_emitted, steps=steps)
         return toks, valid, n_emitted
 
     # -- speculative draft / verify -----------------------------------
@@ -1704,7 +1763,7 @@ class DecodeScheduler:
             raise ValueError(
                 f"request of {prompt.size} + {n_new} tokens exceeds the "
                 f"window of {self.engine.window}: past it the attention "
-                "layers' pages slide while the state-space layers' state "
+                "layers' pages slide while the recurrent layers' state "
                 "has no window, so the two would see different histories "
                 "— size page_size x pages_per_seq to the longest request")
         if (self.engine.position_layers

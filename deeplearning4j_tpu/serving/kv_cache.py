@@ -505,11 +505,13 @@ class PagedKVArena:
     returned or counted for it). An EVA vertex (``nn/conf/eva``) gets a K
     and a V pool as a causal one does; its lanes' pages are a window that
     goes back to the allocator at each edge (:meth:`PageAllocator.recycle`)
-    and summary pages (``serving/decode``). A state-space vertex gives its two per-lane shapes
-    (``Mamba2Mixer.state_shapes(lanes)``) and gets a convolution tail
-    ``[lanes, K-1, C]`` in ``k_pools[i]`` and an SSM state ``[lanes, H, P,
-    N]`` in ``v_pools[i]``: fixed-size whatever the sequence's length, so
-    it takes no page; a lane's row is zeroed inside the first prefill
+    and summary pages (``serving/decode``). A vertex with per-lane
+    recurrent state gives its two per-lane shapes (``state_shapes(lanes)``)
+    and gets a convolution tail ``[lanes, K-1, C]`` in ``k_pools[i]`` and
+    its state in ``v_pools[i]``: an SSM state ``[lanes, H, P, N]``
+    (``nn/conf/ssm.Mamba2Mixer``) or a delta-rule state ``[lanes, H, D,
+    D]`` (``nn/conf/kda.KimiDeltaAttention``); fixed-size whatever the
+    sequence's length, so it takes no page; a lane's row is zeroed inside the first prefill
     program of the sequence it is given to, never by the arena. Both
     kinds ride the engine's donated-pytree dispatch protocol alike and
     are rebuilt together after a failed dispatch (:meth:`reset_pools`).
@@ -587,7 +589,7 @@ class PagedKVArena:
         self.k_pools = []
         self.v_pools = []
         for h, d in self._layer_dims.values():
-            if isinstance(h, tuple):       # a state-space vertex's shapes
+            if isinstance(h, tuple):       # a per-lane state vertex's shapes
                 self.k_pools.append(jnp.zeros(h, jnp.float32))
                 self.v_pools.append(jnp.zeros(d, jnp.float32))
                 continue

@@ -515,10 +515,12 @@ def test_speculative_kinds_are_counted(net):
 
 METRIC_CELLS = {
     "paged_read_window_share": ["serve-opt-chat", "serve-opt-docqa",
-                                "serve-pangu-longdoc"],
+                                "serve-pangu-longdoc",
+                                "serve-solar2-longprompt"],
     "paged_read_long_trip_share": ["serve-opt-chat", "serve-opt-docqa",
                                    "serve-nemotron3-reason",
-                                   "serve-pangu-longdoc"],
+                                   "serve-pangu-longdoc",
+                                   "serve-solar2-longprompt"],
 }
 
 

@@ -401,6 +401,82 @@ def test_compiled_for_v5e_the_latent_programs_fit(one_chip, bucket, request,
         assert peak > LATENT_PEAK_GIB, (bucket, peak)
 
 
+# the hybrid of KDA and gated GQA (`benchmarks/configs/solar-open2-250b.json`):
+# its largest buckets, at most 14.5 GiB each
+SOLAR_BUCKETS = {"prefill_S32xT128": ("prefill", 32, 128),
+                 "fused_S32xN8": ("fused", 32, 8)}
+SOLAR_PEAK_GIB = 14.5
+
+
+@pytest.mark.parametrize("bucket", sorted(SOLAR_BUCKETS))
+def test_compiled_for_v5e_the_solar_programs_fit(one_chip, bucket,
+                                                 monkeypatch):
+    """The 4-layer programs at the cell's sizes (weights in bfloat16, the
+    GQA layer's float32 pools of 28,672 pages, three KDA layers' per-lane
+    state of 32 lanes; the expert layers' gated kernel compiled, as on the
+    chip, blocked over 2048 of the hidden 4096 and 256 of the experts'
+    1280: ``gated_chunks``): arguments plus temporaries stay at or under
+    14.5 GiB. With 32,768 pages the 32-lane prefill program read 14.87
+    (its temporaries 4.31 GiB, mostly the KDA layers' float32 convolution
+    over 32 x 128 x 24,576 and the expert layers' pair-level rows),
+    hence 28,672 (14.37); the fused block 11.25."""
+    from test_pangu import ROOT, common
+    import json
+    import os
+    from deeplearning4j_tpu.util import xla as _xla
+    kind, lanes, t = SOLAR_BUCKETS[bucket]
+    monkeypatch.setattr(_xla, "kernel_mode", lambda: "mosaic")
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        cfg = json.load(f)
+    eng = cfg["engine"]
+    pps, ps = eng["pages_per_seq"], eng["page_size"]
+    conf = common.load_family(cfg).build_conf(cfg, 0, max_cache_t=pps * ps)
+    net = ComputationGraph(conf)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, lane = jnp.int32, lambda dt: arg((lanes,), dt)      # noqa: E731
+    with jax.enable_x64(False):     # as on the chip; the suite runs with x64
+        params = jax.tree_util.tree_map(
+            lambda a: arg(a.shape, jnp.bfloat16 if a.dtype == jnp.float32
+                          else a.dtype),
+            jax.eval_shape(lambda: ComputationGraph(conf).init().params))
+        k, v = [], []
+        for name in _transformer.stateful_vertices(net):
+            layer = net._vertex_layer(name)
+            if name in _transformer.kda_vertices(net):
+                shapes = layer.state_shapes(eng["max_batch"])
+            else:
+                shapes = [(eng["num_pages"], ps, layer.kv_width)] * 2
+            k.append(arg(shapes[0], jnp.float32))
+            v.append(arg(shapes[1], jnp.float32))
+        if kind == "prefill":
+            def step(params, k, v, ids, tables, ws, rel, rows, lane_ids):
+                return _transformer.paged_decode_forward(
+                    net, params, k, v, ids, tables, ws, rel, counts=[],
+                    out_rows=rows, lane_ids=lane_ids)
+            args = (arg((lanes, t), i32), arg((lanes, pps), i32),
+                    arg((lanes, t), i32), lane(i32), lane(i32), lane(i32))
+        else:
+            def step(params, k, v, *a):
+                *a, lane_ids = a
+                return _transformer.fused_decode_loop(net, params, k, v, *a,
+                                                      lane_ids=lane_ids)
+            args = (lane(i32), arg((lanes, pps), i32), lane(i32),
+                    lane(bool), lane(i32), lane(i32), lane(jnp.float32),
+                    lane(i32), lane(jnp.float32),
+                    arg((lanes, t), jnp.float32), lane(i32))
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, k, v, *args).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    assert peak <= SOLAR_PEAK_GIB, (bucket, peak)
+    assert compiled.as_text().count("moe_gated_ffn") >= 4
+
+
 @pytest.mark.parametrize("bh, t, d, calls", [
     (32, 8192, 64, 1),       # the training cell: [1, 8192, 32, 64]
     (4, 16384, 64, 1),       # a head's float32 dq at the budget, 4 MiB
