@@ -45,6 +45,16 @@ Layout conventions (shared with ``serving/kv_cache.py`` and
   ``[S, keys, h*d]`` → ``[S, h, d, keys]``); a reshape of the pool
   itself, even back and forth as a "view", brings the copies back
   twofold. ``tests/test_pool_layout.py`` holds every program to it.
+- THE ONE EXCEPTION: a read over ONE K/V head (the latent read, a
+  one-head or one-K/V-head layer) has no heads to split, so its chunk
+  is folded as gathered, ``[S, keys, row]`` (:func:`paged_gather_rows`):
+  the logits contract the row axis, ``p·v`` the keys axis, and the
+  accumulator is ``[S, rows, d_v]``. The turn exists for heads of 64,
+  which split off the minor axis only by a relayout of every chunk;
+  over one head it is pure cost: at the latent cell's shapes the TPU
+  compiler turned every trip's chunk in float32 (``f32[32,512,640]
+  copy``) and relaid the accumulator after the loop (PERF.md, the
+  paged read). The choice is the read's own static shape, ``h == 1``.
 - That rule is about the jaxpr. THE COMPILED TEXT broke it until PR 38:
   with a bfloat16 query over a float32 pool both products of the read
   are float32 matrix products at default precision, which the MXU feeds
@@ -102,7 +112,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["paged_write", "paged_gather", "paged_read_attention",
+__all__ = ["paged_write", "paged_gather", "paged_gather_rows",
+           "paged_read_attention",
            "read_trip_tokens", "read_chunk_pages", "read_trip_count",
            "read_rounds_chunk", "eva_read_attention", "eva_read_trips",
            "READ_CHUNK_TOKENS", "READ_TRIP_LOGIT_BYTES"]
@@ -226,7 +237,9 @@ def paged_gather(pool, page_table, heads, round_to=None):
     tile) made the compiler pad and relay every chunk, a fifth of a
     decode block's device time (PERF.md, PR 32). Sentinel entries read
     as zeros (masked by the causal window in
-    :func:`paged_read_attention` anyway).
+    :func:`paged_read_attention` anyway). The read of several K/V heads
+    takes this form; a read over one K/V head takes
+    :func:`paged_gather_rows`.
 
     ``round_to`` (a float dtype; an array pool only) rounds the gathered
     pages to that dtype's exponent and mantissa bits and keeps the
@@ -235,11 +248,7 @@ def paged_gather(pool, page_table, heads, round_to=None):
     do it on the pool (module docstring).
     """
     codes = pool[0] if isinstance(pool, tuple) else pool
-    g = jnp.take(codes, page_table, axis=0, mode="fill", fill_value=0)
-    if round_to is not None:
-        to = jnp.finfo(round_to)
-        g = jax.lax.reduce_precision(g, exponent_bits=to.nexp,
-                                     mantissa_bits=to.nmant)
+    g = _take_pages(codes, page_table, round_to)
     s, p, page_size, f = g.shape
     g = jnp.swapaxes(g.reshape(s, p * page_size, f), 1, 2)
     g = g.reshape(s, heads, f // heads, p, page_size)
@@ -249,6 +258,36 @@ def paged_gather(pool, page_table, heads, round_to=None):
         g = g.astype(jnp.float32) * jnp.swapaxes(sc, 1, 2)[:, :, None, :,
                                                             None]
     return g.reshape(s, heads, f // heads, p * page_size)
+
+
+def _take_pages(codes, page_table, round_to):
+    """``[S, P, page_size, row]``: the pages of ``page_table`` (sentinels
+    as zeros), rounded to ``round_to``'s bits where it is given."""
+    g = jnp.take(codes, page_table, axis=0, mode="fill", fill_value=0)
+    if round_to is not None:
+        to = jnp.finfo(round_to)
+        g = jax.lax.reduce_precision(g, exponent_bits=to.nexp,
+                                     mantissa_bits=to.nmant)
+    return g
+
+
+@jax.named_scope("attn.paged_gather")
+def paged_gather_rows(pool, page_table, round_to=None):
+    """Gather pages AS THEY ARE STORED, one key row after another: the
+    read loop's gather where the pool holds one K/V head (module
+    docstring, the one exception). ``pool``, ``page_table`` and
+    ``round_to`` as for :func:`paged_gather`; returns ``[S, P·page_size,
+    row]``, no turn, so the products contract the row and the keys
+    where they lie. An int8 pool's codes are dequantized by their page's
+    one scale."""
+    codes = pool[0] if isinstance(pool, tuple) else pool
+    g = _take_pages(codes, page_table, round_to)
+    s, p, page_size, f = g.shape
+    if isinstance(pool, tuple):
+        sc = jnp.take(pool[1], page_table, axis=0,
+                      mode="fill", fill_value=0.0)            # [S, P, 1]
+        g = g.astype(jnp.float32) * sc[:, :, None, :]
+    return g.reshape(s, p * page_size, f)
 
 
 def read_trip_tokens(d_v: int = READ_CHUNK_TOKENS,
@@ -334,6 +373,13 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
     in the pools' dtype, before it is turned: the same numbers reach the
     products, and no rounding is left for the compiler to place on a
     whole pool.
+
+    The form follows ``h``: over several K/V heads each chunk is turned
+    (:func:`paged_gather`, :func:`_fold_chunk`); over ONE (the latent
+    read, a one-head layer) it is folded as gathered
+    (:func:`paged_gather_rows`, :func:`_fold_rows`: ``[S, keys, row]``,
+    the accumulator ``[S, rows, d_v]``), with the same keys, trips,
+    rounding, precision and softmax bookkeeping.
     """
     codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
     num_pages, page_size = codes.shape[0], codes.shape[1]
@@ -358,34 +404,69 @@ def paged_read_attention(q, k_pool, v_pool, page_table, rel_pos, scale,
     else:
         q_idx = rel_pos[:, None] + (jnp.arange(t_new) // group)[None, :]
 
+    def allow(key_idx):
+        return key_idx[None, None, :] <= q_idx[:, :, None]
+
+    def chunk_table(c):
+        return jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp, axis=1)
+
+    if h == 1:
+        q_rows = q.reshape(s, t_new, d)
+
+        def fold_rows(c, carry):
+            table_c = chunk_table(c)
+            k_c = paged_gather_rows(k_pool, table_c, round_to)  # [S, keys, row]
+            v_c = (paged_gather_rows(v_pool, table_c, round_to)
+                   if v_width is None else k_c[..., :v_width])
+            return _fold_rows(carry, q_rows, k_c, v_c, c, scale, allow)
+
+        carry = jax.lax.fori_loop(
+            0, trips, fold_rows,
+            _softmax_start((s, t_new), (s, t_new, d_v), out_dtype))
+        return _softmax_end(carry, out_dtype).reshape(s, t_new, 1, d_v)
+
     def fold(c, carry):
-        table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp,
-                                               axis=1)
+        table_c = chunk_table(c)
         k_c = paged_gather(k_pool, table_c, h, round_to)      # [S, h, d, chunk]
         v_c = (paged_gather(v_pool, table_c, h, round_to)
                if v_width is None else k_c[:, :, :v_width])
-        return _fold_chunk(
-            carry, q, k_c, v_c, c, scale,
-            lambda key_idx: key_idx[None, None, :] <= q_idx[:, :, None])
+        return _fold_chunk(carry, q, k_c, v_c, c, scale, allow)
 
-    carry = jax.lax.fori_loop(0, trips, fold,
-                              _softmax_start(s, t_new, h, d_v, out_dtype))
-    return _softmax_end(carry, out_dtype)
+    carry = jax.lax.fori_loop(
+        0, trips, fold,
+        _softmax_start((s, h, t_new), (s, t_new, h, d_v), out_dtype))
+    return _softmax_end(carry, out_dtype, turned=True)
 
 
-def _softmax_start(s, t_new, h, d_v, out_dtype):
-    """The running softmax's carry before any key: max, sum, accumulator."""
-    return (jnp.full((s, h, t_new, 1), -jnp.inf, jnp.float32),
-            jnp.zeros((s, h, t_new, 1), jnp.float32),
-            jnp.zeros((s, t_new, h, d_v),
-                      jnp.promote_types(out_dtype, jnp.float32)))
+def _softmax_start(rows, acc_shape, out_dtype):
+    """The running softmax's carry before any key: max and sum ``[*rows,
+    1]``, accumulator ``acc_shape``."""
+    return (jnp.full((*rows, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((*rows, 1), jnp.float32),
+            jnp.zeros(acc_shape, jnp.promote_types(out_dtype, jnp.float32)))
+
+
+def _softmax_step(m, l, logits):
+    """The bookkeeping of one chunk's masked float32 ``logits`` (keys on
+    the last axis) in the running softmax, whatever the layout: the new
+    max, the sum rescaled by the max's move, the chunk's max-subtracted
+    exp ``p`` and the rescale ``alpha`` the caller applies to its
+    accumulator."""
+    m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+    p = jnp.where(jnp.isneginf(logits), 0.0,
+                  jnp.exp(logits - m_safe))
+    alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
+    l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+    return m_new, l, p, alpha
 
 
 def _fold_chunk(carry, q, k_c, v_c, c, scale, allow):
-    """Fold one gathered chunk (``k_c [S, h, d, keys]``, ``v_c [S, h, d_v,
-    keys]``, keys on the minor axis) into the running softmax of ``q [S,
-    t_new, h, d]``: logits in float32, max-subtracted exp, the sum and the
-    accumulator rescaled by the max's move. The chunk is trip ``c`` of its
+    """Fold one TURNED chunk (``k_c [S, h, d, keys]``, ``v_c [S, h, d_v,
+    keys]``, keys on the minor axis: the read over several K/V heads, and
+    EVA's) into the running softmax of ``q [S, t_new, h, d]``: logits in
+    float32, max-subtracted exp, the sum and the accumulator ``[S, t_new,
+    h, d_v]`` rescaled by the max's move. The chunk is trip ``c`` of its
     table, and ``allow(key_idx) [S, t_new, keys]`` says which of its keys
     (numbered by their place in the table) each query row takes."""
     m, l, acc = carry
@@ -395,23 +476,40 @@ def _fold_chunk(carry, q, k_c, v_c, c, scale, allow):
         key_idx = c * chunk + jnp.arange(chunk)
         logits = jnp.where(allow(key_idx)[:, None],
                            logits.astype(jnp.float32), -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.where(jnp.isneginf(logits), 0.0,
-                      jnp.exp(logits - m_safe))
-        alpha = jnp.exp(m - m_safe)       # 0 while m is still -inf
-        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        m_new, l, p, alpha = _softmax_step(m, l, logits)
         pv = jnp.einsum("bhqk,bhdk->bqhd", p.astype(q.dtype), v_c)
         acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
     return m_new, l, acc
 
 
-def _softmax_end(carry, out_dtype):
+def _fold_rows(carry, q, k_c, v_c, c, scale, allow):
+    """Fold one chunk AS GATHERED (``k_c [S, keys, row]``, ``v_c [S, keys,
+    d_v]``: the read over one K/V head) into the running softmax of ``q
+    [S, rows, row]``: the logits contract the row axis, ``p·v`` the keys
+    axis, and the accumulator is ``[S, rows, d_v]``, so nothing is turned
+    before the products or after the loop. ``c`` and ``allow`` as for
+    :func:`_fold_chunk`."""
+    m, l, acc = carry
+    with jax.named_scope("attn.paged_softmax"):
+        logits = jnp.einsum("bqd,bkd->bqk", q, k_c) * scale
+        chunk = k_c.shape[1]
+        key_idx = c * chunk + jnp.arange(chunk)
+        logits = jnp.where(allow(key_idx), logits.astype(jnp.float32),
+                           -jnp.inf)
+        m_new, l, p, alpha = _softmax_step(m, l, logits)
+        pv = jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v_c)
+        acc = alpha * acc + pv.astype(acc.dtype)
+    return m_new, l, acc
+
+
+def _softmax_end(carry, out_dtype, turned=False):
     """The running softmax's output: the accumulator over the sum (floored
-    at 1e-30, so a row that took no key reads 0)."""
+    at 1e-30, so a row that took no key reads 0); ``turned``: the sum is
+    ``[S, h, t_new, 1]`` beside an accumulator ``[S, t_new, h, d_v]``."""
     _, l, acc = carry
     with jax.named_scope("attn.paged_softmax"):
-        out = acc / jnp.swapaxes(jnp.maximum(l, 1e-30), 1, 2)
+        l = jnp.maximum(l, 1e-30)
+        out = acc / (jnp.swapaxes(l, 1, 2) if turned else l)
         return out.astype(out_dtype)
 
 
@@ -496,7 +594,7 @@ def eva_read_attention(q, k_pool, v_pool, page_table, rel_pos, positions,
                                c, scale, allow)
         return lambda carry: jax.lax.fori_loop(lo, hi, fold, carry)
 
-    carry = _softmax_start(s, t_new, h, d, out_dtype)
+    carry = _softmax_start((s, h, t_new), (s, t_new, h, d), out_dtype)
     with jax.named_scope("eva.read_summary"):
         carry = loop(page_table[:, win:], 0, trips,
                      lambda key: key[None, None, :] < seen[:, :, None])(carry)
@@ -504,4 +602,4 @@ def eva_read_attention(q, k_pool, v_pool, page_table, rel_pos, positions,
         carry = loop(own, first, end,
                      lambda key: (key[None, None, :] >= start_rel[:, :, None])
                      & (key[None, None, :] <= q_rel[:, :, None]))(carry)
-    return _softmax_end(carry, out_dtype)
+    return _softmax_end(carry, out_dtype, turned=True)

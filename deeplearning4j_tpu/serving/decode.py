@@ -84,7 +84,9 @@ whole windows hold: ``decode_kv_read_tokens_total{kind}`` over
 ``decode_kv_window_tokens_total{kind}``, each attention vertex with its
 own trip (``ops.paged_attention.read_trip_tokens``), and the part of it
 read in trips longer than 128 keys,
-``decode_kv_long_trip_tokens_total{kind}``. What the Kimi delta attention
+``decode_kv_long_trip_tokens_total{kind}``, and by vertices of one K/V
+head, whose read folds each chunk as gathered,
+``decode_kv_unturned_tokens_total{kind}``. What the Kimi delta attention
 vertices compute and carry is counted the same way:
 ``kda_positions_total{form, kind}`` (chunked or step; a real lane's
 written position or padding) and ``kda_state_bytes_total`` (the per-lane
@@ -440,6 +442,11 @@ class PagedDecodeEngine:
             "The part of decode_kv_read_tokens_total read in trips longer "
             "than ops.paged_attention.READ_CHUNK_TOKENS keys (a read whose "
             "value is wider than that: read_trip_tokens)", ("kind",))
+        self._m_kv_unturned = self.registry.counter(
+            "decode_kv_unturned_tokens_total",
+            "The part of decode_kv_read_tokens_total read by vertices whose "
+            "read folds each chunk as gathered, not turned (one K/V head: "
+            "ops.paged_attention.paged_gather_rows)", ("kind",))
         # the paged read of each attention vertex, (group, kv_heads, d_v)
         # -> vertices that read so, target and draft
         self._reads = {
@@ -1176,7 +1183,8 @@ class PagedDecodeEngine:
         trip count (the very helper the program's loop bound comes from,
         on the same ``rel``, with each vertex's own trip) for each of the
         block's ``steps``, beside the whole windows the lanes hold; which
-        part of it went in trips longer than ``READ_CHUNK_TOKENS``, and
+        part of it went in trips longer than ``READ_CHUNK_TOKENS``, which
+        part was folded as gathered (a vertex with one K/V head), and
         whether the read rounded the chunks it gathered (``draft``: the
         draft net's read). Where vertices take different trips the counts
         are their mean: the read of one vertex."""
@@ -1184,7 +1192,7 @@ class PagedDecodeEngine:
             return
         ps, pps = self.page_size, self.pages_per_seq
         reads = self._reads[draft]
-        visited = long_trip = 0
+        visited = long_trip = unturned = 0
         for (g, h, d_v), count in reads.items():
             rows = len(rel) * h * t_new * g
             chunk = ps * _paged.read_chunk_pages(ps, pps, d_v, rows)
@@ -1194,10 +1202,13 @@ class PagedDecodeEngine:
                 for i in range(steps))
             visited += seen
             long_trip += seen if chunk > _paged.READ_CHUNK_TOKENS else 0
+            unturned += seen if h == 1 else 0
         vertices = max(1, sum(reads.values()))  # per-lane state only: 0
-        visited, long_trip = visited / vertices, long_trip / vertices
+        visited, long_trip, unturned = (
+            visited / vertices, long_trip / vertices, unturned / vertices)
         self._m_kv_read.inc(len(rel) * visited, kind=kind)
         self._m_kv_long_trip.inc(len(rel) * long_trip, kind=kind)
+        self._m_kv_unturned.inc(len(rel) * unturned, kind=kind)
         self._m_kv_window.inc(len(rel) * self.window * steps, kind=kind)
         if self._read_rounds_chunk(t_new, draft):
             self._m_kv_rounded.inc(len(rel) * visited, kind=kind)

@@ -7,7 +7,9 @@ the rounding of the gathered chunk where the products would round K/V
 (``paged_read_chunk_rounded_share``); the trip that follows the value
 width (``read_trip_tokens``: a latent read's 512 keys, the guard's
 halving) and the counter of the keys read in such trips
-(``paged_read_long_trip_share``)."""
+(``paged_read_long_trip_share``); the read over one K/V head, which folds
+each chunk as gathered, against the turned read it replaced, and its
+counter (``paged_read_unturned_share``)."""
 
 import json
 import os
@@ -290,6 +292,105 @@ def test_a_latent_read_in_512_key_trips(t_new, request):
 
 
 # ---------------------------------------------------------------------------
+# a read over one K/V head folds its chunks as gathered
+# ---------------------------------------------------------------------------
+
+def turned_read(q, k_pool, v_pool, page_table, rel_pos, scale, group=1,
+                v_width=None):
+    """The read over one K/V head as every read was made before it kept
+    the gathered layout (kept here as the reference): the same trips,
+    rounding and softmax, each chunk turned to ``[S, 1, row, keys]``
+    (``paged_gather``), the accumulator ``[S, rows, 1, d_v]``."""
+    codes = k_pool[0] if isinstance(k_pool, tuple) else k_pool
+    num_pages, page_size = codes.shape[0], codes.shape[1]
+    kv_dtype = jnp.float32 if isinstance(k_pool, tuple) else codes.dtype
+    out_dtype = jnp.result_type(q.dtype, kv_dtype)
+    s, rows, h, d = q.shape
+    round_to = q.dtype if read_rounds_chunk(
+        q.dtype, None if isinstance(k_pool, tuple) else codes.dtype,
+        rows) else None
+    d_v = d if v_width is None else v_width
+    pages_per_seq = page_table.shape[1]
+    cp = read_chunk_pages(page_size, pages_per_seq, d_v, s * h * rows)
+    page_table = jnp.pad(page_table, ((0, 0), (0, -pages_per_seq % cp)),
+                         constant_values=num_pages)
+    trips = read_trip_count(rel_pos, rows // group, page_size, pages_per_seq,
+                            d_v=d_v, logit_rows=s * h * rows)
+    q_idx = rel_pos[:, None] + (jnp.arange(rows) // group)[None, :]
+
+    def fold(c, carry):
+        m, l, acc = carry
+        table_c = jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp, axis=1)
+        k_c = paged_gather(k_pool, table_c, h, round_to)
+        v_c = (paged_gather(v_pool, table_c, h, round_to)
+               if v_width is None else k_c[:, :, :v_width])
+        logits = jnp.einsum("bqhd,bhdk->bhqk", q, k_c) * scale
+        key_idx = c * k_c.shape[-1] + jnp.arange(k_c.shape[-1])
+        allow = key_idx[None, None, :] <= q_idx[:, :, None]
+        logits = jnp.where(allow[:, None], logits.astype(jnp.float32),
+                           -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.where(jnp.isneginf(logits), 0.0, jnp.exp(logits - m_safe))
+        alpha = jnp.exp(m - m_safe)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("bhqk,bhdk->bqhd", p.astype(q.dtype), v_c)
+        acc = jnp.swapaxes(alpha, 1, 2) * acc + pv.astype(acc.dtype)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(0, trips, fold, (
+        jnp.full((s, h, rows, 1), -jnp.inf, jnp.float32),
+        jnp.zeros((s, h, rows, 1), jnp.float32),
+        jnp.zeros((s, rows, h, d_v), jnp.promote_types(out_dtype,
+                                                       jnp.float32))))
+    out = acc / jnp.swapaxes(jnp.maximum(l, 1e-30), 1, 2)
+    return out.astype(out_dtype)
+
+
+# rows a head -> (new positions a lane, query heads on the one K/V head)
+ONE_HEAD_ROWS = {"1": (1, 1), "4": (1, 4), "128": (8, 16)}
+
+
+@pytest.mark.parametrize("q_dtype,pools", [
+    ("float32", "latent"), ("bfloat16", "latent"), ("float32", "k_and_v"),
+    ("bfloat16", "k_and_v"), ("float32", "int8")])
+@pytest.mark.parametrize("rows", sorted(ONE_HEAD_ROWS))
+def test_a_one_head_read_equals_the_turned_read(rows, q_dtype, pools):
+    """One K/V head, over a table of two trips (the second partial): a
+    lane at position 0, one whose last query sits in the window's last
+    slot, one with a hole (a sentinel page) inside its live range; the
+    latent form (one pool, the value its first columns), a K and a V pool,
+    int8 pools; a float32 and a bfloat16 query over float32 pools (the
+    chunk rounded where more than one row reads a key). Equal, to 1e-5,
+    to the same read made with each chunk turned."""
+    t_new, group = ONE_HEAD_ROWS[rows]
+    d, v_width = (24, 16) if pools == "latent" else (16, None)
+    page_size, pages_per_seq = 4, 40             # trips of 32 pages, then 8
+    window = page_size * pages_per_seq
+    rng = np.random.default_rng(100 * int(rows) + len(q_dtype) + len(pools))
+    rel = np.array([0, window - t_new, 70], np.int32)
+    k, v, table = _arena(rng, 3, page_size, pages_per_seq, rel + t_new,
+                         int8=pools == "int8", h=1, d=d)
+    table = table.at[2, 5].set(k[0].shape[0] if pools == "int8"
+                               else k.shape[0])
+    q = jnp.asarray(rng.standard_normal((3, t_new * group, 1, d)),
+                    jnp.dtype(q_dtype))
+    args = (q, k, None if pools == "latent" else v, table, jnp.asarray(rel),
+            jnp.asarray(1.0 / np.sqrt(d), q.dtype))
+    kw = {"group": group, "v_width": v_width}
+    got = paged_read_attention(*args, **kw)
+    want = jax.jit(turned_read, static_argnames=("group", "v_width"))(
+        *args, **kw)
+    assert got.shape == want.shape == (3, t_new * group, 1, v_width or d)
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    prims = set(primitives(jax.make_jaxpr(
+        lambda *a: paged_read_attention(*a, **kw))(*args).jaxpr))
+    assert "transpose" not in prims
+
+
+# ---------------------------------------------------------------------------
 # the chunk rounded where the products would round it (PR 38)
 # ---------------------------------------------------------------------------
 
@@ -521,6 +622,10 @@ METRIC_CELLS = {
                                    "serve-nemotron3-reason",
                                    "serve-pangu-longdoc",
                                    "serve-solar2-longprompt"],
+    "paged_read_unturned_share": ["serve-opt-chat", "serve-opt-docqa",
+                                  "serve-nemotron3-reason",
+                                  "serve-pangu-longdoc",
+                                  "serve-solar2-longprompt"],
 }
 
 
@@ -555,6 +660,8 @@ def test_benchmark_metric_names_counters_the_registry_has(net, name):
     share = _metric_share(metric, _serve(net, [[1, 2, 3]], [4]))
     if name == "paged_read_long_trip_share":
         assert share == 0               # OPT-like heads of 8: 128-key trips
+    elif name == "paged_read_unturned_share":
+        assert share == 0               # two K/V heads: every chunk turned
     else:
         assert 0 < share < 100
     with open(os.path.join(os.path.dirname(__file__), "..",
@@ -599,6 +706,34 @@ def test_a_latent_engine_counts_its_512_key_trips(latent_net, net):
     assert opt.registry.get("decode_kv_read_tokens_total").value(
         kind="paged") > 0
     assert opt.registry.get("decode_kv_long_trip_tokens_total").value(
+        kind="paged") == 0
+
+
+def test_a_one_head_read_is_counted_unturned(latent_net, net):
+    """``decode_kv_unturned_tokens_total`` is all of
+    ``decode_kv_read_tokens_total`` for a latent engine (one K/V head a
+    vertex, whatever the dispatch) and 0 for an OPT-like engine (two)."""
+    eng = PagedDecodeEngine(latent_net, max_batch=2, page_size=16,
+                            pages_per_seq=64, prefill_chunk=16,
+                            block_len=4, prefix_cache=False)
+    read = eng.registry.get("decode_kv_read_tokens_total")
+    unturned = eng.registry.get("decode_kv_unturned_tokens_total")
+    tables = np.full((2, 64), eng.arena.sentinel, np.int32)
+    zi = np.zeros(2, np.int32)
+    eng.run(np.zeros((2, 16), np.int32), np.full((2, 16), -1, np.int32),
+            np.array([0, 700], np.int32), tables, zi)
+    eng.run_fused(zi, tables, np.array([5, 900], np.int32),
+                  np.ones(2, bool), np.full(2, 4, np.int32),
+                  np.full(2, -1, np.int32), np.zeros(2, np.float32), zi,
+                  np.ones(2, np.float32), np.zeros((2, 4), np.float32))
+    for kind in ("paged", "fused"):
+        assert read.value(kind=kind) > 0
+        assert unturned.value(kind=kind) == read.value(kind=kind)
+    assert _metric_share(_metric("paged_read_unturned_share"), eng) == 100
+    opt = _serve(net, [[1, 2, 3, 4, 5]], [6])
+    assert opt.registry.get("decode_kv_read_tokens_total").value(
+        kind="paged") > 0
+    assert opt.registry.get("decode_kv_unturned_tokens_total").value(
         kind="paged") == 0
 
 

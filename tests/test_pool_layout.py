@@ -8,7 +8,9 @@
   hold no copy and no convert whose shape is a pool's, and the read at
   each serving cell's decode shape carries its pools in float32, and the
   latent cell's largest prefill and fused programs fit the chip with
-  their read's trips (skipped where no TPU topology can be described);
+  their read's trips, and the read over one K/V head relays neither its
+  chunks nor its accumulator while the reads of several still turn
+  their chunks (skipped where no TPU topology can be described);
 - ``paged_write``, ``paged_gather`` and ``paged_read_attention`` give,
   bit for bit, what the ``[num_pages, page_size, h, d]`` formulation they
   replaced gave (kept below as the reference; the gather's result has
@@ -268,27 +270,33 @@ def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
 READ_SHAPES = {
     # 128 heads on the one latent row of 576 numbers in 640 columns
     "latent": (32, 128, 1, 640, 12288, 512, 512),
+    # the same in the 32-lane prefill chunk: 128 positions x 128 heads
+    "latent_prefill": (32, 128 * 128, 1, 640, 12288, 512, 512),
     # the hybrid's grouped-query layer: 16 heads on each of 2 K/V heads
     "hybrid": (32, 16, 2, 128, 4096, 128, None),
     # OPT's fused block and ticked step: one row a head
     "opt_one_row": (8, 1, 32, 64, 224, 128, None),
 }
+PAGE_SIZE = 16
 
 
-@pytest.mark.parametrize("cell", sorted(READ_SHAPES))
-def test_compiled_for_v5e_the_read_rounds_no_pool(one_chip, cell):
-    """A bfloat16 query over float32 pools: where the read's products
-    are matrix products (more than one row a K/V head) the compiler
-    rounded each WHOLE pool to bfloat16 before the read loop (one
-    ``bf16[12288,16,640] convert`` at the latent shape, two
-    ``bf16[4096,16,256]`` and two pool-shaped ``copy-done`` at the
-    hybrid's; none with one row, where the products are the vector
-    unit's, in float32). The read rounds the gathered chunk itself, so
-    no pool-shaped ``convert``, ``copy`` or ``bitcast-convert`` is left
-    and the read's ``while`` carries every pool in float32."""
-    lanes, rows, h, d, num_pages, pages_per_seq, v_width = READ_SHAPES[cell]
+@pytest.fixture(scope="module")
+def read_text(one_chip):
+    """cell -> the compiled text of the read at ``READ_SHAPES[cell]``
+    (a bfloat16 query over float32 pools), compiled once a module."""
+    texts = {}
+
+    def text(cell):
+        if cell not in texts:
+            texts[cell] = _compiled_read(one_chip, *READ_SHAPES[cell])
+        return texts[cell]
+    return text
+
+
+def _compiled_read(one_chip, lanes, rows, h, d, num_pages, pages_per_seq,
+                   v_width):
     group = rows if rows > 1 else 1
-    page_size, latent = 16, v_width is not None
+    latent = v_width is not None
 
     def step(k_pool, v_pool, q, new, table, slots, rel):
         k_pool = paged_write(k_pool, new, table, slots)
@@ -302,21 +310,126 @@ def test_compiled_for_v5e_the_read_rounds_no_pool(one_chip, cell):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = arg((num_pages, page_size, h * d), jnp.float32)
+    pool = arg((num_pages, PAGE_SIZE, h * d), jnp.float32)
     with jax.enable_x64(False):     # as on the chip; the suite runs with x64
-        text = jax.jit(step, donate_argnums=(0, 1)).lower(
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
             pool, pool, arg((lanes, rows, h, d), jnp.bfloat16),
             arg((lanes, 1, h, d), jnp.bfloat16),
             arg((lanes, pages_per_seq), jnp.int32),
             arg((lanes, 1), jnp.int32),
             arg((lanes,), jnp.int32)).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(READ_SHAPES))
+def test_compiled_for_v5e_the_read_rounds_no_pool(read_text, cell):
+    """A bfloat16 query over float32 pools: where the read's products
+    are matrix products (more than one row a K/V head) the compiler
+    rounded each WHOLE pool to bfloat16 before the read loop (one
+    ``bf16[12288,16,640] convert`` at the latent shape, two
+    ``bf16[4096,16,256]`` and two pool-shaped ``copy-done`` at the
+    hybrid's; none with one row, where the products are the vector
+    unit's, in float32). The read rounds the gathered chunk itself, so
+    no pool-shaped ``convert``, ``copy`` or ``bitcast-convert`` is left
+    and the read's ``while`` carries every pool in float32."""
+    _, _, h, d, num_pages, _, v_width = READ_SHAPES[cell]
+    latent = v_width is not None
+    pool_shape = (num_pages, PAGE_SIZE, h * d)
+    text = read_text(cell)
     assert "scatter" in text and "gather" in text
-    assert _pool_shaped(text, pool.shape, [
+    assert _pool_shaped(text, pool_shape, [
         "convert", "copy", "bitcast-convert", "copy-done"]) == []
-    shape = ",".join(str(n) for n in pool.shape)
+    shape = ",".join(str(n) for n in pool_shape)
     carried = [dt for carry in re.findall(r"= (\(.*?\)) while\(", text)
                for dt in re.findall(r"(\w+)\[" + shape + r"\]", carry)]
     assert carried == ["f32"] * (1 if latent else 2)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]\{([\d,]*)")
+
+
+def _arrays(type_text):
+    """``(dtype, dims, minor_to_major)`` of each array in an HLO type."""
+    return [(dt, tuple(int(n) for n in dims.split(",") if n),
+             tuple(int(n) for n in layout.split(",") if n))
+            for dt, dims, layout in _ARRAY.findall(type_text)]
+
+
+def _computations(text):
+    """name -> {instruction -> (type text, opcode, operands text)}."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head:
+            body = comps.setdefault(head.group(1), {})
+        elif body is not None and (m := _INSTR.match(line)):
+            body[m.group(1)] = m.group(2, 3, 4)
+    return comps
+
+
+def _loop_bodies(comps):
+    """The computations a ``while`` runs, nested loops included."""
+    bodies = set()
+    for instrs in comps.values():
+        for _, op, rest in instrs.values():
+            if op == "while":
+                bodies.update(re.findall(r"body=%([\w.\-]+)", rest))
+    return bodies
+
+
+def _same_dims(dims, want):
+    return (sorted(n for n in dims if n != 1)
+            == sorted(n for n in want if n != 1))
+
+
+def _relayouts(comps, names, want):
+    """Every ``copy`` and ``copy-start`` in computations ``names`` whose
+    result has the dims ``want`` (in any order, unit axes aside) and a
+    layout other than its operand's. A ``copy-start`` whose two buffers
+    differ only in memory space moves an array between HBM and the chip's
+    fast memory and turns nothing."""
+    found = []
+    for name in names:
+        instrs = comps[name]
+        for dest, (type_text, op, rest) in instrs.items():
+            if op not in ("copy", "copy-start"):
+                continue
+            arrays = _arrays(type_text)
+            if not arrays or not _same_dims(arrays[0][1], want):
+                continue
+            if op == "copy-start":
+                src = arrays[1] if len(arrays) > 1 else None
+            else:
+                operand = re.match(r"%([\w.\-]+)", rest)
+                src = (_arrays(instrs[operand.group(1)][0]) or [None])[0] \
+                    if operand and operand.group(1) in instrs else None
+            if src is None or src[1:] != arrays[0][1:]:
+                found.append(f"{name}: {dest} = {type_text} {op}")
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(READ_SHAPES))
+def test_compiled_for_v5e_a_one_head_read_turns_nothing(read_text, cell):
+    """The latent read (one K/V head) folds each chunk as it was
+    gathered: in the read loop no chunk ``[S, keys, row]`` is relaid (the
+    turned read copied ``f32[32,512,640]`` on every trip of the fused
+    block, ``f32[32,256,640]`` in the 32-lane prefill), and no copy of the
+    accumulator ``[S, rows, d_v]`` follows the loop (``f32[32,128,1,512]``,
+    and 1 GiB, ``f32[32,16384,1,512]``, in the prefill). The reads of
+    several heads still turn their chunks (two ``bf16[32,128,256]`` in
+    the hybrid's loop, two ``f32[8,2048,128]`` in OPT's): the form follows
+    the read's K/V heads and nothing else."""
+    lanes, rows, h, d, _, pages_per_seq, v_width = READ_SHAPES[cell]
+    d_v = d if v_width is None else v_width
+    keys = PAGE_SIZE * _paged.read_chunk_pages(PAGE_SIZE, pages_per_seq, d_v,
+                                               lanes * h * rows)
+    comps = _computations(read_text(cell))
+    turns = _relayouts(comps, _loop_bodies(comps), (lanes, keys, h * d))
+    acc = _relayouts(comps, comps, (lanes, rows, h, d_v))
+    if h == 1:
+        assert turns == [] and acc == [], (turns, acc)
+    else:
+        assert len(turns) >= 2, turns
 
 
 # the latent cell's largest buckets (`benchmarks/configs/openpangu-ultra-
