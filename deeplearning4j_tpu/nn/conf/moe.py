@@ -185,6 +185,16 @@ def tile_rows(pairs: int, n_published: int) -> int:
     return int(min(256, max(2, 1 << (math.ceil(mean) - 1).bit_length())))
 
 
+def tile_grid(pairs: int, e_held: int, n_published: int) -> tuple:
+    """``(rows, max_tiles)`` of a dispatch of ``pairs`` (token, expert)
+    pairs over ``e_held`` of ``n_published`` experts: the tiles'
+    :func:`tile_rows` and how many tiles the arrays are sized for, the
+    bound of the sum over held experts of ``ceil(pairs / rows)`` whatever
+    the routing."""
+    rows = tile_rows(pairs, n_published)
+    return rows, e_held + pairs // rows
+
+
 def sparse_expert_ffn(lat, idx, weights, *stacks, offset: int,
                       n_published: int, token_mask=None):
     """The routed part of an expert layer by sparse dispatch: for each
@@ -215,7 +225,7 @@ def sparse_expert_ffn(lat, idx, weights, *stacks, offset: int,
     t, k = idx.shape
     e_held = stacks[0].shape[0]
     m = t * k
-    rows = tile_rows(m, n_published)
+    rows, max_tiles = tile_grid(m, e_held, n_published)
     local = idx - offset
     held = (local >= 0) & (local < e_held)
     if token_mask is not None:
@@ -241,7 +251,6 @@ def sparse_expert_ffn(lat, idx, weights, *stacks, offset: int,
     tile_end = jnp.cumsum(tiles_per)
     tile_start = tile_end - tiles_per
     n_tiles = tile_end[-1]
-    max_tiles = e_held + m // rows                       # the sum's bound
     tile = jnp.arange(max_tiles, dtype=jnp.int32)
     tile_e = jnp.minimum(jnp.searchsorted(tile_end, tile, side="right"),
                          e_held - 1).astype(jnp.int32)
@@ -257,11 +266,14 @@ def sparse_expert_ffn(lat, idx, weights, *stacks, offset: int,
     out_tiles = tile_ffn(x_tiles, tile_e, n_tiles.astype(jnp.int32), *stacks)
     # back to each pair's own slot: pair j is the rank-th of its expert's
     # sorted run, so row rank % rows of that expert's tile rank // rows
+    # (an absent pair's index is clamped to some tile; what the gated
+    # kernel leaves past the live tiles is undefined, so it is selected
+    # away, never multiplied by a zero weight)
     g = jnp.minimum(gid, e_held - 1)
     per_pair = out_tiles[jnp.minimum(tile_start[g] + rank // rows,
                                      max_tiles - 1), rank % rows]
-    w = jnp.where(held, weights, 0.0).astype(jnp.float32)
-    out = jnp.sum(w[:, :, None] * per_pair.reshape(t, k, -1), axis=1)
+    per_pair = jnp.where(held[..., None], per_pair.reshape(t, k, -1), 0.0)
+    out = jnp.sum(weights.astype(jnp.float32)[:, :, None] * per_pair, axis=1)
     held_n = jnp.sum(counts)
     stats = jnp.stack([held_n, absent_n, n_tiles * rows, jnp.max(counts),
                        (held_n + absent_n > 0),

@@ -11,7 +11,8 @@ expert ``tile_e[i]``. For each of the first ``n_tiles`` tiles
 
 with ``w1 [E, L, F]`` and ``w2 [E, F, L]`` the held experts' stacked
 matrices; the tiles after ``n_tiles`` (the arrays are sized for the worst
-routing) are not computed and read as zeros. Work and weight traffic grow
+routing) are not computed and read as zeros, except where the gated
+kernel leaves them (below). Work and weight traffic grow
 with the tiles the routing needs, not with the experts held: an expert
 nobody chose is never read.
 
@@ -24,7 +25,8 @@ Two implementations of the same numbers, chosen by
   DMA'd straight from the stacks by their expert's index; both products
   accumulate in float32, the second over the chunks of ``F``. A tile past
   ``n_tiles`` names the block the last live tile ended on, so nothing is
-  fetched for it. Why a kernel: XLA materialises ``w1[e]`` and ``w2[e]``
+  fetched for it (the gated kernel walks no such tile: below). Why a
+  kernel: XLA materialises ``w1[e]`` and ``w2[e]``
   (a dynamic slice cannot be fused into the product's operand), which
   writes and reads again every expert matrix a tile touches: three times
   the weight traffic of a step that is bound by it (TPU compiler, PR 33).
@@ -47,6 +49,15 @@ wu`` over the chunks of ``D`` in float32 scratch, the last of them forms
 ``silu(g) * u``, and the second ``D`` steps add that chunk's ``h @ wd`` into
 the tile's output row, chunk of ``D`` by chunk of ``D``; the tile's input row
 and its float32 output row stay resident for the whole tile.
+
+Its grid walks the live tiles alone: the tile axis is a bound computed on
+the device, ``n_tiles`` (at least one: with no tile live, the one tile
+walked keeps its first blocks and computes nothing), so no grid step and
+no block is spent on a tile past them. Its output rows past ``n_tiles``
+are never written and hold anything (the interpreter leaves NaN); the XLA
+``while`` still leaves zeros there. A consumer reads only the rows of live
+tiles: ``sparse_expert_ffn`` selects each held pair's row and never
+multiplies another by a zero weight.
 """
 
 from __future__ import annotations
@@ -153,15 +164,14 @@ def _kernel(tile_e_ref, n_tiles_ref, x_ref, w1_ref, w2_ref, o_ref):
 def _gated_kernel(n_d, dc, tile_e_ref, n_tiles_ref, x_ref, wg_ref, wu_ref,
                   wd_ref, o_ref, g_acc, u_acc, h_buf):
     from jax.experimental import pallas as pl
-    i, j, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    j, s = pl.program_id(1), pl.program_id(2)
+    some = n_tiles_ref[0] > 0      # none live: one tile walked, no work
 
     @pl.when((j == 0) & (s == 0))
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    live = i < n_tiles_ref[0]
-
-    @pl.when(live & (s < n_d))
+    @pl.when(some & (s < n_d))
     def _():                       # x @ wg, x @ wu over this chunk of D
         @pl.when(s == 0)
         def _():
@@ -178,7 +188,7 @@ def _gated_kernel(n_d, dc, tile_e_ref, n_tiles_ref, x_ref, wg_ref, wu_ref,
             h_buf[...] = (jax.nn.silu(g_acc[...]) * u_acc[...]).astype(
                 h_buf.dtype)
 
-    @pl.when(live & (s >= n_d))
+    @pl.when(some & (s >= n_d))
     def _():                       # this chunk of F into a chunk of the row
         at = pl.ds(pl.multiple_of((s - n_d) * dc, 128), dc)
         o_ref[0, :, at] += jnp.dot(h_buf[...],
@@ -193,27 +203,24 @@ def _gated_pallas(x_tiles, tile_e, n_tiles, wg, wu, wd, *, interpret=False):
     f = wg.shape[2]
     dc, fc = gated_chunks(d, f)
     n_d, n_f = d // dc, f // fc
-    # as in the kernel below: a tile past the live ones keeps the blocks
-    # the last live tile ended on, so nothing is fetched for it
-    last = jnp.maximum(n_tiles - 1, 0)
-    tile_e = jnp.where(jnp.arange(tiles) < n_tiles, tile_e,
-                       tile_e[last]).astype(jnp.int32)
     n_tiles = jnp.reshape(n_tiles, (1,)).astype(jnp.int32)
+    # the grid's tile axis is the live tiles alone (a bound of at least
+    # one: with none live, the one tile walked keeps its first blocks)
 
     def up_block(i, j, s, te, n):        # while the row goes down: held
-        live = i < n[0]
-        return (te[i], jnp.where(live, jnp.minimum(s, n_d - 1), n_d - 1),
-                jnp.where(live, j, n_f - 1))
+        some = n[0] > 0
+        return (te[i], jnp.where(some, jnp.minimum(s, n_d - 1), 0),
+                jnp.where(some, j, 0))
 
     def down_block(i, j, s, te, n):      # while the row goes up: the first
-        live = i < n[0]
-        return (te[i], jnp.where(live, j, n_f - 1),
-                jnp.where(live, jnp.maximum(s - n_d, 0), n_d - 1))
+        some = n[0] > 0
+        return (te[i], jnp.where(some, j, 0),
+                jnp.where(some, jnp.maximum(s - n_d, 0), 0))
 
     row = pl.BlockSpec((1, rows, d), lambda i, j, s, te, n: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(tiles, n_f, 2 * n_d),
+        grid=(jnp.maximum(n_tiles[0], 1), n_f, 2 * n_d),
         in_specs=[row,
                   pl.BlockSpec((1, dc, fc), up_block),
                   pl.BlockSpec((1, dc, fc), up_block),
@@ -231,7 +238,7 @@ def _gated_pallas(x_tiles, tile_e, n_tiles, wg, wu, wd, *, interpret=False):
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
         name=GATED_KERNEL_NAME,
-    )(tile_e, n_tiles, x_tiles, wg, wu, wd)
+    )(tile_e.astype(jnp.int32), n_tiles, x_tiles, wg, wu, wd)
 
 
 def tile_ffn_pallas(x_tiles, tile_e, n_tiles, *stacks, interpret=False):

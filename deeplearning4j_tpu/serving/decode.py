@@ -124,7 +124,9 @@ import numpy as np
 
 from ..models import transformer as _transformer
 from ..nn.conf.layers import EmbeddingSequenceLayer
+from ..nn.conf import moe as _moe
 from ..nn.conf.mla import MLAttentionLayer
+from ..ops import grouped_ffn as _ffn
 from ..ops import paged_attention as _paged
 from ..util import faults as _faults
 from ..util import flightrecorder as _flight
@@ -322,8 +324,17 @@ class PagedDecodeEngine:
         # recurrent state, and expert layers whose routing is counted
         self.state_layers = _transformer.lane_state_vertices(net)
         self.kda_layers = _transformer.kda_vertices(net)
-        self._counting_layers = len(_transformer.counting_vertices(net))
-        self._counting = bool(self._counting_layers)
+        experts = [net._vertex_layer(v)
+                   for v in _transformer.counting_vertices(net)]
+        self._counting_layers = len(experts)
+        self._counting = bool(experts)
+        # the expert kernel and the routing shape its grid is sized by,
+        # where every expert layer shares them (three stacks: the gated
+        # kernel, whose grid walks only the live tiles)
+        grids = {(_ffn.GATED_KERNEL_NAME if isinstance(e, _moe.GatedMoELayer)
+                  else _ffn.KERNEL_NAME, e.top_k, e.held, e.n_experts)
+                 for e in experts}
+        self._moe_grid = grids.pop() if len(grids) == 1 else None
         # vertices with a rotary term take each lane's absolute position
         self.position_layers = _transformer.position_vertices(net)
         # EVA vertices hold a window of raw K/V pages and summary pages a
@@ -499,6 +510,14 @@ class PagedDecodeEngine:
                 "moe_grouped_calls_total",
                 "Calls of the expert layers' grouped product: dispatches "
                 "x expert layers x steps, counted on the host")
+            self._m_moe_grid = self.registry.counter(
+                "moe_grid_tiles_total",
+                "Tiles of the expert layers' Pallas kernel over its calls, "
+                "by part: walked (the gated kernel walks the live tiles "
+                "alone, the rows computed over a tile's rows; the grouped "
+                "one every tile the arrays are sized for) or skipped (the "
+                "rest of that size); counted on the host from the "
+                "routing counts a dispatch brings back", ("kernel", "part"))
         if self.eva_layers:
             self._init_eva_metrics()
         if self.kda_layers:
@@ -1027,7 +1046,7 @@ class PagedDecodeEngine:
                            (write_slots >= 0).sum(axis=1))
         else:
             self._note_kv_read("paged", rel_pos, t_new)
-        self._note_routing(counts, steps=1)
+        self._note_routing(counts, steps=1, tokens=b * t_new)
         if self.kda_layers:
             self._note_kda(lanes, t_new, (write_slots >= 0).sum(axis=1))
         return probs
@@ -1049,21 +1068,32 @@ class PagedDecodeEngine:
                    "positions": np.asarray(rel, np.int64) + base}
         return tuple(np.asarray(by_name[name], np.int32) for name in names)
 
-    def _note_routing(self, counts: list, steps: int) -> None:
+    def _note_routing(self, counts: list, steps: int, tokens: int) -> None:
         """Account the expert layers' routing counts that a dispatch of
-        ``steps`` decode steps brought back beside its tokens
-        (``nn.conf.moe.MOE_STATS``)."""
+        ``steps`` decode steps, ``tokens`` tokens a step, brought back
+        beside its tokens (``nn.conf.moe.MOE_STATS``), and the tiles of
+        the expert kernel's grid they imply."""
         if not counts or self._warming:
             return
-        self._m_moe_calls.inc(self._counting_layers * steps)
-        held, absent, computed, peak, steps, touched = (
+        calls = self._counting_layers * steps
+        self._m_moe_calls.inc(calls)
+        held, absent, computed, peak, observed, touched = (
             int(v) for v in counts[0])
         self._m_moe_routed.inc(held, where="held")
         self._m_moe_routed.inc(absent, where="absent")
         self._m_moe_computed.inc(computed)
         self._m_moe_peak.inc(peak)
-        self._m_moe_steps.inc(steps)
+        self._m_moe_steps.inc(observed)
         self._m_moe_touched.inc(touched)
+        if self._moe_grid is not None:
+            kernel, k, e_held, n_published = self._moe_grid
+            rows, max_tiles = _moe.tile_grid(tokens * k, e_held, n_published)
+            sized = calls * max_tiles
+            walked = (computed // rows if kernel == _ffn.GATED_KERNEL_NAME
+                      else sized)
+            self._m_moe_grid.inc(walked, kernel=kernel, part="walked")
+            self._m_moe_grid.inc(sized - walked, kernel=kernel,
+                                 part="skipped")
 
     def _dispatch(self, name: str, step, arena, params, args: tuple, *,
                   kind: str, sync: bool = True) -> list:
@@ -1394,7 +1424,7 @@ class PagedDecodeEngine:
             self._note_eva("fused", rel, lanes, 1, n_emitted, steps=steps)
         else:
             self._note_kv_read("fused", rel, 1, steps=steps)
-        self._note_routing(counts, steps=steps)
+        self._note_routing(counts, steps=steps, tokens=b)
         if self.kda_layers:
             self._note_kda(lanes, 1, n_emitted, steps=steps)
         return toks, valid, n_emitted

@@ -626,6 +626,9 @@ METRIC_CELLS = {
                                   "serve-nemotron3-reason",
                                   "serve-pangu-longdoc",
                                   "serve-solar2-longprompt"],
+    "moe_grid_walked_share": ["serve-nemotron3-reason",
+                              "serve-pangu-longdoc",
+                              "serve-solar2-longprompt"],
 }
 
 
@@ -651,13 +654,17 @@ def _metric_share(metric, eng):
 
 
 @pytest.mark.parametrize("name", sorted(METRIC_CELLS))
-def test_benchmark_metric_names_counters_the_registry_has(net, name):
+def test_benchmark_metric_names_counters_the_registry_has(net, latent_net,
+                                                          name):
     metric = _metric(name)
     assert metric["name"] == name
-    assert metric["layer"] == "paged read"
+    experts = name.startswith("moe_")
+    assert metric["layer"] == ("expert layer" if experts else "paged read")
     assert metric["moves"] == "serve_tokens_per_s"
     assert metric["reader"]["kind"] == "counter_ratio"
-    share = _metric_share(metric, _serve(net, [[1, 2, 3]], [4]))
+    eng = (_serve(latent_net, [[1, 2, 3]], [4], pages_per_seq=64)
+           if experts else _serve(net, [[1, 2, 3]], [4]))
+    share = _metric_share(metric, eng)
     if name == "paged_read_long_trip_share":
         assert share == 0               # OPT-like heads of 8: 128-key trips
     elif name == "paged_read_unturned_share":
@@ -735,6 +742,77 @@ def test_a_one_head_read_is_counted_unturned(latent_net, net):
         kind="paged") > 0
     assert opt.registry.get("decode_kv_unturned_tokens_total").value(
         kind="paged") == 0
+
+
+def _recording_routing(monkeypatch):
+    """``(counts, steps, tokens)`` of every dispatch the engines of a
+    test account their routing for."""
+    seen = []
+    real = PagedDecodeEngine._note_routing
+
+    def recording(self, counts, steps, tokens):
+        if counts and not self._warming:
+            seen.append(([int(v) for v in counts[0]], steps, tokens))
+        return real(self, counts, steps, tokens)
+
+    monkeypatch.setattr(PagedDecodeEngine, "_note_routing", recording)
+    return seen
+
+
+def _sized(seen, layers, k, e_held, n_published):
+    """Tiles computed over the tiles' rows, and the tiles the kernel's
+    arrays are sized for, over the recorded dispatches."""
+    from deeplearning4j_tpu.nn.conf.moe import tile_rows
+    computed = sized = 0
+    for counts, steps, tokens in seen:
+        rows = tile_rows(tokens * k, n_published)
+        assert counts[2] % rows == 0
+        computed += counts[2] // rows
+        sized += layers * steps * (e_held + tokens * k // rows)
+    return computed, sized
+
+
+def _grid_tiles(eng):
+    series = eng.registry.get("moe_grid_tiles_total").snapshot()["series"]
+    return {(s["labels"]["kernel"], s["labels"]["part"]): s["value"]
+            for s in series}
+
+
+def test_a_gated_engine_counts_the_live_tiles_its_kernel_walks(
+        latent_net, monkeypatch):
+    """Two expert layers holding 4 of 16 experts, 3 a token: the gated
+    kernel walks the tiles the routing filled (rows computed over a
+    tile's rows, per dispatch) and skips the rest of the tiles its arrays
+    are sized for (``e_held + T·k // rows`` a call), in prefill chunks
+    and fused blocks alike; counted from what the dispatch brought back."""
+    seen = _recording_routing(monkeypatch)
+    eng = _serve(latent_net, [list(range(1, 12)), [5, 6, 7]], [7, 6],
+                 pages_per_seq=64)
+    assert {tokens for _, _, tokens in seen} >= {2 * 8, 2}
+    walked, sized = _sized(seen, 2, 3, 4, 16)
+    assert _grid_tiles(eng) == {("moe_gated_ffn", "walked"): walked,
+                                ("moe_gated_ffn", "skipped"): sized - walked}
+    assert 0 < walked < sized
+    share = _metric_share(_metric("moe_grid_walked_share"), eng)
+    assert share == pytest.approx(100.0 * walked / sized)
+
+
+def test_a_squared_relu_engine_walks_every_tile(monkeypatch):
+    """The grouped kernel's grid is static: every tile it is sized for
+    is walked, none skipped, and the share reads 100."""
+    hybrid = ComputationGraph(nemotron_h_lm(
+        VOCAB, pattern="*E", d_model=16, n_heads=4, n_kv_heads=2,
+        mamba_heads=1, mamba_head_dim=8, mamba_groups=1, state_size=8,
+        n_experts=16, top_k=3, d_latent=8, d_expert=8, d_shared=8,
+        experts_held=4, expert_offset=4, max_cache_t=256)).init()
+    seen = _recording_routing(monkeypatch)
+    eng = _serve(hybrid, [list(range(1, 12)), [5, 6, 7]], [7, 6],
+                 prefix_cache=False)
+    _, sized = _sized(seen, 1, 3, 4, 16)
+    assert sized > 0
+    assert _grid_tiles(eng) == {("moe_grouped_ffn", "walked"): sized,
+                                ("moe_grouped_ffn", "skipped"): 0}
+    assert _metric_share(_metric("moe_grid_walked_share"), eng) == 100
 
 
 @pytest.fixture(scope="module")

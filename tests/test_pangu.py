@@ -16,6 +16,7 @@ probabilities then agree to some 1e-6; the limit is 5e-5, and the same
 comparison with one product's operands rounded to bfloat16 reads 1e-3 and
 more (each test that states float32 shows it)."""
 
+import contextlib
 import json
 import os
 import sys
@@ -219,7 +220,8 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
 def test_gated_grouped_product_against_the_dense_oracle(d, f):
     """The interpreted kernel and the XLA ``while`` against every expert
     computing every token; at 2304 x 640 the kernel walks two chunks of
-    ``D`` and five of ``F``."""
+    ``D`` and five of ``F``. The kernel's grid walks the live tiles alone,
+    so the two agree on those; what it leaves past them is never read."""
     if d > 64:
         assert grouped_ffn.gated_chunks(d, f) == (1152, 128)
     rng = np.random.default_rng(3)
@@ -242,14 +244,69 @@ def test_gated_grouped_product_against_the_dense_oracle(d, f):
     by_xla = grouped_ffn.tile_ffn_xla(tiles, tile_e, n_tiles, *stacks)
     by_kernel = grouped_ffn.tile_ffn_pallas(tiles, tile_e, n_tiles, *stacks,
                                             interpret=True)
-    assert np.abs(by_kernel - by_xla).max() < 1e-5 * scale
-    assert not np.asarray(by_kernel[3]).any()              # past n_tiles
+    assert np.abs(by_kernel[:3] - by_xla[:3]).max() < 1e-5 * scale
+    assert not np.asarray(by_xla[3]).any()                 # past n_tiles
+    with poisoned_past_the_live_tiles():
+        got, _ = sparse_expert_ffn(x, idx, w, *stacks, offset=offset,
+                                   n_published=8)
+    assert np.abs(got - want).max() < 1e-5 * scale
     hand = gated_ffn(tiles[1], *(s[2] for s in stacks))
     assert np.abs(by_kernel[1] - hand).max() < 1e-5 * scale
     # float32 is stated: bfloat16 rows are a hundred times beyond
     low = grouped_ffn.tile_ffn_xla(tiles.astype(jnp.bfloat16), tile_e,
                                    n_tiles, *stacks)
     assert np.abs(low - by_xla).max() > 1e-3 * scale
+
+
+@contextlib.contextmanager
+def poisoned_past_the_live_tiles():
+    """``tile_ffn`` whose rows past ``n_tiles`` are NaN: what the gated
+    kernel may leave there (the interpreter does)."""
+    real = grouped_ffn.tile_ffn
+
+    def poisoned(x_tiles, tile_e, n_tiles, *stacks):
+        out = real(x_tiles, tile_e, n_tiles, *stacks)
+        past = jnp.arange(out.shape[0]) >= n_tiles
+        return jnp.where(past[:, None, None], jnp.nan, out)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(grouped_ffn, "tile_ffn", poisoned)
+        yield
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+@pytest.mark.parametrize("offset", [2, 40])
+def test_what_lies_past_the_live_tiles_is_never_read(gated, offset):
+    """Tiles past ``n_tiles`` full of NaN, for gated and squared-ReLU
+    stacks alike: ``sparse_expert_ffn`` still equals the dense oracle,
+    absent pairs included (their indices are clamped onto some tile). At
+    offset 40 every pair goes to an absent expert (``n_tiles`` 0): finite
+    zeros, poisoned or through the interpreted kernel."""
+    rng = np.random.default_rng(7)
+    t, k, e_held, d, f = 7, 3, 3, 32, 24
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 8, (t, k)), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (t, k)), jnp.float32)
+    shapes = (((e_held, d, f), (e_held, d, f), (e_held, f, d)) if gated
+              else ((e_held, d, f), (e_held, f, d)))
+    stacks = tuple(jnp.asarray(rng.normal(size=s) / np.sqrt(s[1]),
+                               jnp.float32) for s in shapes)
+    want = dense_expert_ffn(x, idx, w, *stacks, offset=offset)
+    with poisoned_past_the_live_tiles():
+        got, stats = sparse_expert_ffn(x, idx, w, *stacks, offset=offset,
+                                       n_published=8)
+    held = int(((idx >= offset) & (idx < offset + e_held)).sum())
+    assert (int(stats[0]), int(stats[1])) == (held, t * k - held)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(got - want).max() < 1e-5 * max(
+        float(jnp.abs(want).max()), 1.0)
+    if offset == 40:
+        assert held == 0 and int(stats[2]) == 0
+        assert not np.asarray(got).any()
+        by_kernel, _ = sparse_expert_ffn(x, idx, w, *stacks, offset=offset,
+                                         n_published=8)   # interpreted
+        assert np.isfinite(np.asarray(by_kernel)).all()
+        assert not np.asarray(by_kernel).any()
 
 
 # -- prefix reuse under an expert layer ---------------------------------------

@@ -590,6 +590,62 @@ def test_compiled_for_v5e_the_solar_programs_fit(one_chip, bucket,
     assert compiled.as_text().count("moe_gated_ffn") >= 4
 
 
+# the expert kernels at the cells' shapes, (tiles, rows, width, expert
+# width, experts held, stacks): the latent decode step (32 lanes x 8 pairs
+# in tiles of 2 rows over 16 held: 16 + 128 tiles), Solar's 32-lane
+# prefill (32 x 128 x 8 pairs in tiles of 128 over 40 held: 40 + 256), and
+# the hybrid's squared-ReLU decode step (32 x 22 pairs, 128 held: 480)
+EXPERT_KERNELS = {"gated_latent_decode": (144, 2, 7680, 2048, 16, 3),
+                  "gated_solar_prefill": (296, 128, 4096, 1280, 40, 3),
+                  "relu2_hybrid_decode": (480, 2, 1024, 2688, 128, 2)}
+
+
+def _pallas_grids(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["grid_mapping"]
+        for sub in _sub_jaxprs(eqn):
+            yield from _pallas_grids(sub)
+
+
+@pytest.mark.parametrize("kernel", sorted(EXPERT_KERNELS))
+def test_compiled_for_v5e_the_gated_kernel_walks_a_dynamic_tile_axis(
+        one_chip, kernel):
+    """The gated kernel's tile axis is a bound computed on the device
+    (the live tiles), its two chunk axes static, and it compiles so; the
+    squared-ReLU kernel's grid stays all static, every tile it is sized
+    for."""
+    from deeplearning4j_tpu.ops import grouped_ffn
+    tiles, rows, d, f, e, n_stacks = EXPERT_KERNELS[kernel]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    stacks = [arg((e, d, f)), arg((e, f, d))]
+    if n_stacks == 3:
+        stacks.insert(0, arg((e, d, f)))
+        dc, fc = grouped_ffn.gated_chunks(d, f)
+        static = (f // fc, 2 * d // dc)
+    else:
+        static = (tiles, f // grouped_ffn.f_chunk(f))
+    args = (arg((tiles, rows, d)), arg((tiles,), jnp.int32),
+            arg((), jnp.int32), *stacks)
+
+    def call(x, tile_e, n_tiles, *stacks):
+        return grouped_ffn.tile_ffn_pallas(x, tile_e, n_tiles, *stacks)
+
+    with jax.enable_x64(False):     # as on the chip; the suite runs with x64
+        (grid,) = _pallas_grids(jax.make_jaxpr(call)(*args).jaxpr)
+        text = jax.jit(call).lower(*args).compile().as_text()
+    if n_stacks == 3:
+        assert grid.num_dynamic_grid_bounds == 1
+        assert not isinstance(grid.grid[0], int)
+        assert tuple(grid.grid[1:]) == static
+    else:
+        assert grid.num_dynamic_grid_bounds == 0
+        assert tuple(grid.grid) == static
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("bh, t, d, calls", [
     (32, 8192, 64, 1),       # the training cell: [1, 8192, 32, 64]
     (4, 16384, 64, 1),       # a head's float32 dq at the budget, 4 MiB
