@@ -975,10 +975,8 @@ def _bench_prefix_cache(net, baseline_engine, vocab, lanes, page_size,
         reg.get("kv_pages_cow_total").value())
 
     # ---- int8 quality bound vs the dense float oracle ----------------
-    dims = {}
-    for name in attention_vertices(net):
-        layer = net.conf.vertices[name].layer
-        dims[name] = (layer.n_heads, layer.n_in // layer.n_heads)
+    dims = {name: net._vertex_layer(name).paged_entry(1)
+            for name in attention_vertices(net)}
     t = 4 * ps
     seq = rng.integers(0, vocab, t).astype(np.int32)
     oracle = oracle_stream_probs(net, seq)                  # [t, V]

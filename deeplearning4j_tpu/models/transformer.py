@@ -32,11 +32,9 @@ import jax
 import numpy as np
 
 from ..nn.conf.attention import SelfAttentionLayer
-from ..nn.conf.eva import EvaAttentionLayer
 from ..nn.conf.kda import KimiDeltaAttention
-from ..nn.conf.mla import MLAttentionLayer
 from ..nn.conf.builders import NeuralNetConfiguration
-from ..nn.conf.graph import ElementWiseVertex, LayerVertex
+from ..nn.conf.graph import ElementWiseVertex
 from ..nn.conf.inputs import InputType
 from ..nn.conf.layers import (EmbeddingSequenceLayer, LayerNormalization,
                               RnnOutputLayer)
@@ -134,61 +132,33 @@ def transformer_lm(vocab_size: int, *, n_layers: int = 4,
 # --------------------------------------------------------------------------
 
 
-# the layers that own a K/V cache (dense or paged) during decode
-ATTENTION_LAYERS = (SelfAttentionLayer, MLAttentionLayer, EvaAttentionLayer)
+def stateful_vertices(net, wants: Optional[str] = None) -> List[str]:
+    """Topo-ordered names of the vertices that own state during paged
+    decode, one ordered list for the walker, the arena and the engine: the
+    causal layers that declare ``paged_inputs``, the dispatch arrays their
+    ``apply_paged`` takes after their state (a layer also declares its
+    arena entry, ``paged_entry``, and its read, ``paged_read``).
+    ``wants``: only those that take that array (``"lane_ids"``: a row of
+    state a lane; ``"positions"``: absolute positions, a rotary term)."""
+    layers = ((name, net._vertex_layer(name)) for name in net.topo_order)
+    return [name for name, layer in layers
+            if getattr(layer, "paged_inputs", ())
+            and getattr(layer, "causal", True)
+            and (wants is None or wants in layer.paged_inputs)]
 
 
 def attention_vertices(net) -> List[str]:
-    """Topo-ordered names of the net's causal attention vertices
-    (:data:`ATTENTION_LAYERS`) — the layers that own a K/V cache (dense
+    """Those of :func:`stateful_vertices` that read K/V through the lanes'
+    page tables (a ``paged_read``): the layers that own a K/V cache (dense
     or paged) during decode."""
-    names = []
-    for name in net.topo_order:
-        v = net.conf.vertices[name]
-        layer = v.layer if isinstance(v, LayerVertex) else None
-        if isinstance(layer, ATTENTION_LAYERS) and layer.causal:
-            names.append(name)
-    return names
-
-
-def stateful_vertices(net) -> List[str]:
-    """Topo-ordered names of the vertices that own state during paged
-    decode, one ordered list for the walker, the arena and the engine:
-    causal attention (a K and a V pool; a latent layer one pool) and the
-    layers that say they hold a fixed-size row of state a lane
-    (``holds_lane_state``: Mamba-2's convolution tail and SSM state, Kimi
-    delta attention's convolution tail and delta-rule state)."""
-    attn = set(attention_vertices(net))
-    return [name for name in net.topo_order
-            if name in attn
-            or getattr(net._vertex_layer(name), "holds_lane_state", False)]
-
-
-def lane_state_vertices(net) -> List[str]:
-    """Those of :func:`stateful_vertices` whose state is a fixed-size row a
-    lane, not pages."""
     return [name for name in stateful_vertices(net)
-            if getattr(net._vertex_layer(name), "holds_lane_state", False)]
+            if net._vertex_layer(name).paged_read is not None]
 
 
 def kda_vertices(net) -> List[str]:
     """Names of the Kimi delta attention vertices (``nn/conf/kda``)."""
-    return [name for name in lane_state_vertices(net)
+    return [name for name in stateful_vertices(net)
             if isinstance(net._vertex_layer(name), KimiDeltaAttention)]
-
-
-def position_vertices(net) -> List[str]:
-    """Names of the vertices that take each lane's ABSOLUTE position (a
-    rotary term): the walkers hand it to no other."""
-    return [name for name in net.topo_order
-            if getattr(net._vertex_layer(name), "wants_positions", False)]
-
-
-def eva_vertices(net) -> List[str]:
-    """Names of the EVA attention vertices (``nn/conf/eva``): a window of
-    raw K/V pages and summary pages a lane, in one table."""
-    return [name for name in attention_vertices(net)
-            if isinstance(net._vertex_layer(name), EvaAttentionLayer)]
 
 
 def counting_vertices(net) -> List[str]:
@@ -196,6 +166,18 @@ def counting_vertices(net) -> List[str]:
     take the dispatch's valid positions as their mask."""
     return [name for name in net.topo_order
             if getattr(net._vertex_layer(name), "wants_token_mask", False)]
+
+
+def ids_embedding(net):
+    """The ``EmbeddingSequenceLayer`` that takes the net's input (the
+    integer-id input path), or None."""
+    in_name = net.conf.network_inputs[0]
+    for name in net.topo_order:
+        layer = getattr(net.conf.vertices[name], "layer", None)
+        if (in_name in net.conf.vertex_inputs[name]
+                and isinstance(layer, EmbeddingSequenceLayer)):
+            return layer
+    return None
 
 
 def filtered_probs_host(p: np.ndarray, temperature: float, top_k: int,
@@ -324,16 +306,14 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
                          out_rows=None, positions=None, fed=None):
     """ONE traced forward of an ids-mode decoder graph in paged-decode
     mode: every stateful vertex (:func:`stateful_vertices`) reads and
-    writes ITS entry of the two state lists; every other vertex applies
-    exactly as in ``output()``. A causal attention vertex owns a K and a V
-    block pool, read and written through the lanes' page tables
-    (``SelfAttentionLayer.apply_paged``; an EVA vertex's table holds its
-    window pages and then its summary pages, ``EvaAttentionLayer.
-    apply_paged``); a vertex with per-lane state owns a ``[lanes, K-1,
-    C]`` convolution tail and a ``[lanes, ...]`` recurrent state (an SSM
-    state, ``Mamba2Mixer.apply_paged``; a delta-rule state,
-    ``KimiDeltaAttention.apply_paged``), of which the dispatch's rows are
-    ``lane_ids``. Pure w.r.t. its arguments, so the serving engine jits it
+    writes ITS entry of the two state lists, one state for a vertex of one
+    pool (None in ``v_pools``) and two otherwise, through one call,
+    ``layer.apply_paged(params, x, *state, *inputs, policy=...)``, whose
+    ``inputs`` are the dispatch's arrays its ``paged_inputs`` name:
+    ``tables`` (``page_tables``), ``write_slots``, ``rel_pos``,
+    ``positions``, ``lane_ids``, ``valid`` (below) and ``fresh`` (a lane
+    at ``rel_pos`` 0). Every other vertex applies exactly as in
+    ``output()``. Pure w.r.t. its arguments, so the serving engine jits it
     once per (lanes, chunk) bucket and admission/retirement only ever
     change array CONTENTS.
 
@@ -353,7 +333,7 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     is resident) for their distribution: ``fed [S]`` then gives each lane's
     number of fed positions and "computed" is ``column < fed``; without it
     the two masks are one. ``positions [S]`` (a net with
-    :func:`position_vertices` only): the absolute position of each lane's
+    vertices that take them only): the absolute position of each lane's
     first new token, which is ``rel_pos`` plus what the lane's window has
     evicted. A lane at ``rel_pos`` 0 starts a sequence, so its
     recurrent state starts from zero inside this program (no dispatch of
@@ -382,10 +362,13 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
     mbs = net._minibatch_map(ids.shape[0])
     # a position is valid where its write is kept: what a per-lane state
     # vertex advances over and an expert layer counts
-    valid = (write_slots >= 0 if lane_state_vertices(net)
+    valid = (write_slots >= 0 if stateful_vertices(net, "valid")
              or counting_vertices(net) else None)
     computed = valid if fed is None else (
         jnp.arange(ids.shape[1], dtype=fed.dtype)[None, :] < fed[:, None])
+    view = {"tables": page_tables, "write_slots": write_slots,
+            "rel_pos": rel_pos, "positions": positions, "lane_ids": lane_ids,
+            "valid": valid}
     stats = None
     head = net.conf.network_outputs[0]
     for name in net.topo_order:
@@ -397,23 +380,13 @@ def paged_decode_forward(net, params, k_pools, v_pools, ids, page_tables,
             acts = dict(acts, **{n: jnp.take_along_axis(
                 acts[n], out_rows[:, None, None], axis=1, mode="clip")
                 for n in in_names})
-        if i is not None and isinstance(layer, MLAttentionLayer):
-            out, k_pools[i] = layer.apply_paged(
-                params[name], acts[in_names[0]], k_pools[i], page_tables,
-                write_slots, rel_pos, positions, policy=net.policy)
-        elif i is not None and isinstance(layer, EvaAttentionLayer):
-            out, k_pools[i], v_pools[i] = layer.apply_paged(
-                params[name], acts[in_names[0]], k_pools[i], v_pools[i],
-                page_tables, write_slots, rel_pos, positions,
-                policy=net.policy)
-        elif i is not None and isinstance(layer, SelfAttentionLayer):
-            out, k_pools[i], v_pools[i] = layer.apply_paged(
-                params[name], acts[in_names[0]], k_pools[i], v_pools[i],
-                page_tables, write_slots, rel_pos, policy=net.policy)
-        elif i is not None:
-            out, k_pools[i], v_pools[i] = layer.apply_paged(
-                params[name], acts[in_names[0]], k_pools[i], v_pools[i],
-                lane_ids, valid, rel_pos == 0, policy=net.policy)
+        if i is not None:
+            state = [p for p in (k_pools[i], v_pools[i]) if p is not None]
+            out, *state = layer.apply_paged(
+                params[name], acts[in_names[0]], *state,
+                *(rel_pos == 0 if n == "fresh" else view[n]
+                  for n in layer.paged_inputs), policy=net.policy)
+            k_pools[i], v_pools[i] = (*state, None)[:2]
         elif getattr(layer, "wants_token_mask", False):
             out, st = net._apply_vertex(name, params[name], acts, {}, None,
                                         train=False, in_masks=[computed],
@@ -486,7 +459,7 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     (:func:`paged_decode_forward`), and the ``while`` carries them, as it
     carries a per-lane state vertex's state in the two state lists
     (``lane_ids``): a retired lane's dropped slot keeps its state still.
-    ``positions [S]`` (a net with :func:`position_vertices`): each lane's
+    ``positions [S]`` (a net with vertices that take them): each lane's
     absolute position at the block's first step; step ``i`` is at
     ``positions + i``.
 
@@ -510,7 +483,7 @@ def fused_decode_loop(net, params, k_pools, v_pools, last_tokens,
     k_pools, v_pools = list(k_pools), list(v_pools)
     owners = stateful_vertices(net)
     state_ix = [] if lane_ids is None else [
-        owners.index(name) for name in lane_state_vertices(net)]
+        owners.index(name) for name in stateful_vertices(net, "lane_ids")]
     whole = {i: (k_pools[i], v_pools[i]) for i in state_ix}
     for i in state_ix:
         k_pools[i], v_pools[i] = _ssm.gather_lanes(k_pools[i], v_pools[i],

@@ -72,6 +72,8 @@ class SelfAttentionLayer(Layer):
     # BEFORE the overflowing dispatch (for callers whose correctness
     # depends on full history)
     cache_overflow: str = "evict"
+    # the dispatch arrays apply_paged takes after its pools (serving/decode)
+    paged_inputs = ("tables", "write_slots", "rel_pos")
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out or self.n_in,
@@ -298,6 +300,18 @@ class SelfAttentionLayer(Layer):
         v_cache = jnp.concatenate(
             [body_v, v_cache[:, max_t:].at[:, 0, 0].set(new_pos)], axis=1)
         return out, {"h": k_cache, "c": v_cache}
+
+    def paged_entry(self, lanes: int):
+        """The arena's entry (``serving/kv_cache.PagedEntry``): a K and a V
+        pool of ``kv_width`` a token."""
+        from ...serving.kv_cache import PagedEntry
+        return PagedEntry((self.kv_width,) * 2, self.kv_heads)
+
+    @property
+    def paged_read(self) -> Tuple[int, int, int]:
+        """``(group, kv_heads, d_v)``: query heads a K/V head, K/V heads and
+        the value's width, which sets the paged read's trip."""
+        return self.n_heads // self.kv_heads, self.kv_heads, self.head_dim
 
     def apply_paged(self, params, x, k_pool, v_pool, page_table,
                     write_slots, rel_pos, *, policy=None):

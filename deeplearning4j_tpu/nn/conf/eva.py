@@ -79,7 +79,8 @@ class EvaAttentionLayer(Layer):
     rope_theta: float = 10000.0
     causal: bool = True               # the only kind there is
     max_cache_t: Optional[int] = None
-    wants_positions = True            # the paged walker hands it absolute ones
+    # the dispatch arrays apply_paged takes after its pools (serving/decode)
+    paged_inputs = ("tables", "write_slots", "rel_pos", "positions")
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out or self.n_in,
@@ -97,11 +98,6 @@ class EvaAttentionLayer(Layer):
                              f"whole number of chunks of {self.chunk_size}")
         if not self.causal:
             raise ValueError("EvaAttentionLayer is causal")
-
-    @property
-    def kv_heads(self) -> int:
-        """K/V heads of a cache row (multi-head: one a query head)."""
-        return self.n_heads
 
     def has_params(self) -> bool:
         return True
@@ -204,6 +200,18 @@ class EvaAttentionLayer(Layer):
         return out, state
 
     # ---- the paged arena -----------------------------------------------
+
+    def paged_entry(self, lanes: int):
+        """The arena's entry: a K and a V pool of ``H·D``, and a lane's table
+        of one window and its summary pages."""
+        from ...serving.kv_cache import PagedEntry
+        return PagedEntry((self.n_heads * self.head_dim,) * 2, self.n_heads,
+                          window=(self.window_size, self.chunk_size))
+
+    @property
+    def paged_read(self) -> Tuple[int, int, int]:
+        """One query head a K/V head."""
+        return 1, self.n_heads, self.head_dim
 
     def apply_paged(self, params, x, k_pool, v_pool, page_table, write_slots,
                     rel_pos, positions, *, policy=None):

@@ -147,7 +147,10 @@ class KimiDeltaAttention(BaseRecurrentLayer):
     gate_rank: int = 16           # r of the decay's and the output's gates
     conv_kernel: int = 4          # K
     norm_eps: float = 1e-5
-    holds_lane_state = True       # the engine's arena keeps a row a lane
+    # the dispatch arrays apply_paged takes after its state (serving/decode);
+    # a row of state a lane, no paged read
+    paged_inputs = ("lane_ids", "valid", "fresh")
+    paged_read = None
 
     # ---- shapes ------------------------------------------------------
 
@@ -211,6 +214,11 @@ class KimiDeltaAttention(BaseRecurrentLayer):
         """(convolution tail, delta-rule state) of ``lanes`` sequences."""
         return ((lanes, self.conv_kernel - 1, self.conv_channels),
                 (lanes, self.n_heads, self.head_dim, self.head_dim))
+
+    def paged_entry(self, lanes: int):
+        """The arena's entry: every lane's tail and delta-rule state."""
+        from ...serving.kv_cache import PagedEntry
+        return PagedEntry(lane=self.state_shapes(lanes))
 
     def _zero_state(self, batch, policy):
         """The dense streaming carry: ``h`` is the convolution's tail,

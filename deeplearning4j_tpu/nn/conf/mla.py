@@ -88,7 +88,8 @@ class MLAttentionLayer(Layer):
     causal: bool = True               # the only kind there is
     max_cache_t: Optional[int] = None
     cache_overflow: str = "evict"
-    wants_positions = True            # the paged walker hands it absolute ones
+    # the dispatch arrays apply_paged takes after its pool (serving/decode)
+    paged_inputs = ("tables", "write_slots", "rel_pos", "positions")
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out or self.n_in,
@@ -302,6 +303,16 @@ class MLAttentionLayer(Layer):
         return out, {"h": cache, "c": state["c"]}
 
     # ---- the paged arena -------------------------------------------------
+
+    def paged_entry(self, lanes: int):
+        """The arena's entry: ONE pool of ``pool_width``, rows of no heads."""
+        from ...serving.kv_cache import PagedEntry
+        return PagedEntry((self.pool_width,))
+
+    @property
+    def paged_read(self) -> Tuple[int, int, int]:
+        """Every head on ONE K/V head, the value ``kv_rank`` wide."""
+        return self.n_heads, 1, self.kv_rank
 
     def apply_paged(self, params, x, pool, page_table, write_slots, rel_pos,
                     positions, *, policy=None):

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import dtypes as _dtypes
+from ...ops import grouped_ffn as _ffn
 from .inputs import InputType
 from .layers import Layer, register_layer
 from ..weights import init_weights
@@ -342,6 +343,7 @@ class LatentMoELayer(Layer):
     top_k: int = 2
     routed_scale: float = 1.0
     wants_token_mask = True              # the paged walker hands it `valid`
+    kernel_name = _ffn.KERNEL_NAME       # its grouped product's Pallas kernel
 
     @property
     def held(self) -> int:
@@ -458,6 +460,8 @@ class GatedMoELayer(LatentMoELayer):
 
     Scopes: ``moe.router``, ``moe.experts``, ``moe.shared``.
     """
+
+    kernel_name = _ffn.GATED_KERNEL_NAME   # walks only the live tiles
 
     def param_shapes(self, policy=None) -> Dict[str, Tuple[int, ...]]:
         d, f, fs = self.n_in, self.d_hidden, self.d_shared

@@ -75,7 +75,10 @@ class Mamba2Mixer(BaseRecurrentLayer):
     norm_eps: float = 1e-5
     dt_min: float = 0.001
     dt_max: float = 0.1
-    holds_lane_state = True       # the engine's arena keeps a row a lane
+    # the dispatch arrays apply_paged takes after its state (serving/decode);
+    # a row of state a lane, no paged read
+    paged_inputs = ("lane_ids", "valid", "fresh")
+    paged_read = None
 
     # ---- shapes ------------------------------------------------------
 
@@ -142,6 +145,11 @@ class Mamba2Mixer(BaseRecurrentLayer):
         """(convolution tail, SSM state) of ``lanes`` sequences."""
         return ((lanes, self.conv_kernel - 1, self.conv_channels),
                 (lanes, self.n_heads, self.head_dim, self.state_size))
+
+    def paged_entry(self, lanes: int):
+        """The arena's entry: every lane's tail and SSM state."""
+        from ...serving.kv_cache import PagedEntry
+        return PagedEntry(lane=self.state_shapes(lanes))
 
     def _zero_state(self, batch, policy):
         """The dense streaming carry: ``h`` is the convolution's tail,
@@ -332,15 +340,16 @@ class Mamba2Mixer(BaseRecurrentLayer):
 
 
 def gather_lanes(conv_state, ssm_state, lane_ids):
-    """Rows ``lane_ids`` of the two per-lane state arrays; an id past the
-    last lane (a padded slot of the bucket) reads zeros."""
-    return (jnp.take(conv_state, lane_ids, axis=0, mode="fill",
-                     fill_value=0),
-            jnp.take(ssm_state, lane_ids, axis=0, mode="fill", fill_value=0))
+    """Rows ``lane_ids`` of the two per-lane state arrays (the second None
+    for a vertex of one); an id past the last lane (a padded slot of the
+    bucket) reads zeros."""
+    return tuple(None if s is None else jnp.take(
+        s, lane_ids, axis=0, mode="fill", fill_value=0)
+        for s in (conv_state, ssm_state))
 
 
 def scatter_lanes(conv_state, ssm_state, lane_ids, tail, ssm):
     """The two arrays with rows ``lane_ids`` replaced; a padded slot's row
     is dropped."""
-    return (conv_state.at[lane_ids].set(tail, mode="drop"),
-            ssm_state.at[lane_ids].set(ssm, mode="drop"))
+    return tuple(None if s is None else s.at[lane_ids].set(r, mode="drop")
+                 for s, r in ((conv_state, tail), (ssm_state, ssm)))

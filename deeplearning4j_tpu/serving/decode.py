@@ -27,10 +27,9 @@ Two layers:
   decode-aware ``drain()``, and a ``fence()`` that holds the loop at a
   step boundary (mid-decode model swaps are refused through it).
 
-Three decode-step shapes (ISSUE 11 — the host-tick headroom PERF.md r9
-measured is the thing being removed):
+Three decode-step shapes:
 
-- ``block_len=1`` (default): the PR-6 host-ticked step — one dispatch,
+- ``block_len=1`` (default): the host-ticked step — one dispatch,
   one host round-trip per generated token.
 - ``block_len=N``: the FUSED loop — ``models.transformer.
   fused_decode_loop`` runs N decode steps (paged scatter, forward,
@@ -92,19 +91,21 @@ vertices compute and carry is counted the same way:
 written position or padding) and ``kda_state_bytes_total`` (the per-lane
 state read and written, ``_note_kda``).
 
-What a net makes the engine carry beside K/V pages, each found on the
-net and handed to the decode programs as one more argument, so that a net
-without it keeps the programs it had: per-lane recurrent state
-(``lane_ids``; Mamba-2 and Kimi delta attention vertices, a row of the
-arena's state entry a lane each), each lane's ABSOLUTE position
-(``positions``; a rotary term, ``nn/conf/mla``: the view-relative position
-indexes the cache, the absolute one turns the keys), and under the prefix
-cache the positions each lane fed (``fed``; an expert layer computes a
-re-fed position whose write is dropped, and skips padding). A latent
-attention vertex owns one pool, not two. An EVA vertex (``nn/conf/eva``)
-owns a K and a V pool as a plain one does, but its lane's table is its
-current window's pages and then its summary pages: at each window edge the
-finished window goes back to the allocator (``_ensure_eva_pages``).
+What the engine serves is read off the net's stateful vertices, each of
+whose layers declares the dispatch arrays its ``apply_paged`` takes
+(``paged_inputs``), what the arena holds for it (``paged_entry``) and the
+shape of its read (``paged_read``): the engine names no kind of layer.
+What a net makes it carry beside K/V pages is handed to the decode
+programs as one more argument, so that a net without it keeps the
+programs it had: the lanes' ids where a vertex keeps a row of state a lane
+(``lane_ids``), each lane's ABSOLUTE position where a vertex takes it
+(``positions``; a rotary term: the view-relative position indexes the
+cache, the absolute one turns the keys), and under the prefix cache the
+positions each lane fed (``fed``; an expert layer computes a re-fed
+position whose write is dropped, and skips padding). The lanes' page
+tables, and admission, paging and release against the allocator, are
+``kv_cache.LaneTables``'s, which also keeps the one other kind of table
+(an EVA lane's window and summary pages, ``nn/conf/eva``).
 
 Fault seam: ``"serving.decode_step"`` before every prefill/decode
 dispatch (chaos tests script outages at exact step boundaries).
@@ -123,9 +124,7 @@ import jax
 import numpy as np
 
 from ..models import transformer as _transformer
-from ..nn.conf.layers import EmbeddingSequenceLayer
 from ..nn.conf import moe as _moe
-from ..nn.conf.mla import MLAttentionLayer
 from ..ops import grouped_ffn as _ffn
 from ..ops import paged_attention as _paged
 from ..util import faults as _faults
@@ -135,7 +134,7 @@ from ..util import tracing as _tracing
 from ..util import xla as _xla
 from ..util.resilience import SYSTEM_CLOCK, Clock, Deadline
 from ..util.tracing import region
-from .kv_cache import PagedKVArena
+from .kv_cache import LaneTables, PagedKVArena
 
 __all__ = ["PagedDecodeEngine", "DecodeScheduler", "DecodeRequest",
            "SchedulerSaturated", "SchedulerDraining"]
@@ -236,18 +235,6 @@ _TICK_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 1.0)
 
 
-def _read_shape(layer) -> Tuple[int, int, int]:
-    """``(group, kv_heads, d_v)`` of an attention vertex's paged read:
-    query heads a K/V head (a grouped-query vertex its group's, a latent
-    one all its heads, read as further rows of one K/V head), K/V heads,
-    and the value's width (a latent vertex's: its row's first ``kv_rank``
-    columns), which sets the read's trip
-    (``ops.paged_attention.read_trip_tokens``)."""
-    if isinstance(layer, MLAttentionLayer):
-        return layer.n_heads, 1, layer.kv_rank
-    return layer.n_heads // layer.kv_heads, layer.kv_heads, layer.head_dim
-
-
 def _module_name(ladder_key: str) -> str:
     """``paged_decode[S8xT128xP128]`` → ``paged_decode_S8_T128_P128``:
     the trace-ladder key as a function name, so each decode program's
@@ -320,31 +307,27 @@ class PagedDecodeEngine:
         self.registry = registry if registry is not None \
             else _metrics.MetricsRegistry()
         self._check_decode_config(net)
-        # what the net makes the engine carry beside K/V pages: per-lane
-        # recurrent state, and expert layers whose routing is counted
-        self.state_layers = _transformer.lane_state_vertices(net)
+        # what the stateful vertices declare: a row of state a lane,
+        # absolute positions (a rotary term), a lane's table of one window
+        # and its summary pages (EVA); and the expert layers whose routing
+        # is counted
+        self.state_layers = _transformer.stateful_vertices(net, "lane_ids")
+        self.position_layers = _transformer.stateful_vertices(
+            net, "positions")
+        self.eva_layers = [n for n, e in self._entries(net).items()
+                           if e.window]
         self.kda_layers = _transformer.kda_vertices(net)
         experts = [net._vertex_layer(v)
                    for v in _transformer.counting_vertices(net)]
         self._counting_layers = len(experts)
         self._counting = bool(experts)
         # the expert kernel and the routing shape its grid is sized by,
-        # where every expert layer shares them (three stacks: the gated
-        # kernel, whose grid walks only the live tiles)
-        grids = {(_ffn.GATED_KERNEL_NAME if isinstance(e, _moe.GatedMoELayer)
-                  else _ffn.KERNEL_NAME, e.top_k, e.held, e.n_experts)
+        # where every expert layer shares them
+        grids = {(e.kernel_name, e.top_k, e.held, e.n_experts)
                  for e in experts}
         self._moe_grid = grids.pop() if len(grids) == 1 else None
-        # vertices with a rotary term take each lane's absolute position
-        self.position_layers = _transformer.position_vertices(net)
-        # EVA vertices hold a window of raw K/V pages and summary pages a
-        # lane, both in the lane's one table (its width below)
-        self.eva_layers = _transformer.eva_vertices(net)
         self._refuse_unsupported(net, prefix_cache=bool(prefix_cache),
                                  draft_net=draft_net, kv_dtype=kv_dtype)
-        self.table_pages = self.pages_per_seq
-        if self.eva_layers:
-            self._eva_geometry(net, num_pages)
         # what a decode program takes after its other arguments, by what
         # the net needs (none for a net that needs none: its programs keep
         # the arguments they always had): the lanes' ids (recurrent state
@@ -356,7 +339,7 @@ class PagedDecodeEngine:
                        + ("positions",) * bool(self.position_layers))
         self._extra_paged = self._extra + ("fed",) * bool(
             self._counting and prefix_cache)
-        self.vocab = self._embed_vocab(net)
+        self.vocab = int(_transformer.ids_embedding(net).n_in)
         self.draft_net = draft_net
         self.draft_k = int(draft_k)
         if draft_net is not None:
@@ -374,24 +357,22 @@ class PagedDecodeEngine:
                     f"({self.window})")
             self._validate_net(draft_net)
             self._check_decode_config(draft_net)
-            if self._embed_vocab(draft_net) != self.vocab:
+            draft_vocab = int(_transformer.ids_embedding(draft_net).n_in)
+            if draft_vocab != self.vocab:
                 raise ValueError(
-                    f"draft vocab {self._embed_vocab(draft_net)} != "
+                    f"draft vocab {draft_vocab} != "
                     f"target vocab {self.vocab} — accept/reject compares "
                     "distributions over one vocabulary")
         with _xla.startup_region("startup.engine_build", self.registry):
             self._build_arenas(net, int(num_pages), bool(prefix_cache),
                                kv_dtype)
-        # per-lane host state
-        s, p = self.lanes, self.table_pages
-        self._tables = np.full((s, p), self.arena.sentinel, np.int32)
-        self._pos = np.zeros(s, np.int64)       # global fed positions
-        self._base = np.zeros(s, np.int64)      # evicted positions
-        self._held: List[List[int]] = [[] for _ in range(s)]
-        self._sum_held: List[List[int]] = [[] for _ in range(s)]  # EVA
-        self._reserve_left = np.zeros(s, np.int64)
-        self._covered = np.zeros(s, np.int64)   # prefix-hit tokens/lane
-        self._free_lanes = deque(range(s))
+        # the lanes' page tables and what fills them; the free lanes here
+        self.lane_tables = LaneTables(
+            self.arena, self.lanes, self.pages_per_seq,
+            prefill_chunk=self.prefill_chunk, block_len=self.block_len,
+            registry=self.registry, shadow=self.draft_arena)
+        self.table_pages = self.lane_tables.width
+        self._free_lanes = deque(range(self.lanes))
         self._jit_cache: Dict[str, object] = {}
         # prefix-cache observability (the allocator owns the page-level
         # gauge/histogram; admission-level outcomes live here)
@@ -461,7 +442,7 @@ class PagedDecodeEngine:
         # the paged read of each attention vertex, (group, kv_heads, d_v)
         # -> vertices that read so, target and draft
         self._reads = {
-            draft: Counter(_read_shape(n._vertex_layer(v))
+            draft: Counter(n._vertex_layer(v).paged_read
                            for v in _transformer.attention_vertices(n))
             for draft, n in ((False, net), (True, draft_net))
             if n is not None}
@@ -519,7 +500,7 @@ class PagedDecodeEngine:
                 "rest of that size); counted on the host from the "
                 "routing counts a dispatch brings back", ("kernel", "part"))
         if self.eva_layers:
-            self._init_eva_metrics()
+            self._init_eva()
         if self.kda_layers:
             self._init_kda_metrics()
         self._tick_dispatch_wall = 0.0
@@ -536,46 +517,32 @@ class PagedDecodeEngine:
         decoding, the draft model's K/V in a pools-only SHADOW arena
         indexed by the same page tables (one admission/eviction decision
         covers both models)."""
-        dims, dtype = self._arena_dims(net)
-        self.arena = PagedKVArena(dims, num_pages=num_pages,
-                                  page_size=self.page_size, dtype=dtype,
-                                  registry=self.registry,
-                                  kv_dtype=kv_dtype,
-                                  prefix_cache=prefix_cache)
-        self.draft_arena = None
-        if self.draft_net is not None:
-            ddims, ddtype = self._arena_dims(self.draft_net)
-            self.draft_arena = PagedKVArena(
-                ddims, num_pages=num_pages, page_size=self.page_size,
-                dtype=ddtype, with_allocator=False, kv_dtype=kv_dtype)
+        import jax.numpy as jnp
+
+        def arena(net, **kw):
+            # the pools' dtype is the dense streaming cache's rule
+            # (``_zero_state``), at least f32, so bf16 compute policies
+            # keep exact K/V (int8 pools name it as their fp fallback)
+            return PagedKVArena(
+                self._entries(net), num_pages=num_pages,
+                page_size=self.page_size, kv_dtype=kv_dtype,
+                dtype=jnp.promote_types(net.policy.compute_dtype,
+                                        jnp.float32), **kw)
+        self.arena = arena(net, registry=self.registry,
+                           prefix_cache=prefix_cache)
+        self.draft_arena = (None if self.draft_net is None
+                            else arena(self.draft_net, with_allocator=False))
         jax.block_until_ready([
             (a.k_pools, a.v_pools)
             for a in (self.arena, self.draft_arena) if a is not None])
 
-    # -- construction-time validation ---------------------------------
+    def _entries(self, net) -> dict:
+        """Each stateful vertex of ``net`` (target or draft) in the walker's
+        order, with what its arena holds for it (``paged_entry``)."""
+        return {name: net._vertex_layer(name).paged_entry(self.lanes)
+                for name in _transformer.stateful_vertices(net)}
 
-    def _arena_dims(self, net):
-        """``(layer_dims, dtype)`` of the arena of ``net``, target or
-        draft: each stateful vertex in the walker's order with what it
-        holds (``PagedKVArena``: K/V heads and their size, a latent
-        vertex's row width, a per-lane state vertex's two shapes), and the
-        pools' dtype: the dense
-        streaming cache's rule (``_zero_state``), at least f32, so bf16
-        compute policies keep exact K/V (``kv_dtype="int8"`` replaces the
-        pools with quantized (codes, scales) tuples; the dtype then only
-        names the fp fallback)."""
-        import jax.numpy as jnp
-        dims = {}
-        for name in _transformer.stateful_vertices(net):
-            layer = net._vertex_layer(name)
-            if isinstance(layer, MLAttentionLayer):
-                dims[name] = (layer.pool_width, None)    # one latent pool
-            elif hasattr(layer, "state_shapes"):         # per-lane state
-                dims[name] = layer.state_shapes(self.lanes)
-            else:
-                dims[name] = (layer.kv_heads, layer.head_dim)
-        return dims, jnp.promote_types(net.policy.compute_dtype,
-                                       jnp.float32)
+    # -- construction-time validation ---------------------------------
 
     def _refuse_unsupported(self, net, *, prefix_cache: bool,
                             draft_net, kv_dtype=None) -> None:
@@ -618,7 +585,7 @@ class PagedDecodeEngine:
                 "state — serve this net with prefix_cache=False")
         if draft_net is not None and (
                 self.position_layers
-                or _transformer.position_vertices(draft_net)):
+                or _transformer.stateful_vertices(draft_net, "positions")):
             raise ValueError(
                 "draft_net with vertices that take absolute positions "
                 f"{(self.position_layers or ['the draft net'])[:2]}...: the "
@@ -628,60 +595,13 @@ class PagedDecodeEngine:
                 "prefix is mapped — serve this net without a draft_net")
         if draft_net is not None and (
                 self.state_layers
-                or _transformer.lane_state_vertices(draft_net)):
+                or _transformer.stateful_vertices(draft_net, "lane_ids")):
             raise ValueError(
                 "draft_net with per-lane recurrent state (Mamba-2 or Kimi "
                 "delta attention vertices): a rejected draft "
                 "token's K/V is simply overwritten, but a recurrent state "
                 "that advanced over it cannot be rolled back (no state "
                 "roll-back yet) — serve this net without a draft_net")
-
-    def _eva_geometry(self, net, num_pages: int) -> None:
-        """An EVA net's lane table: the window's pages (``window /
-        page_size``, and one more, which a fused block that crosses an edge
-        draws before the last window's pages go back) and then the summary
-        pages of the longest sequence (``page_size`` chunks a page). One
-        table serves every EVA vertex, so they must agree, and no other
-        kind of attention may share it."""
-        layers = [net._vertex_layer(n) for n in self.eva_layers]
-        others = sorted(set(_transformer.attention_vertices(net))
-                        - set(self.eva_layers))
-        if others:
-            raise ValueError(
-                f"EVA vertices beside other attention vertices {others[:2]}: "
-                "an EVA lane's table holds one window and its summaries, "
-                "where a full attention vertex needs every page of the "
-                "sequence (no tables per kind of layer yet)")
-        geometry = {(lay.window_size, lay.chunk_size) for lay in layers}
-        if len(geometry) > 1:
-            raise ValueError(f"EVA vertices with different windows or chunks "
-                             f"{sorted(geometry)}: one table serves them all")
-        (window, chunk), = geometry
-        ps = self.page_size
-        if ps != chunk:
-            raise ValueError(
-                f"page_size={ps} with EVA chunks of {chunk}: a page holds "
-                "one chunk, which is pooled into its summary row when it "
-                "fills — set page_size to the chunk size")
-        if window % self.prefill_chunk:
-            raise ValueError(
-                f"prefill_chunk={self.prefill_chunk} does not divide the EVA "
-                f"window of {window}: a prefill chunk must not cross a "
-                "window edge (the lane's table holds one window and the "
-                "next window's first page)")
-        if self.block_len > ps:
-            raise ValueError(
-                f"block_len={self.block_len} exceeds the page of {ps}: a "
-                "fused block crosses an EVA window edge into one page of "
-                "the next window at most")
-        self._eva_window, self._eva_chunk = window, chunk
-        self._eva_win_pages = window // ps + 1
-        self._eva_sum_pages = -(-(self.window // chunk) // ps)
-        self.table_pages = self._eva_win_pages + self._eva_sum_pages
-        if self.table_pages > num_pages:
-            raise ValueError(
-                f"an EVA lane may hold {self.table_pages} pages, more than "
-                f"the arena's {num_pages}: one sequence could never run")
 
     @staticmethod
     def _validate_net(net) -> None:
@@ -698,25 +618,19 @@ class PagedDecodeEngine:
         if not owners:
             raise ValueError("no causal attention or per-lane state "
                              "vertices — nothing to cache")
-        in_name = net.conf.network_inputs[0]
-        consumers = [n for n in net.topo_order
-                     if in_name in net.conf.vertex_inputs[n]]
-        if not any(isinstance(getattr(net.conf.vertices[n], "layer", None),
-                              EmbeddingSequenceLayer) for n in consumers):
+        if _transformer.ids_embedding(net) is None:
             raise ValueError(
                 "paged decode requires the integer-id input path — build "
                 "with transformer_lm(..., input_ids=True)")
         for name in net.topo_order:
             v = net.conf.vertices[name]
             layer = getattr(v, "layer", None)
-            if isinstance(layer, _transformer.ATTENTION_LAYERS):
-                if not layer.causal:
-                    raise ValueError(
-                        f"vertex {name!r}: non-causal attention cannot "
-                        "decode incrementally")
-                continue
             if name in owners:
                 continue
+            if getattr(layer, "paged_inputs", None):    # left out: not causal
+                raise ValueError(
+                    f"vertex {name!r}: non-causal attention cannot "
+                    "decode incrementally")
             if layer is not None and hasattr(layer, "_zero_state"):
                 raise ValueError(
                     f"vertex {name!r} ({type(layer).__name__}) carries "
@@ -752,249 +666,44 @@ class PagedDecodeEngine:
                     "pages_per_seq) — decode through the arena would "
                     "diverge from the net's own streaming semantics")
 
-    @staticmethod
-    def _embed_vocab(net) -> int:
-        for name in net.topo_order:
-            layer = getattr(net.conf.vertices[name], "layer", None)
-            if isinstance(layer, EmbeddingSequenceLayer):
-                return int(layer.n_in)
-        return 0
-
     # -- lane lifecycle ------------------------------------------------
 
     def acquire_lane(self, total_tokens: int,
                      prompt=None) -> Optional[int]:
-        """Admission: a free lane + a worst-case page reservation, or
-        None when either is unavailable (the request stays queued;
-        ``refused_by`` then says which: "lanes" or "pages").
-
-        With the prefix cache enabled and ``prompt`` given, the longest
-        resident full-page prefix is mapped (retained) into the lane's
-        table — those pages skip prefill entirely — and the reservation
-        covers only the UNCOVERED pages. Sequences that will outgrow the
-        window still reserve the full ``pages_per_seq``: every shared
-        page they map may later detach copy-on-write, which draws a
-        private replacement. A fully covered prompt re-feeds its LAST
-        token with a dropped write (the K/V is already resident; the
-        re-feed only produces the first-token distribution), so the
-        feed cursor starts at ``len(prompt) - 1``."""
+        """Admission: a free lane + a worst-case page reservation
+        (``LaneTables.admit``: with the prefix cache and ``prompt`` given,
+        the resident full-page prefix is mapped and the feed starts after
+        it), or None when either is unavailable (the request stays queued;
+        ``refused_by`` then says which: "lanes" or "pages")."""
         self.refused_by = None
         if not self._free_lanes:
             self.refused_by = "lanes"
             return None
-        alloc = self.arena.allocator
-        index = self.arena.prefix_index
-        worst = self.arena.pages_for(total_tokens)
-        ps = self.page_size
-        covered_pages: List[int] = []
-        if index is not None and prompt is not None:
-            # lookup + admit under one lock: a page the lookup returned
-            # cannot be reclaimed before admit() pins it
-            with alloc._lock:
-                covered_pages = index.lookup(prompt, self.pages_per_seq)
-                if worst > self.pages_per_seq:
-                    need = self.pages_per_seq      # CoW detaches may draw
-                else:
-                    need = worst - len(covered_pages)
-                if not alloc.admit(need, covered_pages):
-                    self.refused_by = "pages"
-                    return None
-        else:
-            need = (self._eva_pages(total_tokens) if self.eva_layers
-                    else min(self.pages_per_seq, worst))
-            if not alloc.reserve(need):
-                self.refused_by = "pages"
-                return None
+        cov = self.lane_tables.admit(self._free_lanes[0], total_tokens,
+                                     prompt)
+        if cov is None:
+            self.refused_by = "pages"
+            return None
         lane = self._free_lanes.popleft()
         if self.state_layers:
             # the lane's recurrent state starts from zero inside the
             # sequence's first prefill program (rel_pos 0): no dispatch
             self._m_state_resets.inc()
-        cov = len(covered_pages)
-        covered_tokens = cov * ps
-        self._base[lane] = 0
-        self._reserve_left[lane] = need
-        self._held[lane] = list(covered_pages)
-        self._tables[lane, :] = self.arena.sentinel
-        if cov:
-            self._tables[lane, :cov] = covered_pages
-        self._covered[lane] = covered_tokens
-        # feed resumes after the covered prefix; a full cover re-feeds
-        # the last prompt token (write dropped) for its distribution
-        if prompt is not None and covered_tokens >= len(prompt):
-            self._pos[lane] = len(prompt) - 1
-        else:
-            self._pos[lane] = covered_tokens
-        if index is not None and prompt is not None:
-            if covered_tokens == 0:
-                self._m_prefix_hits.inc(result="miss")
-            elif covered_tokens >= len(prompt):
-                self._m_prefix_hits.inc(result="full")
-            else:
-                self._m_prefix_hits.inc(result="partial")
+        if self.arena.prefix_index is not None and prompt is not None:
+            covered = self.lane_tables.covered[lane]
+            self._m_prefix_hits.inc(
+                result="miss" if covered == 0
+                else "full" if covered >= len(prompt) else "partial")
             if cov:
                 self._m_prefix_pages.inc(cov)
         return lane
 
-    def register_prefix(self, lane: int, prompt_ids) -> int:
-        """Publish a freshly prefilled lane's full-page prompt prefix to
-        the index (no-op without one, or if the lane's window already
-        slid — its leading pages no longer hold the prompt's start).
-        Called by the scheduler the moment prefill completes, while the
-        lane still holds its pages."""
-        index = self.arena.prefix_index
-        if index is None or self._base[lane] != 0:
-            return 0
-        full = min(len(prompt_ids) // self.page_size, self.pages_per_seq)
-        if full <= 0:
-            return 0
-        return index.register(prompt_ids, self._held[lane][:full])
-
     def release_lane(self, lane: int) -> None:
-        """Retirement: the lane's page references released (a page
-        returns to the free list at refcount 0 — prefix-cached pages
-        stay resident under the index's reference), unused reservation
-        returned, the lane reusable by the next admission."""
-        self.arena.allocator.free(self._held[lane] + self._sum_held[lane])
-        if self._reserve_left[lane]:
-            self.arena.allocator.unreserve(int(self._reserve_left[lane]))
-        self._held[lane] = []
-        self._sum_held[lane] = []
-        self._reserve_left[lane] = 0
-        self._covered[lane] = 0
-        self._tables[lane, :] = self.arena.sentinel
-        self._pos[lane] = 0
-        self._base[lane] = 0
+        """Retirement: the lane's pages and unused reservation go back
+        (``LaneTables.release``), the lane reusable by the next
+        admission."""
+        self.lane_tables.release(lane)
         self._free_lanes.append(lane)
-
-    def ensure_pages(self, lane: int, n_new: int) -> None:
-        """Pre-dispatch host bookkeeping: make the lane's view hold slots
-        for ``n_new`` tokens at positions ``pos .. pos+n_new-1`` —
-        recycling the oldest page (window eviction, ``base`` advances)
-        when the view is full, lazily drawing reserved pages as the
-        sequence grows."""
-        if n_new > self.window:
-            raise ValueError(f"chunk of {n_new} exceeds the "
-                             f"window ({self.window})")
-        if self.eva_layers:
-            return self._ensure_eva_pages(lane, n_new)
-        pos, base = int(self._pos[lane]), int(self._base[lane])
-        ps = self.page_size
-        held = self._held[lane]
-        alloc = self.arena.allocator
-        fresh: List[int] = []      # newly drawn pages (stale content)
-        while pos + n_new - 1 - base >= self.window:
-            # sliding window at page granularity: the oldest page is
-            # recycled as the LAST LIVE table entry. Only the live
-            # prefix [0, len(held)) shifts — rotating the full row when
-            # the table still has sentinel holes would smear a hole into
-            # the middle and drop the chunk's writes. The recycled
-            # page's stale slots are either overwritten by this chunk
-            # or sit beyond the causal mask until they are.
-            oldest = held.pop(0)
-            if alloc.refcount(oldest) > 1:
-                # COPY-ON-WRITE detach: the oldest page is shared (the
-                # prefix index and/or another lane still reads it) —
-                # recycling it in place would overwrite their K/V.
-                # Sharing is full-page only and tails re-prefill from
-                # the page boundary, so no content copy is ever needed:
-                # release our reference and draw a private tail instead
-                # (admission reserved pages_per_seq for window-sliding
-                # sequences precisely so these draws cannot fail).
-                alloc.free([oldest])
-                replacement = self._draw(lane)
-                fresh.append(replacement)
-                alloc.note_cow()
-            else:
-                replacement = oldest
-                fresh.append(oldest)   # its rows are all pre-window now
-            held.append(replacement)
-            n = len(held)
-            self._tables[lane, :n - 1] = self._tables[lane, 1:n]
-            self._tables[lane, n - 1] = replacement
-            base += ps
-            alloc.note_eviction()
-        last_idx = (pos + n_new - 1 - base) // ps
-        while len(held) <= last_idx:
-            page = self._draw(lane)
-            self._tables[lane, len(held)] = page
-            held.append(page)
-            fresh.append(page)
-        self._base[lane] = base
-        if fresh:
-            self._reset_page_scales(fresh)
-
-    def _eva_pages(self, total_tokens: int) -> int:
-        """Pages an EVA lane of ``total_tokens`` may hold at once: its
-        window's, one more while a block crosses an edge, and the summary
-        pages of every chunk it can complete."""
-        return (min(self._eva_win_pages, self.arena.pages_for(total_tokens))
-                + -(-(total_tokens // self._eva_chunk) // self.page_size))
-
-    def _ensure_eva_pages(self, lane: int, n_new: int) -> None:
-        """:meth:`ensure_pages` for an EVA lane, whose table is its
-        window's pages and then its summary pages. A lane whose next
-        position lies past a window edge gives the finished window's pages
-        back to the allocator (their chunks are summary rows by now) and
-        keeps them reserved for its next window; ``base`` is the window's
-        first position, so view-relative slots index the window pages as
-        they index a plain lane's view. Then the window pages and the
-        summary pages for positions ``pos .. pos+n_new-1`` are drawn."""
-        pos, base = int(self._pos[lane]), int(self._base[lane])
-        ps, held, alloc = self.page_size, self._held[lane], \
-            self.arena.allocator
-        start = pos // self._eva_window * self._eva_window
-        if start > base:
-            with region("sched.eva_retire"):
-                n = min(len(held), (start - base) // ps)
-                done, held[:] = held[:n], held[n:]
-                alloc.recycle(done)
-                self._reserve_left[lane] += len(done)
-                self._tables[lane, :self._eva_win_pages] = self.arena.sentinel
-                self._tables[lane, :len(held)] = held
-                self._m_eva_retired.inc(len(done))
-                base = self._base[lane] = start
-        last = pos + n_new - 1
-        while len(held) <= (last - base) // ps:
-            page = self._draw(lane)
-            self._tables[lane, len(held)] = page
-            held.append(page)
-        sums = self._sum_held[lane]
-        while len(sums) * ps < (last + 1) // self._eva_chunk:
-            page = self._draw(lane)
-            self._tables[lane, self._eva_win_pages + len(sums)] = page
-            sums.append(page)
-
-    def _draw(self, lane: int) -> int:
-        """One page for ``lane``, against its reservation."""
-        self._reserve_left[lane] -= 1
-        return self.arena.allocator.draw()
-
-    def _reset_page_scales(self, pages: List[int]) -> None:
-        """int8 arenas: zero the quantization scales of freshly drawn
-        pages. A recycled page's scale is a max over its PREVIOUS
-        owner's rows — folding new writes into it would quantize them
-        needlessly coarsely, and stale codes × zero scale dequantize to
-        exact zeros (fp pools get the same hygiene from the causal
-        mask). Host-side eager updates on the small ``[num_pages, h]``
-        scale arrays, between dispatches, under the scheduler's tick."""
-        idx = np.asarray(pages, np.int32)
-        for arena in (self.arena, self.draft_arena):
-            if arena is None or arena.kv_dtype != "int8":
-                continue
-            for pools in (arena.k_pools, arena.v_pools):
-                for i, pool in enumerate(pools):
-                    if isinstance(pool, tuple):     # not recurrent state
-                        q, s = pool
-                        pools[i] = (q, s.at[idx].set(0.0))
-
-    def advance(self, lane: int, n: int) -> None:
-        """Account ``n`` tokens written by the dispatch that just ran."""
-        self._pos[lane] += int(n)
-
-    def rel_pos(self, lane: int) -> int:
-        """View-relative position of the lane's next token."""
-        return int(self._pos[lane] - self._base[lane])
 
     # -- the jitted paged step ----------------------------------------
 
@@ -1041,14 +750,8 @@ class PagedDecodeEngine:
              np.asarray(out_rows, np.int32),
              *self._extra_args(names, lanes, rel_pos, fed)),
             kind="paged")
-        if self.eva_layers:
-            self._note_eva("paged", rel_pos, lanes, t_new,
-                           (write_slots >= 0).sum(axis=1))
-        else:
-            self._note_kv_read("paged", rel_pos, t_new)
-        self._note_routing(counts, steps=1, tokens=b * t_new)
-        if self.kda_layers:
-            self._note_kda(lanes, t_new, (write_slots >= 0).sum(axis=1))
+        self._note_step("paged", rel_pos, lanes, t_new,
+                        (write_slots >= 0).sum(axis=1), 1, counts, b * t_new)
         return probs
 
     def _extra_args(self, names: tuple, lanes: Optional[np.ndarray],
@@ -1063,10 +766,26 @@ class PagedDecodeEngine:
         if lanes is None:
             lanes = np.full(len(rel), self.lanes, np.int32)
         lanes = np.asarray(lanes, np.int32)
-        base = np.append(self._base, 0)[np.minimum(lanes, self.lanes)]
         by_name = {"lane_ids": lanes, "fed": fed,
-                   "positions": np.asarray(rel, np.int64) + base}
+                   "positions": self.lane_tables.absolute(lanes, rel)}
         return tuple(np.asarray(by_name[name], np.int32) for name in names)
+
+    def _note_step(self, kind: str, rel: np.ndarray,
+                   lanes: Optional[np.ndarray], t_new: int,
+                   written: np.ndarray, steps: int, counts: list,
+                   tokens: int) -> None:
+        """Account one dispatch (``steps`` steps of ``t_new`` positions a
+        lane, ``written`` of them real): its reads, its routing (``tokens``
+        tokens a step) and its KDA positions and state."""
+        if lanes is None:               # every slot padded, as _extra_args
+            lanes = np.full(len(rel), self.lanes, np.int32)
+        if self.eva_layers:
+            self._note_eva(kind, rel, lanes, t_new, written, steps=steps)
+        else:
+            self._note_kv_read(kind, rel, t_new, steps=steps)
+        self._note_routing(counts, steps=steps, tokens=tokens)
+        if self.kda_layers:
+            self._note_kda(lanes, t_new, written, steps=steps)
 
     def _note_routing(self, counts: list, steps: int, tokens: int) -> None:
         """Account the expert layers' routing counts that a dispatch of
@@ -1196,17 +915,6 @@ class PagedDecodeEngine:
             self._m_tick.observe(wall, component="dispatch")
             self._m_d2h.inc(nbytes, kind=kind)
 
-    def _read_rounds_chunk(self, t_new: int, draft: bool) -> bool:
-        """Whether the paged reads of a dispatch of ``t_new`` new tokens
-        a lane round their chunks: the read's own helper, asked with each
-        attention vertex's query rows a K/V head."""
-        net, arena = ((self.draft_net, self.draft_arena) if draft
-                      else (self.net, self.arena))
-        pool_dtype = None if arena.kv_dtype == "int8" else arena.dtype
-        return all(_paged.read_rounds_chunk(
-            net.policy.compute_dtype, pool_dtype, t_new * g)
-            for g, _, _ in self._reads[draft])
-
     def _note_kv_read(self, kind: str, rel: np.ndarray, t_new: int,
                       steps: int = 1, draft: bool = False) -> None:
         """Account how far the paged read of one dispatch went: its
@@ -1215,8 +923,9 @@ class PagedDecodeEngine:
         block's ``steps``, beside the whole windows the lanes hold; which
         part of it went in trips longer than ``READ_CHUNK_TOKENS``, which
         part was folded as gathered (a vertex with one K/V head), and
-        whether the read rounded the chunks it gathered (``draft``: the
-        draft net's read). Where vertices take different trips the counts
+        whether the read rounded the chunks it gathered (the read's own
+        helper, asked with each vertex's query rows a K/V head; ``draft``:
+        the draft net's read). Where vertices take different trips the counts
         are their mean: the read of one vertex."""
         if self._warming:
             return
@@ -1240,60 +949,44 @@ class PagedDecodeEngine:
         self._m_kv_long_trip.inc(len(rel) * long_trip, kind=kind)
         self._m_kv_unturned.inc(len(rel) * unturned, kind=kind)
         self._m_kv_window.inc(len(rel) * self.window * steps, kind=kind)
-        if self._read_rounds_chunk(t_new, draft):
+        net, arena = ((self.draft_net, self.draft_arena) if draft
+                      else (self.net, self.arena))
+        pool_dtype = None if arena.kv_dtype == "int8" else arena.dtype
+        if all(_paged.read_rounds_chunk(net.policy.compute_dtype, pool_dtype,
+                                        t_new * g) for g, _, _ in reads):
             self._m_kv_rounded.inc(len(rel) * visited, kind=kind)
 
-    def _init_eva_metrics(self) -> None:
+    def _init_eva(self) -> None:
         """What the EVA vertices' reads and pages are counted in: on the
         host, from what the engine dispatches, as
         ``decode_kv_read_tokens_total`` is (:meth:`_note_eva`)."""
-        reg = self.registry
-        self._m_eva_rows = reg.counter(
+        reg, self._m_eva = self.registry, {}
+        self._m_eva["rows"] = reg.counter(
             "eva_read_rows_total",
             "K/V rows the EVA reads of the dispatches visited, summed over "
             "the EVA vertices, by part: the window's raw rows and the "
             "summary rows (live lanes x trips x chunk rows, for every step "
             "of a block; ops.paged_attention.eva_read_trips)", ("part",))
-        self._m_eva_bytes = reg.counter(
+        self._m_eva["bytes"] = reg.counter(
             "eva_read_bytes_total",
             "Bytes of K and V rows the same reads gathered")
-        self._m_eva_written = reg.counter(
+        self._m_eva["written"] = reg.counter(
             "eva_summary_rows_written_total",
             "Summary rows written: chunks the dispatches completed, summed "
             "over the EVA vertices")
-        self._m_eva_attended = reg.counter(
+        self._m_eva["attended"] = reg.counter(
             "eva_attended_rows_total",
             "Rows one EVA vertex's softmax takes, summed over the written "
             "positions: the own window's rows up to the position and the "
             "summary rows of every chunk before that window")
-        self._m_eva_retired = reg.counter(
-            "eva_window_pages_retired_total",
-            "Window pages EVA lanes gave back to the allocator at a window "
-            "edge")
-        self._m_eva_held = reg.counter(
+        self._m_eva["held"] = reg.counter(
             "eva_held_rows_total",
             "Rows of the pages the dispatched EVA lanes held (window and "
             "summary pages x page size), summed over dispatches")
-        self._m_eva_context = reg.counter(
+        self._m_eva["context"] = reg.counter(
             "eva_context_tokens_total",
             "Positions the same lanes had fed after the dispatch, summed "
             "over dispatches")
-        ref = weakref.ref(self)
-
-        def held_per_token():
-            eng = ref()
-            if eng is None:
-                raise LookupError("engine retired")
-            live = [i for i in range(eng.lanes) if eng._pos[i] > 0]
-            tokens = sum(int(eng._pos[i]) for i in live)
-            return (sum(len(eng._held[i]) + len(eng._sum_held[i])
-                        for i in live) * eng.page_size / tokens
-                    if tokens else 0.0)
-        reg.gauge(
-            "eva_held_rows_per_context_token",
-            "Rows of the pages the live EVA lanes hold over the positions "
-            "they have fed (1 and more if windows were never given back)"
-        ).set_function(held_per_token)
 
     def _note_eva(self, kind: str, rel: np.ndarray,
                   lanes: Optional[np.ndarray], t_new: int,
@@ -1305,44 +998,39 @@ class PagedDecodeEngine:
         took, and the pages they hold against the positions fed."""
         if self._warming:
             return
-        if lanes is None:               # every slot padded, as _extra_args
-            lanes = np.full(len(rel), self.lanes, np.int32)
         live = np.asarray(lanes) < self.lanes
-        base = np.append(self._base, 0)[np.minimum(lanes, self.lanes)]
+        t = self.lane_tables
         rel = np.asarray(rel, np.int64)
-        pos, n = rel + base, np.asarray(written, np.int64)
-        ps, chunk, window = self.page_size, self._eva_chunk, self._eva_window
+        pos, n = t.absolute(lanes, rel), np.asarray(written, np.int64)
+        ps, chunk, window = self.page_size, t.eva_chunk, t.eva_window
         layers, lanes_live = len(self.eva_layers), int(live.sum())
         window_rows = summary_rows = 0
         for (_, h, d_v), count in self._reads[False].items():
             rows = len(rel) * h * t_new
-            rows_w = ps * _paged.read_chunk_pages(
-                ps, self._eva_win_pages, d_v, rows)
-            rows_s = ps * _paged.read_chunk_pages(
-                ps, self._eva_sum_pages, d_v, rows)
+            rows_w = ps * _paged.read_chunk_pages(ps, t.win_pages, d_v, rows)
+            rows_s = ps * _paged.read_chunk_pages(ps, t.sum_pages, d_v, rows)
             for i in range(steps):
                 first, end, trips = _paged.eva_read_trips(
                     rel + i, pos + i, t_new, window, chunk, ps,
-                    self._eva_win_pages, self._eva_sum_pages, np, d_v, rows)
+                    t.win_pages, t.sum_pages, np, d_v, rows)
                 window_rows += (count * lanes_live
                                 * max(0, int(end - first)) * rows_w)
                 summary_rows += count * lanes_live * int(trips) * rows_s
-        self._m_eva_rows.inc(window_rows, part="window")
-        self._m_eva_rows.inc(summary_rows, part="summary")
+        self._m_eva["rows"].inc(window_rows, part="window")
+        self._m_eva["rows"].inc(summary_rows, part="summary")
         row_bytes = 2 * int(self.arena.k_pools[0].shape[-1]) \
             * self.arena.k_pools[0].dtype.itemsize
-        self._m_eva_bytes.inc((window_rows + summary_rows) * row_bytes)
+        self._m_eva["bytes"].inc((window_rows + summary_rows) * row_bytes)
         pos, n, idx = pos[live], n[live], np.asarray(lanes)[live]
-        self._m_eva_written.inc(
+        self._m_eva["written"].inc(
             int(((pos + n) // chunk - pos // chunk).sum()) * layers)
         fed = np.concatenate([np.arange(p, p + m) for p, m in zip(pos, n)]
                              + [np.zeros(0, np.int64)])
         start = fed // window * window
-        self._m_eva_attended.inc(int((fed - start + 1 + start // chunk)
+        self._m_eva["attended"].inc(int((fed - start + 1 + start // chunk)
                                      .sum()))
-        self._m_eva_held.inc(ps * sum(len(self._held[i])
-                                      + len(self._sum_held[i]) for i in idx))
-        self._m_eva_context.inc(int((pos + n).sum()))
+        self._m_eva["held"].inc(ps * sum(t.held_pages(i) for i in idx))
+        self._m_eva["context"].inc(int((pos + n).sum()))
 
     def _init_kda_metrics(self) -> None:
         """What the Kimi delta attention vertices compute and carry: on
@@ -1384,8 +1072,7 @@ class PagedDecodeEngine:
         self._m_kda_positions.inc(
             layers * (len(written) * t_new * steps - valid), form=form,
             kind="pad")
-        real = 0 if lanes is None else int(
-            np.sum(np.asarray(lanes) < self.lanes))
+        real = int(np.sum(np.asarray(lanes) < self.lanes))
         self._m_kda_state.inc(2 * real * steps * self._kda_lane_bytes)
 
     # -- fused multi-token block --------------------------------------
@@ -1419,14 +1106,8 @@ class PagedDecodeEngine:
             (last, tables, rel, active, budget, eos, temps, top_k, top_p,
              uniforms, *self._extra_args(names, lanes, rel)), kind="fused")
         # the block's loop ends with its last live lane
-        steps = int(n_emitted.max())
-        if self.eva_layers:
-            self._note_eva("fused", rel, lanes, 1, n_emitted, steps=steps)
-        else:
-            self._note_kv_read("fused", rel, 1, steps=steps)
-        self._note_routing(counts, steps=steps, tokens=b)
-        if self.kda_layers:
-            self._note_kda(lanes, 1, n_emitted, steps=steps)
+        self._note_step("fused", rel, lanes, 1, n_emitted,
+                        int(n_emitted.max()), counts, b)
         return toks, valid, n_emitted
 
     # -- speculative draft / verify -----------------------------------
@@ -1566,25 +1247,21 @@ class PagedDecodeEngine:
             inactive = np.zeros(b, bool)
             zeros_f = np.zeros(b, np.float32)
             zeros_i = np.zeros(b, np.int32)
-            self.run(np.zeros((b, c), np.int32),
-                     np.full((b, c), -1, np.int32),
-                     zeros_i, sentinel_tables, zeros_i)
+
+            def feed(t, run=self.run):      # a chunk of t, every slot padded
+                run(np.zeros((b, t), np.int32), np.full((b, t), -1, np.int32),
+                    zeros_i, sentinel_tables, zeros_i)
+            feed(c)
             if self.arena.prefix_index is not None and c > 1:
                 # prefix-cache hit ticks re-feed at t=1 (the scheduler
                 # collapses an all-≤1-token prefill tick to the decode
                 # shape) — compile it in every mode or the first hit
                 # pays a mid-serve trace
-                self.run(np.zeros((b, 1), np.int32),
-                         np.full((b, 1), -1, np.int32),
-                         zeros_i, sentinel_tables, zeros_i)
+                feed(1)
                 if self.draft_net is not None:
-                    self.run_draft_prefill(np.zeros((b, 1), np.int32),
-                                           np.full((b, 1), -1, np.int32),
-                                           zeros_i, sentinel_tables, zeros_i)
+                    feed(1, self.run_draft_prefill)
             if self.draft_net is not None:
-                self.run_draft_prefill(np.zeros((b, c), np.int32),
-                                       np.full((b, c), -1, np.int32),
-                                       zeros_i, sentinel_tables, zeros_i)
+                feed(c, self.run_draft_prefill)
                 d_toks, d_dists = self.run_draft(
                     zeros_i, sentinel_tables, zeros_i, inactive, zeros_i,
                     zeros_f, zeros_i, np.ones(b, np.float32),
@@ -1602,9 +1279,7 @@ class PagedDecodeEngine:
                     np.ones(b, np.float32),
                     np.zeros((b, self.block_len), np.float32))
             else:
-                self.run(np.zeros((b, 1), np.int32),
-                         np.full((b, 1), -1, np.int32),
-                         zeros_i, sentinel_tables, zeros_i)
+                feed(1)
             if b >= self.lanes:
                 break
             b <<= 1           # same ladder _compact produces
@@ -1799,23 +1474,20 @@ class DecodeScheduler:
                     else self.default_max_new_tokens)
         if n_new < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if (self.engine.state_layers
-                and prompt.size + n_new > self.engine.window):
+        eng = self.engine
+        if (prompt.size + n_new > eng.window
+                and (eng.state_layers or eng.position_layers)):
+            why = ("the attention layers' pages slide while the recurrent "
+                   "layers' state has no window, so the two would see "
+                   "different histories" if eng.state_layers else
+                   "the pages slide and the first tokens leave the "
+                   "attention, but a rotary model is trained, and its "
+                   "reference computed, with every earlier position in "
+                   "view, so what it would generate past the window is no "
+                   "longer the model's")
             raise ValueError(
                 f"request of {prompt.size} + {n_new} tokens exceeds the "
-                f"window of {self.engine.window}: past it the attention "
-                "layers' pages slide while the recurrent layers' state "
-                "has no window, so the two would see different histories "
-                "— size page_size x pages_per_seq to the longest request")
-        if (self.engine.position_layers
-                and prompt.size + n_new > self.engine.window):
-            raise ValueError(
-                f"request of {prompt.size} + {n_new} tokens exceeds the "
-                f"window of {self.engine.window}: past it the pages slide "
-                "and the first tokens leave the attention, but a rotary "
-                "model is trained, and its reference computed, with every "
-                "earlier position in view, so what it would generate past "
-                "the window is no longer the model's — size page_size x "
+                f"window of {eng.window}: past it {why} — size page_size x "
                 "pages_per_seq to the longest request")
         if int(top_k) < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
@@ -1966,8 +1638,8 @@ class DecodeScheduler:
             # prefix-cache hit: the engine parked the feed cursor past
             # the covered tokens (a full cover re-feeds the last prompt
             # token with its write dropped)
-            seq.cursor = int(self.engine._pos[lane])
-            seq.covered = int(self.engine._covered[lane])
+            seq.cursor = int(self.engine.lane_tables.pos[lane])
+            seq.covered = int(self.engine.lane_tables.covered[lane])
             req.prefix_covered_tokens = min(seq.covered, len(req.prompt))
             self._active[lane] = seq
             self._m_admitted.inc()
@@ -1993,7 +1665,7 @@ class DecodeScheduler:
                          np.int32)
         lanes = np.full(b, eng.lanes, np.int32)     # padded slot: no lane
         for i, seq in enumerate(seqs):
-            tables[i] = eng._tables[seq.lane]
+            tables[i] = eng.lane_tables.tables[seq.lane]
             lanes[i] = seq.lane
         return ids, wslots, rel, tables, lanes
 
@@ -2011,7 +1683,7 @@ class DecodeScheduler:
         chunk_len: List[int] = []
         for seq in seqs:
             n = min(c, len(seq.req.prompt) - seq.cursor)
-            eng.ensure_pages(seq.lane, n)
+            eng.lane_tables.ensure(seq.lane, n)
             chunk_len.append(n)
         # prefix-cache fast path: when every admitting lane has at most
         # one token left to feed (the full-hit re-feed), dispatch at the
@@ -2024,7 +1696,7 @@ class DecodeScheduler:
         rows = np.zeros(len(rel), np.int32)
         for i, seq in enumerate(seqs):
             n = chunk_len[i]
-            r = eng.rel_pos(seq.lane)
+            r = eng.lane_tables.rel_pos(seq.lane)
             ids[i, :n] = seq.req.prompt[seq.cursor:seq.cursor + n]
             slots = r + np.arange(n)
             if seq.covered > seq.cursor:
@@ -2065,13 +1737,13 @@ class DecodeScheduler:
         self._m_tokens.inc(sum(chunk_len), phase="prefill")
         for i, seq in enumerate(seqs):
             n = chunk_len[i]
-            eng.advance(seq.lane, n)
+            eng.lane_tables.advance(seq.lane, n)
             seq.cursor += n
             if seq.cursor == len(seq.req.prompt):
                 # publish the prompt's full-page prefix to the cache
                 # BEFORE emitting (emit may retire the lane and release
                 # its pages); a hit re-registers only as an LRU touch
-                eng.register_prefix(seq.lane, seq.req.prompt)
+                eng.lane_tables.register_prefix(seq.lane, seq.req.prompt)
                 # the last prompt position's distribution yields the
                 # FIRST generated token (TTFT lands here)
                 self._emit_token(seq, probs[i])
@@ -2095,10 +1767,10 @@ class DecodeScheduler:
     def _ticked_step(self, seqs: List[_Sequence]) -> None:
         eng = self.engine
         for seq in seqs:
-            eng.ensure_pages(seq.lane, 1)
+            eng.lane_tables.ensure(seq.lane, 1)
         ids, wslots, rel, tables, lanes = self._compact(seqs, 1)
         for i, seq in enumerate(seqs):
-            r = eng.rel_pos(seq.lane)
+            r = eng.lane_tables.rel_pos(seq.lane)
             ids[i, 0] = seq.last_token
             wslots[i, 0] = r
             rel[i] = r
@@ -2119,7 +1791,7 @@ class DecodeScheduler:
         # under sample_token's monotone float64 cast)
         greedy = np.argmax(probs, axis=-1)
         for i, seq in enumerate(seqs):
-            eng.advance(seq.lane, 1)
+            eng.lane_tables.advance(seq.lane, 1)
             self._emit_token(seq, probs[i], greedy_tok=int(greedy[i]))
 
     def _block_arrays(self, seqs: List[_Sequence], n_uniform: int):
@@ -2129,10 +1801,8 @@ class DecodeScheduler:
         config, and ``n_uniform`` host-drawn uniforms per sampled lane
         (from each request's seeded rng — per-request reproducibility is
         independent of batch composition)."""
-        eng = self.engine
-        b = 1
-        while b < len(seqs):
-            b <<= 1
+        _, _, _, tables, lanes = self._compact(seqs, 1)
+        b = len(lanes)
         arr = {
             "last": np.zeros(b, np.int32),
             "rel": np.zeros(b, np.int32),
@@ -2142,16 +1812,12 @@ class DecodeScheduler:
             "top_k": np.zeros(b, np.int32),
             "top_p": np.ones(b, np.float32),
             "u": np.zeros((b, n_uniform), np.float32),
-            "tables": np.full((b, eng.table_pages), eng.arena.sentinel,
-                              np.int32),
-            "lanes": np.full(b, eng.lanes, np.int32),
+            "tables": tables, "lanes": lanes,
         }
         for i, seq in enumerate(seqs):
             req = seq.req
-            arr["tables"][i] = eng._tables[seq.lane]
-            arr["lanes"][i] = seq.lane
             arr["last"][i] = seq.last_token
-            arr["rel"][i] = eng.rel_pos(seq.lane)
+            arr["rel"][i] = self.engine.lane_tables.rel_pos(seq.lane)
             arr["active"][i] = True
             if req.eos_id is not None:
                 arr["eos"][i] = req.eos_id
@@ -2172,7 +1838,7 @@ class DecodeScheduler:
         for seq in seqs:
             remaining = seq.req.max_new_tokens - len(seq.req.tokens)
             budgets.append(min(n, remaining))
-            eng.ensure_pages(seq.lane, budgets[-1])
+            eng.lane_tables.ensure(seq.lane, budgets[-1])
         a = self._block_arrays(seqs, n)
         budget = np.zeros(a["last"].shape[0], np.int32)
         budget[:len(seqs)] = budgets
@@ -2193,7 +1859,7 @@ class DecodeScheduler:
         emitted_total = 0
         for i, seq in enumerate(seqs):
             m = int(n_emitted[i])
-            eng.advance(seq.lane, m)
+            eng.lane_tables.advance(seq.lane, m)
             emitted_total += m
             self._deliver(seq, toks[i, :m])
         self._m_tokens.inc(emitted_total, phase="decode")
@@ -2217,7 +1883,7 @@ class DecodeScheduler:
         for seq in seqs:
             remaining = seq.req.max_new_tokens - len(seq.req.tokens)
             wbudget.append(min(k + 1, remaining))
-            eng.ensure_pages(seq.lane, wbudget[-1])
+            eng.lane_tables.ensure(seq.lane, wbudget[-1])
         a = self._block_arrays(seqs, k + 1)
         n_sampled = int(np.count_nonzero(a["temps"] > 0))
         write_budget = np.zeros(a["last"].shape[0], np.int32)
@@ -2254,7 +1920,7 @@ class DecodeScheduler:
                 # a finished lane was already released by _deliver's
                 # retire — advancing it would stamp a phantom position
                 # onto a freed lane
-                eng.advance(seq.lane, m)
+                eng.lane_tables.advance(seq.lane, m)
             emitted_total += m
             # acceptance accounting over drafts that had a CHANCE of
             # being served (valid context within the write budget):

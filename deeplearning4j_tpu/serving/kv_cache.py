@@ -41,6 +41,9 @@ the decode-arena analog of the dense cache's per-token eviction in
 ``SelfAttentionLayer._apply_streaming``, accounted in
 ``kv_pages_evicted_total``.
 
+The lanes' host page state and the one policy that fills it live here too
+(:class:`LaneTables`): the serving engine keeps only its free lanes.
+
 Thread-safety: the allocator locks itself (submit threads reserve while
 the decode loop draws); the prefix index shares the allocator's RLock so
 lookup→admit and draw→reclaim compose atomically. The pools are owned by
@@ -54,14 +57,16 @@ import hashlib
 import threading
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from ..util import metrics as _metrics
+from ..util.tracing import region
 
-__all__ = ["PageAllocator", "PagedKVArena", "PrefixIndex"]
+__all__ = ["PageAllocator", "PagedEntry", "PagedKVArena", "PrefixIndex",
+           "LaneTables"]
 
 # kv_page_refcount histogram buckets: refcounts are small integers
 # (1 = private, 2+ = shared); powers of two cover fan-out up to a
@@ -489,32 +494,42 @@ class PrefixIndex:
             return n
 
 
+class PagedEntry(NamedTuple):
+    """What the arena holds for one stateful vertex, as its layer's
+    ``paged_entry(lanes)`` declares it. ``rows``: the width of a token's
+    row in each of its paged pools, two (a K and a V pool) or one (a latent
+    vertex's), with ``heads`` the heads a row holds (an int8 pool keeps a
+    scale a (page, head); None: a row of no heads, never quantized).
+    ``lane``: instead, the shapes of its per-lane state, one or two arrays
+    of a row a lane, float32, no pages. ``window``: ``(window, chunk)``
+    where a lane's table is a window of pages and then summary pages (EVA,
+    :class:`LaneTables`)."""
+
+    rows: Tuple[int, ...] = ()
+    heads: Optional[int] = None
+    lane: Tuple[Tuple[int, ...], ...] = ()
+    window: Optional[Tuple[int, int]] = None
+
+
 class PagedKVArena:
     """What the stateful vertices of a decode graph own, in the order the
-    walker visits them, + the shared page allocator: one manager for three
-    kinds of state.
+    walker visits them, + the shared page allocator.
 
-    ``layer_dims`` maps vertex name → what it holds. A causal attention
-    vertex gives ``(kv heads, head_dim)`` and gets a K and a V block pool
-    (``k_pools[i]``, ``v_pools[i]``), paged: ``SENTINEL`` (= num_pages,
-    one past the pool) marks page-table holes: gathers fill zeros there,
-    scatters drop. A LATENT attention vertex (``nn/conf/mla``) gives
-    ``(row width, None)`` and gets ONE block pool ``[num_pages, page_size,
-    row width]`` in ``k_pools[i]``, paged, written and read as the others
-    are; ``v_pools[i]`` is None (an empty pytree: nothing is donated,
-    returned or counted for it). An EVA vertex (``nn/conf/eva``) gets a K
-    and a V pool as a causal one does; its lanes' pages are a window that
-    goes back to the allocator at each edge (:meth:`PageAllocator.recycle`)
-    and summary pages (``serving/decode``). A vertex with per-lane
-    recurrent state gives its two per-lane shapes (``state_shapes(lanes)``)
-    and gets a convolution tail ``[lanes, K-1, C]`` in ``k_pools[i]`` and
-    its state in ``v_pools[i]``: an SSM state ``[lanes, H, P, N]``
-    (``nn/conf/ssm.Mamba2Mixer``) or a delta-rule state ``[lanes, H, D,
-    D]`` (``nn/conf/kda.KimiDeltaAttention``); fixed-size whatever the
-    sequence's length, so it takes no page; a lane's row is zeroed inside the first prefill
-    program of the sequence it is given to, never by the arena. Both
-    kinds ride the engine's donated-pytree dispatch protocol alike and
-    are rebuilt together after a failed dispatch (:meth:`reset_pools`).
+    ``entries`` maps vertex name → its :class:`PagedEntry`. Each of a
+    vertex's ``rows`` is a block pool ``[num_pages, page_size, width]``
+    (``k_pools[i]``, and ``v_pools[i]`` for a second), paged: ``SENTINEL``
+    (= num_pages, one past the pool) marks page-table holes: gathers fill
+    zeros there, scatters drop. A vertex of one pool (latent attention,
+    ``nn/conf/mla``) has None in ``v_pools[i]`` (an empty pytree: nothing is
+    donated, returned or counted for it). A vertex with per-lane state gets
+    its ``lane`` arrays in the same two slots: a convolution tail ``[lanes,
+    K-1, C]`` and an SSM state ``[lanes, H, P, N]`` (``nn/conf/ssm``) or a
+    delta-rule state ``[lanes, H, D, D]`` (``nn/conf/kda``); fixed-size
+    whatever the sequence's length, so it takes no page; a lane's row is
+    zeroed inside the first prefill program of the sequence it is given to,
+    never by the arena. Both kinds ride the engine's donated-pytree
+    dispatch protocol alike and are rebuilt together after a failed
+    dispatch (:meth:`reset_pools`).
 
     A pool is stored ``[num_pages, page_size, h*d]`` (a token's heads in
     one row), for every layer, dtype and arena alike: a decode program's
@@ -534,7 +549,7 @@ class PagedKVArena:
     is never quantized.
     """
 
-    def __init__(self, layer_dims: Dict[str, Tuple[int, int]], *,
+    def __init__(self, entries: Dict[str, PagedEntry], *,
                  num_pages: int, page_size: int, dtype=jnp.float32,
                  registry: Optional[_metrics.MetricsRegistry] = None,
                  with_allocator: bool = True,
@@ -550,7 +565,7 @@ class PagedKVArena:
         :class:`PrefixIndex` to the allocator."""
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
-        if not layer_dims:
+        if not entries:
             raise ValueError("arena needs at least one stateful layer")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"kv_dtype must be None or 'int8', "
@@ -560,10 +575,9 @@ class PagedKVArena:
         self.sentinel = self.num_pages
         self.dtype = dtype
         self.kv_dtype = kv_dtype
-        self.layer_names = list(layer_dims)
-        self._layer_dims = dict(layer_dims)
-        if kv_dtype == "int8" and any(d is None
-                                      for _, d in layer_dims.values()):
+        self.entries = dict(entries)
+        if kv_dtype == "int8" and any(e.rows and e.heads is None
+                                      for e in self.entries.values()):
             raise ValueError(
                 "kv_dtype='int8' with a latent attention vertex: the int8 "
                 "pools keep one scale a (page, head), and a latent row has "
@@ -588,61 +602,354 @@ class PagedKVArena:
         zeroed pools would serve stale prefix hits."""
         self.k_pools = []
         self.v_pools = []
-        for h, d in self._layer_dims.values():
-            if isinstance(h, tuple):       # a per-lane state vertex's shapes
-                self.k_pools.append(jnp.zeros(h, jnp.float32))
-                self.v_pools.append(jnp.zeros(d, jnp.float32))
-                continue
-            if d is None:                  # a latent vertex: one pool
-                self.k_pools.append(jnp.zeros(
-                    (self.num_pages, self.page_size, h), self.dtype))
-                self.v_pools.append(None)
-                continue
-            shape = (self.num_pages, self.page_size, h * d)
-            if self.kv_dtype == "int8":
-                self.k_pools.append((jnp.zeros(shape, jnp.int8),
-                                     jnp.zeros((self.num_pages, h),
-                                               jnp.float32)))
-                self.v_pools.append((jnp.zeros(shape, jnp.int8),
-                                     jnp.zeros((self.num_pages, h),
-                                               jnp.float32)))
-            else:
-                self.k_pools.append(jnp.zeros(shape, self.dtype))
-                self.v_pools.append(jnp.zeros(shape, self.dtype))
+        for e in self.entries.values():
+            pools = ([jnp.zeros(shape, jnp.float32) for shape in e.lane]
+                     or [self._pool(width, e.heads) for width in e.rows])
+            self.k_pools.append(pools[0])
+            self.v_pools.append(pools[1] if len(pools) > 1 else None)
+
+    def _pool(self, width: int, heads: Optional[int]):
+        shape = (self.num_pages, self.page_size, width)
+        if self.kv_dtype == "int8":
+            return (jnp.zeros(shape, jnp.int8),
+                    jnp.zeros((self.num_pages, heads), jnp.float32))
+        return jnp.zeros(shape, self.dtype)
 
     def pages_for(self, n_tokens: int) -> int:
         """Pages needed to hold ``n_tokens`` (ceil)."""
         return -(-int(n_tokens) // self.page_size)
 
+    def _owned(self, lane: bool):
+        """The pools of the vertices with (``lane``) or without per-lane
+        state, None left out."""
+        return [p for e, k, v in zip(self.entries.values(), self.k_pools,
+                                     self.v_pools)
+                if bool(e.lane) == lane for p in (k, v) if p is not None]
+
     def state_nbytes(self) -> int:
         """Bytes of the per-lane recurrent state (no pool among them)."""
-        return sum(int(k.nbytes) + int(v.nbytes)
-                   for (h, _), k, v in zip(self._layer_dims.values(),
-                                           self.k_pools, self.v_pools)
-                   if isinstance(h, tuple))
+        return sum(int(p.nbytes) for p in self._owned(lane=True))
 
     def token_nbytes(self) -> int:
         """Bytes ONE cached token takes over all the paged pools (a
         quantized pool's per-page scales and the per-lane recurrent state,
         which no token adds to, left out)."""
-        total = 0
-        for (h, _), k, v in zip(self._layer_dims.values(), self.k_pools,
-                                self.v_pools):
-            if isinstance(h, tuple):
-                continue
-            for p in (k, v):
-                if p is not None:
-                    codes = p[0] if isinstance(p, tuple) else p
-                    total += codes.shape[-1] * codes.dtype.itemsize
-        return total
+        return sum(codes.shape[-1] * codes.dtype.itemsize
+                   for codes in (p[0] if isinstance(p, tuple) else p
+                                 for p in self._owned(lane=False)))
 
     def nbytes(self) -> int:
-        total = 0
-        for p in self.k_pools + self.v_pools:
-            if p is None:                  # a latent vertex's second slot
-                continue
-            if isinstance(p, tuple):
-                total += sum(int(x.nbytes) for x in p)
+        return sum(int(x.nbytes) for p in self._owned(True)
+                   + self._owned(False)
+                   for x in (p if isinstance(p, tuple) else (p,)))
+
+
+class LaneTables:
+    """The lanes' host page state and the one policy that fills it: each
+    lane's page table (``tables [lanes, width]``, the row a dispatch hands
+    the paged reads), fed positions (``pos``), evicted positions
+    (``base``), held pages (``held``; an EVA lane's summary pages in
+    ``summaries``), unused reservation (``reserve_left``) and prefix-cache
+    cover (``covered``), through admission (:meth:`admit`), paging
+    (:meth:`ensure`) and release (:meth:`release`); ``shadow`` is a draft
+    arena the same tables index.
+
+    A lane's table is its window of ``pages_per_seq`` pages, which slides a
+    page at a time. Where the arena's vertices declare a window
+    (``PagedEntry.window``: EVA) it is that window's pages (and one more,
+    which a fused block that crosses an edge draws before the last window's
+    pages go back), then the summary pages of the longest sequence
+    (``page_size`` chunks a page); one table serves every such vertex, and
+    no other paged vertex may share it."""
+
+    def __init__(self, arena: PagedKVArena, lanes: int, pages_per_seq: int,
+                 *, prefill_chunk: int, block_len: int,
+                 registry: _metrics.MetricsRegistry,
+                 shadow: Optional[PagedKVArena] = None):
+        self.arena, self.shadow = arena, shadow
+        self.lanes, self.page_size = int(lanes), arena.page_size
+        self.pages_per_seq = int(pages_per_seq)
+        self.window = self.page_size * self.pages_per_seq
+        self.width = self.pages_per_seq
+        self.eva = any(e.window for e in arena.entries.values())
+        if self.eva:
+            self._eva_geometry(prefill_chunk, block_len, registry)
+        self.tables = np.full((self.lanes, self.width), arena.sentinel,
+                              np.int32)
+        self.pos = np.zeros(self.lanes, np.int64)     # global fed positions
+        self.base = np.zeros(self.lanes, np.int64)    # evicted positions
+        self.held: List[List[int]] = [[] for _ in range(self.lanes)]
+        self.summaries: List[List[int]] = [[] for _ in range(self.lanes)]
+        self.reserve_left = np.zeros(self.lanes, np.int64)
+        self.covered = np.zeros(self.lanes, np.int64)  # prefix-hit tokens
+
+    def _eva_geometry(self, prefill_chunk: int, block_len: int,
+                      registry: _metrics.MetricsRegistry) -> None:
+        entries = self.arena.entries
+        others = sorted(n for n, e in entries.items()
+                        if e.rows and not e.window)
+        if others:
+            raise ValueError(
+                f"EVA vertices beside other attention vertices {others[:2]}: "
+                "an EVA lane's table holds one window and its summaries, "
+                "where a full attention vertex needs every page of the "
+                "sequence (no tables per kind of layer yet)")
+        geometry = {e.window for e in entries.values() if e.window}
+        if len(geometry) > 1:
+            raise ValueError(f"EVA vertices with different windows or chunks "
+                             f"{sorted(geometry)}: one table serves them all")
+        (window, chunk), = geometry
+        ps = self.page_size
+        if ps != chunk:
+            raise ValueError(
+                f"page_size={ps} with EVA chunks of {chunk}: a page holds "
+                "one chunk, which is pooled into its summary row when it "
+                "fills — set page_size to the chunk size")
+        if window % prefill_chunk:
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} does not divide the EVA "
+                f"window of {window}: a prefill chunk must not cross a "
+                "window edge (the lane's table holds one window and the "
+                "next window's first page)")
+        if block_len > ps:
+            raise ValueError(
+                f"block_len={block_len} exceeds the page of {ps}: a "
+                "fused block crosses an EVA window edge into one page of "
+                "the next window at most")
+        self.eva_window, self.eva_chunk = window, chunk
+        self.win_pages = window // ps + 1
+        self.sum_pages = -(-(self.window // chunk) // ps)
+        self.width = self.win_pages + self.sum_pages
+        if self.width > self.arena.num_pages:
+            raise ValueError(
+                f"an EVA lane may hold {self.width} pages, more than "
+                f"the arena's {self.arena.num_pages}: one sequence could "
+                "never run")
+        self._m_retired = registry.counter(
+            "eva_window_pages_retired_total",
+            "Window pages EVA lanes gave back to the allocator at a window "
+            "edge")
+        ref = weakref.ref(self)
+
+        def held_per_token():
+            t = ref()
+            if t is None:
+                raise LookupError("lane tables retired")
+            live = [i for i in range(t.lanes) if t.pos[i] > 0]
+            tokens = sum(int(t.pos[i]) for i in live)
+            return (sum(t.held_pages(i) for i in live) * t.page_size / tokens
+                    if tokens else 0.0)
+        registry.gauge(
+            "eva_held_rows_per_context_token",
+            "Rows of the pages the live EVA lanes hold over the positions "
+            "they have fed (1 and more if windows were never given back)"
+        ).set_function(held_per_token)
+
+    # -- admission and release ------------------------------------------
+
+    def admit(self, lane: int, total_tokens: int,
+              prompt=None) -> Optional[int]:
+        """The page half of admitting a sequence of ``total_tokens`` into
+        ``lane``: a worst-case page reservation, or None when the arena
+        cannot guarantee it. With the prefix cache and ``prompt`` given,
+        the longest resident full-page prefix is mapped (retained) into the
+        table, those pages skip prefill, and only the UNCOVERED pages are
+        reserved (``pages_per_seq`` for a sequence that will outgrow the
+        window: every shared page may detach copy-on-write, drawing a
+        private one). Returns the number of pages mapped."""
+        alloc, index = self.arena.allocator, self.arena.prefix_index
+        worst = self.arena.pages_for(total_tokens)
+        covered: List[int] = []
+        if index is not None and prompt is not None:
+            # lookup + admit under one lock: a page the lookup returned
+            # cannot be reclaimed before admit() pins it
+            with alloc._lock:
+                covered = index.lookup(prompt, self.pages_per_seq)
+                if worst > self.pages_per_seq:
+                    need = self.pages_per_seq      # CoW detaches may draw
+                else:
+                    need = worst - len(covered)
+                if not alloc.admit(need, covered):
+                    return None
+        else:
+            need = (self._eva_pages(total_tokens) if self.eva
+                    else min(self.pages_per_seq, worst))
+            if not alloc.reserve(need):
+                return None
+        cov = len(covered)
+        self.base[lane] = 0
+        self.reserve_left[lane] = need
+        self.held[lane] = list(covered)
+        self.tables[lane, :] = self.arena.sentinel
+        self.tables[lane, :cov] = covered
+        self.covered[lane] = cov * self.page_size
+        # feed resumes after the covered prefix; a full cover re-feeds
+        # the last prompt token (write dropped) for its distribution
+        full = prompt is not None and self.covered[lane] >= len(prompt)
+        self.pos[lane] = len(prompt) - 1 if full else self.covered[lane]
+        return cov
+
+    def register_prefix(self, lane: int, prompt_ids) -> int:
+        """Publish a freshly prefilled lane's full-page prompt prefix to
+        the index (no-op without one, or if the lane's window already
+        slid — its leading pages no longer hold the prompt's start).
+        Called the moment prefill completes, while the lane still holds
+        its pages."""
+        index = self.arena.prefix_index
+        if index is None or self.base[lane] != 0:
+            return 0
+        full = min(len(prompt_ids) // self.page_size, self.pages_per_seq)
+        if full <= 0:
+            return 0
+        return index.register(prompt_ids, self.held[lane][:full])
+
+    def release(self, lane: int) -> None:
+        """The lane's page references released (a page returns to the
+        free list at refcount 0 — prefix-cached pages stay resident under
+        the index's reference) and its unused reservation returned."""
+        alloc = self.arena.allocator
+        alloc.free(self.held[lane] + self.summaries[lane])
+        if self.reserve_left[lane]:
+            alloc.unreserve(int(self.reserve_left[lane]))
+        self.held[lane], self.summaries[lane] = [], []
+        self.reserve_left[lane] = self.covered[lane] = 0
+        self.pos[lane] = self.base[lane] = 0
+        self.tables[lane, :] = self.arena.sentinel
+
+    # -- paging -----------------------------------------------------------
+
+    def ensure(self, lane: int, n_new: int) -> None:
+        """Pre-dispatch host bookkeeping: make the lane's view hold slots
+        for ``n_new`` tokens at positions ``pos .. pos+n_new-1`` — first
+        sliding the window a page at a time when the view is full (an EVA
+        lane: giving back the finished window at its edge), then lazily
+        drawing reserved pages as the sequence grows (an EVA lane: its
+        summary pages too)."""
+        if n_new > self.window:
+            raise ValueError(f"chunk of {n_new} exceeds the "
+                             f"window ({self.window})")
+        last = int(self.pos[lane]) + n_new - 1
+        fresh = self._retire(lane) if self.eva else self._slide(lane, last)
+        held, ps = self.held[lane], self.page_size
+        while len(held) <= (last - self.base[lane]) // ps:
+            page = self._draw(lane)
+            self.tables[lane, len(held)] = page
+            held.append(page)
+            fresh.append(page)
+        sums = self.summaries[lane]
+        while self.eva and len(sums) * ps < (last + 1) // self.eva_chunk:
+            page = self._draw(lane)
+            self.tables[lane, self.win_pages + len(sums)] = page
+            sums.append(page)
+        if fresh:
+            self._reset_page_scales(fresh)
+
+    def _slide(self, lane: int, last: int) -> List[int]:
+        """Evict the oldest pages until position ``last`` is in the view;
+        returns the pages whose rows are now stale."""
+        base, held = int(self.base[lane]), self.held[lane]
+        alloc = self.arena.allocator
+        fresh: List[int] = []
+        while last - base >= self.window:
+            # sliding window at page granularity: the oldest page is
+            # recycled as the LAST LIVE table entry. Only the live
+            # prefix [0, len(held)) shifts — rotating the full row when
+            # the table still has sentinel holes would smear a hole into
+            # the middle and drop the chunk's writes. The recycled
+            # page's stale slots are either overwritten by this chunk
+            # or sit beyond the causal mask until they are.
+            oldest = held.pop(0)
+            if alloc.refcount(oldest) > 1:
+                # COPY-ON-WRITE detach: the oldest page is shared (the
+                # prefix index and/or another lane still reads it) —
+                # recycling it in place would overwrite their K/V.
+                # Sharing is full-page only and tails re-prefill from
+                # the page boundary, so no content copy is ever needed:
+                # release our reference and draw a private tail instead
+                # (admission reserved pages_per_seq for window-sliding
+                # sequences precisely so these draws cannot fail).
+                alloc.free([oldest])
+                replacement = self._draw(lane)
+                alloc.note_cow()
             else:
-                total += int(p.nbytes)
-        return total
+                replacement = oldest   # its rows are all pre-window now
+            fresh.append(replacement)
+            held.append(replacement)
+            n = len(held)
+            self.tables[lane, :n - 1] = self.tables[lane, 1:n]
+            self.tables[lane, n - 1] = replacement
+            base += self.page_size
+            alloc.note_eviction()
+        self.base[lane] = base
+        return fresh
+
+    def _retire(self, lane: int) -> List[int]:
+        """An EVA lane whose next position lies past a window edge gives
+        the finished window's pages back to the allocator (their chunks
+        are summary rows by now) and keeps them reserved for its next
+        window; ``base`` is the window's first position, so view-relative
+        slots index the window pages as they index a plain lane's view."""
+        held, start = self.held[lane], (int(self.pos[lane]) // self.eva_window
+                                        * self.eva_window)
+        if start > self.base[lane]:
+            with region("sched.eva_retire"):
+                n = min(len(held), (start - int(self.base[lane]))
+                        // self.page_size)
+                done, held[:] = held[:n], held[n:]
+                self.arena.allocator.recycle(done)
+                self.reserve_left[lane] += len(done)
+                self.tables[lane, :self.win_pages] = self.arena.sentinel
+                self.tables[lane, :len(held)] = held
+                self._m_retired.inc(len(done))
+                self.base[lane] = start
+        return []
+
+    def _eva_pages(self, total_tokens: int) -> int:
+        """Pages an EVA lane of ``total_tokens`` may hold at once: its
+        window's, one more while a block crosses an edge, and the summary
+        pages of every chunk it can complete."""
+        return (min(self.win_pages, self.arena.pages_for(total_tokens))
+                + -(-(total_tokens // self.eva_chunk) // self.page_size))
+
+    def _draw(self, lane: int) -> int:
+        """One page for ``lane``, against its reservation."""
+        self.reserve_left[lane] -= 1
+        return self.arena.allocator.draw()
+
+    def _reset_page_scales(self, pages: List[int]) -> None:
+        """int8 arenas: zero the quantization scales of freshly drawn
+        pages. A recycled page's scale is a max over its PREVIOUS
+        owner's rows — folding new writes into it would quantize them
+        needlessly coarsely, and stale codes × zero scale dequantize to
+        exact zeros (fp pools get the same hygiene from the causal
+        mask). Host-side eager updates on the small ``[num_pages, h]``
+        scale arrays, between dispatches, under the scheduler's tick."""
+        idx = np.asarray(pages, np.int32)
+        for arena in (self.arena, self.shadow):
+            if arena is None or arena.kv_dtype != "int8":
+                continue
+            for pools in (arena.k_pools, arena.v_pools):
+                for i, pool in enumerate(pools):
+                    if isinstance(pool, tuple):     # not recurrent state
+                        q, s = pool
+                        pools[i] = (q, s.at[idx].set(0.0))
+
+    # -- what a dispatch reads --------------------------------------------
+
+    def advance(self, lane: int, n: int) -> None:
+        """Account ``n`` tokens written by the dispatch that just ran."""
+        self.pos[lane] += int(n)
+
+    def rel_pos(self, lane: int) -> int:
+        """View-relative position of the lane's next token."""
+        return int(self.pos[lane] - self.base[lane])
+
+    def absolute(self, lanes: np.ndarray, rel) -> np.ndarray:
+        """``rel`` of ``lanes`` plus what their windows evicted (a lane id
+        past the last, a bucket's padded slot, evicted nothing)."""
+        base = np.append(self.base, 0)[np.minimum(lanes, self.lanes)]
+        return np.asarray(rel, np.int64) + base
+
+    def held_pages(self, lane: int) -> int:
+        """Pages the lane holds, window and summary pages alike."""
+        return len(self.held[lane]) + len(self.summaries[lane])

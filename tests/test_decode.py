@@ -547,18 +547,18 @@ class TestPartialTableEviction:
 
     def test_live_table_prefix_stays_contiguous(self):
         eng = self._engine()
-        lane = eng.acquire_lane(16)
-        eng.ensure_pages(lane, 8)               # fills pages 0,1 of 3
-        eng.advance(lane, 8)
-        eng.ensure_pages(lane, 8)               # evicts with a hole left
-        held = eng._held[lane]
-        live = eng._tables[lane][:len(held)]
-        assert (live != eng.arena.sentinel).all(), eng._tables[lane]
+        lane, t = eng.acquire_lane(16), eng.lane_tables
+        t.ensure(lane, 8)                       # fills pages 0,1 of 3
+        t.advance(lane, 8)
+        t.ensure(lane, 8)                       # evicts with a hole left
+        held = t.held[lane]
+        live = t.tables[lane][:len(held)]
+        assert (live != eng.arena.sentinel).all(), t.tables[lane]
         assert sorted(live.tolist()) == sorted(held)
         # every slot the pending chunk writes maps to a REAL page
-        rel = eng.rel_pos(lane)
+        rel = t.rel_pos(lane)
         for s in range(rel, rel + 8):
-            assert eng._tables[lane][s // 4] != eng.arena.sentinel, s
+            assert t.tables[lane][s // 4] != eng.arena.sentinel, s
 
     def test_long_prompt_through_scheduler_stays_deterministic(self):
         eng = self._engine()

@@ -106,23 +106,24 @@ def engine_logprobs(eng, ids, n_prefill, lane=None, before=None):
     prefill chunks, the rest as one-token steps; the log-probabilities the
     engine returns at each position it was asked for (a chunk's last)."""
     lane = eng.acquire_lane(len(ids) + 1) if lane is None else lane
-    got, pos = {}, int(eng._pos[lane])
+    lt = eng.lane_tables
+    got, pos = {}, int(lt.pos[lane])
     while pos < len(ids):
         c = eng.prefill_chunk
         n = min(c, n_prefill - pos) if pos < n_prefill else 1
         t = c if pos < n_prefill else 1
-        eng.ensure_pages(lane, n)
+        lt.ensure(lane, n)
         if before is not None:
             before(eng, lane)
         fed = np.zeros((1, t), np.int32)
         fed[0, :n] = ids[pos:pos + n]
         slots = np.full((1, t), -1, np.int32)
-        slots[0, :n] = eng.rel_pos(lane) + np.arange(n)
-        row = eng.run(fed, slots, np.array([eng.rel_pos(lane)], np.int32),
-                      eng._tables[lane][None], np.array([n - 1], np.int32),
+        slots[0, :n] = lt.rel_pos(lane) + np.arange(n)
+        row = eng.run(fed, slots, np.array([lt.rel_pos(lane)], np.int32),
+                      lt.tables[lane][None], np.array([n - 1], np.int32),
                       np.array([lane], np.int32))
         got[pos + n - 1] = np.log(np.asarray(row[0], np.float64))
-        eng.advance(lane, n)
+        lt.advance(lane, n)
         pos += n
     return got, lane
 
@@ -157,7 +158,7 @@ def test_prefill_then_decode_across_two_edges_is_the_reference(net, ids,
     eng = PagedDecodeEngine(net, **ENGINE)
     got, lane = engine_logprobs(eng, ids, n_prefill=96)
     assert_close(got, want)
-    assert eng._base[lane] == 128
+    assert eng.lane_tables.base[lane] == 128
     retired = eng.registry.get("eva_window_pages_retired_total")
     assert retired.value() == 2 * W // C
 
@@ -178,17 +179,18 @@ def test_a_fused_block_across_an_edge_is_one_block_and_correct(net, ids,
                                 lane=eng.acquire_lane(100))
     first = int(np.argmax(got[59]))
     seq, last = list(prompt) + [first], first
+    lt = eng.lane_tables
     for _ in range(2):                                   # 61 .. 76
-        eng.ensure_pages(lane, 8)
-        rel = np.array([eng.rel_pos(lane)], np.int32)
+        lt.ensure(lane, 8)
+        rel = np.array([lt.rel_pos(lane)], np.int32)
         toks, valid, n = eng.run_fused(
-            np.array([last], np.int32), eng._tables[lane][None], rel,
+            np.array([last], np.int32), lt.tables[lane][None], rel,
             np.ones(1, bool), np.array([8], np.int32),
             np.array([-1], np.int32), np.zeros(1, np.float32),
             np.zeros(1, np.int32), np.ones(1, np.float32),
             np.zeros((1, 8), np.float32), np.array([lane], np.int32))
         assert int(n[0]) == 8 and valid.all()
-        eng.advance(lane, 8)
+        lt.advance(lane, 8)
         seq += [int(t) for t in toks[0]]
         last = int(toks[0, -1])
     assert eng.registry.get("decode_dispatches_total").value(kind="fused") \
@@ -248,7 +250,7 @@ def test_the_summary_rows_are_the_references(net, ids, reference, flat):
     alone)."""
     eng = PagedDecodeEngine(net, **ENGINE)
     _, lane = engine_logprobs(eng, ids, n_prefill=96)
-    rows = np.asarray(eng._sum_held[lane])
+    rows = np.asarray(eng.lane_tables.summaries[lane])
     n, h = len(ids) // C, SMALL["num_attention_heads"]
     d = SMALL["hidden_size"] // h
     for i in range(SMALL["num_hidden_layers"]):
@@ -325,19 +327,19 @@ def test_a_lane_holds_one_window_and_its_summaries(net):
     eng = PagedDecodeEngine(net, **dict(ENGINE, block_len=8))
     alloc = eng.arena.allocator
     total = 250
-    lane = eng.acquire_lane(total)
+    lane, lt = eng.acquire_lane(total), eng.lane_tables
     assert alloc.reserved == W // C + 1 + -(-(total // C) // C)
     pos = 0
     while pos < total - 8:
         n = 32 if pos < 96 else 8
-        eng.ensure_pages(lane, n)
-        held, sums = len(eng._held[lane]), len(eng._sum_held[lane])
+        lt.ensure(lane, n)
+        held, sums = len(lt.held[lane]), len(lt.summaries[lane])
         assert held <= W // C + 1
         assert sums == -(-((pos + n) // C) // C)
         assert alloc.pages_in_use == held + sums
         assert alloc.pages_in_use + alloc.reserved == \
             W // C + 1 + -(-(total // C) // C)
-        eng.advance(lane, n)
+        lt.advance(lane, n)
         pos += n
     eng.release_lane(lane)
     assert alloc.pages_in_use == 0 and alloc.reserved == 0
@@ -352,8 +354,9 @@ def test_no_page_the_lane_gave_back_is_read(net, ids, want):
     ever = set()
 
     def poison(eng, lane):
-        ever.update(eng._held[lane])
-        gone = np.asarray(sorted(ever - set(eng._held[lane])), np.int32)
+        held = eng.lane_tables.held[lane]
+        ever.update(held)
+        gone = np.asarray(sorted(ever - set(held)), np.int32)
         eng.arena.k_pools = [p.at[gone].set(jnp.nan)
                              for p in eng.arena.k_pools]
         eng.arena.v_pools = [p.at[gone].set(jnp.nan)
@@ -547,6 +550,7 @@ def test_the_other_attention_kinds_lower_to_the_parents_text(tag):
 
 
 def test_the_walker_hands_eva_vertices_positions(net):
-    names = _transformer.eva_vertices(net)
+    names = [n for n in _transformer.stateful_vertices(net)
+             if net._vertex_layer(n).paged_entry(1).window]
     assert names == ["l0_attn", "l1_attn"]
-    assert _transformer.position_vertices(net) == names
+    assert _transformer.stateful_vertices(net, "positions") == names

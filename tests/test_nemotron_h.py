@@ -94,24 +94,24 @@ def engine_logprobs(net, ids, n_prefill, chunk=8):
     positions come from the same program with no row named, and the row
     the engine did return is held to it."""
     eng = PagedDecodeEngine(net, **dict(ENGINE, prefill_chunk=chunk))
-    lane = eng.acquire_lane(len(ids) + 1)
+    lane, lt = eng.acquire_lane(len(ids) + 1), eng.lane_tables
     out, pos = [], 0
     while pos < len(ids):
         t = chunk if pos < n_prefill else 1
         n = min(t, n_prefill - pos) if pos < n_prefill else 1
-        eng.ensure_pages(lane, n)
+        lt.ensure(lane, n)
         fed = np.zeros((1, t), np.int32)
         fed[0, :n] = ids[pos:pos + n]
         slots = np.full((1, t), -1, np.int32)
-        slots[0, :n] = eng.rel_pos(lane) + np.arange(n)
-        args = (fed, slots, np.array([eng.rel_pos(lane)], np.int32),
-                eng._tables[lane][None])
+        slots[0, :n] = lt.rel_pos(lane) + np.arange(n)
+        args = (fed, slots, np.array([lt.rel_pos(lane)], np.int32),
+                lt.tables[lane][None])
         probs, _, _ = all_positions(eng, *args, np.array([lane], np.int32))
         row = eng.run(*args, np.array([n - 1], np.int32),
                       np.array([lane], np.int32))
         np.testing.assert_allclose(row[0], probs[0, n - 1], rtol=1e-5)
         out.append(np.asarray(probs[0, :n], np.float32))
-        eng.advance(lane, n)
+        lt.advance(lane, n)
         pos += n
     return np.log(np.concatenate(out)), eng
 
@@ -239,9 +239,9 @@ def test_what_the_engine_refuses(net, family):
 def test_failed_dispatch_rebuilds_the_state_arrays_too(net, ids, monkeypatch):
     eng = PagedDecodeEngine(net, **ENGINE)
     lane = eng.acquire_lane(20)
-    eng.ensure_pages(lane, 8)
+    eng.lane_tables.ensure(lane, 8)
     args = (ids[None, :8], np.arange(8, dtype=np.int32)[None],
-            np.zeros(1, np.int32), eng._tables[lane][None],
+            np.zeros(1, np.int32), eng.lane_tables.tables[lane][None],
             np.full(1, 7, np.int32), np.array([lane], np.int32))
     eng.run(*args)
     owners = _transformer.stateful_vertices(net)
@@ -328,7 +328,8 @@ def test_grouped_kv_heads_paged_is_streaming_is_dense(kv):
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, 1), dense, atol=1e-5)
     # paged: a chunk of 12, then one-token steps, pools kv·d wide
-    arena = PagedKVArena({"a": (kv, 8)}, num_pages=16, page_size=4)
+    arena = PagedKVArena({"a": layer.paged_entry(2)}, num_pages=16,
+                         page_size=4)
     assert arena.k_pools[0].shape == (16, 4, kv * 8)
     tables = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
     kp, vp = arena.k_pools[0], arena.v_pools[0]

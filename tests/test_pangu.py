@@ -37,7 +37,7 @@ from deeplearning4j_tpu.nn.graph_runtime import ComputationGraph
 from deeplearning4j_tpu.ops import grouped_ffn
 from deeplearning4j_tpu.serving.decode import (DecodeScheduler,
                                                PagedDecodeEngine)
-from deeplearning4j_tpu.serving.kv_cache import PagedKVArena
+from deeplearning4j_tpu.serving.kv_cache import PagedEntry, PagedKVArena
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
@@ -166,8 +166,9 @@ def test_positions_handed_to_the_layer_are_absolute(net):
     eng = PagedDecodeEngine(net, **ENGINE)
     assert eng._extra == ("positions",) and eng._extra_paged == eng._extra
     lane = eng.acquire_lane(40)
-    eng._base[lane], eng._pos[lane] = 8, 19          # a window that slid
-    rel = np.array([eng.rel_pos(lane), 3], np.int32)
+    lt = eng.lane_tables
+    lt.base[lane], lt.pos[lane] = 8, 19                   # a window that slid
+    rel = np.array([lt.rel_pos(lane), 3], np.int32)
     (pos,) = eng._extra_args(eng._extra, np.array([lane, eng.lanes]), rel)
     assert pos.tolist() == [19, 3]                   # a padded slot: its rel
 
@@ -372,7 +373,8 @@ def test_a_prefix_hit_under_an_expert_layer_gives_the_misss_logits(net, ids):
 # -- the arena's one-pool entry, and what is refused --------------------------
 
 def test_the_arena_gives_a_latent_vertex_one_pool():
-    arena = PagedKVArena({"lat": (128, None), "kv": (2, 8)}, num_pages=6,
+    arena = PagedKVArena({"lat": PagedEntry((128,)),
+                          "kv": PagedEntry((16, 16), 2)}, num_pages=6,
                          page_size=4)
     assert arena.k_pools[0].shape == (6, 4, 128)
     assert arena.v_pools[0] is None and arena.v_pools[1].shape == (6, 4, 16)
@@ -382,7 +384,7 @@ def test_the_arena_gives_a_latent_vertex_one_pool():
     arena.reset_pools()
     assert arena.v_pools[0] is None and not np.asarray(arena.k_pools[0]).any()
     with pytest.raises(ValueError, match="a latent row has no heads"):
-        PagedKVArena({"lat": (128, None)}, num_pages=6, page_size=4,
+        PagedKVArena({"lat": PagedEntry((128,))}, num_pages=6, page_size=4,
                      kv_dtype="int8")
 
 
@@ -423,5 +425,5 @@ def test_what_the_engine_refuses_of_a_rotary_model(net):
     gauge = sched.engine.registry.get("decode_kv_bytes_per_token")
     assert gauge.snapshot()["series"][0]["value"] == 3 * 128 * 4
     assert isinstance(net._vertex_layer("l0_attn"), MLAttentionLayer)
-    assert _transformer.position_vertices(net) == [
+    assert _transformer.stateful_vertices(net, "positions") == [
         "l0_attn", "l1_attn", "l2_attn"]

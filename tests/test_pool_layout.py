@@ -235,7 +235,8 @@ def test_compiled_for_v5e_no_program_copies_a_pool(one_chip, program):
     params = _described(
         jax.eval_shape(lambda: ComputationGraph(conf).init().params),
         one_chip)
-    dims = {n: (4, 64) for n in _transformer.attention_vertices(net)}
+    dims = {n: net._vertex_layer(n).paged_entry(lanes)
+            for n in _transformer.attention_vertices(net)}
     k, v = _described(jax.eval_shape(lambda: (lambda a: (
         a.k_pools, a.v_pools))(PagedKVArena(
             dims, num_pages=224, page_size=page_size,

@@ -72,15 +72,16 @@ def pack(eng, work, t):
     rows = np.zeros(b, np.int32)
     tables = np.full((b, eng.pages_per_seq), eng.arena.sentinel, np.int32)
     lanes = np.full(b, eng.lanes, np.int32)
+    lt = eng.lane_tables
     for i, (lane, tokens, n_dropped) in enumerate(work):
         n = len(tokens)
-        eng.ensure_pages(lane, n)
+        lt.ensure(lane, n)
         ids[i, :n] = tokens
-        wslots[i, :n] = eng.rel_pos(lane) + np.arange(n)
+        wslots[i, :n] = lt.rel_pos(lane) + np.arange(n)
         wslots[i, :n_dropped] = -1
-        rel[i] = eng.rel_pos(lane)
+        rel[i] = lt.rel_pos(lane)
         rows[i] = n - 1
-        tables[i] = eng._tables[lane]
+        tables[i] = lt.tables[lane]
         lanes[i] = lane
     return ids, wslots, rel, tables, rows, lanes
 
@@ -98,7 +99,7 @@ def dispatch(eng, work, t):
         np.testing.assert_allclose(got[i], want[i, rows[i]], rtol=2e-6,
                                    atol=1e-9)
         assert np.argmax(got[i]) == np.argmax(want[i, rows[i]])
-        eng.advance(lane, len(tokens))
+        eng.lane_tables.advance(lane, len(tokens))
     # every position's K/V, state and routing went where it always went
     for a, b in zip(
             jax.tree_util.tree_leaves((eng.arena.k_pools, eng.arena.v_pools)),
@@ -142,10 +143,11 @@ def refeed_covered(eng, toks):
     prompt = toks(CHUNK)
     first = eng.acquire_lane(CHUNK + 4, prompt=prompt)
     dispatch(eng, [(first, prompt, 0)], CHUNK)
-    assert eng.register_prefix(first, prompt) == CHUNK // eng.page_size
+    lt = eng.lane_tables
+    assert lt.register_prefix(first, prompt) == CHUNK // eng.page_size
     eng.release_lane(first)
     hit = eng.acquire_lane(CHUNK + 4, prompt=prompt)
-    assert eng._covered[hit] == CHUNK and eng._pos[hit] == CHUNK - 1
+    assert lt.covered[hit] == CHUNK and lt.pos[hit] == CHUNK - 1
     other = eng.acquire_lane(64, prompt=toks(9))
     dispatch(eng, [(hit, prompt[-1:], 1), (other, toks(9), 0)], CHUNK)
     hit2 = eng.acquire_lane(CHUNK + 4, prompt=prompt)   # alone: the t = 1 form

@@ -224,11 +224,11 @@ class TestAdmissionAccounting:
         # 16 prompt + 6 new = 22 tokens → worst 3 pages, 2 covered
         lane = eng.acquire_lane(22, prompt=PROMPT16)
         assert lane is not None
-        assert int(eng._covered[lane]) == 16
+        assert int(eng.lane_tables.covered[lane]) == 16
         assert alloc.reserved == 1              # only the uncovered page
         assert alloc.shared_pages == 2          # chain pinned by the lane
         # full cover: the feed cursor re-feeds the LAST prompt token
-        assert int(eng._pos[lane]) == 15
+        assert int(eng.lane_tables.pos[lane]) == 15
         eng.release_lane(lane)
         assert alloc.reserved == 0
         assert alloc.shared_pages == 0
@@ -241,7 +241,7 @@ class TestAdmissionAccounting:
         # the full quota even though 2 pages are mapped from the cache
         lane = eng.acquire_lane(56, prompt=PROMPT16)
         assert lane is not None
-        assert int(eng._covered[lane]) == 16
+        assert int(eng.lane_tables.covered[lane]) == 16
         assert alloc.reserved == eng.pages_per_seq
         eng.release_lane(lane)
         assert alloc.reserved == 0
@@ -476,10 +476,8 @@ class TestInt8Quality:
         """The measured quality gate: int8 paged forward vs the dense
         float oracle over a full window, max |Δ log p| within the bound
         PERF.md records, greedy argmax identical."""
-        dims = {}
-        for name in attention_vertices(oracle_net):
-            layer = oracle_net.conf.vertices[name].layer
-            dims[name] = (layer.n_heads, layer.n_in // layer.n_heads)
+        dims = {name: oracle_net._vertex_layer(name).paged_entry(1)
+                for name in attention_vertices(oracle_net)}
         seq = np.random.default_rng(11).integers(
             0, VOCAB, 32).astype(np.int32)
         oracle = oracle_stream_probs(oracle_net, seq)

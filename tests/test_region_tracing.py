@@ -204,17 +204,17 @@ def test_d2h_bytes_equal_nbytes_of_what_came_back(net):
     eng = _engine(net, block_len=4)
     d2h = eng.registry.get("decode_d2h_bytes_total")
     lane = eng.acquire_lane(12, prompt=None)
-    eng.ensure_pages(lane, 4)
-    tables = eng._tables[lane][None, :]
+    eng.lane_tables.ensure(lane, 4)
+    tables = eng.lane_tables.tables[lane][None, :]
     probs = eng.run(np.array([[1, 2, 3, 4]], np.int32),
                     np.arange(4, dtype=np.int32)[None, :],
                     np.zeros(1, np.int32), tables, np.full(1, 3, np.int32))
     assert probs.shape == (1, VOCAB)
     assert d2h.value(kind="paged") == probs.nbytes
-    eng.advance(lane, 4)
-    eng.ensure_pages(lane, 4)
+    eng.lane_tables.advance(lane, 4)
+    eng.lane_tables.ensure(lane, 4)
     toks, valid, n_emitted = eng.run_fused(
-        np.array([5], np.int32), eng._tables[lane][None, :],
+        np.array([5], np.int32), eng.lane_tables.tables[lane][None, :],
         np.array([4], np.int32), np.ones(1, bool), np.array([4], np.int32),
         np.full(1, -1, np.int32), np.zeros(1, np.float32),
         np.zeros(1, np.int32), np.ones(1, np.float32),
@@ -234,10 +234,10 @@ def test_failed_dispatch_is_not_a_sample(net, monkeypatch):
 
     monkeypatch.setattr(jax, "block_until_ready", boom)
     lane = eng.acquire_lane(8, prompt=None)
-    eng.ensure_pages(lane, 4)
+    eng.lane_tables.ensure(lane, 4)
     with pytest.raises(RuntimeError):
         eng.run(np.zeros((1, 4), np.int32), np.full((1, 4), -1, np.int32),
-                np.zeros(1, np.int32), eng._tables[lane][None, :],
+                np.zeros(1, np.int32), eng.lane_tables.tables[lane][None, :],
                 np.zeros(1, np.int32))
     phases = eng.registry.get("decode_dispatch_phase_seconds")
     assert phases.count(kind="paged", phase="enqueue") == 1

@@ -178,24 +178,24 @@ def engine_logprobs(net, ids, n_prefill, chunk=16):
     tokens in prefill chunks of ``chunk`` (the last one partly padding),
     the rest as one-token steps."""
     eng = PagedDecodeEngine(net, **dict(ENGINE, prefill_chunk=chunk))
-    lane = eng.acquire_lane(len(ids) + 1)
+    lane, lt = eng.acquire_lane(len(ids) + 1), eng.lane_tables
     out, pos = [], 0
     while pos < len(ids):
         t = chunk if pos < n_prefill else 1
         n = min(t, n_prefill - pos) if pos < n_prefill else 1
-        eng.ensure_pages(lane, n)
+        lt.ensure(lane, n)
         fed = np.zeros((1, t), np.int32)
         fed[0, :n] = ids[pos:pos + n]
         slots = np.full((1, t), -1, np.int32)
-        slots[0, :n] = eng.rel_pos(lane) + np.arange(n)
-        args = (fed, slots, np.array([eng.rel_pos(lane)], np.int32),
-                eng._tables[lane][None])
+        slots[0, :n] = lt.rel_pos(lane) + np.arange(n)
+        args = (fed, slots, np.array([lt.rel_pos(lane)], np.int32),
+                lt.tables[lane][None])
         lanes = np.array([lane], np.int32)
         probs = all_positions(eng, *args, lanes)
         row = eng.run(*args, np.array([n - 1], np.int32), lanes)
         np.testing.assert_allclose(row[0], probs[0, n - 1], rtol=1e-5)
         out.append(np.asarray(probs[0, :n], np.float32))
-        eng.advance(lane, n)
+        lt.advance(lane, n)
         pos += n
     return np.log(np.concatenate(out)), eng
 
@@ -367,7 +367,8 @@ def test_head_width_and_gate_dense_streaming_paged_against_the_textbook():
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, atol=1e-5)
     # paged: a chunk of 12, then one-token steps, pools kv x d wide
-    arena = PagedKVArena({"a": (2, 12)}, num_pages=16, page_size=4)
+    arena = PagedKVArena({"a": layer.paged_entry(2)}, num_pages=16,
+                         page_size=4)
     tables = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
     kp, vp = arena.k_pools[0], arena.v_pools[0]
     slots = jnp.tile(jnp.arange(12, dtype=jnp.int32), (2, 1))
@@ -400,7 +401,7 @@ def test_opt_and_the_hybrid_keep_their_attention_parameters():
         n_experts=4, top_k=2, d_latent=8, d_expert=8, d_shared=8)).init()
     assert attention_shapes(hybrid) == {
         "l1_mix": {"Wqkv": (16, 32), "Wo": (16, 16)}}
-    assert _transformer.lane_state_vertices(hybrid) == ["l0_mix"]
+    assert _transformer.stateful_vertices(hybrid, "lane_ids") == ["l0_mix"]
     assert _transformer.kda_vertices(hybrid) == []
     with pytest.raises(ValueError, match="n_in=30 not divisible"):
         SelfAttentionLayer(n_heads=4).set_n_in(InputType.recurrent(30))
@@ -410,8 +411,8 @@ def test_opt_and_the_hybrid_keep_their_attention_parameters():
 
 def test_what_the_engine_refuses_for_a_kda_net(net):
     assert _transformer.kda_vertices(net) == ["l1_mix", "l2_mix", "l3_mix"]
-    assert _transformer.lane_state_vertices(net) == _transformer.kda_vertices(
-        net)
+    assert _transformer.stateful_vertices(net, "lane_ids") == \
+        _transformer.kda_vertices(net)
     with pytest.raises(ValueError, match="Kimi delta attention vertices.*"
                                          "recurrent state after that prefix "
                                          "is kept nowhere"):
